@@ -1,0 +1,738 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — BASELINE.json config #2 end to end on one TPU chip.
+
+    python3 chip_smoke.py [--seed N] [--events-per-window N]
+
+The quickest proof that the system still starts on the chip: one
+process, no children, data made from ``--seed``.  It drives
+``keyBy().window(1 s).aggregate(HLL)`` — 1,000,000 uniform int64 keys,
+HLL precision 12, three 1 s tumbling event-time windows of 2^22 events
+— through ``StreamExecutionEnvironment.execute()`` on every route a
+user can reach, checks each route's output against a plain
+``np.unique`` count of the same events, and ends with one report.
+
+Legs:
+  1  device gate        platform must be "tpu"
+  2  state backend      env.set_state_backend("tpu"): WindowOperator →
+                        TpuKeyedStateBackend → DeviceAggregatingState,
+                        ~4 GiB of registers live in HBM per window
+  3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
+  3b DataStream default aggregate() → DeviceWindowOperator, window 1
+  4  device kernels     the entry() step, the log tier's device finish
+                        against its host finish, an AvgAggregate job on
+                        the scatter tier
+  5  fused chain        map → filter → keyBy as ONE jitted program
+  6  mesh (>= 4 chips)  leg 3a over a 4-device mesh
+
+Exit status 0 and a last stdout line
+``{"ok": true, "device": {"platform": "tpu", ...}}`` only when every
+leg passed on a TPU.  ``--cpu-preflight`` runs every leg at a tiny
+size on whatever device jax has — a rehearsal for the sandbox, printed
+as such; without it, no chip is a failure.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import sys
+import time
+import traceback
+
+try:
+    import jax
+    import numpy as np
+
+    import flink_tpu
+except ImportError as e:  # alone in a directory: nothing to smoke
+    print(f"chip_smoke: cannot import the system beside this file: {e}",
+          file=sys.stderr)
+    sys.exit(2)
+
+import flink_tpu.native as nat  # noqa: E402
+from flink_tpu.ops import link_probe  # noqa: E402
+from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
+from flink_tpu.ops.sketches import HyperLogLogAggregate  # noqa: E402
+from flink_tpu.runtime import tracing  # noqa: E402
+from flink_tpu.runtime.device_stats import get_telemetry  # noqa: E402
+from flink_tpu.streaming import chain_fusion  # noqa: E402
+from flink_tpu.streaming.columnar import (  # noqa: E402
+    ColumnarSource,
+    ColumnarWindowOperator,
+)
+from flink_tpu.streaming.datastream import (  # noqa: E402
+    StreamExecutionEnvironment,
+)
+from flink_tpu.streaming.device_window_operator import (  # noqa: E402
+    DeviceWindowOperator,
+)
+from flink_tpu.streaming.elements import RecordBatch  # noqa: E402
+from flink_tpu.streaming.sources import SinkFunction  # noqa: E402
+from flink_tpu.streaming.window_operator import WindowOperator  # noqa: E402
+from flink_tpu.streaming.windowing import TumblingEventTimeWindows  # noqa: E402
+from flink_tpu.table import StreamTableEnvironment  # noqa: E402
+
+WINDOW_MS = 1000
+#: operators work in batches of this many rows; it also bounds how many
+#: slots a batch that straddles a window end can claim before the old
+#: window's slots are released
+BATCH_ROWS = 8192
+
+FULL = dict(keys=1_000_000, events_per_window=1 << 22, windows=3,
+            precision=12, side_events=1 << 20, fused_events=1 << 18,
+            fused_keys=4096)
+TINY = dict(keys=256, events_per_window=4096, windows=3,
+            precision=12, side_events=4096, fused_events=8192,
+            fused_keys=64)
+
+
+# ---------------------------------------------------------------------
+# user code of the jobs: source, sink, aggregates
+# ---------------------------------------------------------------------
+
+class _BatchElements:
+    """SourceContext view that forwards a RecordBatch as a first-class
+    stream ELEMENT (the DataStream pipeline's convention) where
+    ColumnarSource collects it as one record's VALUE (the SQL tier's
+    convention)."""
+
+    def __init__(self, ctx):
+        self._ctx = ctx
+
+    def collect(self, batch):
+        self._ctx.collect_batch(batch)
+
+    def emit_watermark(self, watermark):
+        self._ctx.emit_watermark(watermark)
+
+
+class EventSource(ColumnarSource):
+    """Config #2's synthetic source: array-born (key, value, ts) rows
+    in BATCH_ROWS-row batches, a watermark after each."""
+
+    def __init__(self, keys, values, ts):
+        super().__init__({"f0": keys, "f1": values, "f2": ts},
+                         rowtime="f2", chunk=BATCH_ROWS)
+
+    def emit_step(self, ctx, max_records):
+        return super().emit_step(_BatchElements(ctx), max_records)
+
+
+class ArraySink(SinkFunction):
+    """Keeps what arrives as column chunks: rows (tuples), batch
+    elements, or RecordBatch-valued records from the SQL tier."""
+
+    def __init__(self):
+        self.rows = []
+        self.chunks = []
+
+    def invoke(self, value, context=None):
+        if isinstance(value, RecordBatch):
+            self.invoke_batch(value)
+        else:
+            self.rows.append(value)
+
+    def invoke_batch(self, batch):
+        self.chunks.append(tuple(batch.cols.values()))
+
+    def columns(self):
+        """One array per output field, arrival order."""
+        chunks = list(self.chunks)
+        if self.rows:
+            chunks.append(tuple(np.asarray(c) for c in zip(*self.rows)))
+        if not chunks:
+            return ()
+        return tuple(np.concatenate([c[i] for c in chunks])
+                     for i in range(len(chunks[0])))
+
+
+class UserHll(HyperLogLogAggregate):
+    """COUNT DISTINCT over field 1 (the user) of a (key, user) row."""
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1]
+
+
+class FieldAvg(AvgAggregate):
+    def extract_value(self, value):
+        return value[1]
+
+
+class FieldSum(SumAggregate):
+    def __init__(self):
+        super().__init__(np.float32)
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1].astype(np.float32)
+
+
+def emit_row(key, window, vals):
+    return [(key, window.start, float(vals[0]))]
+
+
+def capture_operators(env):
+    """Every operator instance the executor builds for this job, so a
+    leg can name the engine that really ran."""
+    made = []
+    for node in env.get_stream_graph().nodes.values():
+        def factory(inner=node.operator_factory):
+            op = inner()
+            made.append(op)
+            return op
+        node.operator_factory = factory
+    return made
+
+
+def one_of(ops, cls):
+    """The one instance of `cls` that processed rows (the pre-flight
+    linter dry-constructs operators too)."""
+    found = [op for op in ops if type(op) is cls
+             and (getattr(op, "engine", None) is not None
+                  or op.columnar_rows or op.boxed_rows)]
+    if len(found) != 1:
+        raise AssertionError(
+            f"expected one working {cls.__name__}, the job built "
+            f"{[type(op).__name__ for op in ops]}")
+    return found[0]
+
+
+def run_window_job(name, arrays, agg, on_state_backend=False):
+    """source → keyBy(field 0) → 1 s tumbling window → aggregate →
+    sink, through env.execute().  `on_state_backend` takes the scalar
+    WindowOperator with its state in the `tpu` backend; otherwise the
+    default aggregate() picks the operator.  Returns (operators, sink)."""
+    env = StreamExecutionEnvironment()
+    windowed = (env.add_source(EventSource(*arrays), name="events")
+                .key_by(0)
+                .window(TumblingEventTimeWindows.of(WINDOW_MS)))
+    if on_state_backend:
+        env.set_state_backend("tpu")
+        windowed.disable_device_operator()
+    sink = ArraySink()
+    windowed.aggregate(agg, window_function=emit_row).add_sink(sink)
+    ops = capture_operators(env)
+    env.execute(name)
+    return ops, sink
+
+
+def by_key_window(keys, starts, values, n_keys):
+    """(key, window) rows → (flat ids sorted, values in that order)."""
+    flat = (np.asarray(starts, np.int64) // WINDOW_MS) * n_keys \
+        + np.asarray(keys, np.int64)
+    order = np.argsort(flat, kind="stable")
+    return flat[order], np.asarray(values)[order]
+
+
+# ---------------------------------------------------------------------
+# data and the plain reference
+# ---------------------------------------------------------------------
+
+def make_events(seed, n_keys, events_per_window, windows):
+    """Uniform int64 keys and users, time-sorted, exactly
+    events_per_window events in each 1 s window."""
+    rng = np.random.default_rng(seed)
+    n = events_per_window * windows
+    keys = rng.integers(0, n_keys, n, dtype=np.int64)
+    users = rng.integers(0, 1 << 40, n, dtype=np.int64)
+    ts = (np.arange(n, dtype=np.int64) * WINDOW_MS) // events_per_window
+    return keys, users, ts
+
+
+def exact_distinct(keys, users, ts):
+    """{window start: (sorted keys, exact distinct users per key)} by
+    np.unique — independent of every hash and sketch under test."""
+    assert int(keys.max()) < (1 << 23) and int(users.max()) < (1 << 40)
+    starts = ts - ts % WINDOW_MS
+    ref = {}
+    for w in np.unique(starts).tolist():
+        m = starts == w
+        pairs = np.unique((keys[m].astype(np.uint64) << np.uint64(40))
+                          | users[m].astype(np.uint64))
+        k, c = np.unique(pairs >> np.uint64(40), return_counts=True)
+        ref[w] = (k.astype(np.int64), c)
+    return ref
+
+
+def check_hll(got_keys, got_starts, got_est, ref, precision):
+    """Every (key, window) emitted exactly once, and the estimates
+    within HyperLogLog's bounds of the exact counts.  Returns
+    (problems, facts)."""
+    m = 1 << precision
+    sigma = 1.04 / np.sqrt(m)
+    problems = []
+    got_keys = np.asarray(got_keys, np.int64)
+    got_starts = np.asarray(got_starts, np.int64)
+    got_est = np.asarray(got_est, np.float64)
+    extra = sorted(set(np.unique(got_starts).tolist()) - set(ref))
+    if extra:
+        problems.append(f"windows nobody asked for: {extra[:5]}")
+    errs, exact = [], []
+    for w, (rk, rc) in ref.items():
+        sel = got_starts == w
+        order = np.argsort(got_keys[sel], kind="stable")
+        gk, ge = got_keys[sel][order], got_est[sel][order]
+        if not np.array_equal(gk, rk):
+            problems.append(
+                f"window {w}: emitted {len(gk)} (key, window) rows "
+                f"({len(np.unique(gk))} distinct keys), reference has "
+                f"{len(rk)}")
+            continue
+        errs.append(ge - rc)
+        exact.append(rc.astype(np.float64))
+    if not errs:
+        return problems or ["nothing to compare"], {}
+    err, exact = np.concatenate(errs), np.concatenate(exact)
+    if not np.isfinite(err).all():
+        problems.append("non-finite estimates")
+    # a hard bound no healthy sketch crosses: three lost registers, or
+    # six standard errors
+    worst = np.abs(err) - np.maximum(3.0, 6.0 * sigma * exact)
+    if (worst > 0).any():
+        i = int(np.argmax(worst))
+        problems.append(f"estimate {exact[i] + err[i]:.3f} for an exact "
+                        f"count of {exact[i]:.0f}")
+    rms = float(np.sqrt(np.mean((err / exact) ** 2)))
+    if rms > sigma:
+        problems.append(f"rms relative error {rms:.5f} > 1.04/sqrt(m) "
+                        f"= {sigma:.5f}")
+    # an estimate misses by more than 0.5 only when two of the key's
+    # values share a register; lost or misrouted updates show up as
+    # more misses than the birthday bound explains
+    counts, freq = np.unique(exact.astype(np.int64), return_counts=True)
+    p_clean = np.array([np.prod(1.0 - np.arange(min(c, m)) / m)
+                        for c in counts.tolist()])
+    expected = float(((1.0 - p_clean) * freq).sum())
+    off = int((np.abs(err) > 0.5).sum())
+    if off > 2.0 * expected + 10:
+        problems.append(f"{off} estimates off by more than 0.5, register "
+                        f"collisions explain {expected:.1f}")
+    return problems, {"key_windows": int(len(err)),
+                      "rms_rel_err": round(rms, 6),
+                      "max_abs_err": round(float(np.abs(err).max()), 3),
+                      "off_by_half": off,
+                      "collisions_expected": round(expected, 1)}
+
+
+# ---------------------------------------------------------------------
+# compile accounting: every jit, traced_jit or not
+# ---------------------------------------------------------------------
+
+class CompileMeter:
+    def __init__(self):
+        self.count = collections.Counter()
+        self.secs = collections.defaultdict(float)
+        jax.monitoring.register_event_listener(
+            lambda name, **kw: self.count.update([name]))
+        jax.monitoring.register_event_duration_secs_listener(self._dur)
+
+    def _dur(self, name, secs, **kw):
+        self.count[name] += 1
+        self.secs[name] += secs
+
+    def report(self):
+        c = "/jax/core/compile/backend_compile_duration"
+        return {"backend_compiles": self.count[c],
+                "backend_compile_s": round(self.secs[c], 2),
+                "cache_hits": self.count["/jax/compilation_cache/cache_hits"],
+                "cache_misses":
+                    self.count["/jax/compilation_cache/cache_misses"]}
+
+
+# ---------------------------------------------------------------------
+# legs
+# ---------------------------------------------------------------------
+
+def leg_device_gate(cfg):
+    dev = jax.devices()[0]
+    info = {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+    print(f"[1] device: {info}; jax {jax.__version__}; native library "
+          f"{nat.library_path()}; compile cache "
+          f"{jax.config.jax_compilation_cache_dir}", flush=True)
+    if not nat.available():
+        raise AssertionError(f"native runtime: {nat.load_error()}")
+    if dev.platform != "tpu" and not cfg["preflight"]:
+        raise AssertionError(
+            f"no TPU: jax found {dev.platform} ({dev.device_kind}); "
+            f"--cpu-preflight rehearses on it at a tiny size")
+    return [], info
+
+
+def leg_state_backend(cfg, events, ref):
+    keys = events[0]
+    ops, sink = run_window_job("chip-smoke-state-backend", events,
+                               UserHll(cfg["precision"]),
+                               on_state_backend=True)
+    wop = one_of(ops, WindowOperator)
+    state = wop.window_state
+    problems, facts = check_hll(*sink.columns(), ref, cfg["precision"])
+    if wop.columnar_rows != len(keys) or wop.boxed_fallbacks:
+        problems.append(
+            f"columnar_rows {wop.columnar_rows} of {len(keys)} events, "
+            f"{wop.boxed_fallbacks} boxed fallbacks "
+            f"({wop.columnar_fallback_reason})")
+    regs = state.device_state["regs"]
+    return problems, {
+        "route": "WindowOperator.process_batch -> "
+                 f"{type(wop.keyed_backend).__name__}.add_batch -> "
+                 f"{type(state).__name__}",
+        "events": len(keys), "columnar_rows": wop.columnar_rows,
+        "boxed_fallbacks": wop.boxed_fallbacks,
+        "slots": state.capacity,
+        "register_bytes": int(regs.size) * regs.dtype.itemsize,
+        "evictions": state.evictions, **facts}
+
+
+def leg_sql(cfg, events, ref, mesh=None):
+    keys, users, ts = events
+    env = StreamExecutionEnvironment()
+    if mesh is not None:
+        env.set_mesh(mesh)
+    t_env = StreamTableEnvironment.create(env)
+    t_env.register_table("ev", t_env.from_columns(
+        {"k": keys, "u": users, "ts": ts}, rowtime="ts"))
+    out = t_env.sql_query(
+        "SELECT k, TUMBLE_START(ts) AS ws, APPROX_COUNT_DISTINCT(u) AS d "
+        "FROM ev GROUP BY TUMBLE(ts, INTERVAL '1' SECOND), k")
+    sink = ArraySink()
+    out.to_append_stream(batched=True).add_sink(sink)
+    ops = capture_operators(env)
+    env.execute("chip-smoke-sql")
+    cop = one_of(ops, ColumnarWindowOperator)
+    cols = sink.columns()
+    problems, facts = check_hll(*cols, ref, cfg["precision"])
+    facts = {"route": "SQL TUMBLE + APPROX_COUNT_DISTINCT -> "
+                      "ColumnarWindowOperator",
+             "events": len(keys), **engine_facts(cop.engine), **facts}
+    # engine and columns go on to the mesh leg
+    return problems, facts, (cop.engine, cols)
+
+
+def leg_datastream_default(cfg, events, ref):
+    """The default aggregate(): DeviceWindowOperator, whose door is a
+    per-record loop — so the first window only."""
+    n = cfg["events_per_window"]
+    ops, sink = run_window_job("chip-smoke-datastream",
+                               [a[:n] for a in events],
+                               UserHll(cfg["precision"]))
+    dop = one_of(ops, DeviceWindowOperator)
+    problems, facts = check_hll(*sink.columns(), {0: ref[0]},
+                                cfg["precision"])
+    return problems, {"route": "aggregate() -> DeviceWindowOperator",
+                      "events": n, **engine_facts(dop.engine), **facts}
+
+
+def engine_facts(engine):
+    mode = getattr(engine, "mode", None)
+    if mode is None and getattr(engine, "shards", None):
+        mode = getattr(engine.shards[0], "mode", None)
+    h2d = link_probe.measure()["h2d_gbps"]
+    return {"engine": type(engine).__name__,
+            "finish_tier": getattr(mode, "finish_tier", None),
+            "h2d_gbps": round(h2d, 3) if np.isfinite(h2d) else str(h2d)}
+
+
+def leg_entry_step(cfg):
+    """The jitted step of __graft_entry__.entry() against numpy."""
+    import __graft_entry__ as graft
+    step, args = graft.entry()
+    out = jax.jit(step)(*args)
+    regs = np.asarray(out["regs"])
+    state, slots, _values, vh_hi, vh_lo, _mask = args
+    agg = HyperLogLogAggregate(precision=12)
+    rank, reg = agg.compress_value_hash(np.asarray(vh_hi), np.asarray(vh_lo))
+    want = np.zeros(state["regs"].shape, np.uint8)
+    np.maximum.at(want, (np.asarray(slots), reg.astype(np.int64)), rank)
+    problems = []
+    if regs.shape != want.shape or not np.array_equal(regs, want):
+        problems.append("entry() step differs from the numpy scatter-max")
+    return problems, {"route": "jit(__graft_entry__.entry step)",
+                      "registers_set": int((regs > 0).sum())}
+
+
+def leg_device_finish(cfg, events):
+    """One window fired by the log tier with the finish on the device
+    equals the same window with the finish on the host."""
+    from flink_tpu.streaming.log_windows import LogStructuredTumblingWindows
+    from flink_tpu.streaming.vectorized import hash_keys_np
+    n = cfg["events_per_window"]
+    keys, users, ts = (a[:n] for a in events)
+    vh = hash_keys_np(users)
+    fired = {}
+    for tier in ("host", "device"):
+        eng = LogStructuredTumblingWindows(
+            HyperLogLogAggregate(cfg["precision"]), WINDOW_MS,
+            finish_tier=tier)
+        eng.emit_arrays = True
+        eng.process_batch(keys, ts, None, value_hashes=vh)
+        eng.advance_watermark(WINDOW_MS - 1)
+        k, r, _s, _e = eng.fired[0]
+        order = np.argsort(k, kind="stable")
+        fired[tier] = (np.asarray(k)[order], np.asarray(r)[order])
+    problems = []
+    (hk, hr), (dk, dr) = fired["host"], fired["device"]
+    if not np.array_equal(hk, dk):
+        problems.append("device finish fired other keys than the host")
+    elif not np.allclose(dr, hr, rtol=1e-3, atol=1e-3):
+        problems.append(f"device finish differs from host finish by up "
+                        f"to {float(np.abs(dr - hr).max()):.4f}")
+    return problems, {
+        "route": "LogStructuredTumblingWindows(finish_tier='device') "
+                 "vs 'host'",
+        "events": n, "keys_fired": int(len(hk))}
+
+
+def leg_avg_job(cfg, seed):
+    """An aggregate with no cell decomposition rides the scatter tier
+    (VectorizedTumblingWindows): its contiguous fire and clear
+    kernels and the full-arena fire."""
+    n_w = cfg["side_events"]
+    rng = np.random.default_rng(seed + 1)
+    keys = rng.integers(0, cfg["keys"], 2 * n_w, dtype=np.int64)
+    vals = rng.integers(0, 1000, 2 * n_w, dtype=np.int64)
+    ts = (np.arange(2 * n_w, dtype=np.int64) * WINDOW_MS) // n_w
+    before = dispatches()
+    ops, sink = run_window_job("chip-smoke-avg", (keys, vals, ts),
+                               FieldAvg())
+    dop = one_of(ops, DeviceWindowOperator)
+    uniq, inv = np.unique((ts // WINDOW_MS) * cfg["keys"] + keys,
+                          return_inverse=True)
+    want = np.bincount(inv, vals.astype(np.float64)) / np.bincount(inv)
+    got_flat, got = by_key_window(*sink.columns(), cfg["keys"])
+    problems = []
+    if not np.array_equal(got_flat, uniq):
+        problems.append(f"emitted {len(got_flat)} (key, window) rows, "
+                        f"reference has {len(uniq)}")
+    elif not np.allclose(got, want, rtol=1e-5):
+        problems.append("averages differ from numpy")
+    ran = {name: n - before.get(name, 0)
+           for name, n in dispatches().items()
+           if name.startswith("window.") and n > before.get(name, 0)}
+    if type(dop.engine).__name__ != "VectorizedTumblingWindows":
+        problems.append(f"engine {type(dop.engine).__name__}")
+    if not cfg["preflight"]:
+        # at a tiny size no window owns a whole fire tile
+        for kernel in ("window.result_contig", "window.clear_contig",
+                       "window.result_all"):
+            if kernel not in ran:
+                problems.append(f"{kernel} never dispatched")
+    return problems, {"route": "aggregate(AvgAggregate) -> "
+                               "DeviceWindowOperator",
+                      "engine": type(dop.engine).__name__,
+                      "events": 2 * n_w, "key_windows": int(len(uniq)),
+                      "kernels": ran}
+
+
+def dispatches():
+    return {name: s["recompiles"] + s["cache_hits"]
+            for name, s in tracing.jit_stats().items()}
+
+
+def leg_fused_chain(cfg, seed, float64):
+    """source → map → filter → keyBy → window on the tpu backend.  At
+    parallelism 2 the keyBy exchange folds into the fused program:
+    its splitmix64 and its value-sort partition run on the device."""
+    n, n_keys = cfg["fused_events"], cfg["fused_keys"]
+    rng = np.random.default_rng(seed + 2)
+    keys = rng.integers(0, n_keys, n, dtype=np.int64)
+    vals = rng.integers(0, 100, n, dtype=np.int64)
+    if float64:
+        vals = vals.astype(np.float64)
+    ts = (np.arange(n, dtype=np.int64) * 2 * WINDOW_MS) // n
+    stats = chain_fusion.FUSION_STATS
+    stats.reset()
+    env = StreamExecutionEnvironment()
+    env.set_state_backend("tpu").set_parallelism(2)
+    sink = ArraySink()
+    (env.add_source(EventSource(keys, vals, ts), name="events")
+        .map(lambda t: (t[0], t[1] * 3 + 1))
+        .filter(lambda t: t[1] % 5 != 0)
+        .key_by(0)
+        .window(TumblingEventTimeWindows.of(WINDOW_MS))
+        .disable_device_operator()
+        .aggregate(FieldSum(), window_function=emit_row)
+        .add_sink(sink))
+    ops = capture_operators(env)
+    env.execute("chip-smoke-fused")
+    programs = [op._fused_chain for op in ops
+                if op.__dict__.get("_fused_chain") is not None]
+    modes = sorted({mode for p in programs for mode, _, _ in p._fns})
+    meshed = any(use_mesh for p in programs for _, _, use_mesh in p._fns)
+    mapped = vals * 3 + 1
+    keep = mapped % 5 != 0
+    flat = (ts[keep] // WINDOW_MS) * n_keys + keys[keep]
+    uniq, inv = np.unique(flat, return_inverse=True)
+    want = np.bincount(inv, mapped[keep].astype(np.float64))
+    got_flat, got = by_key_window(*sink.columns(), n_keys)
+    problems = []
+    if not np.array_equal(got_flat, uniq) or not np.array_equal(got, want):
+        problems.append("window sums differ from numpy")
+    facts = {"route": "source -> map -> filter -> keyBy(fused) -> "
+                      "WindowOperator on the tpu backend",
+             "events": n, "programs": stats.programs, "modes": modes,
+             "sharded_over_devices": meshed,
+             "fused_batches": stats.fused_batches,
+             "demotions": stats.demotions,
+             "last_demotion": stats.last_demotion}
+    if not float64 and (stats.fused_batches == 0 or stats.demotions
+                        or "route" not in modes):
+        problems.append(f"int64 chain: {stats.fused_batches} fused "
+                        f"batches in modes {modes}, {stats.demotions} "
+                        f"demotions ({stats.last_demotion})")
+    return problems, facts
+
+
+def leg_mesh(cfg, events, ref, one_chip_cols):
+    """Leg 3a over four devices: the mesh log tier."""
+    from jax.sharding import Mesh
+    telemetry = get_telemetry()
+    telemetry.enable()
+    try:
+        mesh = Mesh(np.array(jax.devices()[:4]), ("kg",))
+        problems, facts, (engine, cols) = leg_sql(cfg, events, ref,
+                                                  mesh=mesh)
+        rounds = sum(p["rounds"] for p in
+                     telemetry.payload()["exchange_phases"].values())
+    finally:
+        telemetry.disable()
+
+    def by_key(c):
+        k, ws, d = (np.asarray(a) for a in c)
+        order = np.lexsort((k, ws))
+        return k[order], ws[order], d[order]
+    a, b = by_key(cols), by_key(one_chip_cols)
+    if not (np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
+            and np.allclose(a[2], b[2], rtol=1e-3, atol=1e-3)):
+        problems.append("mesh results differ from the one-chip run")
+    if rounds == 0:
+        problems.append("no exchange round ran")
+    # where the exchange leaves its output: one shard per device
+    S, m = engine.n_shards, engine.step_batch // engine.n_shards
+    recv, _counts = engine._packed_exchange(
+        np.zeros((S, m, engine.n_lanes), np.uint32),
+        np.full((S, m), S, np.int32))
+    devices = {s.device for s in recv.addressable_shards}
+    if len(devices) != 4:
+        problems.append(f"exchange output on {len(devices)} devices")
+    facts.update(route=facts["route"] + " over a 4-device mesh",
+                 exchange_rounds=rounds,
+                 exchange_devices=sorted(str(d) for d in devices))
+    return problems, facts
+
+
+# ---------------------------------------------------------------------
+# driver
+# ---------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--events-per-window", type=int, default=None,
+                    help="cut the events per window (never keys or "
+                         "precision) when wall time forces one")
+    ap.add_argument("--cpu-preflight", action="store_true",
+                    help="rehearse every leg at a tiny size on any device")
+    args = ap.parse_args(argv)
+    cfg = dict(TINY if args.cpu_preflight else FULL,
+               preflight=args.cpu_preflight)
+    if args.cpu_preflight:
+        print("chip_smoke: --cpu-preflight — a rehearsal at a tiny size, "
+              "NOT a chip run", flush=True)
+    if args.events_per_window is not None:
+        cfg["events_per_window"] = args.events_per_window
+    cut = ("none" if cfg["events_per_window"] == FULL["events_per_window"]
+           else f"events per window {cfg['events_per_window']} instead "
+                f"of {FULL['events_per_window']}")
+    print(f"chip_smoke: seed {args.seed}, {cfg['keys']} keys, HLL "
+          f"precision {cfg['precision']}, {cfg['windows']} windows of "
+          f"{cfg['events_per_window']} events; cut: {cut}", flush=True)
+
+    meter = CompileMeter()
+    t_start = time.perf_counter()
+    report = {}
+    failed = []
+
+    def run(name, fn, *a, required=True):
+        """One leg: a failure is recorded, printed with its traceback,
+        and the remaining legs still run — one report covers them.
+        Returns what the leg hands on to a later leg, if anything."""
+        t0 = time.perf_counter()
+        extra = None
+        try:
+            problems, facts, *rest = fn(*a)
+            extra = rest[0] if rest else None
+        except Exception as e:  # noqa: BLE001 — leg boundary
+            traceback.print_exc()
+            problems, facts = [f"{type(e).__name__}: {e}"], {}
+        facts["wall_s"] = round(time.perf_counter() - t0, 2)
+        facts["passed"] = not problems
+        if problems:
+            facts["problems"] = problems
+            if required:
+                failed.append(name)
+        report[name] = facts
+        print(f"[{name}] {'ok' if not problems else 'FAILED'} "
+              f"{json.dumps(facts, default=str)}", flush=True)
+        return extra
+
+    run("1 device gate", leg_device_gate, cfg)
+    if failed:
+        return 1
+    device = {k: report["1 device gate"][k]
+              for k in ("platform", "kind", "count")}
+
+    events = make_events(args.seed, cfg["keys"], cfg["events_per_window"],
+                         cfg["windows"])
+    ref = exact_distinct(*events)
+    run("2 state backend", leg_state_backend, cfg, events, ref)
+    sql = run("3a sql", leg_sql, cfg, events, ref)
+    run("3b datastream", leg_datastream_default, cfg, events, ref)
+    run("4a entry step", leg_entry_step, cfg)
+    run("4b device finish", leg_device_finish, cfg, events)
+    run("4c avg job", leg_avg_job, cfg, args.seed)
+    run("5 fused chain int64", leg_fused_chain, cfg, args.seed, False)
+    run("5 fused chain float64 (not required)", leg_fused_chain, cfg,
+        args.seed, True, required=False)
+    if device["count"] >= 4 and sql is not None:
+        run("6 mesh", leg_mesh, cfg, events, ref, sql[1])
+    else:
+        print(f"[6 mesh] not run: {device['count']} device(s) visible",
+              flush=True)
+
+    jit = tracing.jit_stats()
+    hbm = get_telemetry().hbm_snapshot()
+    summary = {
+        "device": device, "cut": cut, "preflight": cfg["preflight"],
+        "wall_s": round(time.perf_counter() - t_start, 1),
+        "traced_jit": {
+            "compiles": sum(s["recompiles"] for s in jit.values()),
+            "compile_s": round(sum(s["compile_time_ms"]
+                                   for s in jit.values()) / 1e3, 2)},
+        "all_jits": meter.report(),
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        "native_library": nat.library_path(),
+        "hbm": hbm,
+    }
+    if hbm["source"] != "memory_stats" and not cfg["preflight"]:
+        failed.append("hbm_snapshot fell back to framework accounting")
+    print("chip_smoke report: " + json.dumps(summary, default=str),
+          flush=True)
+    if failed:
+        print(f"chip_smoke: FAILED — {failed}", flush=True)
+        return 1
+    result = {"ok": True, "device": device}
+    if cfg["preflight"]:
+        result["preflight"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -21,11 +21,6 @@ import os
 import tempfile
 import time
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 from flink_tpu.core.records import RecordSchema, RecordSerializer
 from flink_tpu.core.state import ValueStateDescriptor
 from flink_tpu.streaming.datastream import StreamExecutionEnvironment

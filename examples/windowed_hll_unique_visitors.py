@@ -1,6 +1,8 @@
-"""Per-page unique visitors with the HLL device kernel — the TPU fast
-path (BASELINE.md config #2 shape): keyBy(page) → tumbling window →
-APPROX COUNT DISTINCT(user) on the vectorized device engine."""
+"""Per-page unique visitors (BASELINE.md config #2 shape): keyBy(page)
+→ tumbling window → APPROX COUNT DISTINCT(user).  Integer keys and
+HLL put the default `aggregate()` on the log-structured tier
+(streaming/log_windows.py): C++ host ingest and sort, and a fire
+finish that runs on the host or the device as the link probe says."""
 
 import os as _os
 import sys as _sys

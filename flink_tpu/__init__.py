@@ -51,6 +51,24 @@ quickstarts incl. SocketWindowWordCount).
 
 __version__ = "0.1.0"
 
+import os as _os
+
+import jax as _jax
+
+#: where this checkout keeps XLA's persistent compile cache when the
+#: environment names no other place (git-ignored; the path is part of
+#: what makes a cache entry findable, so it never moves)
+COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+
+# Every process that can compile imports this package first.  A
+# directory given from outside (JAX_COMPILATION_CACHE_DIR, which jax
+# reads itself) is left alone; setting the option initialises no
+# backend.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
 from flink_tpu.core.config import ConfigOption, ConfigOptions, Configuration
 from flink_tpu.core.functions import (
     AggregateFunction,

@@ -1,249 +1,304 @@
 """ctypes loader for the native host runtime (native/host_runtime.cpp).
 
-Compiles on first use with g++ (cached by source mtime) — the image
-has no pybind11, so the boundary is plain C ABI + numpy ctypeslib
-(environment constraint; ref for the role: the reference's one native
-component is rocksdbjni, SURVEY.md §2.2).  Everything degrades
-gracefully: `available()` is False when no compiler is present and
-callers fall back to the numpy paths.
+The image has no pybind11, so the boundary is plain C ABI + numpy
+ctypeslib (environment constraint; ref for the role: the reference's
+one native component is rocksdbjni, SURVEY.md §2.2).
+
+The library is never committed: it is built with g++ on first use into
+``native/build/`` under a file name that carries a digest of the
+source bytes, the compile command and this CPU's feature flags.  The
+command says ``-march=native``, so a library built on another CPU (or
+from other source) has another name and is never loaded here — a
+copied checkout rebuilds instead of dying on an illegal instruction.
+When the build or the load fails, the compiler's stderr is logged once
+at error level and `available()` is False.
 """
 
 from __future__ import annotations
 
 import ctypes
 import functools
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import time
-from typing import Optional
+from typing import Optional, Sequence
 
 import numpy as np
 
 from flink_tpu.runtime import tracing as _tracing
+
+log = logging.getLogger(__name__)
 
 _perf_ns = time.perf_counter_ns
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "host_runtime.cpp")
-_LIB = os.path.join(_REPO_ROOT, "native", "libhost_runtime.so")
+_BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
+_COMPILE = ("g++", "-O3", "-march=native", "-shared", "-fPIC")
 
 _lib: Optional[ctypes.CDLL] = None
+_lib_path: Optional[str] = None
 _load_error: Optional[str] = None
 
 
-def _build() -> None:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-           "-o", _LIB, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+def cpu_feature_flags() -> str:
+    """This CPU's feature flags as the kernel reports them — what
+    ``-march=native`` compiles for."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def artifact_name(src: bytes, compile_cmd: Sequence[str],
+                  cpu_flags: str) -> str:
+    """File name of the library built from exactly these inputs."""
+    h = hashlib.sha256()
+    for part in (src, "\0".join(compile_cmd).encode(), cpu_flags.encode()):
+        h.update(len(part).to_bytes(8, "little"))
+        h.update(part)
+    return f"libhost_runtime-{h.hexdigest()[:20]}.so"
+
+
+def _build(out_path: str) -> None:
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    # compile beside the target, then rename: a concurrent process
+    # sees the finished library or none
+    tmp = f"{out_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run([*_COMPILE, "-o", tmp, _SRC], check=True,
+                       capture_output=True, text=True)
+        os.replace(tmp, out_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _ensure_loaded() -> Optional[ctypes.CDLL]:
-    global _lib, _load_error
+    global _lib, _lib_path, _load_error
     if _lib is not None or _load_error is not None:
         return _lib
     try:
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            _build()
-        lib = ctypes.CDLL(_LIB)
-        u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
-        i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
-        i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
-        f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
-        f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
-        u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
-        c = ctypes
-        lib.ft_splitmix64.argtypes = [u64p, u64p, c.c_int64]
-        lib.ft_key_groups.argtypes = [u64p, i32p, c.c_int64, c.c_int32,
-                                      c.c_int32]
-        lib.ft_heap_tumbling_baseline.argtypes = [
-            u64p, u64p, f64p, c.c_int64, c.c_int, c.c_int, c.c_int64]
-        lib.ft_heap_tumbling_baseline.restype = c.c_double
-        lib.ft_heap_tumbling_meanmax_baseline.argtypes = [
-            u64p, f64p, c.c_int64, c.c_int64]
-        lib.ft_heap_tumbling_meanmax_baseline.restype = c.c_double
-        lib.ft_heap_tumbling_lse_baseline.argtypes = [
-            u64p, f32p, c.c_int64, c.c_int64]
-        lib.ft_heap_tumbling_lse_baseline.restype = c.c_double
-        lib.ft_argsort_u64.argtypes = [u64p, c.c_int64, i64p]
-        lib.ft_cep_new.argtypes = [c.c_int64, c.c_int64, c.c_int64]
-        lib.ft_cep_new.restype = c.c_void_p
-        lib.ft_cep_free.argtypes = [c.c_void_p]
-        lib.ft_cep_advance.argtypes = [
-            c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64,
-            i64p, i64p, c.c_int64]
-        lib.ft_cep_advance.restype = c.c_int64
-        lib.ft_cep_advance_seq.argtypes = [
-            c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64,
-            i64p, i64p, c.c_int64]
-        lib.ft_cep_advance_seq.restype = c.c_int64
-        lib.ft_cep_size.argtypes = [c.c_void_p]
-        lib.ft_cep_size.restype = c.c_int64
-        lib.ft_cep_min_ref.argtypes = [c.c_void_p]
-        lib.ft_cep_min_ref.restype = c.c_int64
-        lib.ft_cep_expire.argtypes = [c.c_void_p, c.c_int64]
-        lib.ft_cep_export.argtypes = [c.c_void_p, u64p, u32p, i64p]
-        lib.ft_cep_export.restype = c.c_int64
-        lib.ft_cep_import.argtypes = [c.c_void_p, u64p, u32p, i64p,
-                                      c.c_int64]
-        lib.ft_cep_strict_baseline.argtypes = [
-            u64p, f64p, i64p, c.c_int64, c.c_double, c.c_double,
-            c.c_double, c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
-        lib.ft_cep_strict_baseline.restype = c.c_double
-        lib.ft_cep_eval_masks.argtypes = [
-            i64p, i64p, c.c_int64, f64p, f64p, c.c_int64, c.c_int64,
-            u32p]
-        lib.ft_cep_advance_prog.argtypes = [
-            c.c_void_p, u64p, i64p, c.c_int64, c.c_int64,
-            i64p, i64p, f64p, f64p, c.c_int64, c.c_int64,
-            i64p, i64p, c.c_int64]
-        lib.ft_cep_advance_prog.restype = c.c_int64
-        lib.ft_cepr_new.argtypes = [c.c_int64, c.c_int64, c.c_int64,
-                                    c.c_int64]
-        lib.ft_cepr_new.restype = c.c_void_p
-        lib.ft_cepr_free.argtypes = [c.c_void_p]
-        lib.ft_cepr_advance.argtypes = [
-            c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64]
-        lib.ft_cepr_advance.restype = c.c_int64
-        lib.ft_cepr_advance_prog.argtypes = [
-            c.c_void_p, u64p, i64p, c.c_int64, c.c_int64,
-            i64p, i64p, f64p, f64p, c.c_int64]
-        lib.ft_cepr_advance_prog.restype = c.c_int64
-        lib.ft_cepr_matches.argtypes = [c.c_void_p, i64p, i64p]
-        lib.ft_cepr_matches.restype = c.c_int64
-        lib.ft_cepr_size.argtypes = [c.c_void_p]
-        lib.ft_cepr_size.restype = c.c_int64
-        lib.ft_cepr_expire.argtypes = [c.c_void_p, c.c_int64]
-        lib.ft_cepr_min_ref.argtypes = [c.c_void_p]
-        lib.ft_cepr_min_ref.restype = c.c_int64
-        lib.ft_cepr_export_size.argtypes = [c.c_void_p]
-        lib.ft_cepr_export_size.restype = c.c_int64
-        lib.ft_cepr_export.argtypes = [c.c_void_p, i64p]
-        lib.ft_cepr_export.restype = c.c_int64
-        lib.ft_cepr_import.argtypes = [c.c_void_p, i64p, c.c_int64]
-        lib.ft_cep_followed_baseline.argtypes = [
-            u64p, f64p, i64p, c.c_int64, c.c_double, c.c_double,
-            c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
-        lib.ft_cep_followed_baseline.restype = c.c_double
-        lib.ft_fold_prep.argtypes = [u64p, c.c_int64, i64p, i64p, i64p,
-                                     u64p]
-        lib.ft_fold_prep.restype = c.c_int64
-        lib.ft_group_cols.argtypes = [
-            u64p, c.c_int64, c.c_int64, i64p,
-            c.POINTER(c.c_void_p), c.POINTER(c.c_void_p), c.c_void_p,
-            i64p, i64p, u64p]
-        lib.ft_group_cols.restype = c.c_int64
-        lib.ft_heap_windowed_hll_baseline.argtypes = [
-            u64p, u64p, i64p, c.c_int64, c.c_int64, c.c_int, c.c_int64]
-        lib.ft_heap_windowed_hll_baseline.restype = c.c_double
-        lib.ft_heap_sliding_hist_baseline.argtypes = [
-            u64p, f32p, i64p, c.c_int64, c.c_int64, c.c_int64, c.c_int,
-            c.c_int64]
-        lib.ft_heap_sliding_hist_baseline.restype = c.c_double
-        lib.ft_heap_session_cm_baseline.argtypes = [
-            u64p, u64p, i64p, c.c_int64, c.c_int64, c.c_int, c.c_int,
-            c.c_int64]
-        lib.ft_heap_session_cm_baseline.restype = c.c_double
-        lib.ft_index_new.argtypes = [c.c_int64]
-        lib.ft_index_new.restype = c.c_void_p
-        lib.ft_index_free.argtypes = [c.c_void_p]
-        lib.ft_index_size.argtypes = [c.c_void_p]
-        lib.ft_index_size.restype = c.c_int64
-        lib.ft_index_probe.argtypes = [c.c_void_p, u64p, c.c_int64, i64p,
-                                       i64p]
-        lib.ft_index_probe.restype = c.c_int64
-        lib.ft_index_assign.argtypes = [c.c_void_p, i64p, c.c_int64, i64p]
-        lib.ft_index_set.argtypes = [c.c_void_p, u64p, i64p, c.c_int64]
-        lib.ft_index_export.argtypes = [c.c_void_p, u64p, i64p]
-        lib.ft_index_export.restype = c.c_int64
-        u16p = np.ctypeslib.ndpointer(np.uint16, flags="C_CONTIGUOUS")
-        u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
-        lib.ft_hll_make_cells.argtypes = [
-            u64p, c.c_int64, c.c_int, u16p, u8p]
-        lib.ft_hll_log_compact.argtypes = [
-            u64p, u16p, u8p, c.c_int64, c.c_int,
-            u64p, u16p, u8p, i32p, c.POINTER(c.c_int64)]
-        lib.ft_hll_log_compact.restype = c.c_int64
-        lib.ft_hll_log_fire.argtypes = [
-            u64p, u16p, u8p, c.c_int64, c.c_int, u64p, f64p]
-        lib.ft_hll_log_fire.restype = c.c_int64
-        lib.ft_sum_log_fire.argtypes = [u64p, f64p, c.c_int64, u64p, f64p]
-        lib.ft_sum_log_fire.restype = c.c_int64
-        lib.ft_sumtab_new.argtypes = [c.c_int64]
-        lib.ft_sumtab_new.restype = c.c_void_p
-        lib.ft_sumtab_free.argtypes = [c.c_void_p]
-        lib.ft_sumtab_size.argtypes = [c.c_void_p]
-        lib.ft_sumtab_size.restype = c.c_int64
-        lib.ft_sumtab_ingest.argtypes = [c.c_void_p, u64p, f64p,
-                                         c.c_int64, c.c_int64]
-        lib.ft_sumtab_ingest.restype = c.c_int64
-        lib.ft_sumtab_export.argtypes = [c.c_void_p, u64p, f64p]
-        lib.ft_sumtab_export.restype = c.c_int64
-        lib.ft_qsketch_log_fire.argtypes = [
-            u64p, u16p, c.c_int64, c.c_int, f64p, c.c_int,
-            c.c_double, c.c_int64, c.c_double, u64p, f64p]
-        lib.ft_qsketch_log_fire.restype = c.c_int64
-        lib.ft_qsketch_log_fire2.argtypes = [
-            u64p, u16p, u32p, c.c_int64, c.c_int, f64p, c.c_int,
-            c.c_double, c.c_int64, c.c_double, u64p, f64p]
-        lib.ft_qsketch_log_fire2.restype = c.c_int64
-        lib.ft_qsketch_log_compact.argtypes = [
-            u64p, u16p, u32p, c.c_int64, c.c_int, u64p, u16p, u32p]
-        lib.ft_qsketch_log_compact.restype = c.c_int64
-        lib.ft_session_log_fire.argtypes = [
-            u64p, i64p, f32p, u64p, c.c_int64, c.c_int64, c.c_int64,
-            c.c_int, c.c_int,
-            u64p, i64p, i64p, f64p,
-            u64p, i64p, f32p, u64p, c.POINTER(c.c_int64)]
-        lib.ft_session_log_fire.restype = c.c_int64
-        lib.ft_session_log_fire2.argtypes = [
-            u64p, i64p, f32p, u64p, c.c_int64,
-            u64p, i64p, f32p, u64p, c.c_int64,
-            c.c_int64, c.c_int64, c.c_int, c.c_int,
-            u64p, i64p, i64p, f64p,
-            u64p, i64p, f32p, u64p, c.POINTER(c.c_int64)]
-        lib.ft_session_log_fire2.restype = c.c_int64
-        lib.ft_intern_new.argtypes = [c.c_int64]
-        lib.ft_intern_new.restype = c.c_void_p
-        lib.ft_intern_free.argtypes = [c.c_void_p]
-        lib.ft_intern_size.argtypes = [c.c_void_p]
-        lib.ft_intern_size.restype = c.c_int64
-        lib.ft_intern_rows.argtypes = [c.c_void_p, u8p, c.c_int64,
-                                       c.c_int64, c.c_int64, u64p, i64p]
-        lib.ft_intern_rows.restype = c.c_int64
-        lib.ft_heap_tumbling_baseline_str.argtypes = [
-            u8p, c.c_int64, c.c_int64, c.c_int64, f64p, c.c_int64]
-        lib.ft_heap_tumbling_baseline_str.restype = c.c_double
-        lib.ft_wordsums_new.argtypes = []
-        lib.ft_wordsums_new.restype = c.c_void_p
-        lib.ft_wordsums_free.argtypes = [c.c_void_p]
-        lib.ft_wordsums_count.argtypes = [c.c_void_p]
-        lib.ft_wordsums_count.restype = c.c_int64
-        lib.ft_wordsums_fire.argtypes = [c.c_void_p, i64p, f64p]
-        lib.ft_wordsums_fire.restype = c.c_int64
-        lib.ft_wordsums_load.argtypes = [c.c_void_p, i64p, f64p, c.c_int64]
-        lib.ft_intern_sum.argtypes = [c.c_void_p, c.c_void_p, u8p,
-                                      c.c_int64, c.c_int64, f64p,
-                                      c.c_int64, c.c_int64, i64p]
-        lib.ft_intern_sum.restype = c.c_int64
-        lib.ft_interval_join_baseline.argtypes = [
-            u64p, i64p, c.c_int64, u64p, i64p, c.c_int64,
-            c.c_int64, c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
-        lib.ft_interval_join_baseline.restype = c.c_double
-        lib.ft_ivjoin_new.argtypes = [c.c_int64, c.c_int64, c.c_int64]
-        lib.ft_ivjoin_new.restype = c.c_void_p
-        lib.ft_ivjoin_free.argtypes = [c.c_void_p]
-        lib.ft_ivjoin_push.argtypes = [c.c_void_p, c.c_int64, u64p, i64p,
-                                       c.c_int64]
-        lib.ft_ivjoin_push.restype = c.c_int64
-        lib.ft_ivjoin_pairs.argtypes = [c.c_void_p, i64p, i64p]
-        lib.ft_ivjoin_pairs.restype = c.c_int64
-        lib.ft_ivjoin_prune.argtypes = [c.c_void_p, c.c_int64]
-        _lib = lib
-    except Exception as e:  # noqa: BLE001 — no compiler / bad env
-        _load_error = str(e)
+        with open(_SRC, "rb") as f:
+            src = f.read()
+        path = os.path.join(
+            _BUILD_DIR, artifact_name(src, _COMPILE, cpu_feature_flags()))
+        if not os.path.exists(path):
+            _build(path)
+        lib = ctypes.CDLL(path)
+    except (OSError, subprocess.CalledProcessError) as e:
+        _load_error = (getattr(e, "stderr", None) or str(e)).strip()
+        log.error("native host runtime unavailable (%s): %s",
+                  type(e).__name__, _load_error)
+        return None
+    _declare(lib)
+    _lib, _lib_path = lib, path
     return _lib
+
+
+def _declare(lib: ctypes.CDLL) -> None:
+    """argtypes/restype for every entry point."""
+    u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
+    i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+    f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
+    f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
+    u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
+    c = ctypes
+    lib.ft_splitmix64.argtypes = [u64p, u64p, c.c_int64]
+    lib.ft_key_groups.argtypes = [u64p, i32p, c.c_int64, c.c_int32,
+                                  c.c_int32]
+    lib.ft_heap_tumbling_baseline.argtypes = [
+        u64p, u64p, f64p, c.c_int64, c.c_int, c.c_int, c.c_int64]
+    lib.ft_heap_tumbling_baseline.restype = c.c_double
+    lib.ft_heap_tumbling_meanmax_baseline.argtypes = [
+        u64p, f64p, c.c_int64, c.c_int64]
+    lib.ft_heap_tumbling_meanmax_baseline.restype = c.c_double
+    lib.ft_heap_tumbling_lse_baseline.argtypes = [
+        u64p, f32p, c.c_int64, c.c_int64]
+    lib.ft_heap_tumbling_lse_baseline.restype = c.c_double
+    lib.ft_argsort_u64.argtypes = [u64p, c.c_int64, i64p]
+    lib.ft_cep_new.argtypes = [c.c_int64, c.c_int64, c.c_int64]
+    lib.ft_cep_new.restype = c.c_void_p
+    lib.ft_cep_free.argtypes = [c.c_void_p]
+    lib.ft_cep_advance.argtypes = [
+        c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64,
+        i64p, i64p, c.c_int64]
+    lib.ft_cep_advance.restype = c.c_int64
+    lib.ft_cep_advance_seq.argtypes = [
+        c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64,
+        i64p, i64p, c.c_int64]
+    lib.ft_cep_advance_seq.restype = c.c_int64
+    lib.ft_cep_size.argtypes = [c.c_void_p]
+    lib.ft_cep_size.restype = c.c_int64
+    lib.ft_cep_min_ref.argtypes = [c.c_void_p]
+    lib.ft_cep_min_ref.restype = c.c_int64
+    lib.ft_cep_expire.argtypes = [c.c_void_p, c.c_int64]
+    lib.ft_cep_export.argtypes = [c.c_void_p, u64p, u32p, i64p]
+    lib.ft_cep_export.restype = c.c_int64
+    lib.ft_cep_import.argtypes = [c.c_void_p, u64p, u32p, i64p,
+                                  c.c_int64]
+    lib.ft_cep_strict_baseline.argtypes = [
+        u64p, f64p, i64p, c.c_int64, c.c_double, c.c_double,
+        c.c_double, c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
+    lib.ft_cep_strict_baseline.restype = c.c_double
+    lib.ft_cep_eval_masks.argtypes = [
+        i64p, i64p, c.c_int64, f64p, f64p, c.c_int64, c.c_int64,
+        u32p]
+    lib.ft_cep_advance_prog.argtypes = [
+        c.c_void_p, u64p, i64p, c.c_int64, c.c_int64,
+        i64p, i64p, f64p, f64p, c.c_int64, c.c_int64,
+        i64p, i64p, c.c_int64]
+    lib.ft_cep_advance_prog.restype = c.c_int64
+    lib.ft_cepr_new.argtypes = [c.c_int64, c.c_int64, c.c_int64,
+                                c.c_int64]
+    lib.ft_cepr_new.restype = c.c_void_p
+    lib.ft_cepr_free.argtypes = [c.c_void_p]
+    lib.ft_cepr_advance.argtypes = [
+        c.c_void_p, u64p, u32p, i64p, c.c_int64, c.c_int64]
+    lib.ft_cepr_advance.restype = c.c_int64
+    lib.ft_cepr_advance_prog.argtypes = [
+        c.c_void_p, u64p, i64p, c.c_int64, c.c_int64,
+        i64p, i64p, f64p, f64p, c.c_int64]
+    lib.ft_cepr_advance_prog.restype = c.c_int64
+    lib.ft_cepr_matches.argtypes = [c.c_void_p, i64p, i64p]
+    lib.ft_cepr_matches.restype = c.c_int64
+    lib.ft_cepr_size.argtypes = [c.c_void_p]
+    lib.ft_cepr_size.restype = c.c_int64
+    lib.ft_cepr_expire.argtypes = [c.c_void_p, c.c_int64]
+    lib.ft_cepr_min_ref.argtypes = [c.c_void_p]
+    lib.ft_cepr_min_ref.restype = c.c_int64
+    lib.ft_cepr_export_size.argtypes = [c.c_void_p]
+    lib.ft_cepr_export_size.restype = c.c_int64
+    lib.ft_cepr_export.argtypes = [c.c_void_p, i64p]
+    lib.ft_cepr_export.restype = c.c_int64
+    lib.ft_cepr_import.argtypes = [c.c_void_p, i64p, c.c_int64]
+    lib.ft_cep_followed_baseline.argtypes = [
+        u64p, f64p, i64p, c.c_int64, c.c_double, c.c_double,
+        c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
+    lib.ft_cep_followed_baseline.restype = c.c_double
+    lib.ft_fold_prep.argtypes = [u64p, c.c_int64, i64p, i64p, i64p,
+                                 u64p]
+    lib.ft_fold_prep.restype = c.c_int64
+    lib.ft_group_cols.argtypes = [
+        u64p, c.c_int64, c.c_int64, i64p,
+        c.POINTER(c.c_void_p), c.POINTER(c.c_void_p), c.c_void_p,
+        i64p, i64p, u64p]
+    lib.ft_group_cols.restype = c.c_int64
+    lib.ft_heap_windowed_hll_baseline.argtypes = [
+        u64p, u64p, i64p, c.c_int64, c.c_int64, c.c_int, c.c_int64]
+    lib.ft_heap_windowed_hll_baseline.restype = c.c_double
+    lib.ft_heap_sliding_hist_baseline.argtypes = [
+        u64p, f32p, i64p, c.c_int64, c.c_int64, c.c_int64, c.c_int,
+        c.c_int64]
+    lib.ft_heap_sliding_hist_baseline.restype = c.c_double
+    lib.ft_heap_session_cm_baseline.argtypes = [
+        u64p, u64p, i64p, c.c_int64, c.c_int64, c.c_int, c.c_int,
+        c.c_int64]
+    lib.ft_heap_session_cm_baseline.restype = c.c_double
+    lib.ft_index_new.argtypes = [c.c_int64]
+    lib.ft_index_new.restype = c.c_void_p
+    lib.ft_index_free.argtypes = [c.c_void_p]
+    lib.ft_index_size.argtypes = [c.c_void_p]
+    lib.ft_index_size.restype = c.c_int64
+    lib.ft_index_probe.argtypes = [c.c_void_p, u64p, c.c_int64, i64p,
+                                   i64p]
+    lib.ft_index_probe.restype = c.c_int64
+    lib.ft_index_assign.argtypes = [c.c_void_p, i64p, c.c_int64, i64p]
+    lib.ft_index_set.argtypes = [c.c_void_p, u64p, i64p, c.c_int64]
+    lib.ft_index_export.argtypes = [c.c_void_p, u64p, i64p]
+    lib.ft_index_export.restype = c.c_int64
+    u16p = np.ctypeslib.ndpointer(np.uint16, flags="C_CONTIGUOUS")
+    u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+    lib.ft_hll_make_cells.argtypes = [
+        u64p, c.c_int64, c.c_int, u16p, u8p]
+    lib.ft_hll_log_compact.argtypes = [
+        u64p, u16p, u8p, c.c_int64, c.c_int,
+        u64p, u16p, u8p, i32p, c.POINTER(c.c_int64)]
+    lib.ft_hll_log_compact.restype = c.c_int64
+    lib.ft_hll_log_fire.argtypes = [
+        u64p, u16p, u8p, c.c_int64, c.c_int, u64p, f64p]
+    lib.ft_hll_log_fire.restype = c.c_int64
+    lib.ft_sum_log_fire.argtypes = [u64p, f64p, c.c_int64, u64p, f64p]
+    lib.ft_sum_log_fire.restype = c.c_int64
+    lib.ft_sumtab_new.argtypes = [c.c_int64]
+    lib.ft_sumtab_new.restype = c.c_void_p
+    lib.ft_sumtab_free.argtypes = [c.c_void_p]
+    lib.ft_sumtab_size.argtypes = [c.c_void_p]
+    lib.ft_sumtab_size.restype = c.c_int64
+    lib.ft_sumtab_ingest.argtypes = [c.c_void_p, u64p, f64p,
+                                     c.c_int64, c.c_int64]
+    lib.ft_sumtab_ingest.restype = c.c_int64
+    lib.ft_sumtab_export.argtypes = [c.c_void_p, u64p, f64p]
+    lib.ft_sumtab_export.restype = c.c_int64
+    lib.ft_qsketch_log_fire.argtypes = [
+        u64p, u16p, c.c_int64, c.c_int, f64p, c.c_int,
+        c.c_double, c.c_int64, c.c_double, u64p, f64p]
+    lib.ft_qsketch_log_fire.restype = c.c_int64
+    lib.ft_qsketch_log_fire2.argtypes = [
+        u64p, u16p, u32p, c.c_int64, c.c_int, f64p, c.c_int,
+        c.c_double, c.c_int64, c.c_double, u64p, f64p]
+    lib.ft_qsketch_log_fire2.restype = c.c_int64
+    lib.ft_qsketch_log_compact.argtypes = [
+        u64p, u16p, u32p, c.c_int64, c.c_int, u64p, u16p, u32p]
+    lib.ft_qsketch_log_compact.restype = c.c_int64
+    lib.ft_session_log_fire.argtypes = [
+        u64p, i64p, f32p, u64p, c.c_int64, c.c_int64, c.c_int64,
+        c.c_int, c.c_int,
+        u64p, i64p, i64p, f64p,
+        u64p, i64p, f32p, u64p, c.POINTER(c.c_int64)]
+    lib.ft_session_log_fire.restype = c.c_int64
+    lib.ft_session_log_fire2.argtypes = [
+        u64p, i64p, f32p, u64p, c.c_int64,
+        u64p, i64p, f32p, u64p, c.c_int64,
+        c.c_int64, c.c_int64, c.c_int, c.c_int,
+        u64p, i64p, i64p, f64p,
+        u64p, i64p, f32p, u64p, c.POINTER(c.c_int64)]
+    lib.ft_session_log_fire2.restype = c.c_int64
+    lib.ft_intern_new.argtypes = [c.c_int64]
+    lib.ft_intern_new.restype = c.c_void_p
+    lib.ft_intern_free.argtypes = [c.c_void_p]
+    lib.ft_intern_size.argtypes = [c.c_void_p]
+    lib.ft_intern_size.restype = c.c_int64
+    lib.ft_intern_rows.argtypes = [c.c_void_p, u8p, c.c_int64,
+                                   c.c_int64, c.c_int64, u64p, i64p]
+    lib.ft_intern_rows.restype = c.c_int64
+    lib.ft_heap_tumbling_baseline_str.argtypes = [
+        u8p, c.c_int64, c.c_int64, c.c_int64, f64p, c.c_int64]
+    lib.ft_heap_tumbling_baseline_str.restype = c.c_double
+    lib.ft_wordsums_new.argtypes = []
+    lib.ft_wordsums_new.restype = c.c_void_p
+    lib.ft_wordsums_free.argtypes = [c.c_void_p]
+    lib.ft_wordsums_count.argtypes = [c.c_void_p]
+    lib.ft_wordsums_count.restype = c.c_int64
+    lib.ft_wordsums_fire.argtypes = [c.c_void_p, i64p, f64p]
+    lib.ft_wordsums_fire.restype = c.c_int64
+    lib.ft_wordsums_load.argtypes = [c.c_void_p, i64p, f64p, c.c_int64]
+    lib.ft_intern_sum.argtypes = [c.c_void_p, c.c_void_p, u8p,
+                                  c.c_int64, c.c_int64, f64p,
+                                  c.c_int64, c.c_int64, i64p]
+    lib.ft_intern_sum.restype = c.c_int64
+    lib.ft_interval_join_baseline.argtypes = [
+        u64p, i64p, c.c_int64, u64p, i64p, c.c_int64,
+        c.c_int64, c.c_int64, c.c_int64, c.POINTER(c.c_int64)]
+    lib.ft_interval_join_baseline.restype = c.c_double
+    lib.ft_ivjoin_new.argtypes = [c.c_int64, c.c_int64, c.c_int64]
+    lib.ft_ivjoin_new.restype = c.c_void_p
+    lib.ft_ivjoin_free.argtypes = [c.c_void_p]
+    lib.ft_ivjoin_push.argtypes = [c.c_void_p, c.c_int64, u64p, i64p,
+                                   c.c_int64]
+    lib.ft_ivjoin_push.restype = c.c_int64
+    lib.ft_ivjoin_pairs.argtypes = [c.c_void_p, i64p, i64p]
+    lib.ft_ivjoin_pairs.restype = c.c_int64
+    lib.ft_ivjoin_prune.argtypes = [c.c_void_p, c.c_int64]
 
 
 def available() -> bool:
@@ -253,6 +308,12 @@ def available() -> bool:
 def load_error() -> Optional[str]:
     _ensure_loaded()
     return _load_error
+
+
+def library_path() -> Optional[str]:
+    """The library file this process loaded (None when unavailable)."""
+    _ensure_loaded()
+    return _lib_path
 
 
 def _kernel(name: str):

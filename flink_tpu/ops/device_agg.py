@@ -174,34 +174,28 @@ class DeviceAggregateFunction(AggregateFunction):
     # ---- scalar AggregateFunction contract (heap-backend twin) ------
     # single-record programs are jit-cached: the scalar path runs once
     # per record (heap backend / composite SQL aggregates), so eager
-    # dispatch per op would dominate — especially through a remote
-    # device transport
+    # dispatch per op would dominate
     def _scalar_jits(self):
         jits = getattr(self, "_scalar_jit_cache", None)
         if jits is None:
             # pinned to the CPU backend: single-record accumulators are
-            # tiny, and dispatching them to a (possibly remote) TPU per
-            # record costs milliseconds each — the scalar path exists
-            # exactly where per-record semantics are required, so it
-            # must stay a microsecond-scale host call
-            try:
-                kw = {"backend": "cpu"}
-                jax.jit(lambda x: x, **kw)  # probe support
-            except TypeError:  # pragma: no cover — very old jax
-                kw = {}
+            # tiny, and dispatching them to the TPU per record costs a
+            # device round trip each — the scalar path exists exactly
+            # where per-record semantics are required, so it must stay
+            # a microsecond-scale host call
             agg_name = type(self).__name__
             jits = {
                 "add": traced_jit(lambda st, v, hi, lo: self.update(
                     st, jnp.zeros(1, jnp.int32), v, hi, lo,
                     jnp.ones(1, bool)),
-                    name=f"agg.{agg_name}.add", **kw),
+                    name=f"agg.{agg_name}.add", backend="cpu"),
                 "result": traced_jit(lambda st: self.result(
                     st, jnp.zeros(1, jnp.int32)),
-                    name=f"agg.{agg_name}.result", **kw),
+                    name=f"agg.{agg_name}.result", backend="cpu"),
                 "merge": traced_jit(lambda st: self.merge_slots(
                     st, jnp.array([0], jnp.int32),
                     jnp.array([1], jnp.int32)),
-                    name=f"agg.{agg_name}.merge", **kw),
+                    name=f"agg.{agg_name}.merge", backend="cpu"),
             }
             self._scalar_jit_cache = jits
         return jits

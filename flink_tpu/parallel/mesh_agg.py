@@ -29,10 +29,7 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_tpu.ops.device_agg import DeviceAggregateFunction

@@ -27,10 +27,10 @@ Design notes:
   so a mesh job and a MiniCluster job agree on key placement.
 - The static worst case of the exchange is every record targeting one
   shard, so the received buffer is [n_shards, G] for a G-row step —
-  the all_to_all tax measured in BENCH_NOTES.md's scaling table.
+  the all_to_all padding tax.
 - On a multi-host pod each host would consume only its addressable
   shards' outputs; this process consumes all shards (single-host
-  runtime, virtual or tunnel-attached mesh).
+  runtime).
 """
 
 from __future__ import annotations
@@ -72,18 +72,14 @@ def _make_lane_exchange(mesh, axis: str):
     Buckets are CAPPED at `bucket_cap` rows per (source, target) pair
     instead of the static worst case m = G // S — with balanced key
     groups each bucket holds ~m/S rows, so a cap of a few times the
-    mean cuts the exchanged volume from S×m to S×cap per device (the
-    padding tax in BENCH_NOTES.md's scaling table).  Rows that
-    overflow a bucket take the out-of-band path (see _run_step).
+    mean cuts the exchanged volume from S×m to S×cap per device.  Rows
+    that overflow a bucket take the out-of-band path (see _run_step).
 
     fn(bucks [S, S, cap, K] u32, counts [S, S] i32) →
       (recv [S, S, cap, K], recv_counts [S, S]) where recv[j][s] is
     the bucket source s sent to shard j (count rows valid)."""
     import jax
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(bucks_blk, counts_blk):
@@ -114,20 +110,13 @@ def _make_packed_exchange(mesh, axis: str, cap: int):
     step, and the H2D leg ships ``m*K`` lanes instead of the legacy
     ``S*cap*K`` pre-padded buckets.
 
-    Loop-free by construction (sort + scatter + one collective): this
-    env has no shard_map replication rule for ``lax.while_loop``, so
-    nothing here may iterate on device.
-
     Overflow discipline: the host pre-checks bucket counts with one
     vectorized bincount and only takes this path when NO (source,
     target) bucket overflows ``cap`` — the device program itself would
     silently truncate (rows past ``cap`` land in the garbage bin), so
     the guard keeps the fallback exact rather than best-effort."""
     import jax
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     import jax.numpy as jnp
@@ -324,8 +313,7 @@ class _MeshShardedLogEngine:
         device work is dispatched asynchronously and the PREVIOUS
         step's results are converted/delivered while the fabric moves
         this one, so collective time overlaps host delivery instead of
-        serializing with it (the all_to_all tax in BENCH_NOTES.md's
-        scaling table).  Rows still reach shard engines in step order
+        serializing with it.  Rows still reach shard engines in step order
         — every consumer of shard state drains the in-flight step
         first (flush / advance_watermark / snapshot)."""
         S, cap = self.n_shards, self.bucket_cap

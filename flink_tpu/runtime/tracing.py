@@ -612,10 +612,9 @@ def traced_jit(fn, name: Optional[str] = None, **jit_kwargs):
     the call traced+compiled (count it, with wall time — compilation
     dominates the call so attributing the whole call is a fine
     estimate, plus the triggering arg-shape signature); no growth is a
-    cache hit.  Falls back to plain timing when the private API is
-    absent.  When the device telemetry plane is enabled every dispatch
-    additionally accumulates wall time and bytes in/out per kernel
-    name (``runtime/device_stats.py``)."""
+    cache hit.  When the device telemetry plane is enabled every
+    dispatch additionally accumulates wall time and bytes in/out per
+    kernel name (``runtime/device_stats.py``)."""
     import jax
 
     from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
@@ -623,18 +622,9 @@ def traced_jit(fn, name: Optional[str] = None, **jit_kwargs):
     jitted = jax.jit(fn, **jit_kwargs)
     label = name or getattr(fn, "__name__", None) or "jit_fn"
     stat = _jit_entry(label)
-    cache_size = getattr(jitted, "_cache_size", None)
+    cache_size = jitted._cache_size
 
     def wrapper(*args, **kwargs):
-        if cache_size is None:
-            if not TELEMETRY.enabled:
-                return jitted(*args, **kwargs)
-            t0 = _perf_ns()
-            out = jitted(*args, **kwargs)
-            TELEMETRY.record_kernel_dispatch(
-                label, (_perf_ns() - t0) / 1e6,
-                tree_nbytes((args, kwargs)), tree_nbytes(out))
-            return out
         before = cache_size()
         t0 = _perf_ns()
         out = jitted(*args, **kwargs)

@@ -40,19 +40,20 @@ The per-operator ``_ColumnKernelMixin`` boxed fallback stays fully
 intact.  The first batch of every new dtype signature is verified against
 a full numpy twin (values, timestamps, validity masks, routing hashes,
 channel bounds, pane starts — exact equality, NaN-aware) BEFORE
-anything is emitted; any mismatch, trace failure, or runtime error
-demotes the WHOLE chain back to per-operator dispatch with a recorded
-reason.  Demotion can never produce wrong output because the failing
-batch is replayed through the untouched per-operator path.
+anything is emitted.  Demotion is for what the DATA or the UDF can
+cause — a column dtype the device cannot represent, a kernel output
+that is not a column, a UDF jax cannot trace, a twin mismatch: the
+WHOLE chain goes back to per-operator dispatch with a recorded reason,
+and the failing batch is replayed through the untouched per-operator
+path, so demotion can never produce wrong output.  An exception from
+this module's own code is a bug and propagates: it fails the job.
 
 Mesh sharding
 -------------
 With >1 device and a large enough bucket the same program runs under
 ``shard_map`` on a named mesh (batch axis): each shard compacts its
 row block locally and the host reassembles shard-order prefixes —
-bit-identical to the single-device program, and loop-free (this env
-has no ``shard_map`` replication rule for ``lax.while_loop``, so no
-collective may sit behind one).
+bit-identical to the single-device program.
 """
 
 from __future__ import annotations
@@ -62,7 +63,15 @@ import os
 import time
 from typing import Callable, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64, shard_map
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
+
+from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
+from flink_tpu.runtime.tracing import traced_jit
 
 log = logging.getLogger(__name__)
 
@@ -101,8 +110,15 @@ class _Demoted(Exception):
     """Internal: raised inside _execute after demote() already ran."""
 
 
+class _NotFusable(TypeError):
+    """Raised while TRACING the fused body when a UDF kernel's output
+    cannot ride the device program (not a column, not a bool mask, a
+    key column that is not int64) — a property of the UDF and the
+    batch dtypes, so it demotes."""
+
+
 # ---------------------------------------------------------------------
-# AOT eligibility (no jax import — safe for linters and reports)
+# AOT eligibility
 # ---------------------------------------------------------------------
 
 def _kernel_stage(op) -> Optional[Tuple[str, Callable, str]]:
@@ -194,7 +210,7 @@ def select_run(operators) -> Tuple[int, int, Optional[int]]:
 
 def fusion_report(operators) -> dict:
     """AOT fusion summary for one chain — feeds ``chain_report``,
-    FT184 and ``flink_tpu lint --types``.  Never imports jax."""
+    FT184 and ``flink_tpu lint --types``."""
     start, k, widx = select_run(operators)
     names = [getattr(op, "operator_id", "") or type(op).__name__
              for op in operators]
@@ -233,38 +249,30 @@ def fusion_report(operators) -> dict:
 def try_fuse_subtask(subtask) -> None:
     """Compile and anchor a fused program for one SubtaskInstance —
     called at the end of ``SubtaskInstance.open()`` (routes wired,
-    operators opened).  Never raises: any failure leaves the ordinary
-    per-operator path untouched."""
+    operators opened)."""
     if not FUSION_ENABLED:
         return
-    try:
-        from flink_tpu.streaming import columnar
-        if not columnar.PIPELINE_ENABLED:
+    from flink_tpu.streaming import columnar
+    if not columnar.PIPELINE_ENABLED:
+        return
+    ops = getattr(subtask, "operators", None)
+    if not ops:
+        return
+    # idempotent: open() can run again after a restore
+    for op in ops:
+        if "_fused_chain" in op.__dict__ and op._fused_chain is not None:
             return
-        ops = getattr(subtask, "operators", None)
-        if not ops:
-            return
-        # idempotent: open() can run again after a restore
-        for op in ops:
-            if "_fused_chain" in op.__dict__ and op._fused_chain is not None:
-                return
-        program = compile_chain(ops, router=getattr(subtask, "router", None))
-        if program is not None:
-            program.anchor._fused_chain = program
-            FUSION_STATS.programs += 1
-    except Exception as e:  # noqa: BLE001
-        log.warning("chain fusion disabled for subtask: %r", e)
+    program = compile_chain(ops, router=getattr(subtask, "router", None))
+    if program is not None:
+        program.anchor._fused_chain = program
+        FUSION_STATS.programs += 1
 
 
 def compile_chain(operators, router=None) -> Optional["FusedChainProgram"]:
     """Lower the maximal fusable run of ``operators`` into a
-    :class:`FusedChainProgram`, or None when nothing fuses (no jax,
-    no proven run, run of a single stage with no routing/window leg
-    to amortize it)."""
-    try:
-        import jax  # noqa: F401
-    except Exception:  # noqa: BLE001
-        return None
+    :class:`FusedChainProgram`, or None when nothing fuses (no proven
+    run, run of a single stage with no routing/window leg to amortize
+    it)."""
     start, k, widx = select_run(operators)
     if k == 0:
         return None
@@ -348,17 +356,11 @@ class FusedChainProgram:
         # mesh: largest power-of-two device prefix, batch ("rows") axis
         self.mesh = None
         self.mesh_shards = 1
-        try:
-            import jax
-            devs = jax.devices()
-            if len(devs) >= 2:
-                s = 1 << (len(devs).bit_length() - 1)
-                from jax.sharding import Mesh
-                self.mesh = Mesh(np.array(devs[:s]), ("rows",))
-                self.mesh_shards = s
-        except Exception:  # noqa: BLE001
-            self.mesh = None
-            self.mesh_shards = 1
+        devs = jax.devices()
+        if len(devs) >= 2:
+            s = 1 << (len(devs).bit_length() - 1)
+            self.mesh = Mesh(np.array(devs[:s]), ("rows",))
+            self.mesh_shards = s
         for op in self.members:
             op._fused_member = self
         if self.window_op is not None:
@@ -407,28 +409,19 @@ class FusedChainProgram:
 
     # ---- run ---------------------------------------------------------
     def run(self, batch) -> None:
-        """Execute the fused program on ``batch``; on ANY failure the
-        chain demotes and the batch replays through the untouched
-        per-operator path (nothing was emitted yet — compute-all-
-        then-emit)."""
+        """Execute the fused program on ``batch``.  When the batch or
+        the UDFs demote the chain, the batch replays through the
+        untouched per-operator path (nothing was emitted yet —
+        compute-all-then-emit); any other exception propagates."""
         try:
             emit = self._execute(batch)
         except _Demoted:
-            self.anchor.process_batch(batch)
-            return
-        except Exception as e:  # noqa: BLE001
-            self.demote(f"fused program raised {e!r}")
             self.anchor.process_batch(batch)
             return
         emit()
 
     # ---- internals ---------------------------------------------------
     def _execute(self, batch):
-        import jax
-        from jax.experimental import enable_x64
-
-        from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
-
         n = len(batch)
         col_arrays = tuple(batch.cols.values())
         for name, a in batch.cols.items():
@@ -473,11 +466,8 @@ class FusedChainProgram:
             if tel.enabled:
                 # explicit boundary copies so the ledger shows the fused
                 # region's ONLY host↔device traffic: one h2d, one d2h
-                sharding = None
-                if use_mesh:
-                    from jax.sharding import NamedSharding
-                    from jax.sharding import PartitionSpec as P
-                    sharding = NamedSharding(self.mesh, P("rows"))
+                sharding = (NamedSharding(self.mesh, P("rows"))
+                            if use_mesh else None)
                 t0 = time.perf_counter_ns()
                 args = jax.device_put(args, sharding)
                 jax.block_until_ready(args)
@@ -486,10 +476,11 @@ class FusedChainProgram:
                                     "chain.boundary")
             try:
                 outs = fn(*args)
-            except _Demoted:
-                raise
-            except Exception as e:  # noqa: BLE001
-                self.demote(f"device trace/dispatch failed: {e!r}")
+            except (_NotFusable, jax.errors.JAXTypeError) as e:
+                # the UDF's kernel does not trace into a column
+                # program (JAXTypeError: it concretizes a tracer or
+                # hands one to numpy) — the per-operator kernels cope
+                self.demote(f"UDF kernel is not device-traceable: {e!r}")
                 raise _Demoted from e
             if tel.enabled:
                 jax.block_until_ready(outs)
@@ -714,10 +705,6 @@ class FusedChainProgram:
         return fn
 
     def _build_fn(self, mode, scalar, use_mesh):
-        import jax.numpy as jnp
-
-        from flink_tpu.runtime.tracing import traced_jit
-
         stages = self.stages
         route_field = self.route_field
         maxpar = getattr(self, "_r_maxpar", 0)
@@ -826,9 +813,6 @@ class FusedChainProgram:
         if not use_mesh:
             return traced_jit(body, self.label)
 
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-
         def shard_body(cols, ts, tsm, valid):
             out_cols, out_ts, out_tsm, srows, count, b, hashes, pane = \
                 body(cols, ts, tsm, valid)
@@ -845,12 +829,12 @@ class FusedChainProgram:
             shard_body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec, spec, spec, spec, bspec, spec, spec),
-            check_rep=False)
+            check_vma=False)
         return traced_jit(sharded, self.label)
 
 
 def _trace_err(msg: str) -> Exception:
-    return TypeError(f"chain fusion: {msg}")
+    return _NotFusable(f"chain fusion: {msg}")
 
 
 # ---------------------------------------------------------------------
@@ -860,7 +844,6 @@ def _trace_err(msg: str) -> Exception:
 def _jnp_splitmix64(x):
     """splitmix64 on an int64 column — bit-identical to
     ``keygroups.splitmix64_np`` / ``_routing_hashes`` int keys."""
-    import jax.numpy as jnp
     z = x.astype(jnp.uint64) + jnp.uint64(0x9E3779B97F4A7C15)
     z = (z ^ (z >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
     z = (z ^ (z >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
@@ -870,7 +853,6 @@ def _jnp_splitmix64(x):
 def _jnp_operator_indexes(hashes, max_parallelism, num_channels):
     """hash → key group (32-bit murmur avalanche) → operator index —
     bit-identical to ``keygroups.assign_operator_indexes_np``."""
-    import jax.numpy as jnp
     m32 = jnp.uint64(0xFFFFFFFF)
     h = hashes & m32
     h = h ^ (h >> jnp.uint64(16))

@@ -371,8 +371,7 @@ class ColumnarWindowOperator(StreamOperator):
             if eng is None:
                 raise RuntimeError(
                     "checkpoint was taken on the log engine tier, which "
-                    "is unavailable here (native runtime / eligible "
-                    "aggregate required)")
+                    "does not cover this aggregate/assigner")
             return eng
         eng = None
         if self.mesh is not None and np.issubdtype(key_dtype, np.integer):
@@ -405,8 +404,8 @@ class ColumnarWindowOperator(StreamOperator):
 
     def _string_engine(self):
         """Fused wordcount engine for a STRING key column (tumbling
-        float sum — the SQL wordcount shape); None when the shape or
-        native runtime doesn't fit."""
+        float sum — the SQL wordcount shape); None when the shape
+        doesn't fit."""
         from flink_tpu.streaming.device_window_operator import (
             string_sum_engine_for_assigner,
         )

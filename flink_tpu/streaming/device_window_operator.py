@@ -64,10 +64,7 @@ def string_sum_engine_for_assigner(assigner, agg: DeviceAggregateFunction):
             and np.issubdtype(agg.value_dtype, np.floating)
             and isinstance(assigner, TumblingEventTimeWindows)
             and assigner.offset == 0):
-        try:
-            return StringSumTumblingWindows(agg, assigner.size)
-        except RuntimeError:
-            pass  # no native runtime
+        return StringSumTumblingWindows(agg, assigner.size)
     return None
 
 
@@ -75,7 +72,9 @@ def log_engine_for_assigner(assigner, agg: DeviceAggregateFunction):
     """Log-structured combiner tier for this assigner+aggregate, or
     None when the cell decomposition / assigner shape doesn't fit
     (streaming/log_windows.py scope: integer keys, HLL/Sum/Quantile
-    cells, Count-Min sessions)."""
+    cells, Count-Min sessions).  A missing native runtime is an error
+    (the engines raise RuntimeError), never a reason to hand the job
+    to another engine."""
     from flink_tpu.streaming import log_windows as lw
     try:
         if isinstance(assigner, TumblingEventTimeWindows) \
@@ -88,8 +87,8 @@ def log_engine_for_assigner(assigner, agg: DeviceAggregateFunction):
                                                   assigner.slide)
         if isinstance(assigner, EventTimeSessionWindows):
             return lw.LogStructuredSessionWindows(agg, assigner.gap)
-    except (TypeError, ValueError, RuntimeError):
-        pass  # unsupported cell decomposition / params / no native lib
+    except (TypeError, ValueError):
+        pass  # unsupported cell decomposition / params
     return None
 
 

@@ -427,9 +427,9 @@ class VectorizedTumblingWindows:
         # (the steady tumbling cadence — one window live at a time) and
         # covers enough of the arena, one fused full-array reduce beats
         # tiled dynamic-slice gathers (a [tile, m] dynamic_slice out of
-        # a multi-GB array materializes unfused, ~4x the bandwidth cost
-        # — measured, BENCH_NOTES.md), and the clear becomes one
-        # donated full fill at write bandwidth
+        # a multi-GB array materializes unfused, ~4x the bandwidth
+        # cost), and the clear becomes one donated full fill at write
+        # bandwidth
         self._jit_result_all = traced_jit(agg.result_dense,
                                           name="window.result_all")
         # fire/clear tile bounded by BYTES not slot count: a gather or

@@ -33,9 +33,13 @@ class _LFilter(FilterFunction):
 class _CapOut:
     def __init__(self):
         self.batches = []
+        self.records = []
 
     def collect_batch(self, batch):
         self.batches.append(batch)
+
+    def collect(self, record):
+        self.records.append(record)
 
 
 class _ChainOut:
@@ -44,6 +48,9 @@ class _ChainOut:
 
     def collect_batch(self, batch):
         self.op.process_batch(batch)
+
+    def collect(self, record):
+        self.op.process_element(record)
 
 
 def _mk_chain(out, map_fn=None, filter_fn=None):
@@ -200,7 +207,7 @@ def test_precomputed_routing_hashes_match_per_row():
     from flink_tpu.streaming.chain_fusion import _jnp_splitmix64
     pytest.importorskip("jax")
     import jax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         dev = np.asarray(jax.jit(_jnp_splitmix64)(
             jax.device_put(keys.view(np.uint64))))
@@ -411,6 +418,63 @@ def test_demoted_output_matches_per_operator():
     for gb, rb in zip(out.batches, ref_out.batches):
         for k in rb.cols:
             assert np.array_equal(gb.cols[k], rb.cols[k])
+
+
+def test_own_code_exception_fails_instead_of_demoting(monkeypatch):
+    """Demotion is for what the data or a UDF can cause.  An exception
+    out of the fusion code itself (here: its jit wrapper) is a bug and
+    must surface, not turn into a silently unfused chain."""
+    def broken_jit(fn, name=None, **kw):
+        def dispatch(*args):
+            raise RuntimeError("bug in the fused program's own code")
+        return dispatch
+
+    monkeypatch.setattr(cf, "traced_jit", broken_jit)
+    out = _CapOut()
+    m, f = _mk_chain(out)
+    prog = cf.compile_chain([m, f])
+    batch = RecordBatch({"f0": np.arange(600, dtype=np.int64),
+                         "f1": np.arange(600, dtype=np.int64)})
+    with pytest.raises(RuntimeError, match="own code"):
+        prog.run(batch)
+    assert prog.active and cf.FUSION_STATS.demotions == 0
+    assert not out.batches, "nothing may be emitted by a failed batch"
+
+
+def test_own_code_exception_fails_the_job(monkeypatch):
+    from flink_tpu.streaming.columnar import VectorizedCollectionSource
+    from flink_tpu.streaming.datastream import StreamExecutionEnvironment
+    from flink_tpu.streaming.sources import CollectSink
+
+    def broken_verify(self, *args, **kw):
+        raise RuntimeError("bug in the fused program's own code")
+
+    monkeypatch.setattr(cf.FusedChainProgram, "_verify", broken_verify)
+    env = StreamExecutionEnvironment()
+    (env.add_source(VectorizedCollectionSource(
+        [(i % 7, i) for i in range(2000)], chunk=512))
+        .map(lambda t: (t[0], t[1] * 3))
+        .filter(lambda t: t[1] % 7 != 0)
+        .add_sink(CollectSink()))
+    with pytest.raises(Exception, match="own code"):
+        env.execute("fusion-own-bug")
+    assert cf.FUSION_STATS.demotions == 0
+
+
+def test_untraceable_udf_still_demotes():
+    """A LIFTABLE numpy kernel that jax cannot trace (a numpy ufunc
+    called on a tracer) is the UDF's property: the chain demotes and
+    the batch flows per-operator."""
+    out = _CapOut()
+    m, f = _mk_chain(out, map_fn=lambda t: (t[0], np.add(t[1], 1)),
+                     filter_fn=lambda t: t[1] >= 0)
+    prog = cf.compile_chain([m, f])
+    assert prog is not None
+    prog.run(RecordBatch({"f0": np.arange(600, dtype=np.int64),
+                          "f1": np.arange(600, dtype=np.int64)}))
+    assert not prog.active
+    assert "not device-traceable" in prog.demoted_reason
+    assert sum(len(b) for b in out.batches) + len(out.records) == 600
 
 
 # ---------------------------------------------------------------------
