@@ -320,15 +320,19 @@ def _kernel(name: str):
     """Per-kernel dispatch counter + wall-time accounting around a
     host_runtime entry point.  Feeds runtime.tracing's kernel store
     (gauges under ``native.<name>``) and, when the tracer is enabled,
-    emits a ``native.<name>`` span into the Chrome trace.  The wrapper
-    is transparent to the no-compiler degradation path — errors pass
+    emits a ``native.<name>`` span into the Chrome trace; a profiler
+    trace shows the call as ``flink/native.<name>``.  The wrapper is
+    transparent to the no-compiler degradation path — errors pass
     straight through."""
+    label = "native." + name
+
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             t0 = _perf_ns()
             try:
-                return fn(*args, **kwargs)
+                with _tracing.phase_annotation(label):
+                    return fn(*args, **kwargs)
             finally:
                 _tracing.record_kernel(name, t0, _perf_ns())
         return wrapper

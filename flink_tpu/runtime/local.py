@@ -768,20 +768,23 @@ class SubtaskInstance:
         return processed
 
     def _dispatch(self, ch: _InputChannel, element):
+        # records, batches and watermarks are the operator chain's work:
+        # one span each while the tracer is on (per element, so gated;
+        # the always-on phases lie inside the operators)
+        tracer = get_tracer()
+        if tracer.enabled and (element.__class__ is StreamRecord
+                               or element.is_record or element.is_batch
+                               or element.is_watermark):
+            with tracer.span(self._span_process):
+                self._dispatch_element(ch, element)
+        else:
+            self._dispatch_element(ch, element)
+
+    def _dispatch_element(self, ch: _InputChannel, element):
         if element.__class__ is StreamRecord or element.is_record:
-            tracer = get_tracer()
-            if tracer.enabled:
-                with tracer.span(self._span_process):
-                    self.process_record(ch.input_index, element)
-            else:
-                self.process_record(ch.input_index, element)
+            self.process_record(ch.input_index, element)
         elif element.is_batch:
-            tracer = get_tracer()
-            if tracer.enabled:
-                with tracer.span(self._span_process):
-                    self.process_batch_element(ch.input_index, element)
-            else:
-                self.process_batch_element(ch.input_index, element)
+            self.process_batch_element(ch.input_index, element)
         elif element.is_watermark:
             self.process_channel_watermark(ch.input_index, ch.channel_id,
                                            element)

@@ -11,7 +11,11 @@ surface:
   trace-event JSON export (loadable in Perfetto / ``chrome://tracing``).
   When disabled, ``span()`` returns a shared no-op object — one
   attribute check and a dict-free return, so instrumented hot paths pay
-  near zero.
+  near zero.  ``phase(name, **attrs)`` is the always-on form for work
+  at batch or fire granularity: it feeds the same per-name stats with
+  the tracer off, and enters a ``jax.profiler.TraceAnnotation`` named
+  ``flink/<name>`` so a profiler trace shows the phase on the clock of
+  the device ops.
 
 * **Kernel profiling** — ``record_kernel(name, t0_ns, t1_ns)`` called
   by the wrappers in :mod:`flink_tpu.native` around every
@@ -22,6 +26,9 @@ surface:
 * **JAX compile tracking** — :func:`traced_jit` wraps ``jax.jit`` and
   detects recompiles via the jitted callable's ``_cache_size()``
   (grows across a call ⇒ that call compiled; otherwise a cache hit).
+  One ``jax.monitoring`` listener books every backend compile of the
+  process, ``traced_jit`` or not, on the innermost open span or phase
+  and on :func:`backend_compile_totals`.
   Non-JAX compilation events (the CEP predicate bytecode compiler)
   report through :func:`record_compile_event` into the same store.
 
@@ -33,6 +40,7 @@ full picture.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -55,12 +63,18 @@ __all__ = [
     "record_compile_event",
     "kernel_stats",
     "jit_stats",
+    "backend_compile_totals",
+    "phase_annotation",
     "reset_kernel_stats",
     "reset_jit_stats",
     "register_runtime_profile_gauges",
 ]
 
 _perf_ns = time.perf_counter_ns
+
+#: every phase's profiler annotation is named PHASE_PREFIX + its name
+PHASE_PREFIX = "flink/"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # one lock guards the aggregate stores (kernel + jit + span stats and
 # the registered-registry list); all updates are batch-level, not
@@ -114,7 +128,7 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("tracer", "name", "attrs", "start_ns", "child_ns",
-                 "parent")
+                 "parent", "compile_ns", "compiles")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self.tracer = tracer
@@ -122,6 +136,10 @@ class _Span:
         self.attrs = attrs
         self.child_ns = 0
         self.parent: Optional[_Span] = None
+        #: backend compiles that ran while this span was the innermost
+        #: open one (booked by the jax.monitoring listener)
+        self.compile_ns = 0
+        self.compiles = 0
 
     def set_attr(self, key: str, value: Any) -> None:
         if self.attrs is None:
@@ -147,13 +165,32 @@ class _Span:
         return False
 
 
+class _Phase(_Span):
+    """A span that is always on and is also a profiler annotation."""
+
+    __slots__ = ("annotation",)
+
+    def __enter__(self):
+        self.annotation = phase_annotation(self.name, **(self.attrs or {}))
+        self.annotation.__enter__()
+        return _Span.__enter__(self)
+
+    def __exit__(self, *exc):
+        _Span.__exit__(self)
+        self.annotation.__exit__(*exc)
+        return False
+
+
 class _SpanStat:
-    __slots__ = ("count", "total_ms", "self_ms", "reservoir")
+    __slots__ = ("count", "total_ms", "self_ms", "compile_ms", "compiles",
+                 "reservoir")
 
     def __init__(self):
         self.count = 0
         self.total_ms = 0.0
         self.self_ms = 0.0
+        self.compile_ms = 0.0
+        self.compiles = 0
         self.reservoir = _Reservoir()
 
 
@@ -185,6 +222,17 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, attrs or None)
+
+    def phase(self, name: str, **attrs):
+        """Context manager for work at BATCH or FIRE granularity,
+        always on: never per record, key or timer.  With the tracer
+        off it still feeds :meth:`stats` (count, total, self time,
+        compiles) and enters a ``jax.profiler.TraceAnnotation`` named
+        ``flink/<name>`` — inert without a profiler session; with one,
+        the phase lies in the trace on the clock of the device ops.
+        Only while ``enabled`` does it also land in the event ring, as
+        a span does."""
+        return _Phase(self, name, attrs or None)
 
     def span_linked(self, name: str, ctx: Optional[dict], **attrs):
         """Like :meth:`span`, but causally linked to a propagated
@@ -228,25 +276,30 @@ class Tracer:
         self._events.append(event)
 
     def _finish(self, span: _Span, dur_ns: int) -> None:
-        event = {
-            "name": span.name,
-            "ph": "X",
-            "ts": span.start_ns / 1000.0,
-            "dur": dur_ns / 1000.0,
-            "pid": self._pid,
-            "tid": threading.get_ident(),
-        }
-        lane = getattr(self._tls, "lane", None)
-        if lane is not None:
-            event["lane"] = lane
-        if span.parent is not None:
-            event["parent"] = span.parent.name
-        if span.attrs:
-            event["args"] = span.attrs
+        event = None
+        # a phase reaches the ring only while the tracer is on; a span
+        # exists only because it was
+        if self.enabled or span.__class__ is _Span:
+            event = {
+                "name": span.name,
+                "ph": "X",
+                "ts": span.start_ns / 1000.0,
+                "dur": dur_ns / 1000.0,
+                "pid": self._pid,
+                "tid": threading.get_ident(),
+            }
+            lane = getattr(self._tls, "lane", None)
+            if lane is not None:
+                event["lane"] = lane
+            if span.parent is not None:
+                event["parent"] = span.parent.name
+            if span.attrs:
+                event["args"] = span.attrs
         total_ms = dur_ns / 1e6
-        self_ms = (dur_ns - span.child_ns) / 1e6
+        self_ms = (dur_ns - span.child_ns - span.compile_ns) / 1e6
         with self._lock:
-            self._append_locked(event)
+            if event is not None:
+                self._append_locked(event)
             stat = self._stats.get(span.name)
             if stat is None:
                 stat = self._stats[span.name] = _SpanStat()
@@ -254,6 +307,9 @@ class Tracer:
             stat.count += 1
             stat.total_ms += total_ms
             stat.self_ms += self_ms
+            if span.compiles:
+                stat.compiles += span.compiles
+                stat.compile_ms += span.compile_ns / 1e6
             stat.reservoir.update(total_ms)
 
     def record_instant(self, name: str, **attrs) -> None:
@@ -351,6 +407,8 @@ class Tracer:
                     "count": st.count,
                     "total_ms": st.total_ms,
                     "self_ms": st.self_ms,
+                    "compiles": st.compiles,
+                    "compile_ms": st.compile_ms,
                     "p50_ms": _percentile(vals, 0.50),
                     "p99_ms": _percentile(vals, 0.99),
                 }
@@ -389,11 +447,64 @@ class Tracer:
         g.gauge("count", lambda s=stat: s.count)
         g.gauge("totalMs", lambda s=stat: s.total_ms)
         g.gauge("selfMs", lambda s=stat: s.self_ms)
+        g.gauge("compiles", lambda s=stat: s.compiles)
+        g.gauge("compileMs", lambda s=stat: s.compile_ms)
         g.gauge("p50Ms", lambda s=stat: s.reservoir.quantile(0.50))
         g.gauge("p99Ms", lambda s=stat: s.reservoir.quantile(0.99))
 
 
 _tracer = Tracer()
+
+#: jax.profiler.TraceAnnotation, once the first phase() or traced_jit()
+#: has hooked jax (this module imports jax nowhere at import time)
+_TraceAnnotation = None
+#: [count, ns] of every backend compile since the hook
+_backend_compiles = [0, 0]
+
+
+def _hook_jax() -> None:
+    """Once per process: the annotation class phases enter, and the
+    one listener that books backend compiles where they happen."""
+    global _TraceAnnotation
+    import jax
+    with _LOCK:
+        if _TraceAnnotation is None:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _TraceAnnotation = jax.profiler.TraceAnnotation
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    # jax compiles on the calling thread, so the innermost open span of
+    # this thread is the work that needed the program
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    ns = int(secs * 1e9)
+    with _LOCK:
+        _backend_compiles[0] += 1
+        _backend_compiles[1] += ns
+    stack = getattr(_tracer._tls, "stack", None)
+    if stack:
+        stack[-1].compiles += 1
+        stack[-1].compile_ns += ns
+
+
+def phase_annotation(name: str, **attrs):
+    """The profiler half of a phase alone (``flink/<name>`` in a
+    trace), for a call site that keeps its own books: the native
+    kernel wrappers, whose times ``kernel_stats()`` holds."""
+    if _TraceAnnotation is None:
+        _hook_jax()
+    return _TraceAnnotation(PHASE_PREFIX + name, **attrs)
+
+
+def backend_compile_totals() -> Dict[str, float]:
+    """Every backend compile (or read from the compile cache) of the
+    process since the first phase or ``traced_jit``, whoever jitted
+    the program: ``jit_stats()`` names the ones behind a label."""
+    with _LOCK:
+        return {"compiles": _backend_compiles[0],
+                "compile_ms": _backend_compiles[1] / 1e6}
 
 
 def get_tracer() -> Tracer:
@@ -614,13 +725,25 @@ def traced_jit(fn, name: Optional[str] = None, **jit_kwargs):
     estimate, plus the triggering arg-shape signature); no growth is a
     cache hit.  When the device telemetry plane is enabled every
     dispatch additionally accumulates wall time and bytes in/out per
-    kernel name (``runtime/device_stats.py``)."""
+    kernel name (``runtime/device_stats.py``).  The program carries
+    the label: a profiler trace's "XLA Modules" line reads
+    ``jit_state_result`` for ``name="state.result"``, and its ops lie
+    under a ``named_scope`` of the label."""
     import jax
 
     from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
 
-    jitted = jax.jit(fn, **jit_kwargs)
+    if _TraceAnnotation is None:
+        _hook_jax()
     label = name or getattr(fn, "__name__", None) or "jit_fn"
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        with jax.named_scope(label):
+            return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = label.replace(".", "_")
+    jitted = jax.jit(program, **jit_kwargs)
     stat = _jit_entry(label)
     cache_size = jitted._cache_size
 
@@ -750,6 +873,9 @@ def register_runtime_profile_gauges(registry) -> None:
         for name, stat in _kernel_stats.items():
             _add_kernel_gauges(native_group, name, stat)
         jit_group = root.add_group("jit")
+        jit_group.gauge("backendCompiles", lambda: _backend_compiles[0])
+        jit_group.gauge("backendCompileMs",
+                        lambda: _backend_compiles[1] / 1e6)
         for name, stat in _jit_stats.items():
             _add_jit_gauges(jit_group, name, stat)
     _tracer.install_metrics(root.add_group("tracing"))

@@ -65,6 +65,7 @@ from flink_tpu.state.heap_backend import (
     split_column_by_key_group,
 )
 from flink_tpu.runtime.device_stats import TELEMETRY
+from flink_tpu.runtime.tracing import get_tracer, traced_jit
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
 _perf_ns = time.perf_counter_ns
@@ -128,19 +129,23 @@ class DeviceAggregatingState(AggregatingState):
         self._pending_hi: List[int] = []
         self._pending_lo: List[int] = []
         # jit-compiled entry points (cached per state object; XLA caches
-        # per padded batch shape)
-        self._jit_update = jax.jit(self._update_fn, donate_argnums=0)
-        self._jit_upload = jax.jit(
+        # per padded batch shape), under labels jit_stats() keeps
+        self._jit_update = traced_jit(self._update_fn, name="state.update",
+                                      donate_argnums=0)
+        self._jit_upload = traced_jit(
             lambda st, slot, row: {k: st[k].at[slot].set(row[k])
                                    for k in st},
-            donate_argnums=0)
-        self._jit_merge = jax.jit(self.agg.merge_slots, donate_argnums=0)
+            name="state.upload", donate_argnums=0)
+        self._jit_merge = traced_jit(self.agg.merge_slots,
+                                     name="state.merge", donate_argnums=0)
         #: the jit(vmap(merge)) pairwise kernel — unique-dst dispatches
         #: only (merge_namespaces_batch rounds multi-source merges)
-        self._jit_merge_rows = jax.jit(self.agg.merge_rows,
-                                       donate_argnums=0)
-        self._jit_clear = jax.jit(self.agg.clear_slots, donate_argnums=0)
-        self._jit_result = jax.jit(self.agg.result)
+        self._jit_merge_rows = traced_jit(self.agg.merge_rows,
+                                          name="state.merge_rows",
+                                          donate_argnums=0)
+        self._jit_clear = traced_jit(self.agg.clear_slots,
+                                     name="state.clear", donate_argnums=0)
+        self._jit_result = traced_jit(self.agg.result, name="state.result")
         # queryable-state reads come from foreign threads; every
         # device_state REPLACEMENT donates the old tree's buffers, so
         # a concurrent gather on the old tree would read freed memory.
@@ -315,28 +320,32 @@ class DeviceAggregatingState(AggregatingState):
                     else namespaces[sl],
                     pre_extracted=pre_extracted)
             return
-        slot_for = self._slot_for
-        if namespaces is None:
-            slots = [slot_for(k, namespace) for k in keys]
-        else:
-            slots = [slot_for(k, namespaces[i]) for i, k in enumerate(keys)]
-        self._pending_slots.extend(slots)
-        extract = self.agg.extract_value
-        # overridden on the class or per-instance (an instance-attached
-        # plain function has no __func__)
-        if not pre_extracted and getattr(
-                extract, "__func__",
-                None) is not DeviceAggregateFunction.extract_value:
-            values = [extract(v) for v in values]
-        if self.agg.needs_value:
-            self._pending_values.extend(values)
-        if self.agg.needs_value_hash:
-            hi = self._pending_hi
-            lo = self._pending_lo
-            for v in values:
-                h = stable_hash64(v)
-                hi.append(h >> 32)
-                lo.append(h & 0xFFFFFFFF)
+        tracer = get_tracer()
+        with tracer.phase("state.add.slots"):
+            slot_for = self._slot_for
+            if namespaces is None:
+                slots = [slot_for(k, namespace) for k in keys]
+            else:
+                slots = [slot_for(k, namespaces[i])
+                         for i, k in enumerate(keys)]
+            self._pending_slots.extend(slots)
+        with tracer.phase("state.add.hash"):
+            extract = self.agg.extract_value
+            # overridden on the class or per-instance (an
+            # instance-attached plain function has no __func__)
+            if not pre_extracted and getattr(
+                    extract, "__func__",
+                    None) is not DeviceAggregateFunction.extract_value:
+                values = [extract(v) for v in values]
+            if self.agg.needs_value:
+                self._pending_values.extend(values)
+            if self.agg.needs_value_hash:
+                hi = self._pending_hi
+                lo = self._pending_lo
+                for v in values:
+                    h = stable_hash64(v)
+                    hi.append(h >> 32)
+                    lo.append(h & 0xFFFFFFFF)
         if len(self._pending_slots) >= self.microbatch:
             self._flush()
 
@@ -344,7 +353,7 @@ class DeviceAggregatingState(AggregatingState):
         n = len(self._pending_slots)
         if n == 0:
             return
-        with self._device_lock:
+        with get_tracer().phase("state.flush", rows=n), self._device_lock:
             self._flush_locked(n)
 
     def _flush_locked(self, n: int) -> None:
@@ -418,40 +427,44 @@ class DeviceAggregatingState(AggregatingState):
         this path exists to amortize).  No slot allocation or eviction
         can happen here, so no chunking is needed.  Returns
         (results, found_mask); namespace semantics as in `add_batch`."""
-        keys = list(keys)
-        n = len(keys)
-        slot_index = self.slot_index
-        host_tier = self.host_tier
-        slots = np.zeros(n, np.int32)
-        found = np.zeros(n, bool)
-        spill_idx: List[int] = []
-        spill_rows: List[Dict[str, np.ndarray]] = []
-        for i, k in enumerate(keys):
-            entry = (k, namespace if namespaces is None else namespaces[i])
-            s = slot_index.get(entry)
-            if s is not None:
-                slots[i] = s
-                found[i] = True
-                # reads stamp the LRU clock exactly as scalar get()
-                self._clock += 1
-                self._access_stamp[s] = self._clock
-                continue
-            row = host_tier.get(entry)
-            if row is not None:
-                spill_idx.append(i)
-                spill_rows.append(row)
-                found[i] = True
+        tracer = get_tracer()
+        with tracer.phase("state.get.lookup"):
+            keys = list(keys)
+            n = len(keys)
+            slot_index = self.slot_index
+            host_tier = self.host_tier
+            slots = np.zeros(n, np.int32)
+            found = np.zeros(n, bool)
+            spill_idx: List[int] = []
+            spill_rows: List[Dict[str, np.ndarray]] = []
+            for i, k in enumerate(keys):
+                entry = (k, namespace if namespaces is None
+                         else namespaces[i])
+                s = slot_index.get(entry)
+                if s is not None:
+                    slots[i] = s
+                    found[i] = True
+                    # reads stamp the LRU clock exactly as scalar get()
+                    self._clock += 1
+                    self._access_stamp[s] = self._clock
+                    continue
+                row = host_tier.get(entry)
+                if row is not None:
+                    spill_idx.append(i)
+                    spill_rows.append(row)
+                    found[i] = True
         self._flush()  # ONE flush for the whole sweep
-        if TELEMETRY.enabled:
-            t0 = _perf_ns()
-            res = np.asarray(self._jit_result(
-                self.device_state, jnp.asarray(slots)))
-            TELEMETRY.record_transfer("d2h", res.nbytes, t0, _perf_ns(),
-                                      "state.fire")
-            TELEMETRY.note_fire_read()
-        else:
-            res = np.asarray(self._jit_result(
-                self.device_state, jnp.asarray(slots)))
+        with tracer.phase("state.get.device"):
+            if TELEMETRY.enabled:
+                t0 = _perf_ns()
+                res = np.asarray(self._jit_result(
+                    self.device_state, jnp.asarray(slots)))
+                TELEMETRY.record_transfer("d2h", res.nbytes, t0,
+                                          _perf_ns(), "state.fire")
+                TELEMETRY.note_fire_read()
+            else:
+                res = np.asarray(self._jit_result(
+                    self.device_state, jnp.asarray(slots)))
         if spill_idx:
             res = np.array(res)  # the gather's output is read-only
             res[spill_idx] = self._finalize_spilled(spill_rows)
@@ -533,27 +546,30 @@ class DeviceAggregatingState(AggregatingState):
         self._free.append(slot)
 
     def clear_batch(self, keys, namespace, namespaces=None) -> None:
+        tracer = get_tracer()
         slots = []
-        for i, k in enumerate(keys):
-            ns = namespace if namespaces is None else namespaces[i]
-            self.host_tier.pop((k, ns), None)
-            s = self.slot_index.pop((k, ns), None)
-            if s is not None:
-                slots.append(s)
-                self.slot_meta[s] = None
+        with tracer.phase("state.clear.slots"):
+            for i, k in enumerate(keys):
+                ns = namespace if namespaces is None else namespaces[i]
+                self.host_tier.pop((k, ns), None)
+                s = self.slot_index.pop((k, ns), None)
+                if s is not None:
+                    slots.append(s)
+                    self.slot_meta[s] = None
         if not slots:
             return
         self._flush()
-        n = len(slots)
-        padded = _round_up_pow2(n)
-        arr = np.full(padded, slots[0], np.int32)
-        arr[:n] = slots
-        with self._device_lock:
-            self.device_state = self._jit_clear(self.device_state,
-                                                jnp.asarray(arr))
-            for s_ in slots:
-                self._slot_flushed[s_] = 0
-        self._free.extend(slots)
+        with tracer.phase("state.clear.device"):
+            n = len(slots)
+            padded = _round_up_pow2(n)
+            arr = np.full(padded, slots[0], np.int32)
+            arr[:n] = slots
+            with self._device_lock:
+                self.device_state = self._jit_clear(self.device_state,
+                                                    jnp.asarray(arr))
+                for s_ in slots:
+                    self._slot_flushed[s_] = 0
+            self._free.extend(slots)
 
     def merge_namespaces(self, target, sources) -> None:
         """Session-window merge: device merge_slots(dst, src), then

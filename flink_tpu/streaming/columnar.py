@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from flink_tpu.ops.device_agg import DeviceAggregateFunction
+from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.streaming.elements import (  # noqa: F401 — RecordBatch
     RecordBatch,   # re-exported: the batch element moved to elements.py
     StreamRecord,  # when it became a first-class StreamElement
@@ -426,6 +427,10 @@ class ColumnarWindowOperator(StreamOperator):
             batch = batch[1]
         if len(batch) == 0:
             return
+        with get_tracer().phase("window.ingest", rows=len(batch)):
+            self._ingest(batch)
+
+    def _ingest(self, batch):
         keys = batch.cols[self.key_col]
         if self.engine is None:
             self.engine = self._make_engine(np.asarray(keys).dtype)
@@ -443,23 +448,26 @@ class ColumnarWindowOperator(StreamOperator):
             col = batch.cols[self.input_col]
             if self.agg.needs_value_hash:
                 from flink_tpu.streaming.vectorized import hash_keys_np
-                value_hashes = hash_keys_np(np.asarray(col))
+                with get_tracer().phase("columnar.ingest.hash"):
+                    value_hashes = hash_keys_np(np.asarray(col))
             if self.agg.needs_value:
                 values = np.asarray(col)
         self.engine.process_batch(keys, batch.ts, values,
                                   value_hashes=value_hashes)
 
     def process_watermark(self, watermark: Watermark):
-        if self.engine is not None:
-            getattr(self.engine, "flush", lambda: None)()
-            self.engine.advance_watermark(watermark.timestamp)
-            if getattr(self.engine, "emit_arrays", False):
-                self._emit_fired()
-            else:
-                self._emit_rows()
-            self.num_late_records_dropped = self.engine.num_late_dropped
-        self.current_watermark = watermark.timestamp
-        self.output.emit_watermark(watermark)
+        with get_tracer().phase("window.watermark",
+                                watermark=watermark.timestamp):
+            if self.engine is not None:
+                getattr(self.engine, "flush", lambda: None)()
+                self.engine.advance_watermark(watermark.timestamp)
+                if getattr(self.engine, "emit_arrays", False):
+                    self._emit_fired()
+                else:
+                    self._emit_rows()
+                self.num_late_records_dropped = self.engine.num_late_dropped
+            self.current_watermark = watermark.timestamp
+            self.output.emit_watermark(watermark)
 
     def _emit_rows(self):
         """Row-delivering engines (e.g. VectorizedSessionWindows):
@@ -480,29 +488,32 @@ class ColumnarWindowOperator(StreamOperator):
         self.output.collect(StreamRecord(out, timestamp=int(ends.max()) - 1))
 
     def _emit_fired(self):
+        tracer = get_tracer()
         fired = self.engine.fired
         for entry in fired:
             keys_np, results, start, end = entry
-            if isinstance(start, np.ndarray):
-                # session engines fire (keys, totals, starts, ends)
-                starts, ends = start, end
-                out_ts = int(ends.max()) - 1 if len(ends) else 0
-            else:
-                starts = np.full(len(keys_np), start, np.int64)
-                ends = np.full(len(keys_np), end, np.int64)
-                out_ts = end - 1
-            cols = {}
-            for name, kind in self.out_fields:
-                if kind == "key":
-                    cols[name] = keys_np
-                elif kind == "agg":
-                    cols[name] = results
-                elif kind == "wstart":
-                    cols[name] = starts
+            with tracer.phase("window.fire.batch", keys=len(keys_np)):
+                if isinstance(start, np.ndarray):
+                    # session engines fire (keys, totals, starts, ends)
+                    starts, ends = start, end
+                    out_ts = int(ends.max()) - 1 if len(ends) else 0
                 else:
-                    cols[name] = ends
-            out = RecordBatch(cols, ends - 1)
-            self.output.collect(StreamRecord(out, timestamp=out_ts))
+                    starts = np.full(len(keys_np), start, np.int64)
+                    ends = np.full(len(keys_np), end, np.int64)
+                    out_ts = end - 1
+                cols = {}
+                for name, kind in self.out_fields:
+                    if kind == "key":
+                        cols[name] = keys_np
+                    elif kind == "agg":
+                        cols[name] = results
+                    elif kind == "wstart":
+                        cols[name] = starts
+                    else:
+                        cols[name] = ends
+                out = RecordBatch(cols, ends - 1)
+            with tracer.phase("window.fire.downstream"):
+                self.output.collect(StreamRecord(out, timestamp=out_ts))
         del fired[:]
 
     # ---- checkpoint -------------------------------------------------

@@ -291,12 +291,8 @@ class DeviceWindowOperator(StreamOperator):
     def _flush_buffer(self):
         if not self._keys:
             return
-        tracer = get_tracer()
-        if tracer.enabled:
-            with tracer.span("device_window.flush",
-                             batch=len(self._keys)):
-                self._flush_buffer_inner()
-        else:
+        with get_tracer().phase("device_window.flush",
+                                batch=len(self._keys)):
             self._flush_buffer_inner()
 
     def _flush_buffer_inner(self):
@@ -368,12 +364,7 @@ class DeviceWindowOperator(StreamOperator):
         self._flush_buffer()
         if self.engine is not None:
             before = len(self.engine.emitted)
-            tracer = get_tracer()
-            if tracer.enabled:
-                with tracer.span("device_window.fire", watermark=wm):
-                    self.engine.advance_watermark(wm)
-                    self._emit_from(before)
-            else:
+            with get_tracer().phase("device_window.fire", watermark=wm):
                 self.engine.advance_watermark(wm)
                 self._emit_from(before)
             self.num_late_records_dropped = self.engine.num_late_dropped

@@ -55,6 +55,7 @@ import numpy as np
 
 import flink_tpu.native as nat
 from flink_tpu.runtime.device_stats import TELEMETRY
+from flink_tpu.runtime.tracing import get_tracer, traced_jit
 
 _perf_ns = time.perf_counter_ns
 from flink_tpu.ops.device_agg import DeviceAggregateFunction, SumAggregate
@@ -259,36 +260,39 @@ class _HllMode:
                 return jnp.where((est <= 2.5 * m) & (zeros > 0),
                                  linear, est)
 
-            self._jit_finish = jax.jit(finish)
+            self._jit_finish = traced_jit(finish, name="log.hll.finish")
+        tracer = get_tracer()
         n_cells, n_keys = len(ranks), len(ends)
-        pc = 1 << max(0, (n_cells - 1)).bit_length()
-        pk = 1 << max(0, (n_keys - 1)).bit_length()
-        ranks_p = np.zeros(pc, np.uint8)
-        ranks_p[:n_cells] = ranks
-        ends_p = np.ones(pk, np.int32)
-        ends_p[:n_keys] = ends
-        # explicit device_put: the H2D starts before the dispatch
-        dev = jax.devices()[0]
-        if TELEMETRY.enabled:
-            t0 = _perf_ns()
-            d_ranks = jax.device_put(ranks_p, dev)
-            d_ends = jax.device_put(ends_p, dev)
-            TELEMETRY.record_transfer(
-                "h2d", ranks_p.nbytes + ends_p.nbytes, t0, _perf_ns(),
-                "log.finish")
-            t1 = _perf_ns()
-            out = np.asarray(self._jit_finish(d_ranks, d_ends,
-                                              np.int32(n_cells),
-                                              np.int32(n_keys)))
-            TELEMETRY.record_transfer("d2h", out.nbytes, t1, _perf_ns(),
-                                      "log.finish")
-            TELEMETRY.note_fire_read()
-        else:
-            out = np.asarray(self._jit_finish(jax.device_put(ranks_p, dev),
-                                              jax.device_put(ends_p, dev),
-                                              np.int32(n_cells),
-                                              np.int32(n_keys)))
-        return out[:n_keys].astype(np.float64)
+        with tracer.phase("log.finish.pad"):
+            pc = 1 << max(0, (n_cells - 1)).bit_length()
+            pk = 1 << max(0, (n_keys - 1)).bit_length()
+            ranks_p = np.zeros(pc, np.uint8)
+            ranks_p[:n_cells] = ranks
+            ends_p = np.ones(pk, np.int32)
+            ends_p[:n_keys] = ends
+        with tracer.phase("log.finish.device"):
+            # explicit device_put: the H2D starts before the dispatch
+            dev = jax.devices()[0]
+            if TELEMETRY.enabled:
+                t0 = _perf_ns()
+                d_ranks = jax.device_put(ranks_p, dev)
+                d_ends = jax.device_put(ends_p, dev)
+                TELEMETRY.record_transfer(
+                    "h2d", ranks_p.nbytes + ends_p.nbytes, t0, _perf_ns(),
+                    "log.finish")
+                t1 = _perf_ns()
+                out = np.asarray(self._jit_finish(d_ranks, d_ends,
+                                                  np.int32(n_cells),
+                                                  np.int32(n_keys)))
+                TELEMETRY.record_transfer("d2h", out.nbytes, t1, _perf_ns(),
+                                          "log.finish")
+                TELEMETRY.note_fire_read()
+            else:
+                out = np.asarray(self._jit_finish(
+                    jax.device_put(ranks_p, dev),
+                    jax.device_put(ends_p, dev),
+                    np.int32(n_cells), np.int32(n_keys)))
+            return out[:n_keys].astype(np.float64)
 
 
 class _SumMode:
@@ -465,6 +469,10 @@ class LogStructuredTumblingWindows:
     # ---- ingestion --------------------------------------------------
     def process_batch(self, keys, timestamps, values=None,
                       key_hashes=None, value_hashes=None) -> None:
+        with get_tracer().phase("log.append"):
+            self._append_batch(keys, timestamps, values, value_hashes)
+
+    def _append_batch(self, keys, timestamps, values, value_hashes) -> None:
         ts = np.asarray(timestamps, np.int64)
         keys = _as_u64_keys(self, keys)
         starts = ts - np.mod(ts, self.size)
@@ -509,7 +517,8 @@ class LogStructuredTumblingWindows:
             log = self.windows.pop(start)
             if log.count == 0:
                 continue
-            keys, cols = log.concat()
+            with get_tracer().phase("log.concat"):
+                keys, cols = log.concat()
             fired += self._fire_window(keys, cols, start, start + self.size)
         if TELEMETRY.enabled:
             TELEMETRY.note_windows_fired(fired)

@@ -18,7 +18,6 @@ the Evictor before/after the window function
 from __future__ import annotations
 
 import abc
-import contextlib
 from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -453,21 +452,23 @@ class WindowOperator(AbstractUdfStreamOperator):
         n = len(batch)
         if n == 0:
             return
-        reason = self._batch_demote_reason
-        if reason is None and (
-                batch.ts is None
-                or (batch.ts_mask is not None and not batch.ts_mask.all())):
-            reason = "rows without event timestamps"
-        if reason is None and self.key_selector is None:
-            reason = "no key selector bound"
-        if reason is not None:
-            self._note_boxed(n, reason)
-            for record in batch.to_records():
-                self.set_key_context(record)
-                self.process_element(record)
-            return
-        self._process_batch_vectorized(batch, n)
-        self._note_columnar(n)
+        with get_tracer().phase("window.ingest", rows=n):
+            reason = self._batch_demote_reason
+            if reason is None and (
+                    batch.ts is None
+                    or (batch.ts_mask is not None
+                        and not batch.ts_mask.all())):
+                reason = "rows without event timestamps"
+            if reason is None and self.key_selector is None:
+                reason = "no key selector bound"
+            if reason is not None:
+                self._note_boxed(n, reason)
+                for record in batch.to_records():
+                    self.set_key_context(record)
+                    self.process_element(record)
+                return
+            self._process_batch_vectorized(batch, n)
+            self._note_columnar(n)
 
     def process_batch_fused(self, batch, last_start=None) -> None:
         """Ingest a batch whose first-pane starts were already computed
@@ -486,79 +487,86 @@ class WindowOperator(AbstractUdfStreamOperator):
                 or self.key_selector is None):
             self.process_batch(batch)
             return
-        self._process_batch_vectorized(batch, n, last_start=last_start)
-        self._note_fused(n)
+        with get_tracer().phase("window.ingest", rows=n):
+            self._process_batch_vectorized(batch, n, last_start=last_start)
+            self._note_fused(n)
 
     def _process_batch_vectorized(self, batch, n: int,
                                   last_start=None) -> None:
+        tracer = get_tracer()
         ts = np.asarray(batch.ts, np.int64)
-        values = batch.row_values()
-        keys = self._batch_keys(batch, values)
-        wm = self.timer_service.current_watermark
-        assigner = self.assigner
-        size = assigner.size
-        slide = getattr(assigner, "slide", size)
-        offset = assigner.offset
+        with tracer.phase("window.ingest.box"):
+            values = batch.row_values()
+            keys = self._batch_keys(batch, values)
+        size = self.assigner.size
         lateness = self.allowed_lateness
         state = self.window_state
         backend = self.keyed_backend
-        # value column for device states: the aggregate's extract is
-        # identity, so the raw column feeds the scatter directly
-        vcol = None
-        agg = getattr(state, "agg", None)
-        if agg is not None and hasattr(agg, "extract_column"):
-            c = agg.extract_column(batch.value_arrays())
-            if isinstance(c, np.ndarray) and c.ndim == 1 and len(c) == n:
-                vcol = c
-        if last_start is None:
-            last_start = ts - ((ts - offset) % slide)
-        else:
-            last_start = np.asarray(last_start, np.int64)
-        npanes = -(-size // slide)  # ceil; 1 for tumbling
-        assigned = np.zeros(n, bool)
-        immediate = np.zeros(n, bool)
-        idx_parts = []
-        start_parts = []
-        for p in range(npanes):
-            starts = last_start - p * slide
-            maxts = starts + (size - 1)
-            live = starts > (ts - size)
-            window_late = (maxts + lateness) <= wm
-            ok = live & ~window_late
-            if not ok.any():
-                continue
-            assigned |= ok
-            fire_now = ok & (maxts <= wm)
-            immediate |= fire_now
-            vi = np.nonzero(ok & ~fire_now)[0]
-            if vi.size:
-                idx_parts.append(vi)
-                start_parts.append(starts[vi])
-        if idx_parts:
-            all_idx = np.concatenate(idx_parts)
-            all_starts = np.concatenate(start_parts)
-            # group by window; WITHIN a window restore row order —
-            # different rows reach the same sliding window at different
-            # pane indexes, and both the state fold order and
-            # same-timestamp timer order must match the scalar path's
-            # row-major traversal
-            order = np.lexsort((all_idx, all_starts))
-            sidx = all_idx[order]
-            sstarts = all_starts[order]
-            bounds = np.nonzero(np.diff(sstarts))[0] + 1
-            lo = 0
-            for hi in [*bounds.tolist(), len(sidx)]:
-                gidx = sidx[lo:hi]
-                start = int(sstarts[lo])
-                lo = hi
-                ns = (start, start + size)
-                gkeys = [keys[i] for i in gidx]
-                if vcol is not None:
-                    backend.add_batch(state, gkeys, ns, vcol[gidx],
-                                      pre_extracted=True)
-                else:
-                    backend.add_batch(state, gkeys, ns,
-                                      [values[i] for i in gidx])
+        with tracer.phase("window.ingest.assign"):
+            wm = self.timer_service.current_watermark
+            slide = getattr(self.assigner, "slide", size)
+            offset = self.assigner.offset
+            # value column for device states: the aggregate's extract
+            # is identity, so the raw column feeds the scatter directly
+            vcol = None
+            agg = getattr(state, "agg", None)
+            if agg is not None and hasattr(agg, "extract_column"):
+                c = agg.extract_column(batch.value_arrays())
+                if isinstance(c, np.ndarray) and c.ndim == 1 and len(c) == n:
+                    vcol = c
+            if last_start is None:
+                last_start = ts - ((ts - offset) % slide)
+            else:
+                last_start = np.asarray(last_start, np.int64)
+            npanes = -(-size // slide)  # ceil; 1 for tumbling
+            assigned = np.zeros(n, bool)
+            immediate = np.zeros(n, bool)
+            idx_parts = []
+            start_parts = []
+            for p in range(npanes):
+                starts = last_start - p * slide
+                maxts = starts + (size - 1)
+                live = starts > (ts - size)
+                window_late = (maxts + lateness) <= wm
+                ok = live & ~window_late
+                if not ok.any():
+                    continue
+                assigned |= ok
+                fire_now = ok & (maxts <= wm)
+                immediate |= fire_now
+                vi = np.nonzero(ok & ~fire_now)[0]
+                if vi.size:
+                    idx_parts.append(vi)
+                    start_parts.append(starts[vi])
+            #: (window start, row indexes, their keys) per touched window
+            groups = []
+            if idx_parts:
+                all_idx = np.concatenate(idx_parts)
+                all_starts = np.concatenate(start_parts)
+                # group by window; WITHIN a window restore row order —
+                # different rows reach the same sliding window at
+                # different pane indexes, and both the state fold order
+                # and same-timestamp timer order must match the scalar
+                # path's row-major traversal
+                order = np.lexsort((all_idx, all_starts))
+                sidx = all_idx[order]
+                sstarts = all_starts[order]
+                bounds = np.nonzero(np.diff(sstarts))[0] + 1
+                lo = 0
+                for hi in [*bounds.tolist(), len(sidx)]:
+                    gidx = sidx[lo:hi]
+                    groups.append((int(sstarts[lo]), gidx,
+                                   [keys[i] for i in gidx]))
+                    lo = hi
+        for start, gidx, gkeys in groups:
+            ns = (start, start + size)
+            if vcol is not None:
+                backend.add_batch(state, gkeys, ns, vcol[gidx],
+                                  pre_extracted=True)
+            else:
+                backend.add_batch(state, gkeys, ns,
+                                  [values[i] for i in gidx])
+            with tracer.phase("timers.register"):
                 # first-occurrence order, NOT a set: same-timestamp
                 # timers fire in registration order, and the scalar
                 # path registers them in row order
@@ -743,14 +751,16 @@ class WindowOperator(AbstractUdfStreamOperator):
         sweep; everything else (merging assigners, custom triggers,
         evictors, processing-time assigners) keeps the per-timer drain
         in advance_watermark."""
-        if (self.timer_service is None or not self.batch_fires
-                or getattr(self, "_batch_demote_reason", "unopened")
-                is not None):
-            super().process_watermark(watermark)
-            return
-        self.current_watermark = watermark.timestamp
-        self.on_watermark_batch(watermark.timestamp)
-        self.output.emit_watermark(watermark)
+        with get_tracer().phase("window.watermark",
+                                watermark=watermark.timestamp):
+            if (self.timer_service is None or not self.batch_fires
+                    or getattr(self, "_batch_demote_reason", "unopened")
+                    is not None):
+                super().process_watermark(watermark)
+                return
+            self.current_watermark = watermark.timestamp
+            self.on_watermark_batch(watermark.timestamp)
+            self.output.emit_watermark(watermark)
 
     def on_watermark_batch(self, watermark: int) -> None:
         """Columnar fire: ONE timer sweep → vectorized
@@ -770,13 +780,15 @@ class WindowOperator(AbstractUdfStreamOperator):
         differential suite (tests/test_fire_batch.py) pins the two
         paths bit-equal."""
         svc = self.timer_service
-        ts_col, key_col, ns_col = svc.pop_due_event_time_timers(watermark)
-        n = len(ts_col)
-        if n == 0:
-            return
         lateness = self.allowed_lateness
-        tarr = np.fromiter(ts_col, np.int64, n)
-        maxts = np.fromiter((ns[1] for ns in ns_col), np.int64, n) - 1
+        with get_tracer().phase("timers.sweep"):
+            ts_col, key_col, ns_col = svc.pop_due_event_time_timers(
+                watermark)
+            n = len(ts_col)
+            if n == 0:
+                return
+            tarr = np.fromiter(ts_col, np.int64, n)
+            maxts = np.fromiter((ns[1] for ns in ns_col), np.int64, n) - 1
         # EventTimeTrigger.on_event_time: FIRE iff time == maxTimestamp
         fire = tarr == maxts
         if lateness == 0:
@@ -835,10 +847,8 @@ class WindowOperator(AbstractUdfStreamOperator):
         buf = _FireBufferOutput(self.output)
         collector = TimestampedCollector(buf)
         tracer = get_tracer()
-        span = (tracer.span("window.fire.batch") if tracer.enabled
-                else contextlib.nullcontext())
         fired = 0
-        with span:
+        with tracer.phase("window.fire.batch", keys=len(rows)):
             for j, i in enumerate(rows):
                 if not found_mask[j]:
                     continue
@@ -864,15 +874,17 @@ class WindowOperator(AbstractUdfStreamOperator):
         if len(records) > 1:
             from flink_tpu.streaming import columnar
             if columnar.PIPELINE_ENABLED:
-                batch = columnar.batch_from_records(
-                    [r.value for r in records],
-                    [r.timestamp for r in records])
-        if batch is not None:
-            self.output.collect_batch(batch)
-        else:
-            collect = self.output.collect
-            for r in records:
-                collect(r)
+                with tracer.phase("window.fire.columnarize"):
+                    batch = columnar.batch_from_records(
+                        [r.value for r in records],
+                        [r.timestamp for r in records])
+        with tracer.phase("window.fire.downstream"):
+            if batch is not None:
+                self.output.collect_batch(batch)
+            else:
+                collect = self.output.collect
+                for r in records:
+                    collect(r)
         return fired
 
     # ---- helpers ----------------------------------------------------

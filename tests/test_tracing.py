@@ -28,6 +28,7 @@ def _tracer_off_after():
 
 
 from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
+from flink_tpu.ops.sketches import HyperLogLogAggregate  # noqa: E402
 
 
 class TupleSum(SumAggregate):
@@ -450,3 +451,219 @@ def test_minicluster_latency_markers_smoke():
     h = next(iter(lat.values()))
     assert h["count"] >= 1
     assert h["p99"] >= 0
+
+
+# ---------------------------------------------------------------------
+# phases: always-on batch-level spans, also profiler annotations
+# ---------------------------------------------------------------------
+
+def test_phase_feeds_stats_with_the_tracer_off_and_the_ring_only_when_on():
+    tr = Tracer()
+    assert not tr.enabled
+    with tr.phase("batch.step", rows=7):
+        pass
+    assert tr.recent() == []
+    assert tr.stats()["batch.step"]["count"] == 1
+    tr.enabled = True
+    with tr.phase("batch.step", rows=9):
+        pass
+    assert tr.stats()["batch.step"]["count"] == 2
+    [event] = tr.recent()
+    assert event["name"] == "batch.step" and event["ph"] == "X"
+    assert event["args"] == {"rows": 9}
+
+
+def test_nested_phases_give_parent_and_self_time():
+    tr = Tracer()
+    with tr.phase("fire"):
+        time.sleep(0.02)
+        with tr.phase("fire.emit"):
+            time.sleep(0.01)
+    stats = tr.stats()
+    assert stats["fire"]["self_ms"] == pytest.approx(
+        stats["fire"]["total_ms"] - stats["fire.emit"]["total_ms"], abs=1.0)
+    assert stats["fire.emit"]["self_ms"] == pytest.approx(
+        stats["fire.emit"]["total_ms"], abs=0.5)
+    assert stats["fire"]["total_ms"] >= 28
+    # a gated span nests under a phase like under any span
+    tr.enabled = True
+    with tr.phase("fire"):
+        with tr.span("record"):
+            pass
+    assert [e.get("parent") for e in tr.recent()] == ["fire", None]
+
+
+def test_phase_is_a_profiler_annotation_under_the_one_prefix(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with Tracer().phase("window.ingest", rows=3):
+            with tracing.phase_annotation("native.some_kernel"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith(tracing.PHASE_PREFIX)]
+    by_name = {e.name: e for e in events}
+    assert set(by_name) == {"flink/window.ingest",
+                            "flink/native.some_kernel"}
+    assert dict(by_name["flink/window.ingest"].stats)["rows"] == 3
+
+
+def test_inert_phase_costs_under_5_microseconds():
+    tr = Tracer()
+    n = 100_000
+    best = float("inf")
+    for _ in range(5):  # the best of five damps a busy host
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with tr.phase("x"):
+                pass
+        best = min(best, time.perf_counter() - t0)
+    assert best / n < 5e-6, f"{best / n * 1e6:.2f} us per inert phase"
+
+
+def test_traced_jit_names_the_program_after_its_label():
+    import jax.numpy as jnp
+    f = tracing.traced_jit(lambda st, idx: st[idx], name="test.gather")
+    lowered = f._jitted.lower(jnp.ones(8), jnp.arange(3))
+    text = lowered.as_text(debug_info=True)
+    assert "jit_test_gather" in text
+    assert "test.gather" in text  # the named_scope around the body
+
+
+class UserHll(HyperLogLogAggregate):
+    """COUNT DISTINCT over field 1 of a (key, user) row."""
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1]
+
+
+def _phase_data(rows, batches=8, per_window=4):
+    """``batches`` batches of ``rows`` rows; batch b lies wholly in
+    the 1 s window b // per_window, so the batches, the windows a batch
+    touches and the fires are the same for every ``rows``."""
+    rng = np.random.default_rng(5)
+    n = rows * batches
+    keys = rng.integers(0, rows * 2, n)
+    users = rng.integers(0, 1 << 30, n)
+    ts = (np.arange(n) // rows // per_window) * 1000 + np.sort(
+        rng.integers(0, 1000, (batches, rows)), axis=1).reshape(-1)
+    return keys, users, ts
+
+
+def _state_backend_job(rows, env=None):
+    from flink_tpu.streaming.columnar import VectorizedCollectionSource
+    keys, users, ts = _phase_data(rows)
+    values = [((int(k), int(u)), int(t))
+              for k, u, t in zip(keys, users, ts)]
+    env = env or StreamExecutionEnvironment()
+    env.set_state_backend("tpu")
+    sink = CollectSink()
+    windowed = (env.add_source(VectorizedCollectionSource(
+        values, timestamped=True, chunk=rows))
+        .key_by(0).window(TumblingEventTimeWindows.of(Time.seconds(1))))
+    windowed.disable_device_operator()
+    windowed.aggregate(
+        UserHll(8), window_function=lambda k, w, v:
+        [(k, w.start, float(v[0]))]).add_sink(sink)
+    env.execute("phases-state")
+    return len(sink.values)
+
+
+def _sql_tumble_job(rows):
+    from flink_tpu.streaming.columnar import ColumnarCollectSink
+    from flink_tpu.table import StreamTableEnvironment
+    keys, users, ts = _phase_data(rows)
+    env = StreamExecutionEnvironment()
+    t_env = StreamTableEnvironment.create(env)
+    t_env.register_table("ev", t_env.from_columns(
+        {"k": keys, "u": users.astype(np.uint64), "ts": ts},
+        rowtime="ts", chunk=rows))
+    out = t_env.sql_query(
+        "SELECT k, APPROX_COUNT_DISTINCT(u) AS d FROM ev "
+        "GROUP BY TUMBLE(ts, INTERVAL '1' SECOND), k")
+    sink = ColumnarCollectSink()
+    out.to_append_stream(batched=True).add_sink(sink)
+    env.execute("phases-sql")
+
+
+STATE_ROUTE_PHASES = {
+    "window.ingest": 8, "window.ingest.box": 8, "window.ingest.assign": 8,
+    "state.add.slots": 8, "state.add.hash": 8, "timers.register": 8,
+    "window.watermark": 1, "timers.sweep": 1, "state.get.lookup": 1,
+    "state.flush": 1, "state.get.device": 1, "window.fire.batch": 1,
+    "window.fire.columnarize": 1, "window.fire.downstream": 1,
+    "state.clear.slots": 1, "state.clear.device": 1}
+SQL_ROUTE_PHASES = {
+    "window.ingest": 8, "columnar.ingest.hash": 8, "log.append": 8,
+    "window.watermark": 10, "log.concat": 2, "log.finish.pad": 2,
+    "log.finish.device": 2, "window.fire.batch": 2,
+    "window.fire.downstream": 2}
+
+
+@pytest.mark.parametrize("job, expected", [
+    (_state_backend_job, STATE_ROUTE_PHASES),
+    (_sql_tumble_job, SQL_ROUTE_PHASES)], ids=["state_backend", "sql"])
+def test_phase_counts_follow_batches_and_fires_never_rows(
+        job, expected, monkeypatch):
+    """The guard against a span per record, key or timer: exactly the
+    documented phase names, and the same number of each when every
+    batch carries four times the rows and every fire four times the
+    keys.  (The finish tier is the device's, as on the chip; here the
+    link probe would pick the host.)"""
+    import flink_tpu.native as nat
+    from flink_tpu.ops import link_probe
+    if not nat.available():
+        pytest.skip("native runtime unavailable")
+    monkeypatch.setattr(link_probe, "recommended_finish_tier",
+                        lambda override=None: "device")
+    tr = get_tracer()
+    assert not tr.enabled
+    for rows in (64, 256):
+        tr.reset()
+        job(rows)
+        counts = {name: s["count"] for name, s in tr.stats().items()}
+        assert counts == expected, rows
+
+
+def test_a_compile_is_booked_on_the_phase_and_the_label_that_needed_it():
+    tracing.reset_jit_stats()
+    tr = get_tracer()
+    tr.reset()
+    before = tracing.backend_compile_totals()
+    fired_keys = _state_backend_job(96)
+    stats = tr.stats()["state.get.device"]
+    assert stats["compiles"] == 1 and stats["compile_ms"] > 0
+    assert stats["self_ms"] <= stats["total_ms"] - stats["compile_ms"] + 1e-6
+    result = tracing.jit_stats()["state.result"]
+    assert result["recompiles"] == 1
+    assert result["last_shape_sig"].endswith(f"int32[{fired_keys}])")
+    assert tr.stats()["state.flush"]["compiles"] == 1
+    assert tracing.jit_stats()["state.update"]["recompiles"] == 1
+    # the process-wide count holds every compile some phase saw
+    after = tracing.backend_compile_totals()
+    seen = sum(s["compiles"] for s in tr.stats().values())
+    assert after["compiles"] - before["compiles"] >= seen >= 3
+
+
+def test_dispatch_spans_a_watermark_when_the_tracer_is_on():
+    env = StreamExecutionEnvironment()
+    env.enable_tracing()
+    assert _state_backend_job(64, env)
+    watermarks = [e for e in env.get_tracer().recent(10_000)
+                  if e["name"] == "window.watermark"]
+    assert watermarks
+    for e in watermarks:
+        assert e["parent"].startswith("op.")
+        assert e["parent"].endswith(".process")
