@@ -604,6 +604,8 @@ def register_state_gauges(metrics: MetricRegistry) -> None:
     g.gauge("flushRows", lambda: s.flush_rows)
     g.gauge("flushSizeMean", lambda: s.flush_size_mean())
     g.gauge("flushSizeMax", lambda: s.flush_size_max())
+    g.gauge("resultRows", lambda: s.result_rows)
+    g.gauge("resultPaddedRows", lambda: s.result_padded_rows)
     g.gauge("snapshotColumns", lambda: s.snapshot_columns)
     g.gauge("snapshotRows", lambda: s.snapshot_rows)
 

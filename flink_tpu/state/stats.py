@@ -21,7 +21,8 @@ class StateStats:
     __slots__ = (
         "batch_rows", "row_fallback_rows", "batch_calls",
         "row_fallback_calls", "flush_batches", "flush_rows",
-        "flush_sizes", "snapshot_columns", "snapshot_rows",
+        "flush_sizes", "result_rows", "result_padded_rows",
+        "snapshot_columns", "snapshot_rows",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
     )
@@ -41,6 +42,10 @@ class StateStats:
         self.flush_rows = 0
         #: recent flush batch sizes (for mean/max gauges)
         self.flush_sizes = deque(maxlen=512)
+        #: rows batched fire reads asked `state.result` for, and rows it
+        #: was dispatched at (each read padded to a shape bucket)
+        self.result_rows = 0
+        self.result_padded_rows = 0
         #: snapshot rows serialized as columns vs boxed per-row
         self.snapshot_columns = 0
         self.snapshot_rows = 0
@@ -76,6 +81,10 @@ class StateStats:
         self.flush_batches += 1
         self.flush_rows += n
         self.flush_sizes.append(n)
+
+    def note_result(self, n: int, padded: int) -> None:
+        self.result_rows += n
+        self.result_padded_rows += padded
 
     def flush_size_mean(self) -> float:
         sizes = self.flush_sizes

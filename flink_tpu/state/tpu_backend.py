@@ -64,7 +64,7 @@ from flink_tpu.state.heap_backend import (
     StateTable,
     split_column_by_key_group,
 )
-from flink_tpu.runtime.device_stats import TELEMETRY
+from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
 from flink_tpu.runtime.tracing import get_tracer, traced_jit
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
@@ -72,10 +72,24 @@ _perf_ns = time.perf_counter_ns
 
 DEFAULT_INITIAL_CAPACITY = 4096
 DEFAULT_MICROBATCH = 16384
+#: scratch one `state.result` dispatch may materialise beside the
+#: state: XLA writes the gathered [rows, *slot_shape] out before it
+#: reduces it, so the fire's tile is sized by bytes, not by slots (as
+#: vectorized.py sizes FIRE_TILE: 2^16 slots for HLL p12)
+RESULT_SCRATCH_BYTES = 256 << 20
 
 
 def _round_up_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
+
+
+def _pad_slots(slots, width: int) -> np.ndarray:
+    """`slots` as int32[width]; pad entries repeat slots[0], a valid
+    slot whose row the caller cuts off (get_batch) or clears again
+    (clear_batch)."""
+    arr = np.full(width, slots[0], np.int32)
+    arr[:len(slots)] = slots
+    return arr
 
 
 class DeviceAggregatingState(AggregatingState):
@@ -419,8 +433,8 @@ class DeviceAggregatingState(AggregatingState):
 
     def get_batch(self, keys, namespace, namespaces=None) -> Tuple[np.ndarray, np.ndarray]:
         """Gather results for many (key, namespace) pairs in ONE device
-        round-trip: one pending-ring flush, one fused jit gather, one
-        D2H per component — the batched window-fire read.  Spill-tier
+        round-trip: one pending-ring flush, one fused jit gather per
+        tile of slots, one wait — the batched window-fire read.  Spill-tier
         rows are finalized from their host-resident accumulators
         WITHOUT promotion (a fire is a read; lifting cold rows into
         HBM per fired window would re-pay the per-row transfer tax
@@ -454,21 +468,38 @@ class DeviceAggregatingState(AggregatingState):
                     spill_rows.append(row)
                     found[i] = True
         self._flush()  # ONE flush for the whole sweep
-        with tracer.phase("state.get.device"):
+        if n == 0:  # nothing to gather, and no program for int32[0]
+            none = jax.eval_shape(self.agg.result, self.device_state,
+                                  jax.ShapeDtypeStruct((0,), jnp.int32))
+            return np.zeros(none.shape, none.dtype), found
+        # `state.result` runs only at shapes that do not follow the
+        # data: a power of two up to the tile, above it that one shape
+        # again for every tile, so a fire of any size finds its program
+        width = min(_round_up_pow2(n), self._result_tile())
+        padded = -(-n // width) * width
+        with tracer.phase("state.get.device", keys=n, padded=padded):
+            t0 = _perf_ns()
+            arr = _pad_slots(slots, padded)
+            state = self.device_state
+            # every tile is dispatched before the first is waited for
+            parts = [self._jit_result(state, jnp.asarray(arr[i:i + width]))
+                     for i in range(0, padded, width)]
+            res = np.concatenate([np.asarray(p) for p in parts])[:n]
             if TELEMETRY.enabled:
-                t0 = _perf_ns()
-                res = np.asarray(self._jit_result(
-                    self.device_state, jnp.asarray(slots)))
                 TELEMETRY.record_transfer("d2h", res.nbytes, t0,
                                           _perf_ns(), "state.fire")
                 TELEMETRY.note_fire_read()
-            else:
-                res = np.asarray(self._jit_result(
-                    self.device_state, jnp.asarray(slots)))
+        STATE_STATS.note_result(n, padded)
         if spill_idx:
-            res = np.array(res)  # the gather's output is read-only
             res[spill_idx] = self._finalize_spilled(spill_rows)
         return res, found
+
+    def _result_tile(self) -> int:
+        """Most rows one `state.result` dispatch gathers: the power of
+        two whose [rows, *slot_shape] fits RESULT_SCRATCH_BYTES."""
+        bytes_per_slot = tree_nbytes(self.device_state) // self.capacity
+        return 1 << max(
+            0, (RESULT_SCRATCH_BYTES // bytes_per_slot).bit_length() - 1)
 
     def _finalize_spilled(self, rows: List[Dict[str, np.ndarray]]) -> np.ndarray:
         """Result extraction for spill-tier rows without promotion:
@@ -560,10 +591,7 @@ class DeviceAggregatingState(AggregatingState):
             return
         self._flush()
         with tracer.phase("state.clear.device"):
-            n = len(slots)
-            padded = _round_up_pow2(n)
-            arr = np.full(padded, slots[0], np.int32)
-            arr[:n] = slots
+            arr = _pad_slots(slots, _round_up_pow2(len(slots)))
             with self._device_lock:
                 self.device_state = self._jit_clear(self.device_state,
                                                     jnp.asarray(arr))

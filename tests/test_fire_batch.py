@@ -62,7 +62,7 @@ def _chunks():
 
 
 def _run(kind, lateness, backend, batch_fires, snapshot_at=None,
-         ingest="batch", state="agg"):
+         ingest="batch", state="agg", chunks=None):
     if state == "agg":
         descriptor = AggregatingStateDescriptor("fire-sum", _KVSum())
 
@@ -87,7 +87,7 @@ def _run(kind, lateness, backend, batch_fires, snapshot_at=None,
 
     h = fresh()
     out = []
-    for keys, vals, ts, wm in _chunks():
+    for keys, vals, ts, wm in chunks or _chunks():
         if ingest == "batch":
             h.process_batch(RecordBatch({"f0": keys, "f1": vals}, ts=ts))
         else:
@@ -157,6 +157,29 @@ def test_batch_fire_list_state(backend):
                    state="list")
     assert scalar
     assert batched == scalar
+
+
+def test_batch_fires_of_three_key_counts_compile_one_result_program():
+    """Three consecutive windows fire 9, 12 and 15 keys, one watermark
+    each: the batched fire stays bit-equal to the scalar drain and
+    finds the program of its bucket (16 slots) from the second fire
+    on, where a program per key count compiled three times."""
+    from flink_tpu.runtime import tracing
+    rng = np.random.default_rng(3)
+    chunks = []
+    for window, n_keys in enumerate((9, 12, 15)):
+        keys = np.repeat(np.arange(n_keys), 3)
+        chunks.append((keys, rng.integers(0, 50, keys.size).astype(float),
+                       window * 100 + rng.integers(0, 100, keys.size),
+                       window * 100 + 99))
+    scalar = _run("tumbling", 0, "tpu", batch_fires=False, chunks=chunks)
+    tracing.reset_jit_stats()
+    batched = _run("tumbling", 0, "tpu", batch_fires=True, chunks=chunks)
+    assert len(scalar) == 9 + 12 + 15
+    assert batched == scalar
+    result = tracing.jit_stats()["state.result"]
+    assert result["recompiles"] == 1 and result["cache_hits"] == 2
+    assert result["last_shape_sig"].endswith("int32[16])")
 
 
 class _SpyOutput(Output):

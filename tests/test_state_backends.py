@@ -22,17 +22,29 @@ from flink_tpu.core.state import (
     ReducingStateDescriptor,
     ValueStateDescriptor,
 )
-from flink_tpu.ops.device_agg import CountAggregate, SumAggregate
-from flink_tpu.ops.sketches import HyperLogLogAggregate
+from flink_tpu.ops.device_agg import (
+    AvgAggregate,
+    CountAggregate,
+    MinAggregate,
+    SumAggregate,
+)
+from flink_tpu.ops.sketches import (
+    CountMinSketchAggregate,
+    HyperLogLogAggregate,
+    QuantileSketchAggregate,
+)
+from flink_tpu.runtime import tracing
 from flink_tpu.state import (
     HeapKeyedStateBackend,
     TpuKeyedStateBackend,
     load_state_backend,
 )
+from flink_tpu.state import tpu_backend
 from flink_tpu.state.operator_state import (
     OperatorStateBackend,
     OperatorStateSnapshot,
 )
+from flink_tpu.state.stats import STATE_STATS
 
 MAX_PAR = 128
 FULL_RANGE = KeyGroupRange(0, MAX_PAR - 1)
@@ -359,6 +371,113 @@ def test_tpu_get_batch():
     res, found = st.get_batch(keys + ["missing"], "w")
     assert found[:10].all() and not found[10]
     np.testing.assert_allclose(res[:10], np.arange(10, dtype=np.float32))
+
+
+# `state.result` runs only at bucketed shapes: a power of two up to one
+# tile, whole tiles above it.  The tile is RESULT_SCRATCH_BYTES over
+# the state's bytes per slot; the tests shrink the budget to 16 slots.
+RESULT_TILE = 16
+RESULT_AGGS = {
+    "sum": lambda: SumAggregate(np.float32),
+    "min": lambda: MinAggregate(np.float32),
+    "avg": AvgAggregate,
+    "hll_p12": lambda: HyperLogLogAggregate(precision=12),
+    "countmin": lambda: CountMinSketchAggregate(depth=2, width=64),
+    "quantile": lambda: QuantileSketchAggregate((0.5, 0.99)),
+}
+
+
+def _small_tile_state(agg, monkeypatch, **backend_kw):
+    backend = TpuKeyedStateBackend(FULL_RANGE, MAX_PAR, **backend_kw)
+    st = backend.get_or_create_keyed_state(
+        AggregatingStateDescriptor("bucketed", agg))
+    bytes_per_slot = sum(spec.dtype.itemsize * int(np.prod(spec.shape))
+                         for spec in agg.state_specs().values())
+    monkeypatch.setattr(tpu_backend, "RESULT_SCRATCH_BYTES",
+                        RESULT_TILE * bytes_per_slot)
+    assert st._result_tile() == RESULT_TILE
+    return backend, st
+
+
+@pytest.mark.parametrize("n, padded", [
+    (1, 1), (7, 8), (8, 8), (9, 16), (RESULT_TILE, RESULT_TILE),
+    (RESULT_TILE + 1, 2 * RESULT_TILE),
+    (2 * RESULT_TILE + 3, 3 * RESULT_TILE)])
+@pytest.mark.parametrize("agg", sorted(RESULT_AGGS))
+def test_tpu_get_batch_is_scalar_get_at_every_bucket(
+        agg, n, padded, monkeypatch):
+    """Resident rows, rows in the host spill tier and missing keys in
+    one call: every row is the scalar get() of its key bit for bit,
+    whatever the gather was padded to."""
+    backend, st = _small_tile_state(
+        RESULT_AGGS[agg](), monkeypatch, initial_capacity=64,
+        microbatch=4, max_device_slots=64)
+    keys = list(range(n))
+    present = [k for k in keys if k % 5 != 3]
+    rng = np.random.default_rng(n)
+    rows = np.repeat(present, rng.integers(1, 5, len(present)))
+    st.add_batch(rows.tolist(), "w",
+                 rng.integers(1, 1000, len(rows)).astype(np.float32))
+    # age every slot past the LRU's protected window, then spill the
+    # coldest third of the keys to the host tier
+    st._clock += 1000
+    if len(present) >= 3:
+        st._evict_cold(len(present) // 3)
+    assert len(st.host_tier) == len(present) // 3
+    before = (STATE_STATS.result_rows, STATE_STATS.result_padded_rows)
+    res, found = st.get_batch(keys, "w")
+    assert (STATE_STATS.result_rows - before[0],
+            STATE_STATS.result_padded_rows - before[1]) == (n, padded)
+    assert len(res) == n
+    assert found.tolist() == [k % 5 != 3 for k in keys]
+    st.set_current_namespace("w")
+    for k in keys:
+        backend.set_current_key(k)
+        scalar = st.get()  # promotes a spilled row: after the batch read
+        if k % 5 == 3:
+            assert scalar is None
+        else:
+            assert np.asarray(scalar, res.dtype).tobytes() == \
+                res[k].tobytes(), (agg, n, k)
+
+
+def test_tpu_get_batch_of_no_keys_dispatches_nothing():
+    tracing.reset_jit_stats()
+    backend = make_backend("tpu")
+    st = backend.get_or_create_keyed_state(AggregatingStateDescriptor(
+        "empty", QuantileSketchAggregate((0.5, 0.99))))
+    res, found = st.get_batch([], "w")
+    assert res.shape == (0, 2) and res.dtype == np.float32
+    assert found.shape == (0,)
+    result = tracing.jit_stats()["state.result"]
+    assert result["recompiles"] == 0 and result["cache_hits"] == 0
+
+
+def test_tpu_fires_inside_one_bucket_share_one_program(monkeypatch):
+    """Five fires of five key counts, below and above the tile, compile
+    `state.result` once; the phase says what each was padded to."""
+    tracing.reset_jit_stats()
+    backend, st = _small_tile_state(SumAggregate(np.float32), monkeypatch)
+    old = tracing.get_tracer()
+    tr = tracing.set_tracer(tracing.Tracer())
+    tr.enabled = True
+    try:
+        for window, n in enumerate((9, 13, 16, 29, 45)):
+            keys = list(range(n))
+            st.add_batch(keys, window, np.ones(n, np.float32))
+            res, found = st.get_batch(keys, window)
+            assert found.all() and (res == 1.0).all()
+            st.clear_batch(keys, window)
+        phases = [e["args"] for e in tr.recent(1000)
+                  if e["name"] == "state.get.device"]
+    finally:
+        tracing.set_tracer(old)
+    assert [(a["keys"], a["padded"]) for a in phases] == [
+        (9, 16), (13, 16), (16, 16), (29, 32), (45, 48)]
+    result = tracing.jit_stats()["state.result"]
+    assert result["recompiles"] == 1
+    assert result["last_shape_sig"].endswith(f"int32[{RESULT_TILE}])")
+    assert result["cache_hits"] == 7  # 1 + 1 + 1 + 2 + 3 dispatches
 
 
 # ---------------------------------------------------------------------
