@@ -17,7 +17,7 @@ Legs:
                         TpuKeyedStateBackend → DeviceAggregatingState,
                         ~4 GiB of registers live in HBM per window
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
-  3b DataStream default aggregate() → DeviceWindowOperator, window 1
+  3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
                         against its host finish, an AvgAggregate job on
                         the scatter tier
@@ -365,6 +365,15 @@ def leg_device_gate(cfg):
     return [], info
 
 
+def boxed_problems(op, events):
+    """Every event must have entered `op` as part of a batch."""
+    if op.columnar_rows == events and not op.boxed_fallbacks:
+        return []
+    return [f"columnar_rows {op.columnar_rows} of {events} events, "
+            f"{op.boxed_fallbacks} boxed fallbacks "
+            f"({op.columnar_fallback_reason})"]
+
+
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
     ops, sink = run_window_job("chip-smoke-state-backend", events,
@@ -373,11 +382,7 @@ def leg_state_backend(cfg, events, ref):
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
     problems, facts = check_hll(*sink.columns(), ref, cfg["precision"])
-    if wop.columnar_rows != len(keys) or wop.boxed_fallbacks:
-        problems.append(
-            f"columnar_rows {wop.columnar_rows} of {len(keys)} events, "
-            f"{wop.boxed_fallbacks} boxed fallbacks "
-            f"({wop.columnar_fallback_reason})")
+    problems += boxed_problems(wop, len(keys))
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -416,17 +421,20 @@ def leg_sql(cfg, events, ref, mesh=None):
 
 
 def leg_datastream_default(cfg, events, ref):
-    """The default aggregate(): DeviceWindowOperator, whose door is a
-    per-record loop — so the first window only."""
-    n = cfg["events_per_window"]
-    ops, sink = run_window_job("chip-smoke-datastream",
-                               [a[:n] for a in events],
+    """The default aggregate(): DeviceWindowOperator, every batch by
+    its batch door."""
+    keys = events[0]
+    ops, sink = run_window_job("chip-smoke-datastream", events,
                                UserHll(cfg["precision"]))
     dop = one_of(ops, DeviceWindowOperator)
-    problems, facts = check_hll(*sink.columns(), {0: ref[0]},
-                                cfg["precision"])
-    return problems, {"route": "aggregate() -> DeviceWindowOperator",
-                      "events": n, **engine_facts(dop.engine), **facts}
+    problems, facts = check_hll(*sink.columns(), ref, cfg["precision"])
+    problems += boxed_problems(dop, len(keys))
+    return problems, {"route": "aggregate() -> "
+                               "DeviceWindowOperator.process_batch",
+                      "events": len(keys),
+                      "columnar_rows": dop.columnar_rows,
+                      "boxed_fallbacks": dop.boxed_fallbacks,
+                      **engine_facts(dop.engine), **facts}
 
 
 def engine_facts(engine):

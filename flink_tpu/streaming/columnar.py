@@ -130,6 +130,20 @@ def batch_from_arrays(arrays, ts=None, ts_mask=None) -> RecordBatch:
     return RecordBatch({"v": np.asarray(arrays)}, ts, ts_mask)
 
 
+def field_key_column(selector, batch) -> Optional[np.ndarray]:
+    """The key column of a tuple-row batch when the key selector is a
+    positional field (``key_by(0)``): cell for cell what ``get_key``
+    extracts per row.  None for any other selector or batch shape —
+    the caller then boxes the rows and asks the selector."""
+    from flink_tpu.core.functions import _FieldKeySelector
+    if isinstance(selector, _FieldKeySelector) \
+            and type(selector._field) is int and not batch.is_scalar:
+        col = batch.cols.get(f"f{selector._field}")
+        if col is not None:
+            return np.asarray(col)
+    return None
+
+
 class VectorizedCollectionSource(SourceFunction):
     """Bounded source over a Python collection that emits RecordBatch
     elements (columns built ONCE at construction) — the vectorized

@@ -2,12 +2,14 @@
 
 Makes `keyBy().window(...).aggregate(device_agg)` run on the TPU hot
 path (flink_tpu.streaming.vectorized / vectorized_sessions) while
-living as a normal operator in the task layer: records buffer on the
-host, every watermark (and every `flush_batch` records) flushes one
-vectorized `process_batch` + `advance_watermark` into the engine, and
-fires emit through the standard Output with the scalar operator's
-timestamp contract (window.maxTimestamp — ref: WindowOperator.java:544
-emitWindowContents).  Checkpoints snapshot the engine (device arrays
+living as a normal operator in the task layer.  Two doors feed the
+engine: a RecordBatch goes in whole (`process_batch`: key, value and
+timestamp columns, no per-row objects); scalar records buffer on the
+host and every watermark (and every `flush_batch` records) flushes
+them as one such batch.  A fire leaves as ONE RecordBatch per window
+with the scalar operator's timestamp contract (window.maxTimestamp —
+ref: WindowOperator.java:544 emitWindowContents), per-row records when
+the rows do not columnarize.  Checkpoints snapshot the engine (device arrays
 DMA'd to host + host indexes) so barrier checkpointing, recovery, and
 restarts work identically to the scalar path.
 
@@ -21,20 +23,24 @@ operators and the general WindowOperator (WindowOperator.java:192-195).
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, List, Optional
 
 import numpy as np
 
 from flink_tpu.ops.device_agg import DeviceAggregateFunction
 from flink_tpu.runtime.tracing import get_tracer
-from flink_tpu.streaming.elements import (MAX_TIMESTAMP,
+from flink_tpu.streaming import columnar
+from flink_tpu.streaming.elements import (MAX_TIMESTAMP, RecordBatch,
     StreamRecord, Watermark)
 from flink_tpu.streaming.operators import StreamOperator, TimestampedCollector
 from flink_tpu.streaming.vectorized import (
     VectorizedSlidingWindows,
     VectorizedTumblingWindows,
+    hash_keys_np,
 )
 from flink_tpu.streaming.vectorized_sessions import VectorizedSessionWindows
+from flink_tpu.streaming.window_operator import _FireBufferOutput
 from flink_tpu.streaming.windowing import (
     EventTimeSessionWindows,
     SlidingEventTimeWindows,
@@ -165,7 +171,8 @@ def is_device_eligible(assigner, aggregate_function, trigger, evictor,
 class DeviceWindowOperator(StreamOperator):
     """Batched, device-backed twin of WindowOperator for the eligible
     aggregate path.  The key selector is applied per record at buffer
-    time (the operator IS the keyed state; no keyed backend needed)."""
+    time, per column at the batch door (the operator IS the keyed
+    state; no keyed backend needed)."""
 
     def __init__(self, assigner, aggregate_function: DeviceAggregateFunction,
                  window_function=None, flush_batch: int = 8192,
@@ -199,7 +206,6 @@ class DeviceWindowOperator(StreamOperator):
             # fail fast at open, not at the first flush
             raise ValueError(
                 f"no device engine for assigner {self.assigner!r}")
-        self.collector = TimestampedCollector(self.output)
         # metric parity with the scalar WindowOperator (ref:
         # WindowOperator.java:138 numLateRecordsDropped); reset = this
         # execution attempt
@@ -223,7 +229,54 @@ class DeviceWindowOperator(StreamOperator):
         self._ts.append(record.timestamp)
         self._values.append(record.value)
         if len(self._keys) >= self.flush_batch:
+            with get_tracer().phase("window.ingest", rows=len(self._keys)):
+                self._flush_buffer()
+
+    def process_batch(self, batch) -> None:
+        """The batch door: key, value and timestamp columns go to the
+        engine as they are — no per-row object, nothing buffered."""
+        n = len(batch)
+        if n == 0:
+            return
+        tracer = get_tracer()
+        with tracer.phase("window.ingest", rows=n):
+            # records the scalar door buffered came first
             self._flush_buffer()
+            with tracer.phase("device_window.columns"):
+                keys, vals = self._batch_columns(batch, n)
+            self._feed_engine(keys, batch.ts, vals)
+            self._note_columnar(n)
+
+    def _batch_columns(self, batch, n: int):
+        """(keys, values) of a batch as the arrays the record door's
+        flush would have built from its rows."""
+        if batch.ts is None or (batch.ts_mask is not None
+                                and not batch.ts_mask.all()):
+            raise ValueError(
+                "device window operator requires event-time records "
+                "(assign timestamps upstream)")
+        sel = self.key_selector
+        rows = None  # boxed row values, made at most once
+        keys = columnar.field_key_column(sel, batch)
+        if keys is None:
+            rows = batch.row_values()
+            keys = np.asarray([sel.get_key(v) for v in rows]
+                              if sel is not None else rows)
+        elif keys.dtype == object:
+            # a column of strings: the dtype numpy infers from the
+            # cells decides the engine tier, as at the record door
+            keys = np.asarray(keys.tolist())
+        vals = None
+        agg = self.agg
+        if agg.needs_value or agg.needs_value_hash:
+            col = agg.extract_column(batch.value_arrays())
+            if isinstance(col, np.ndarray) and col.ndim == 1 \
+                    and len(col) == n:
+                vals = col
+            else:
+                vals = self._extract_values(
+                    rows if rows is not None else batch.row_values())
+        return self._maybe_intern(keys), vals
 
     def _wants_fused_string_sum(self) -> bool:
         from flink_tpu.ops.device_agg import SumAggregate
@@ -293,31 +346,37 @@ class DeviceWindowOperator(StreamOperator):
             return
         with get_tracer().phase("device_window.flush",
                                 batch=len(self._keys)):
-            self._flush_buffer_inner()
+            agg = self.agg
+            vals = None
+            if agg.needs_value or agg.needs_value_hash:
+                vals = self._extract_values(self._values)
+            self._feed_engine(self._maybe_intern(np.asarray(self._keys)),
+                              np.asarray(self._ts, np.int64), vals)
+            self._keys.clear()
+            self._ts.clear()
+            self._values.clear()
 
-    def _flush_buffer_inner(self):
-        agg = self.agg
-        extract = agg.extract_value
+    def _extract_values(self, values: list) -> np.ndarray:
+        extract = self.agg.extract_value
         # overridden either on the class or per-instance (a plain
         # function set on the instance has no __func__)
         if getattr(extract, "__func__",
                    None) is not DeviceAggregateFunction.extract_value:
-            values = [extract(v) for v in self._values]
-        else:
-            values = self._values
-        if agg.needs_value or agg.needs_value_hash:
-            vals = np.asarray(values)
-        else:
-            vals = None
-        keys_arr = self._maybe_intern(np.asarray(self._keys))
+            values = [extract(v) for v in values]
+        return np.asarray(values)
+
+    def _feed_engine(self, keys_arr, ts, vals) -> None:
+        """One batch into the engine, whichever door it came by; the
+        value column is hashed here, once, where the aggregate is a
+        distinct-count sketch."""
         self._ensure_engine(keys_arr)
-        self.engine.process_batch(
-            keys_arr,
-            np.asarray(self._ts, np.int64),
-            vals)
-        self._keys.clear()
-        self._ts.clear()
-        self._values.clear()
+        hashes = None
+        if self.agg.needs_value_hash:
+            with get_tracer().phase("columnar.ingest.hash"):
+                hashes = hash_keys_np(vals)
+            if not self.agg.needs_value:
+                vals = None
+        self.engine.process_batch(keys_arr, ts, vals, value_hashes=hashes)
 
     def _maybe_intern(self, keys_arr: np.ndarray) -> np.ndarray:
         """Dictionary-encode fixed-width string keys to dense uint64
@@ -349,9 +408,10 @@ class DeviceWindowOperator(StreamOperator):
         # Fires only happen when the watermark crosses a window-end
         # boundary (multiples of size/slide for the aligned engines).
         # Upstreams may emit a watermark per ELEMENT; paying a device
-        # flush + advance for each would serialize the pipeline on
-        # per-record device dispatches.  Between boundaries nothing can
-        # fire, so the watermark forwards without touching the engine.
+        # flush + advance (or even a phase) for each would serialize
+        # the pipeline on per-record work.  Between boundaries nothing
+        # can fire, so the watermark forwards without touching the
+        # engine.
         wm = watermark.timestamp
         grid = self._fire_grid()
         if grid is not None and wm != MAX_TIMESTAMP:
@@ -361,19 +421,32 @@ class DeviceWindowOperator(StreamOperator):
                 self.output.emit_watermark(watermark)
                 return
             self._last_fireable = fireable
-        self._flush_buffer()
-        if self.engine is not None:
-            before = len(self.engine.emitted)
-            with get_tracer().phase("device_window.fire", watermark=wm):
-                self.engine.advance_watermark(wm)
-                self._emit_from(before)
-            self.num_late_records_dropped = self.engine.num_late_dropped
-            if self.metrics is not None:
-                self.metrics.counter(
-                    "numLateRecordsDropped").count = \
-                    self.engine.num_late_dropped
-        self.current_watermark = wm
-        self.output.emit_watermark(watermark)
+        with get_tracer().phase("window.watermark", watermark=wm):
+            self._flush_buffer()
+            if self.engine is not None:
+                self._fire(wm)
+            self.current_watermark = wm
+            self.output.emit_watermark(watermark)
+
+    def _fire(self, wm: int) -> None:
+        engine = self.engine
+        # engines that can hand a fire over as arrays do; the rest
+        # (VectorizedSessionWindows) deliver one tuple per result
+        as_arrays = hasattr(engine, "fired")
+        if as_arrays:
+            engine.emit_arrays = True
+        with get_tracer().phase("device_window.fire", watermark=wm):
+            engine.advance_watermark(wm)
+        if as_arrays:
+            fires, engine.fired = engine.fired, []
+        else:
+            fires = [tuple(zip(*engine.emitted))] if engine.emitted else []
+            del engine.emitted[:]
+        self._emit_fires(fires)
+        self.num_late_records_dropped = engine.num_late_dropped
+        if self.metrics is not None:
+            self.metrics.counter(
+                "numLateRecordsDropped").count = engine.num_late_dropped
 
     def _fire_grid(self):
         """Window-end alignment grid of the assigner, or None when
@@ -384,25 +457,56 @@ class DeviceWindowOperator(StreamOperator):
             return self.assigner.size
         return None
 
-    def _emit_from(self, start_idx: int):
-        emitted = self.engine.emitted
-        if self._emit_batch_hist is not None and len(emitted) > start_idx:
-            self._emit_batch_hist.update(len(emitted) - start_idx)
+    def _emit_fires(self, fires) -> None:
+        """Each fire — (keys, results, window start, window end), the
+        last two scalars, or one per key from a session engine —
+        leaves as ONE RecordBatch: the result column itself, or what
+        the window function returned for every key, buffered and
+        columnarized (per-row records where that does not fit)."""
+        if self._emit_batch_hist is not None and fires:
+            self._emit_batch_hist.update(sum(len(f[0]) for f in fires))
+        tracer = get_tracer()
         fn = self.window_function
         id_to_key = self._id_to_key if self._interner is not None else None
-        for key, result, w_start, w_end in emitted[start_idx:]:
-            self.collector.set_absolute_timestamp(w_end - 1)
-            if fn is None:
-                self.collector.collect(result)
-            else:
+        for keys, results, starts, ends in fires:
+            n = len(keys)
+            one_window = np.ndim(starts) == 0
+            if fn is None and n > 1 and columnar.PIPELINE_ENABLED \
+                    and isinstance(results, np.ndarray) \
+                    and results.ndim == 1 and results.dtype.kind in "iuf":
+                with tracer.phase("window.fire.batch", keys=n):
+                    out = RecordBatch(
+                        {"v": results},
+                        np.full(n, ends - 1, np.int64) if one_window
+                        else np.asarray(ends, np.int64) - 1)
+                with tracer.phase("window.fire.downstream"):
+                    self.output.collect_batch(out)
+                continue
+            buf = _FireBufferOutput(self.output)
+            collector = TimestampedCollector(buf)
+            with tracer.phase("window.fire.batch", keys=n):
+                # python scalars, as the scalar operator hands them on
+                if isinstance(keys, np.ndarray) and keys.ndim == 1:
+                    keys = keys.tolist()
                 if id_to_key is not None:
-                    key = id_to_key[int(key)]
-                out = fn(key, TimeWindow(w_start, w_end), [result])
-                if out is not None:
-                    for v in out:
-                        self.collector.collect(v)
-        # emitted results are delivered; drop them so buffers don't grow
-        del emitted[start_idx:]
+                    keys = [id_to_key[k] for k in keys]
+                if isinstance(results, np.ndarray) and results.ndim == 1:
+                    results = results.tolist()
+                if one_window:
+                    windows = itertools.repeat(TimeWindow(starts, ends), n)
+                else:
+                    windows = map(TimeWindow, np.asarray(starts).tolist(),
+                                  np.asarray(ends).tolist())
+                for key, result, window in zip(keys, results, windows):
+                    collector.timestamp = window.end - 1
+                    if fn is None:
+                        collector.collect(result)
+                        continue
+                    out = fn(key, window, [result])
+                    if out is not None:
+                        for v in out:
+                            collector.collect(v)
+            buf.flush()
 
     # ---- checkpoint -------------------------------------------------
     def snapshot_state(self, checkpoint_id: Optional[int] = None) -> dict:

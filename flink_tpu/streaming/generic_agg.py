@@ -1653,15 +1653,13 @@ class GenericWindowOperator(StreamOperator):
         """Key column for a batch: a ready column when the selector is
         positional (or absent on scalar rows), else per-row get_key —
         always the exact keys the scalar path would have buffered."""
-        from flink_tpu.core.functions import _FieldKeySelector
+        from flink_tpu.streaming.columnar import field_key_column
         sel = self.key_selector
         if sel is None and batch.is_scalar:
             return np.asarray(next(iter(batch.cols.values())))
-        if isinstance(sel, _FieldKeySelector) \
-                and type(sel._field) is int and not batch.is_scalar:
-            col = batch.cols.get(f"f{sel._field}")
-            if col is not None:
-                return np.asarray(col)
+        col = field_key_column(sel, batch)
+        if col is not None:
+            return col
         keys = ([sel.get_key(v) for v in values] if sel is not None
                 else values)
         keys_arr = np.asarray(keys)

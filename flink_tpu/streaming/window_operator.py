@@ -291,6 +291,30 @@ class _FireBufferOutput(Output):
     def collect(self, record: StreamRecord) -> None:
         self.records.append(record)
 
+    def flush(self) -> None:
+        """Send the captured records on: ONE RecordBatch when there is
+        more than one and they columnarize, per-row records in the
+        same order otherwise."""
+        records = self.records
+        if not records:
+            return
+        tracer = get_tracer()
+        batch = None
+        if len(records) > 1:
+            from flink_tpu.streaming import columnar
+            if columnar.PIPELINE_ENABLED:
+                with tracer.phase("window.fire.columnarize"):
+                    batch = columnar.batch_from_records(
+                        [r.value for r in records],
+                        [r.timestamp for r in records])
+        with tracer.phase("window.fire.downstream"):
+            if batch is not None:
+                self._inner.collect_batch(batch)
+            else:
+                collect = self._inner.collect
+                for r in records:
+                    collect(r)
+
     def emit_watermark(self, watermark) -> None:
         self._inner.emit_watermark(watermark)
 
@@ -425,13 +449,11 @@ class WindowOperator(AbstractUdfStreamOperator):
         """Key column for a batch as a python list — bit-identical to
         what set_key_context would have extracted per row (same idiom
         as the generic engine's _batch_keys)."""
-        from flink_tpu.core.functions import _FieldKeySelector
+        from flink_tpu.streaming.columnar import field_key_column
         sel = self.key_selector
-        if isinstance(sel, _FieldKeySelector) \
-                and type(sel._field) is int and not batch.is_scalar:
-            col = batch.cols.get(f"f{sel._field}")
-            if col is not None:
-                return np.asarray(col).tolist()
+        col = field_key_column(sel, batch)
+        if col is not None:
+            return col.tolist()
         return [sel.get_key(v) for v in values]
 
     def process_batch(self, batch) -> None:
@@ -867,24 +889,7 @@ class WindowOperator(AbstractUdfStreamOperator):
                 self._internal_fn.process(key_col[i], window, self,
                                           contents, collector)
                 fired += 1
-        records = buf.records
-        if not records:
-            return fired
-        batch = None
-        if len(records) > 1:
-            from flink_tpu.streaming import columnar
-            if columnar.PIPELINE_ENABLED:
-                with tracer.phase("window.fire.columnarize"):
-                    batch = columnar.batch_from_records(
-                        [r.value for r in records],
-                        [r.timestamp for r in records])
-        with tracer.phase("window.fire.downstream"):
-            if batch is not None:
-                self.output.collect_batch(batch)
-            else:
-                collect = self.output.collect
-                for r in records:
-                    collect(r)
+        buf.flush()
         return fired
 
     # ---- helpers ----------------------------------------------------

@@ -562,23 +562,32 @@ def _phase_data(rows, batches=8, per_window=4):
     return keys, users, ts
 
 
-def _state_backend_job(rows, env=None):
+def _state_backend_job(rows, env=None, pinned=True):
+    """Config #2's job over a vectorized source.  ``pinned``: the
+    scalar WindowOperator over the ``tpu`` state backend; else nothing
+    is pinned and aggregate() builds the DeviceWindowOperator, whose
+    batch door takes every batch."""
     from flink_tpu.streaming.columnar import VectorizedCollectionSource
     keys, users, ts = _phase_data(rows)
     values = [((int(k), int(u)), int(t))
               for k, u, t in zip(keys, users, ts)]
     env = env or StreamExecutionEnvironment()
-    env.set_state_backend("tpu")
     sink = CollectSink()
     windowed = (env.add_source(VectorizedCollectionSource(
         values, timestamped=True, chunk=rows))
         .key_by(0).window(TumblingEventTimeWindows.of(Time.seconds(1))))
-    windowed.disable_device_operator()
+    if pinned:
+        env.set_state_backend("tpu")
+        windowed.disable_device_operator()
     windowed.aggregate(
         UserHll(8), window_function=lambda k, w, v:
         [(k, w.start, float(v[0]))]).add_sink(sink)
-    env.execute("phases-state")
+    env.execute("phases-state" if pinned else "phases-default-door")
     return len(sink.values)
+
+
+def _default_door_job(rows):
+    return _state_backend_job(rows, pinned=False)
 
 
 def _sql_tumble_job(rows):
@@ -610,11 +619,19 @@ SQL_ROUTE_PHASES = {
     "window.watermark": 10, "log.concat": 2, "log.finish.pad": 2,
     "log.finish.device": 2, "window.fire.batch": 2,
     "window.fire.downstream": 2}
-
+#: one watermark, the stream's last, fires both windows
+DEFAULT_DOOR_PHASES = {
+    "window.ingest": 8, "device_window.columns": 8,
+    "columnar.ingest.hash": 8, "log.append": 8,
+    "window.watermark": 1, "device_window.fire": 1, "log.concat": 2,
+    "log.finish.pad": 2, "log.finish.device": 2, "window.fire.batch": 2,
+    "window.fire.columnarize": 2, "window.fire.downstream": 2}
 
 @pytest.mark.parametrize("job, expected", [
     (_state_backend_job, STATE_ROUTE_PHASES),
-    (_sql_tumble_job, SQL_ROUTE_PHASES)], ids=["state_backend", "sql"])
+    (_sql_tumble_job, SQL_ROUTE_PHASES),
+    (_default_door_job, DEFAULT_DOOR_PHASES)],
+    ids=["state_backend", "sql", "default_door"])
 def test_phase_counts_follow_batches_and_fires_never_rows(
         job, expected, monkeypatch):
     """The guard against a span per record, key or timer: exactly the
@@ -635,6 +652,41 @@ def test_phase_counts_follow_batches_and_fires_never_rows(
         job(rows)
         counts = {name: s["count"] for name, s in tr.stats().items()}
         assert counts == expected, rows
+
+
+@pytest.mark.parametrize("n", [9_000, 15_000])
+def test_record_door_phases_follow_buffer_flushes_and_fires(n, monkeypatch):
+    """The default door fed one record at a time: the operator's
+    8,192-row buffer is its batch.  Both sizes fill it once (a
+    ``window.ingest`` of its own) and leave a rest that the stream's
+    last watermark flushes before it fires the three windows."""
+    import flink_tpu.native as nat
+    from flink_tpu.ops import link_probe
+    if not nat.available():
+        pytest.skip("native runtime unavailable")
+    monkeypatch.setattr(link_probe, "recommended_finish_tier",
+                        lambda override=None: "device")
+    rng = np.random.default_rng(5)
+    values = [((int(k), int(u)), int(t)) for k, u, t in zip(
+        rng.integers(0, 50, n), rng.integers(0, 1 << 30, n),
+        np.sort(rng.integers(0, 3000, n)))]
+    tr = get_tracer()
+    tr.reset()
+    env = StreamExecutionEnvironment()
+    sink = CollectSink()
+    (env.from_collection(values, timestamped=True)
+        .key_by(0).window(TumblingEventTimeWindows.of(Time.seconds(1)))
+        .aggregate(UserHll(8), window_function=lambda k, w, v:
+                   [(k, w.start, float(v[0]))]).add_sink(sink))
+    env.execute("phases-record-door")
+    assert len(sink.values) == 150
+    counts = {name: s["count"] for name, s in tr.stats().items()}
+    assert counts == {
+        "window.ingest": 1, "device_window.flush": 2,
+        "columnar.ingest.hash": 2, "log.append": 2,
+        "window.watermark": 1, "device_window.fire": 1, "log.concat": 3,
+        "log.finish.pad": 3, "log.finish.device": 3, "window.fire.batch": 3,
+        "window.fire.columnarize": 3, "window.fire.downstream": 3}
 
 
 def test_a_compile_is_booked_on_the_phase_and_the_label_that_needed_it():
