@@ -374,6 +374,16 @@ def boxed_problems(op, events):
             f"({op.columnar_fallback_reason})"]
 
 
+def fire_tail_problems(op, result_rows):
+    """Every result row must have left its fire with no StreamRecord
+    of its own (the window function here is a plain callable)."""
+    if op.fire_rows_direct == result_rows and not op.fire_rows_via_records:
+        return []
+    return [f"fire_rows_direct {op.fire_rows_direct} of {result_rows} "
+            f"result rows, {op.fire_rows_via_records} through a "
+            f"StreamRecord"]
+
+
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
     ops, sink = run_window_job("chip-smoke-state-backend", events,
@@ -381,8 +391,10 @@ def leg_state_backend(cfg, events, ref):
                                on_state_backend=True)
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
-    problems, facts = check_hll(*sink.columns(), ref, cfg["precision"])
+    cols = sink.columns()
+    problems, facts = check_hll(*cols, ref, cfg["precision"])
     problems += boxed_problems(wop, len(keys))
+    problems += fire_tail_problems(wop, len(cols[0]))
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -390,6 +402,8 @@ def leg_state_backend(cfg, events, ref):
                  f"{type(state).__name__}",
         "events": len(keys), "columnar_rows": wop.columnar_rows,
         "boxed_fallbacks": wop.boxed_fallbacks,
+        "fire_rows_direct": wop.fire_rows_direct,
+        "fire_rows_via_records": wop.fire_rows_via_records,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
         "evictions": state.evictions, **facts}
@@ -427,13 +441,17 @@ def leg_datastream_default(cfg, events, ref):
     ops, sink = run_window_job("chip-smoke-datastream", events,
                                UserHll(cfg["precision"]))
     dop = one_of(ops, DeviceWindowOperator)
-    problems, facts = check_hll(*sink.columns(), ref, cfg["precision"])
+    cols = sink.columns()
+    problems, facts = check_hll(*cols, ref, cfg["precision"])
     problems += boxed_problems(dop, len(keys))
+    problems += fire_tail_problems(dop, len(cols[0]))
     return problems, {"route": "aggregate() -> "
                                "DeviceWindowOperator.process_batch",
                       "events": len(keys),
                       "columnar_rows": dop.columnar_rows,
                       "boxed_fallbacks": dop.boxed_fallbacks,
+                      "fire_rows_direct": dop.fire_rows_direct,
+                      "fire_rows_via_records": dop.fire_rows_via_records,
                       **engine_facts(dop.engine), **facts}
 
 

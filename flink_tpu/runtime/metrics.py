@@ -24,7 +24,7 @@ import bisect
 import json
 import math
 import time as _time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 
 # ---------------------------------------------------------------------------
@@ -82,6 +82,23 @@ class Histogram:
         else:
             self._values[self._pos] = float(value)
             self._pos = (self._pos + 1) % self.window
+
+    def update_many(self, values: Sequence[float]) -> None:
+        """:meth:`update` for each of `values` in order, in one call:
+        the same count and the same reservoir.  Only the values that
+        fill the reservoir, or are among the last `window`, are
+        touched."""
+        n = len(values)
+        self.total_count += n
+        head = min(self.window - len(self._values), n)
+        if head:
+            self._values.extend(float(v) for v in values[:head])
+        skip = max(0, n - head - self.window)
+        pos = (self._pos + skip) % self.window
+        for v in values[head + skip:]:
+            self._values[pos] = float(v)
+            pos = (pos + 1) % self.window
+        self._pos = pos
 
     def get_count(self) -> int:
         return self.total_count

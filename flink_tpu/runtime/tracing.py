@@ -180,6 +180,12 @@ class _Phase(_Span):
         self.annotation.__exit__(*exc)
         return False
 
+    def set_attr(self, key: str, value: Any) -> None:
+        """An attribute known only once the phase runs (a count its
+        loop arrives at), on the profiler's event as in the ring."""
+        _Span.set_attr(self, key, value)
+        self.annotation.set_metadata(**{key: value})
+
 
 class _SpanStat:
     __slots__ = ("count", "total_ms", "self_ms", "compile_ms", "compiles",

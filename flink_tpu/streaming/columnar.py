@@ -29,6 +29,7 @@ not carry across such a change (the runtime warns on restore).
 
 from __future__ import annotations
 
+from operator import itemgetter
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -58,18 +59,19 @@ def columns_from_values(values: Sequence) -> Optional[Dict[str, np.ndarray]]:
     values don't fit a column shape (heterogeneous types, bools, ints
     beyond int64, nested tuples...).  Mirrors the netchannel codec's
     strict type tiers so a batch born here round-trips the wire
-    columnar."""
+    columnar.  One pass per check and per column, none of them a
+    Python-level loop: a window fire hands a million rows here."""
     if not values:
         return None
     v0 = values[0]
     if type(v0) is tuple:
         arity = len(v0)
-        if arity == 0 or any(type(v) is not tuple or len(v) != arity
-                             for v in values):
+        if arity == 0 or set(map(type, values)) != {tuple} \
+                or set(map(len, values)) != {arity}:
             return None
         cols = {}
         for i in range(arity):
-            col = _column_from_cells([v[i] for v in values])
+            col = _column_from_cells(list(map(itemgetter(i), values)))
             if col is None:
                 return None
             cols[f"f{i}"] = col
@@ -80,12 +82,12 @@ def columns_from_values(values: Sequence) -> Optional[Dict[str, np.ndarray]]:
     return {"v": col}
 
 
-def _column_from_cells(cells: list) -> Optional[np.ndarray]:
+def _column_from_cells(cells: Sequence) -> Optional[np.ndarray]:
     """One homogeneous cell list → ndarray, or None.  `bool` is a
     subclass of int and floats don't survive an int64 cast, hence the
     exact `type is` checks (same discipline as the wire codec)."""
     t = type(cells[0])
-    if any(type(c) is not t for c in cells):
+    if set(map(type, cells)) != {t}:
         return None
     if t is int:
         try:
@@ -117,6 +119,24 @@ def batch_from_records(values: Sequence, timestamps: Optional[Sequence]
                            for t in timestamps], np.int64)
         return RecordBatch(cols, stamps, mask)
     return RecordBatch(cols, np.array(list(timestamps), np.int64))
+
+
+def batch_from_runs(values: Sequence, runs: Sequence) -> Optional[RecordBatch]:
+    """:func:`batch_from_records` for rows whose timestamps come as
+    runs of ``(timestamp, row count)`` in row order — a window fire's
+    rows, which share one timestamp per fired window.  The timestamp
+    column is filled per run, never per row."""
+    stamps = [t for t, _ in runs]
+    if None in stamps:
+        return batch_from_records(
+            values, [t for t, count in runs for _ in range(count)])
+    cols = columns_from_values(values)
+    if cols is None:
+        return None
+    if len(runs) == 1:
+        return RecordBatch(cols, np.full(len(values), stamps[0], np.int64))
+    return RecordBatch(cols, np.repeat(np.array(stamps, np.int64),
+                                       [count for _, count in runs]))
 
 
 def batch_from_arrays(arrays, ts=None, ts_mask=None) -> RecordBatch:

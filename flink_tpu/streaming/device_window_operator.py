@@ -23,7 +23,6 @@ operators and the general WindowOperator (WindowOperator.java:192-195).
 
 from __future__ import annotations
 
-import itertools
 from typing import Any, List, Optional
 
 import numpy as np
@@ -33,7 +32,7 @@ from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.streaming import columnar
 from flink_tpu.streaming.elements import (MAX_TIMESTAMP, RecordBatch,
     StreamRecord, Watermark)
-from flink_tpu.streaming.operators import StreamOperator, TimestampedCollector
+from flink_tpu.streaming.operators import StreamOperator
 from flink_tpu.streaming.vectorized import (
     VectorizedSlidingWindows,
     VectorizedTumblingWindows,
@@ -192,6 +191,11 @@ class DeviceWindowOperator(StreamOperator):
         self._values: List[Any] = []
         self._last_fireable = None
         self.num_late_records_dropped = 0  # metric parity
+        #: rows the fires handed on with no StreamRecord of their own
+        #: / wrapped in one on their way (no window function this
+        #: operator takes writes through a collector: always 0)
+        self.fire_rows_direct = 0
+        self.fire_rows_via_records = 0
         # string keys dictionary-encode to dense uint64 ids in ONE C++
         # pass per batch (native.NativeStringInterner), so
         # keyBy("word") over strings rides the integer-keyed fast
@@ -461,8 +465,9 @@ class DeviceWindowOperator(StreamOperator):
         """Each fire — (keys, results, window start, window end), the
         last two scalars, or one per key from a session engine —
         leaves as ONE RecordBatch: the result column itself, or what
-        the window function returned for every key, buffered and
-        columnarized (per-row records where that does not fit)."""
+        the window function returned for every key, its rows straight
+        into the fire buffer and columnarized there (per-row records
+        where that does not fit)."""
         if self._emit_batch_hist is not None and fires:
             self._emit_batch_hist.update(sum(len(f[0]) for f in fires))
         tracer = get_tracer()
@@ -474,17 +479,19 @@ class DeviceWindowOperator(StreamOperator):
             if fn is None and n > 1 and columnar.PIPELINE_ENABLED \
                     and isinstance(results, np.ndarray) \
                     and results.ndim == 1 and results.dtype.kind in "iuf":
-                with tracer.phase("window.fire.batch", keys=n):
+                with tracer.phase("window.fire.batch", keys=n,
+                                  fire_rows_direct=n,
+                                  fire_rows_via_records=0):
                     out = RecordBatch(
                         {"v": results},
                         np.full(n, ends - 1, np.int64) if one_window
                         else np.asarray(ends, np.int64) - 1)
+                self.fire_rows_direct += n
                 with tracer.phase("window.fire.downstream"):
                     self.output.collect_batch(out)
                 continue
             buf = _FireBufferOutput(self.output)
-            collector = TimestampedCollector(buf)
-            with tracer.phase("window.fire.batch", keys=n):
+            with tracer.phase("window.fire.batch", keys=n) as phase:
                 # python scalars, as the scalar operator hands them on
                 if isinstance(keys, np.ndarray) and keys.ndim == 1:
                     keys = keys.tolist()
@@ -493,19 +500,14 @@ class DeviceWindowOperator(StreamOperator):
                 if isinstance(results, np.ndarray) and results.ndim == 1:
                     results = results.tolist()
                 if one_window:
-                    windows = itertools.repeat(TimeWindow(starts, ends), n)
+                    buf.emit_fired(fn, keys, results, True,
+                                   window=TimeWindow(starts, ends))
                 else:
-                    windows = map(TimeWindow, np.asarray(starts).tolist(),
-                                  np.asarray(ends).tolist())
-                for key, result, window in zip(keys, results, windows):
-                    collector.timestamp = window.end - 1
-                    if fn is None:
-                        collector.collect(result)
-                        continue
-                    out = fn(key, window, [result])
-                    if out is not None:
-                        for v in out:
-                            collector.collect(v)
+                    buf.emit_fired(
+                        fn, keys, results, True, windows=list(map(
+                            TimeWindow, np.asarray(starts).tolist(),
+                            np.asarray(ends).tolist())))
+                buf.book(self, phase)
             buf.flush()
 
     # ---- checkpoint -------------------------------------------------

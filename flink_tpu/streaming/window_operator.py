@@ -18,6 +18,7 @@ the Evictor before/after the window function
 from __future__ import annotations
 
 import abc
+import itertools
 from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -116,25 +117,35 @@ class _InternalWindowFunction:
         self.fn = fn
         #: True when window contents are a single pre-aggregated value
         self.single_value = single_value
+        # the function's shape, decided once: the two classes are
+        # abc.ABCs, whose isinstance is too slow to ask per fired key
+        self._is_process = isinstance(fn, ProcessWindowFunction)
+        self._is_window_fn = isinstance(fn, WindowFunction)
+        #: True for the two shapes that are handed a collector (and,
+        #: for ProcessWindowFunction, a context that reaches keyed
+        #: state).  False for a plain callable or None: all such a
+        #: function sees is (key, window, elements), so a batched fire
+        #: may call it with no key context set and take its rows as
+        #: they come (_FireBufferOutput.emit_fired)
+        self.takes_collector = self._is_process or self._is_window_fn
 
     def process(self, key, window, op, contents, collector) -> None:
         if self.fn is None:
             collector.collect(contents)
-        elif isinstance(self.fn, ProcessWindowFunction):
-            elements = [contents] if self.single_value else contents
+            return
+        elements = [contents] if self.single_value else contents
+        if self._is_process:
             self.fn.process(key, WindowContext(window, op), elements, collector)
-        elif isinstance(self.fn, WindowFunction):
-            elements = [contents] if self.single_value else contents
+        elif self._is_window_fn:
             self.fn.apply(key, window, elements, collector)
         else:  # plain callable(key, window, elements) -> iterable
-            elements = [contents] if self.single_value else contents
             result = self.fn(key, window, elements)
             if result is not None:
                 for v in result:
                     collector.collect(v)
 
     def clear(self, key, window, op) -> None:
-        if isinstance(self.fn, ProcessWindowFunction):
+        if self._is_process:
             self.fn.clear(WindowContext(window, op))
 
 
@@ -276,44 +287,125 @@ class _AssignerContext:
 
 
 class _FireBufferOutput(Output):
-    """Captures the main-stream records emitted during ONE batched
-    fire sweep so they can be re-emitted as a single RecordBatch.
-    Watermarks, side outputs, and latency markers pass straight
-    through to the real output (a side tag has no ordering contract
-    against the main stream)."""
+    """Captures the main-stream rows emitted during ONE batched fire
+    sweep so they can be re-emitted as a single RecordBatch.  It holds
+    row values, not records: one list of values and their timestamps
+    as runs of ``[timestamp, row count]``, so a fire of one window
+    holds one run however many keys fired.  Two ways in:
+    :meth:`emit_fired`, the fire's loop for a window function that is
+    a plain callable or None, which allocates nothing of the
+    framework's per fired key; and :meth:`collect`, for the functions
+    that write through a collector.  Watermarks, side outputs, and
+    latency markers pass straight through to the real output (a side
+    tag has no ordering contract against the main stream)."""
 
-    __slots__ = ("_inner", "records")
+    __slots__ = ("_inner", "values", "runs", "_stamped", "rows_direct",
+                 "rows_via_records")
 
     def __init__(self, inner: Output):
         self._inner = inner
-        self.records: List[StreamRecord] = []
+        self.values: list = []
+        self.runs: List[list] = []
+        #: rows of `values` that a run covers already
+        self._stamped = 0
+        #: rows that came by emit_fired / wrapped in a StreamRecord
+        self.rows_direct = 0
+        self.rows_via_records = 0
+
+    def _run(self, timestamp, n: int) -> None:
+        runs = self.runs
+        if runs and runs[-1][0] == timestamp:
+            runs[-1][1] += n
+        else:
+            runs.append([timestamp, n])
+        self._stamped += n
 
     def collect(self, record: StreamRecord) -> None:
-        self.records.append(record)
+        self.values.append(record.value)
+        self._run(record.timestamp, 1)
+        self.rows_via_records += 1
+
+    def _stamp(self, timestamp) -> None:
+        """The rows put into `values` since the last run leave with
+        `timestamp`."""
+        n = len(self.values) - self._stamped
+        if n:
+            self._run(timestamp, n)
+            self.rows_direct += n
+
+    def emit_fired(self, fn, keys, contents, wrap: bool, *, window=None,
+                   windows=None) -> None:
+        """The fire's loop over the fired columns: ``fn(key, window,
+        elements)`` once per key, in order, its rows straight into
+        `values`; with ``fn`` None the contents are the rows.  Give
+        the fire's one `window`, or `windows` with every key's own (a
+        sweep over several windows, a session engine): a run of
+        timestamps then ends where the window changes.  `wrap` hands
+        the function ``[contents]``, a fresh list per key, as a
+        pre-aggregated window's single value."""
+        values = self.values
+        extend = values.extend
+        if windows is None:
+            if fn is None:
+                extend(contents)
+            elif wrap:
+                for key, c in zip(keys, contents):
+                    out = fn(key, window, [c])
+                    if out is not None:
+                        extend(out)
+            else:
+                for key, c in zip(keys, contents):
+                    out = fn(key, window, c)
+                    if out is not None:
+                        extend(out)
+            self._stamp(window.max_timestamp())
+            return
+        last = None
+        for key, c, w in zip(keys, contents, windows):
+            if w is not last:
+                if last is not None:
+                    self._stamp(last.max_timestamp())
+                last = w
+            if fn is None:
+                values.append(c)
+                continue
+            out = fn(key, w, [c] if wrap else c)
+            if out is not None:
+                extend(out)
+        if last is not None:
+            self._stamp(last.max_timestamp())
+
+    def book(self, op, phase) -> None:
+        """The fire's two counts, on the phase of its loop and summed
+        on the operator."""
+        phase.set_attr("fire_rows_direct", self.rows_direct)
+        phase.set_attr("fire_rows_via_records", self.rows_via_records)
+        op.fire_rows_direct += self.rows_direct
+        op.fire_rows_via_records += self.rows_via_records
 
     def flush(self) -> None:
-        """Send the captured records on: ONE RecordBatch when there is
+        """Send the captured rows on: ONE RecordBatch when there is
         more than one and they columnarize, per-row records in the
         same order otherwise."""
-        records = self.records
-        if not records:
+        values = self.values
+        if not values:
             return
         tracer = get_tracer()
         batch = None
-        if len(records) > 1:
+        if len(values) > 1:
             from flink_tpu.streaming import columnar
             if columnar.PIPELINE_ENABLED:
                 with tracer.phase("window.fire.columnarize"):
-                    batch = columnar.batch_from_records(
-                        [r.value for r in records],
-                        [r.timestamp for r in records])
+                    batch = columnar.batch_from_runs(values, self.runs)
         with tracer.phase("window.fire.downstream"):
             if batch is not None:
                 self._inner.collect_batch(batch)
             else:
                 collect = self._inner.collect
-                for r in records:
-                    collect(r)
+                rows = iter(values)
+                for timestamp, n in self.runs:
+                    for value in itertools.islice(rows, n):
+                        collect(StreamRecord(value, timestamp))
 
     def emit_watermark(self, watermark) -> None:
         self._inner.emit_watermark(watermark)
@@ -360,6 +452,10 @@ class WindowOperator(AbstractUdfStreamOperator):
             window_function, single_value_contents)
         # metrics (ref: numLateRecordsDropped, WindowOperator.java:138)
         self.num_late_records_dropped = 0
+        #: rows the batched fires handed on with no StreamRecord of
+        #: their own / wrapped in one by a collector on their way
+        self.fire_rows_direct = 0
+        self.fire_rows_via_records = 0
 
     # ---- lifecycle --------------------------------------------------
     def open(self):
@@ -859,6 +955,23 @@ class WindowOperator(AbstractUdfStreamOperator):
         the rows columnarize (per-row records otherwise, same order).
         Returns the number of windows that emitted — the scalar path's
         windowsFired increments, applied in one note."""
+        buf = _FireBufferOutput(self.output)
+        with get_tracer().phase("window.fire.batch", keys=len(rows)) as phase:
+            if self._internal_fn.takes_collector:
+                fired = self._fire_through_collector(
+                    buf, rows, key_col, ns_col, contents_col, found_mask)
+            else:
+                fired = self._fire_over_columns(
+                    buf, rows, key_col, ns_col, contents_col, found_mask)
+            buf.book(self, phase)
+        buf.flush()
+        return fired
+
+    def _fire_through_collector(self, buf, rows, key_col, ns_col,
+                                contents_col, found_mask) -> int:
+        """A ProcessWindowFunction or WindowFunction, key by key: each
+        is called under its key's context (it may read per-window
+        keyed state) and writes through a collector."""
         wt = self.assigner.window_type()
         backend = self.keyed_backend
         hist = self._emit_batch_hist
@@ -866,30 +979,73 @@ class WindowOperator(AbstractUdfStreamOperator):
         # as scalar get() does (`out.item() if np.ndim(out) == 0`);
         # heap results are python objects and pass through untouched
         unbox = isinstance(contents_col, np.ndarray)
-        buf = _FireBufferOutput(self.output)
         collector = TimestampedCollector(buf)
-        tracer = get_tracer()
         fired = 0
-        with tracer.phase("window.fire.batch", keys=len(rows)):
-            for j, i in enumerate(rows):
-                if not found_mask[j]:
-                    continue
-                contents = contents_col[j]
-                if unbox:
-                    if np.ndim(contents) == 0:
-                        contents = contents.item()
-                elif contents is None:
-                    continue
-                window = wt.from_namespace(ns_col[i])
-                backend.set_current_key(key_col[i])
-                if hist is not None:
-                    hist.update(len(contents)
-                                if hasattr(contents, "__len__") else 1)
-                collector.set_absolute_timestamp(window.max_timestamp())
-                self._internal_fn.process(key_col[i], window, self,
-                                          contents, collector)
-                fired += 1
-        buf.flush()
+        for j, i in enumerate(rows):
+            if not found_mask[j]:
+                continue
+            contents = contents_col[j]
+            if unbox:
+                if np.ndim(contents) == 0:
+                    contents = contents.item()
+            elif contents is None:
+                continue
+            window = wt.from_namespace(ns_col[i])
+            backend.set_current_key(key_col[i])
+            if hist is not None:
+                hist.update(len(contents)
+                            if hasattr(contents, "__len__") else 1)
+            collector.set_absolute_timestamp(window.max_timestamp())
+            self._internal_fn.process(key_col[i], window, self,
+                                      contents, collector)
+            fired += 1
+        return fired
+
+    def _fire_over_columns(self, buf, rows, key_col, ns_col, contents_col,
+                           found_mask) -> int:
+        """A plain callable, or no window function: it is handed
+        (key, window, elements) and nothing else, so nothing is set up
+        per key — absent rows are dropped once, the gather is unboxed
+        once, every distinct window is built once, and the backend's
+        key context is set once, to the last fired key, where the
+        per-key loop leaves it."""
+        keys = [key_col[i] for i in rows]
+        namespaces = [ns_col[i] for i in rows]
+        keep = np.asarray(found_mask, bool)
+        scalars = False
+        if isinstance(contents_col, np.ndarray):
+            # as scalar get() unboxes: python scalars out of a 1-d
+            # gather, the rows of a wider one as they are
+            scalars = contents_col.ndim == 1 \
+                and contents_col.dtype.kind != "O"
+            contents = contents_col.tolist() if contents_col.ndim == 1 \
+                else list(contents_col)
+        else:
+            contents = contents_col
+            keep = keep & np.fromiter((c is not None for c in contents),
+                                      bool, len(contents))
+        if not keep.all():
+            keep = keep.tolist()
+            keys = list(itertools.compress(keys, keep))
+            namespaces = list(itertools.compress(namespaces, keep))
+            contents = list(itertools.compress(contents, keep))
+        fired = len(keys)
+        if not fired:
+            return 0
+        if self._emit_batch_hist is not None:
+            self._emit_batch_hist.update_many(
+                [1] * fired if scalars else
+                [len(c) if hasattr(c, "__len__") else 1 for c in contents])
+        from_namespace = self.assigner.window_type().from_namespace
+        made = {ns: from_namespace(ns) for ns in set(namespaces)}
+        fn = self._internal_fn
+        if len(made) == 1:
+            buf.emit_fired(fn.fn, keys, contents, fn.single_value,
+                           window=made[namespaces[0]])
+        else:
+            buf.emit_fired(fn.fn, keys, contents, fn.single_value,
+                           windows=[made[ns] for ns in namespaces])
+        self.keyed_backend.set_current_key(keys[-1])
         return fired
 
     # ---- helpers ----------------------------------------------------

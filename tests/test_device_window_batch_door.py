@@ -6,6 +6,12 @@ repo's plain reference — on the same seeded events."""
 
 import numpy as np
 import pytest
+from fire_tail_reference import (
+    CountedRecord,
+    FireSpy,
+    assert_fire_left_as,
+    reference_batch,
+)
 
 import flink_tpu.native as nat
 from flink_tpu.ops.device_agg import SumAggregate
@@ -20,6 +26,7 @@ from flink_tpu.streaming.device_window_operator import DeviceWindowOperator
 from flink_tpu.streaming.elements import RecordBatch
 from flink_tpu.streaming.harness import OneInputStreamOperatorTestHarness
 from flink_tpu.streaming.log_windows import (
+    LogStructuredSlidingWindows,
     LogStructuredTumblingWindows,
     StringSumTumblingWindows,
 )
@@ -27,7 +34,9 @@ from flink_tpu.streaming.sources import CollectSink, SinkFunction
 from flink_tpu.streaming.vectorized_sessions import VectorizedSessionWindows
 from flink_tpu.streaming.windowing import (
     EventTimeSessionWindows,
+    SlidingEventTimeWindows,
     Time,
+    TimeWindow,
     TumblingEventTimeWindows,
 )
 
@@ -371,3 +380,157 @@ def test_an_empty_batch_touches_nothing():
                                 np.zeros(0, np.int64)))
     assert op.engine is None and op.columnar_rows == 0
     assert "window.ingest" not in tr.stats()
+
+
+# ---- the fire's emit tail: rows straight into the fire buffer --------
+# Every fire the engine hands over is also put through the old tail
+# (one StreamRecord per row, fire_tail_reference) in the test: what
+# the operator hands on must be that, cell for cell.
+
+def _one_row(key, window, vals):
+    return [(key, window.start, float(vals[0]))]
+
+
+def _several_rows(key, window, vals):
+    return [(key, float(vals[0])), (key, float(window.end))]
+
+
+def _some_keys_only(key, window, vals):
+    return [] if int(vals[0]) % 2 else [(key, float(vals[0]))]
+
+
+def _nothing(key, window, vals):
+    return None if int(vals[0]) % 2 else []
+
+
+def _mixed_types(key, window, vals):
+    return [(key, int(vals[0]) if int(vals[0]) % 2 else float(vals[0]))]
+
+
+def _a_bool(key, window, vals):
+    return [(key, vals[0] > 2)]
+
+
+def _beyond_int64(key, window, vals):
+    return [(key, 2 ** 63 + int(vals[0]))]
+
+
+DOOR_WINDOW_FUNCTIONS = {
+    "one_row": _one_row, "several_rows": _several_rows,
+    "some_keys_only": _some_keys_only, "nothing": _nothing,
+    "mixed_types": _mixed_types, "a_bool": _a_bool,
+    "beyond_int64": _beyond_int64, "no_function": None,
+}
+
+
+def _door_case(engine):
+    """(aggregate, assigner, rows, timestamps, watermarks, engine class)
+    of a small stream that reaches the named engine."""
+    rng = np.random.default_rng(21)
+    n = 600
+    ts = np.sort(rng.integers(0, 4000, n)).tolist()
+    watermarks = [999, 1999, 2999, 3999]
+    assigner = TumblingEventTimeWindows.of(Time.seconds(1))
+    if engine == "interned_strings":
+        rows = [(f"w{int(k)}", int(v)) for k, v in
+                zip(rng.integers(0, 9, n), rng.integers(1, 9, n))]
+        return (FieldSum(np.int64), assigner, rows, ts, watermarks,
+                LogStructuredTumblingWindows)
+    if engine == "sessions":
+        rows = [(int(k), float(v)) for k, v in
+                zip(rng.integers(0, 6, n), rng.integers(1, 5, n))]
+        return (FieldSum(np.float32),
+                EventTimeSessionWindows.with_gap(Time.milliseconds_of(40)),
+                rows, ts, [1500, 10_000], VectorizedSessionWindows)
+    rows = [(int(k), int(u)) for k, u in
+            zip(rng.integers(0, 25, n), rng.integers(0, 1 << 40, n))]
+    if engine == "sliding":
+        return (UserHll(8), SlidingEventTimeWindows.of(
+            Time.seconds(2), Time.seconds(1)), rows, ts, watermarks,
+            LogStructuredSlidingWindows)
+    if engine == "several_windows_a_watermark":
+        watermarks = [3999]
+    return (UserHll(8), assigner, rows, ts, watermarks,
+            LogStructuredTumblingWindows)
+
+
+def _old_tail_rows(op, fire, fn):
+    """The (value, timestamp) rows the old loop made of one fire."""
+    keys, results, starts, ends = fire
+    if isinstance(keys, np.ndarray) and keys.ndim == 1:
+        keys = keys.tolist()
+    if op._interner is not None:
+        keys = [op._id_to_key[k] for k in keys]
+    if isinstance(results, np.ndarray) and results.ndim == 1:
+        results = results.tolist()
+    if np.ndim(starts) == 0:
+        windows = [TimeWindow(starts, ends)] * len(keys)
+    else:
+        windows = [TimeWindow(s, e) for s, e in zip(
+            np.asarray(starts).tolist(), np.asarray(ends).tolist())]
+    rows = []
+    for key, result, window in zip(keys, results, windows):
+        if fn is None:
+            rows.append((result, window.end - 1))
+            continue
+        out = fn(key, window, [result])
+        if out is not None:
+            rows.extend((v, window.end - 1) for v in out)
+    return rows
+
+
+@pytest.mark.parametrize("engine", [
+    "log_tumbling", "several_windows_a_watermark", "sliding",
+    "interned_strings", "sessions"])
+@pytest.mark.parametrize("shape", list(DOOR_WINDOW_FUNCTIONS))
+def test_a_door_fire_leaves_as_the_record_tail_left_it(shape, engine,
+                                                       monkeypatch):
+    from flink_tpu.streaming import device_window_operator as dwo
+    from flink_tpu.streaming import operators, window_operator
+    fn = DOOR_WINDOW_FUNCTIONS[shape]
+    agg, assigner, rows, ts, watermarks, engine_class = _door_case(engine)
+    op, h = harness(agg, assigner, fn)
+    spy = op.output = FireSpy()
+    fires = []
+    emit = op._emit_fires
+
+    def one_fire_at_a_time(fired):
+        for fire in fired:
+            emit([fire])
+            fires.append((fire, spy.take()))
+
+    op._emit_fires = one_fire_at_a_time
+    h.process_batch(batch_from_records(rows, ts))
+    made = CountedRecord.made = 0
+    for module in (operators, window_operator, dwo):
+        monkeypatch.setattr(module, "StreamRecord", CountedRecord)
+    for wm in watermarks:
+        h.process_watermark(wm)
+    assert isinstance(op.engine, engine_class)
+    assert (op._interner is not None) == (engine == "interned_strings")
+    assert len(fires) >= (2 if engine == "sessions" else 4)
+    if engine == "sessions":    # a window of its own for every key
+        assert all(np.ndim(starts) == 1 for (_, _, starts, _), _ in fires)
+    emitted = 0
+    for fire, events in fires:
+        results = fire[1]
+        if fn is None and len(fire[0]) > 1 \
+                and isinstance(results, np.ndarray):
+            # the result column itself, as before
+            ((kind, batch),) = events
+            assert kind == "batch" and list(batch.cols) == ["v"]
+            assert batch.cols["v"] is results
+            assert batch.ts.tolist() == (np.zeros(len(results), np.int64)
+                                         + fire[3] - 1).tolist()
+            emitted += len(results)
+            continue
+        want = _old_tail_rows(op, fire, fn)
+        assert_fire_left_as(events, want)
+        emitted += len(want)
+        if reference_batch(want) is None:
+            made += len(want)   # per-row records, built at the flush
+    assert op.fire_rows_direct == emitted
+    assert op.fire_rows_via_records == 0
+    assert CountedRecord.made == made
+    if shape != "nothing":
+        assert emitted

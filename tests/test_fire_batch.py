@@ -12,16 +12,27 @@ restore)."""
 
 import numpy as np
 import pytest
+from fire_tail_reference import (
+    CountedRecord,
+    FireSpy,
+    assert_fire_left_as,
+)
 
 from flink_tpu.core.state import (
     AggregatingStateDescriptor,
     ListStateDescriptor,
+    ValueStateDescriptor,
 )
 from flink_tpu.ops.device_agg import SumAggregate
+from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.streaming.elements import RecordBatch
 from flink_tpu.streaming.harness import OneInputStreamOperatorTestHarness
 from flink_tpu.streaming.operators import Output
-from flink_tpu.streaming.window_operator import WindowOperator
+from flink_tpu.streaming.window_operator import (
+    ProcessWindowFunction,
+    WindowFunction,
+    WindowOperator,
+)
 from flink_tpu.streaming.windowing import (
     EventTimeTrigger,
     SlidingEventTimeWindows,
@@ -278,3 +289,256 @@ def test_batch_fires_kill_switch():
     h.process_watermark(10 ** 6)
     assert called == []
     assert sorted(h.extract_output_values()) == [(0, 2.0), (0, 2.0)]
+
+
+# ---- the emit tail: rows straight into the fire buffer ---------------
+# The scalar drain (batch_fires=False) still wraps every row in a
+# StreamRecord; what it emits per watermark, put through the old tail
+# (fire_tail_reference), is what a batched fire must hand on.
+
+def _one_row(key, window, vs):
+    return [(key, float(vs[0]), window.start)]
+
+
+def _several_rows(key, window, vs):
+    return [(key, float(vs[0])), (key, -1.0), (key, float(window.end))]
+
+
+def _some_keys_only(key, window, vs):
+    return [(key, float(vs[0]))] if key % 2 else []
+
+
+def _nothing(key, window, vs):
+    return None if key % 2 else []
+
+
+def _generator(key, window, vs):
+    for v in vs:
+        yield (key, float(v))
+        yield (key, float(window.start))
+
+
+def _scalar_rows(key, window, vs):
+    return [float(vs[0])]
+
+
+def _string_cells(key, window, vs):
+    return [(f"k{key}", float(vs[0]))]
+
+
+def _mixed_types(key, window, vs):
+    return [(key, float(vs[0]) if key % 2 else int(vs[0]))]
+
+
+def _a_bool(key, window, vs):
+    return [(key, vs[0] > 100.0)]
+
+
+def _beyond_int64(key, window, vs):
+    return [(key, 2 ** 63 if key == 3 else key)]
+
+
+def _uneven_arity(key, window, vs):
+    return [(key,) if key == 2 else (key, float(vs[0]))]
+
+
+WINDOW_FUNCTIONS = {
+    "one_row": _one_row, "several_rows": _several_rows,
+    "some_keys_only": _some_keys_only, "nothing": _nothing,
+    "generator": _generator, "scalar_rows": _scalar_rows,
+    "string_cells": _string_cells, "mixed_types": _mixed_types,
+    "a_bool": _a_bool, "beyond_int64": _beyond_int64,
+    "uneven_arity": _uneven_arity, "no_function": None,
+}
+
+
+def _tail_harness(kind, backend, fn, batch_fires, lateness=0):
+    from flink_tpu.runtime.metrics import MetricRegistry
+    op = WindowOperator(_assigner(kind),
+                        AggregatingStateDescriptor("fire-sum", _KVSum()),
+                        window_function=fn, allowed_lateness=lateness)
+    op.batch_fires = batch_fires
+    h = OneInputStreamOperatorTestHarness(
+        op, key_selector=lambda x: x[0], state_backend=backend)
+    op.register_standard_metrics(MetricRegistry().job_group("tail"))
+    spy = op.output = FireSpy()  # before open(): the collector binds it
+    h.open()
+    return op, h, spy
+
+
+def _drive_fires(kind, backend, fn, batch_fires, lateness=0):
+    """One list of what left the operator per watermark; the sweeps
+    take in four tumbling (or five sliding) windows each."""
+    op, h, spy = _tail_harness(kind, backend, fn, batch_fires, lateness)
+    per_watermark = []
+    for keys, vals, ts, wm in _chunks():
+        h.process_batch(RecordBatch({"f0": keys, "f1": vals}, ts=ts))
+        h.process_watermark(wm)
+        per_watermark.append(spy.take())
+    h.process_watermark(10 ** 13)
+    per_watermark.append(spy.take())
+    return op, per_watermark
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("kind", ["tumbling", "sliding"])
+@pytest.mark.parametrize("shape", list(WINDOW_FUNCTIONS))
+def test_a_fire_leaves_as_the_record_tail_left_it(shape, kind, backend):
+    fn = WINDOW_FUNCTIONS[shape]
+    scalar_op, scalar = _drive_fires(kind, backend, fn, False)
+    op, batched = _drive_fires(kind, backend, fn, True)
+    assert len(batched) == len(scalar)
+    fired_rows = 0
+    for events, reference in zip(batched, scalar):
+        rows = [what for _, what in reference]
+        assert all(kind == "record" for kind, _ in reference)
+        assert_fire_left_as(events, rows)
+        fired_rows += len(rows)
+    if shape != "nothing":
+        assert fired_rows
+    assert op.fire_rows_direct == fired_rows
+    assert op.fire_rows_via_records == 0
+    assert scalar_op.fire_rows_direct == 0
+    # the backend's key context and the histogram, as the loop left them
+    assert op.keyed_backend.current_key == scalar_op.keyed_backend.current_key
+    hist, want = op._emit_batch_hist, scalar_op._emit_batch_hist
+    assert hist.total_count == want.total_count > 0
+    assert (hist._values, hist._pos) == (want._values, want._pos)
+
+
+def test_a_sweep_over_several_windows_holds_one_run_per_window():
+    """A watermark that jumps four windows: the buffer keeps one
+    (timestamp, rows) run per fired window, not a timestamp per row,
+    and the batch's timestamp column still follows each row's window."""
+    from flink_tpu.streaming import window_operator as wo
+    made = []
+
+    class Buffer(wo._FireBufferOutput):
+        def flush(self):
+            made.append([list(run) for run in self.runs])
+            super().flush()
+
+    op, h, spy = _tail_harness("tumbling", "heap", _one_row, True)
+    keys, vals, ts, _ = next(iter(_chunks()))
+    h.process_batch(RecordBatch({"f0": keys, "f1": vals}, ts=ts))
+    orig, wo._FireBufferOutput = wo._FireBufferOutput, Buffer
+    try:
+        h.process_watermark(399)
+    finally:
+        wo._FireBufferOutput = orig
+    ((kind, batch),) = spy.take()
+    assert kind == "batch"
+    assert made == [[[99, N_KEYS], [199, N_KEYS], [299, N_KEYS],
+                     [399, N_KEYS]]]
+    assert batch.ts.tolist() == (batch.cols["f2"] + 99).tolist()
+
+
+class _CountsPerWindowAndKey(ProcessWindowFunction):
+    """Reads and writes keyed state under the fired key's context:
+    per-window state, and per-key state shared across windows."""
+
+    def process(self, key, context, elements, out):
+        per_window = context.window_state(ValueStateDescriptor("fires"))
+        per_key = context.global_state(ValueStateDescriptor("seen"))
+        per_window.update((per_window.value() or 0) + 1)
+        per_key.update((per_key.value() or 0) + 1)
+        out.collect((key, float(elements[0]), context.window.start,
+                     per_window.value(), per_key.value()))
+
+
+class _WritesThroughOut(WindowFunction):
+    def apply(self, key, window, inputs, out):
+        out.collect((key, float(inputs[0])))
+        out.collect((key, float(window.start)))
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("fn", [_CountsPerWindowAndKey, _WritesThroughOut],
+                         ids=["process_window_function", "window_function"])
+def test_collector_functions_keep_key_context_and_records(fn, backend):
+    scalar_op, scalar = _drive_fires("sliding", backend, fn(), False, 150)
+    op, batched = _drive_fires("sliding", backend, fn(), True, 150)
+    fired_rows = 0
+    for events, reference in zip(batched, scalar):
+        rows = [what for _, what in reference]
+        assert_fire_left_as(events, rows)
+        fired_rows += len(rows)
+    assert fired_rows
+    if fn is _CountsPerWindowAndKey:
+        # a key is seen in several windows: the shared count went up
+        assert max(v[4] for events in scalar for _, (v, _) in events) > 1
+    assert op.fire_rows_via_records == fired_rows
+    assert op.fire_rows_direct == 0
+    assert op.keyed_backend.current_key == scalar_op.keyed_backend.current_key
+
+
+@pytest.mark.parametrize("fn, direct", [
+    (_one_row, True), (None, True), (_CountsPerWindowAndKey(), False),
+    (_WritesThroughOut(), False)],
+    ids=["callable", "no_function", "process_window_function",
+         "window_function"])
+def test_a_plain_callable_fire_builds_no_stream_record(fn, direct,
+                                                       monkeypatch):
+    """The mechanism: through a plain callable (or none) every row
+    reaches the buffer by the direct entry and no StreamRecord is
+    built; through the two collector-taking classes, one per row."""
+    from flink_tpu.streaming import operators, window_operator
+    op, h, spy = _tail_harness("tumbling", "tpu", fn, True)
+    n = 500
+    keys = np.arange(n, dtype=np.int64)
+    h.process_batch(RecordBatch(
+        {"f0": keys, "f1": np.ones(n)}, ts=np.full(n, 50, np.int64)))
+    monkeypatch.setattr(CountedRecord, "made", 0)
+    for module in (operators, window_operator):
+        monkeypatch.setattr(module, "StreamRecord", CountedRecord)
+    tr = get_tracer()
+    tr.reset()
+    monkeypatch.setattr(tr, "enabled", True)
+    h.process_watermark(99)
+    rows = len(spy.rows())
+    assert rows == (2 * n if isinstance(fn, _WritesThroughOut) else n)
+    assert [kind for kind, _ in spy.events] == ["batch"]
+    # the phase of the loop carries the two counts beside its keys
+    (loop,) = [e for e in tr.recent() if e["name"] == "window.fire.batch"]
+    assert loop["args"] == {
+        "keys": n, "fire_rows_direct": rows if direct else 0,
+        "fire_rows_via_records": 0 if direct else rows}
+    if direct:
+        assert (op.fire_rows_direct, op.fire_rows_via_records) == (rows, 0)
+        assert CountedRecord.made == 0
+    else:
+        assert (op.fire_rows_direct, op.fire_rows_via_records) == (0, rows)
+        assert CountedRecord.made == rows
+
+
+def test_rows_that_do_not_fit_become_records_at_the_flush(monkeypatch):
+    """... and only there: n rows, n StreamRecords, in fire order."""
+    from flink_tpu.streaming import operators, window_operator
+    op, h, spy = _tail_harness("tumbling", "heap", _mixed_types, True)
+    keys = np.arange(6, dtype=np.int64)
+    h.process_batch(RecordBatch(
+        {"f0": keys, "f1": np.ones(6)}, ts=np.full(6, 50, np.int64)))
+    monkeypatch.setattr(CountedRecord, "made", 0)
+    for module in (operators, window_operator):
+        monkeypatch.setattr(module, "StreamRecord", CountedRecord)
+    h.process_watermark(99)
+    assert spy.events == [("record", ((k, 1.0 if k % 2 else 1), 99))
+                          for k in range(6)]
+    assert CountedRecord.made == 6
+    assert (op.fire_rows_direct, op.fire_rows_via_records) == (6, 0)
+
+
+@pytest.mark.parametrize("n", [0, 1, 5, 1023, 1024, 1025, 3000])
+@pytest.mark.parametrize("filled", [0, 1000, 1024, 2500])
+def test_histogram_update_many_is_update_for_each(filled, n):
+    from flink_tpu.runtime.metrics import Histogram
+    one, many = Histogram(), Histogram()
+    for v in range(filled):
+        one.update(v)
+        many.update(v)
+    values = [10_000 + v for v in range(n)]
+    for v in values:
+        one.update(v)
+    many.update_many(values)
+    assert (many.total_count, many._values, many._pos) \
+        == (one.total_count, one._values, one._pos)

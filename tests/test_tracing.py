@@ -517,6 +517,34 @@ def test_phase_is_a_profiler_annotation_under_the_one_prefix(tmp_path):
     assert dict(by_name["flink/window.ingest"].stats)["rows"] == 3
 
 
+def test_an_attribute_set_inside_a_phase_is_on_the_profiler_event_too(
+        tmp_path):
+    """A count the phase's own loop arrives at (the fire's
+    ``fire_rows_direct``): ``set_attr`` inside the ``with`` puts it
+    beside the attributes given at entry, in the ring and the trace."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    tr.enabled = True
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tr.phase("window.fire.batch", keys=7) as phase:
+            phase.set_attr("fire_rows_direct", 21)
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.recent()[-1]["args"] == {"keys": 7, "fire_rows_direct": 21}
+    [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    [event] = [e for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "flink/window.fire.batch"]
+    stats = dict(event.stats)
+    assert (stats["keys"], stats["fire_rows_direct"]) == (7, 21)
+
+
 def test_inert_phase_costs_under_5_microseconds():
     tr = Tracer()
     n = 100_000
