@@ -28,7 +28,8 @@ bin/flink script).
     python -m flink_tpu stop --master H:P <job> --savepoint-dir DIR
                                                      savepoint then stop
     python -m flink_tpu info                         version + devices
-    python -m flink_tpu bench [config]               run the benchmark
+    python -m flink_tpu bench --workload <cell> ...   benchmark/run.py with
+                                                     these arguments
     python -m flink_tpu jobmanager [--port P]        start a cluster master
                                                      (Dispatcher + RM + blob)
     python -m flink_tpu taskmanager --master H:P     start a worker process
@@ -83,8 +84,15 @@ def main(argv=None) -> int:
     if verb == "profile":
         return _profile(rest)
     if verb == "bench":
-        import subprocess
-        return subprocess.call([sys.executable, "bench.py"] + rest)
+        # benchmark/run.py is the one program that gives a number; it
+        # runs here, in this process (the process that runs the job
+        # holds the chip), and exits with its own code
+        import os
+        run_py = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "run.py")
+        sys.argv = [run_py] + rest
+        runpy.run_path(run_py, run_name="__main__")
+        return 0
     if verb == "shell":
         return _shell(rest)
     if verb == "config-docs":

@@ -621,7 +621,7 @@ def session_log_fire(keys: np.ndarray, ts: np.ndarray, weights: np.ndarray,
             (rk[:r].copy(), rt[:r].copy(), rw[:r].copy(), rv[:r].copy()))
 
 
-# ---- compiled baselines (bench.py) ----------------------------------------
+# ---- compiled per-record heap baselines ------------------------------------
 
 def _pow2_at_least(n: int) -> int:
     return 1 << max(4, (n - 1).bit_length())

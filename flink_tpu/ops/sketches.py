@@ -121,8 +121,7 @@ class CountMinSketchAggregate(DeviceAggregateFunction):
 
     ``result`` returns the per-slot total weight (exact L1 mass, kept
     in a side counter); per-item frequency estimates are served by
-    :meth:`point_query` (the queryable-state style read used by the
-    heavy-hitter operator, flink_tpu/streaming/heavy_hitters.py).
+    :meth:`point_query` (a queryable-state style read).
     Guarantee: est ≤ true + eps*L1 with prob 1-delta, eps=e/width,
     delta=e^-depth.
     """

@@ -9,10 +9,6 @@ all_to_all inside one jitted SPMD program — collectives ride ICI, not
 a host network stack.
 """
 
-from flink_tpu.parallel.mesh_agg import (
-    MeshWindowAggregation,
-    make_sharded_step,
-)
 from flink_tpu.parallel.mesh_log import (
     MeshLogSessionWindows,
     MeshLogSlidingWindows,
@@ -24,7 +20,6 @@ from flink_tpu.parallel.mesh_windows import (
     MeshTumblingWindows,
 )
 
-__all__ = ["MeshWindowAggregation", "make_sharded_step",
-           "MeshTumblingWindows", "MeshSlidingWindows",
+__all__ = ["MeshTumblingWindows", "MeshSlidingWindows",
            "MeshLogTumblingWindows", "MeshLogSlidingWindows",
            "MeshLogSessionWindows", "mesh_log_engine_for_assigner"]

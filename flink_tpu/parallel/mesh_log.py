@@ -622,30 +622,15 @@ def mesh_log_engine_for_assigner(assigner, agg: DeviceAggregateFunction,
                                  mesh, axis: str = "kg",
                                  max_parallelism: int = 128):
     """Mesh-sharded log tier for this assigner+aggregate, or None when
-    the cell decomposition / assigner shape doesn't fit (same scope as
-    log_engine_for_assigner: integer keys, HLL/Sum/Quantile cells,
+    the cell decomposition / assigner shape doesn't fit (same scope as the
+    single-device log tier: integer keys, HLL/Sum/Quantile cells,
     Count-Min sessions)."""
-    from flink_tpu.streaming.windowing import (
-        EventTimeSessionWindows,
-        SlidingEventTimeWindows,
-        TumblingEventTimeWindows,
-    )
+    from flink_tpu.streaming.window_engines import aligned_shape
+    shape = aligned_shape(assigner)
     try:
-        if isinstance(assigner, TumblingEventTimeWindows) \
-                and assigner.offset == 0:
-            return MeshLogTumblingWindows(
-                agg, assigner.size, mesh, axis=axis,
-                max_parallelism=max_parallelism)
-        if (isinstance(assigner, SlidingEventTimeWindows)
-                and assigner.offset == 0
-                and assigner.size % assigner.slide == 0):
-            return MeshLogSlidingWindows(
-                agg, assigner.size, assigner.slide, mesh, axis=axis,
-                max_parallelism=max_parallelism)
-        if isinstance(assigner, EventTimeSessionWindows):
-            return MeshLogSessionWindows(
-                agg, assigner.gap, mesh, axis=axis,
-                max_parallelism=max_parallelism)
+        return shape and shape.build(
+            MeshLogTumblingWindows, MeshLogSlidingWindows,
+            MeshLogSessionWindows, agg, mesh, axis=axis,
+            max_parallelism=max_parallelism)
     except (TypeError, ValueError, RuntimeError):
-        pass  # unsupported cell decomposition / params / no native lib
-    return None
+        return None  # unsupported cell decomposition / params / no native lib

@@ -1,7 +1,6 @@
-"""Mesh-sharded multi-window tumbling aggregation — the framework path.
+"""Mesh-sharded multi-window tumbling aggregation.
 
-Where flink_tpu.parallel.mesh_agg is the single-window kernel demo,
-this engine is the one the JobGraph drives: it speaks the same host
+The engine the JobGraph drives: it speaks the same host
 interface as the single-chip vectorized engines
 (process_batch / advance_watermark / emitted / snapshot / restore, see
 flink_tpu.streaming.vectorized), so DeviceWindowOperator can host it
@@ -67,9 +66,44 @@ from flink_tpu.ops.device_table import (
     insert_or_lookup_regions_impl,
     make_table,
 )
-from flink_tpu.ops.hashing import split_hash64_np
-from flink_tpu.parallel.mesh_agg import _bucketize, _target_shard
+from flink_tpu.ops.hashing import fmix32, split_hash64_np
 from flink_tpu.streaming.vectorized import hash_keys_np
+
+
+def _target_shard(h_lo: jnp.ndarray, max_parallelism: int, n_shards: int) -> jnp.ndarray:
+    """key hash → key group → shard (device twin of
+    assign_key_groups_np + computeOperatorIndexForKeyGroup)."""
+    kg = fmix32(h_lo) % jnp.uint32(max_parallelism)
+    return ((kg.astype(jnp.int32) * n_shards) // max_parallelism).astype(jnp.int32)
+
+
+def _bucketize(tgt: jnp.ndarray, n_shards: int, payload: Tuple[jnp.ndarray, ...],
+               mask: jnp.ndarray):
+    """Scatter records into [n_shards, M] buckets by target shard
+    (M = local batch size, the static worst case)."""
+    n = tgt.shape[0]
+    # push padding records to a virtual shard so they never exchange
+    tgt_eff = jnp.where(mask, tgt, n_shards)
+    order = jnp.argsort(tgt_eff, stable=True)
+    tgt_sorted = tgt_eff[order]
+    counts = jnp.bincount(tgt_sorted, length=n_shards + 1)
+    offsets = jnp.concatenate([jnp.zeros(1, counts.dtype),
+                               jnp.cumsum(counts)[:-1]])
+    rank = jnp.arange(n) - offsets[tgt_sorted]
+    # padding rows target the virtual shard n_shards, which is out of
+    # bounds for the (n_shards, n) bucket array; mode="drop" discards
+    # those writes instead of letting them collide with real shard-0
+    # entries at [0, rank]
+    valid = tgt_sorted < n_shards
+    out_mask = jnp.zeros((n_shards, n), bool)
+    out_mask = out_mask.at[tgt_sorted, rank].set(valid, mode="drop")
+    outs = []
+    for arr in payload:
+        sorted_arr = arr[order]
+        buck = jnp.zeros((n_shards, n), sorted_arr.dtype)
+        buck = buck.at[tgt_sorted, rank].set(sorted_arr, mode="drop")
+        outs.append(buck)
+    return outs, out_mask
 
 
 class MeshWindowOverflowError(RuntimeError):

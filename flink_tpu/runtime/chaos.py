@@ -3,7 +3,7 @@
 Runs the same keyed windowed-aggregation job twice — once fault-free,
 once under a deterministic `FaultInjector` schedule — and hands back
 both output multisets plus the fault-tolerance counters, so callers
-(tests/test_chaos.py, `bench.py --chaos-smoke`) can assert
+(tests/test_chaos.py) can assert
 exactly-once delivery: the chaos run's output must EQUAL the
 fault-free run's, record for record, despite storage-write failures,
 lost checkpoint acks, and induced task crashes (ref: Basiri et al.,
@@ -51,27 +51,42 @@ class KeyedSumAgg(AggregateFunction):
 
 
 class CheckpointGatedSource(FromCollectionSource):
-    """Emits `FREE` records at full speed, then trickles one record
-    per step until a checkpoint COMPLETES, then floods the rest.  Any
-    injected fault aimed past the gate (e.g. `after=600` with
-    FREE=400) is therefore guaranteed to land with a completed
-    checkpoint to restore from, whatever the host load — on a starved
-    box the checkpoint round trip can outlast many records, and a
-    crash with no restore point replays from offset 0, duplicating
-    already-fired windows into the non-transactional sink.  The flag
-    rides on a class attribute because the source factory deep-copies
-    the function per attempt."""
+    """Emits exactly `FREE` records at full speed, then holds — no
+    record, one short sleep per step, barriers still injected — until a
+    checkpoint COMPLETES, then floods the rest.  Every record before
+    the gate is processed before that checkpoint's barrier, so a fault
+    counted per record and aimed just past the gate (`after=FREE + 10`)
+    lands with a completed checkpoint to restore from AND before any
+    subtask can have fired a window into the non-transactional sink,
+    whatever the host load.  Both failed on a starved host with a gate
+    that trickled one record per step: the count crept towards its
+    mark while the checkpoint round trip (a timeout and a re-trigger
+    included) was still under way — the gate opened at record 570 of
+    600 on an idle host — and with the mark at the first window's end
+    a subtask whose sibling lagged fired that window before the crash,
+    which the restart then fired again.  The flag rides on a class
+    attribute because the source factory deep-copies the function per
+    attempt."""
 
     FREE = 400          # records emitted before the gate closes
+    HOLD_S = 60.0       # a job that never checkpoints ends, not hangs
     completed = False   # class attr: reset per run by the harness
+    _held_since = None  # when this attempt's source reached the gate
 
     def notify_checkpoint_complete(self, checkpoint_id):
         type(self).completed = True
 
     def emit_step(self, ctx, max_records):
-        if not type(self).completed and self.offset >= self.FREE:
-            _time.sleep(0.001)
-            return super().emit_step(ctx, 1)
+        if not type(self).completed:
+            if self.offset < self.FREE:
+                max_records = min(max_records, self.FREE - self.offset)
+            else:
+                now = _time.monotonic()
+                if self._held_since is None:
+                    self._held_since = now
+                if now - self._held_since < self.HOLD_S:
+                    _time.sleep(0.001)
+                    return True
         return super().emit_step(ctx, max_records)
 
 
@@ -95,9 +110,11 @@ def standard_schedule(inj: FaultInjector) -> FaultInjector:
     # the first checkpoint's acks vanish; the pending holds the
     # max_concurrent slot until checkpoint_timeout_ms aborts it
     inj.fail_n_times("checkpoint.ack", 2)
-    # crash past the source's FREE=400 gate, so the timeout re-trigger
-    # has healed and a completed checkpoint exists to restore from
-    inj.fail_n_times("task.process", 1, after=600)
+    # crash just past the source's gate: the timeout re-trigger has
+    # healed, a completed checkpoint exists to restore from, and no
+    # window has reached the sink (the first ends at record 600)
+    inj.fail_n_times("task.process", 1,
+                     after=CheckpointGatedSource.FREE + 10)
     inj.fail_n_times("netchannel.connect", 1)
     # stretch per-record processing so the job outlives the checkpoint
     # timeout deterministically (event time: output is unaffected)

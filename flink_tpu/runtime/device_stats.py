@@ -337,8 +337,7 @@ class DeviceTelemetry:
 
     def payload(self) -> Dict[str, Any]:
         """The full device-plane payload: one shape served by the live
-        ``/jobs/<n>/device`` route, the HistoryServer archive, and
-        ``bench.py --device-ledger``."""
+        ``/jobs/<n>/device`` route and the HistoryServer archive."""
         with self._lock:
             transfers = {
                 f"{direction}.{tag}": {

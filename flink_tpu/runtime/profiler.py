@@ -28,8 +28,8 @@ One payload shape (:meth:`SamplingProfiler.export`) feeds every
 surface: the live REST ``/jobs/<name>/flamegraph`` route, the
 HistoryServer twin frozen into the archive bundle, cluster increment
 shipping (TaskExecutor → JobMaster via ``report_profile``), the
-``flink_tpu top`` HOT column, ``flink_tpu profile --flame`` collapsed
-text, and ``bench.py --flame``.  The d3-flame-graph JSON tree is
+``flink_tpu top`` HOT column and ``flink_tpu profile --flame``
+collapsed text.  The d3-flame-graph JSON tree is
 always built by :func:`flamegraph_payload` from such an export, so
 live and archived responses cannot diverge.
 

@@ -30,7 +30,7 @@ not carry across such a change (the runtime warns on restore).
 from __future__ import annotations
 
 from operator import itemgetter
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -43,6 +43,7 @@ from flink_tpu.streaming.elements import (  # noqa: F401 — RecordBatch
 )
 from flink_tpu.streaming.operators import StreamOperator
 from flink_tpu.streaming.sources import SinkFunction, SourceFunction
+from flink_tpu.streaming.window_engines import WindowEngineHost
 
 #: kill switch for the end-to-end batch pipeline (RecordBatch flowing
 #: as stream elements through sources, operator chains, and the
@@ -356,101 +357,29 @@ def explode_to_rows(stream):
     return stream._add_op("explode_batches", _ExplodeBatches)
 
 
-class ColumnarWindowOperator(StreamOperator):
+class ColumnarWindowOperator(WindowEngineHost):
     """keyBy().window().aggregate(device_agg) over RecordBatch input.
 
     The columnar twin of DeviceWindowOperator: batches feed the engine
     directly (no per-record objects), fires leave as RecordBatches.
-    Engine tier selection: the log-structured combiner engines
-    (streaming/log_windows.py) when the aggregate has a cell
-    decomposition and keys are integral; else the device-resident
-    vectorized engines.
+    The engine is chosen from the key column's dtype on the first batch
+    (streaming/window_engines.py).
 
     out_fields maps each output column name to one of
     ("key", "agg", "wstart", "wend").
     """
+
+    engine_key, tier_key = "columnar_engine", "columnar_tier"
 
     def __init__(self, assigner, agg: DeviceAggregateFunction,
                  key_col: str, input_col: Optional[str],
                  out_fields: Sequence[tuple],
                  initial_capacity: int = 1 << 14,
                  mesh=None, mesh_axis: str = "kg"):
-        super().__init__()
-        self.assigner = assigner
-        self.agg = agg
+        super().__init__(assigner, agg, initial_capacity, mesh, mesh_axis)
         self.key_col = key_col
         self.input_col = input_col
         self.out_fields = list(out_fields)
-        self.initial_capacity = initial_capacity
-        #: with a mesh, the keyBy exchange is lax.all_to_all over the
-        #: mesh axis and the aggregation shards over per-shard log
-        #: engines (parallel/mesh_log.py) — the plan then stays at
-        #: parallelism 1 and the mesh provides the scale axis
-        self.mesh = mesh
-        self.mesh_axis = mesh_axis
-        self.engine = None
-        self.num_late_records_dropped = 0
-
-    # ---- engine selection -------------------------------------------
-    def _make_engine(self, key_dtype, require_log: bool = False) -> Any:
-        """require_log: restoring a log-tier checkpoint — a silent
-        fallback to the vectorized tier would feed it an incompatible
-        snapshot format, so failures must surface."""
-        from flink_tpu.streaming.device_window_operator import (
-            engine_for_assigner,
-            log_engine_for_assigner,
-        )
-        if require_log:
-            from flink_tpu.streaming import log_windows as lw
-            eng = log_engine_for_assigner(self.assigner, self.agg)
-            if eng is None:
-                raise RuntimeError(
-                    "checkpoint was taken on the log engine tier, which "
-                    "does not cover this aggregate/assigner")
-            return eng
-        eng = None
-        if self.mesh is not None and np.issubdtype(key_dtype, np.integer):
-            from flink_tpu.parallel.mesh_log import (
-                mesh_log_engine_for_assigner,
-            )
-            from flink_tpu.streaming.device_window_operator import (
-                resolve_mesh,
-            )
-            # factory resolution stays INSIDE the integer-key branch:
-            # non-mesh-eligible jobs must not pay a device/client init
-            self.mesh = resolve_mesh(self.mesh)
-            eng = mesh_log_engine_for_assigner(
-                self.assigner, self.agg, self.mesh, axis=self.mesh_axis,
-                max_parallelism=self.max_parallelism)
-            if eng is not None:
-                return eng
-        if key_dtype.kind in "US":
-            eng = self._string_engine()
-            if eng is not None:
-                return eng
-        if np.issubdtype(key_dtype, np.integer):
-            eng = log_engine_for_assigner(self.assigner, self.agg)
-        if eng is None:
-            eng = engine_for_assigner(self.assigner, self.agg,
-                                      self.initial_capacity)
-        if eng is None:
-            raise ValueError(f"no engine for assigner {self.assigner!r}")
-        return eng
-
-    def _string_engine(self):
-        """Fused wordcount engine for a STRING key column (tumbling
-        float sum — the SQL wordcount shape); None when the shape
-        doesn't fit."""
-        from flink_tpu.streaming.device_window_operator import (
-            string_sum_engine_for_assigner,
-        )
-        return string_sum_engine_for_assigner(self.assigner, self.agg)
-
-    def open(self):
-        pass  # engine built on first batch (needs the key dtype)
-
-    def set_key_context(self, record):
-        pass
 
     # ---- input ------------------------------------------------------
     def process_element(self, record: StreamRecord):
@@ -467,15 +396,7 @@ class ColumnarWindowOperator(StreamOperator):
     def _ingest(self, batch):
         keys = batch.cols[self.key_col]
         if self.engine is None:
-            self.engine = self._make_engine(np.asarray(keys).dtype)
-            # engines without batch-fire support deliver via .emitted
-            if hasattr(self.engine, "fired"):
-                self.engine.emit_arrays = True
-            # fast-forward to the operator watermark: rows behind it
-            # must count as late, not fire into closed windows
-            wm = getattr(self, "current_watermark", None)
-            if wm is not None and wm > -(2 ** 63):
-                self.engine.advance_watermark(wm)
+            self._build_engine(np.asarray(keys).dtype)
         values = None
         value_hashes = None
         if self.input_col is not None:
@@ -514,12 +435,14 @@ class ColumnarWindowOperator(StreamOperator):
         starts = np.asarray([e[2] for e in emitted], np.int64)
         ends = np.asarray([e[3] for e in emitted], np.int64)
         del emitted[:]
-        cols = {}
-        for name, kind in self.out_fields:
-            cols[name] = {"key": keys_np, "agg": results,
-                          "wstart": starts, "wend": ends}[kind]
-        out = RecordBatch(cols, ends - 1)
+        out = self._out_batch(keys_np, results, starts, ends)
         self.output.collect(StreamRecord(out, timestamp=int(ends.max()) - 1))
+
+    def _out_batch(self, keys, results, starts, ends) -> RecordBatch:
+        by_kind = {"key": keys, "agg": results, "wstart": starts,
+                   "wend": ends}
+        return RecordBatch({name: by_kind[kind]
+                            for name, kind in self.out_fields}, ends - 1)
 
     def _emit_fired(self):
         tracer = get_tracer()
@@ -535,114 +458,10 @@ class ColumnarWindowOperator(StreamOperator):
                     starts = np.full(len(keys_np), start, np.int64)
                     ends = np.full(len(keys_np), end, np.int64)
                     out_ts = end - 1
-                cols = {}
-                for name, kind in self.out_fields:
-                    if kind == "key":
-                        cols[name] = keys_np
-                    elif kind == "agg":
-                        cols[name] = results
-                    elif kind == "wstart":
-                        cols[name] = starts
-                    else:
-                        cols[name] = ends
-                out = RecordBatch(cols, ends - 1)
+                out = self._out_batch(keys_np, results, starts, ends)
             with tracer.phase("window.fire.downstream"):
                 self.output.collect(StreamRecord(out, timestamp=out_ts))
         del fired[:]
-
-    # ---- checkpoint -------------------------------------------------
-    def snapshot_state(self, checkpoint_id: Optional[int] = None) -> dict:
-        snap = super().snapshot_state(checkpoint_id)
-        if self.engine is not None:
-            snap["columnar_engine"] = self.engine.snapshot()
-            from flink_tpu.parallel.mesh_log import _MeshShardedLogEngine
-            from flink_tpu.streaming import log_windows as lw
-            if isinstance(self.engine, lw.StringSumTumblingWindows):
-                snap["columnar_tier"] = "string_sum"
-            elif isinstance(self.engine, _MeshShardedLogEngine):
-                snap["columnar_tier"] = "mesh_log"
-            elif isinstance(self.engine, (lw.LogStructuredTumblingWindows,
-                                          lw.LogStructuredSessionWindows)):
-                snap["columnar_tier"] = "log"
-            else:
-                snap["columnar_tier"] = "vectorized"
-        return snap
-
-    def _kg_keep_fn(self):
-        """Key-group-range filter for rescaled restores (the shared
-        definition, so re-split state lands exactly where the split
-        exchange routes live records)."""
-        from flink_tpu.core.keygroups import make_key_group_keep_fn
-        return make_key_group_keep_fn(self.max_parallelism,
-                                      self.num_subtasks,
-                                      self.subtask_index)
-
-    def _build_engine_for_tier(self, tier):
-        if tier == "string_sum":
-            eng = self._string_engine()
-            if eng is None:
-                raise RuntimeError(
-                    "checkpoint was taken on the fused string-sum "
-                    "tier, unavailable here")
-            return eng
-        if tier == "mesh_log":
-            from flink_tpu.parallel.mesh_log import (
-                mesh_log_engine_for_assigner,
-            )
-            from flink_tpu.streaming.device_window_operator import (
-                resolve_mesh,
-            )
-            self.mesh = resolve_mesh(self.mesh)
-            if self.mesh is None:
-                raise RuntimeError(
-                    "checkpoint was taken on the mesh log tier; "
-                    "restoring requires a mesh (env.set_mesh)")
-            eng = mesh_log_engine_for_assigner(
-                self.assigner, self.agg, self.mesh,
-                axis=self.mesh_axis,
-                max_parallelism=self.max_parallelism)
-            if eng is None:
-                raise RuntimeError(
-                    "checkpoint was taken on the mesh log tier, which "
-                    "is unavailable here (native runtime required)")
-            return eng
-        is_log = tier == "log"
-        key_dtype = (np.dtype(np.uint64) if is_log
-                     else np.dtype(object))
-        return self._make_engine(key_dtype, require_log=is_log)
-
-    def restore_state(self, snapshots) -> None:
-        super().restore_state(snapshots)
-        engine_snaps = [s for s in snapshots if "columnar_engine" in s]
-        if not engine_snaps:
-            return
-        tiers = {s.get("columnar_tier") for s in engine_snaps}
-        if len(tiers) > 1:
-            raise ValueError(
-                f"snapshots span engine tiers {sorted(tiers)}; cannot "
-                "merge across tiers")
-        tier = tiers.pop()
-        rescaled = any(
-            s.get("restore_old_parallelism", self.num_subtasks)
-            != self.num_subtasks for s in engine_snaps)
-        if self.engine is None:
-            self.engine = self._build_engine_for_tier(tier)
-            if hasattr(self.engine, "fired"):
-                self.engine.emit_arrays = True
-        if not rescaled and len(engine_snaps) == 1:
-            self.engine.restore(engine_snaps[0]["columnar_engine"])
-            return
-        # parallelism changed: merge the old subtasks' engine states
-        # and keep only this subtask's key groups (ref:
-        # StateAssignmentOperation key-group re-split)
-        if not hasattr(self.engine, "restore_many"):
-            raise ValueError(
-                f"the {tier!r} engine tier cannot re-split its state "
-                "across a parallelism change; restore at the "
-                "checkpointed parallelism")
-        self.engine.restore_many(
-            [s["columnar_engine"] for s in engine_snaps],
-            keep_fn=self._kg_keep_fn())
 
 
 class BatchKeyGroupSplitOperator(StreamOperator):

@@ -947,37 +947,44 @@ class WindowedStream:
     # ---- terminal ops -----------------------------------------------
     def aggregate(self, aggregate_function: AggregateFunction,
                   window_function=None, name: str = "window_aggregate") -> DataStream:
-        """(ref: WindowedStream.aggregate :687-716).  Device-eligible
-        aggregates (DeviceAggregateFunction + event-time tumbling/
-        sliding/session, default trigger, no evictor, lateness 0) run
-        on the vectorized TPU engines via DeviceWindowOperator; the
-        rest stay on the scalar WindowOperator."""
-        from flink_tpu.streaming.device_window_operator import (
-            DeviceWindowOperator,
-            is_device_eligible,
+        """(ref: WindowedStream.aggregate :687-716).  Windows the
+        batched engines cover (window_engines.batched_operator_kind:
+        event-time tumbling/sliding/session, default trigger, no
+        evictor, lateness 0) run on DeviceWindowOperator where the
+        aggregate is a DeviceAggregateFunction and on
+        GenericWindowOperator where it is any other; the rest stay on
+        the scalar WindowOperator."""
+        from flink_tpu.streaming.window_engines import (
+            batched_operator_kind,
+            is_mesh_factory,
         )
+        kind = None
         if (self._device_enabled
-                and self._keyed.env.time_characteristic == "event"
-                and is_device_eligible(
-                    self._assigner, aggregate_function, self._trigger,
-                    self._evictor, self._allowed_lateness, self._late_tag,
-                    window_function)):
-            assigner = self._assigner
+                and self._keyed.env.time_characteristic == "event"):
+            kind = batched_operator_kind(
+                self._assigner, aggregate_function, self._trigger,
+                self._evictor, self._allowed_lateness, self._late_tag,
+                window_function)
+        assigner = self._assigner
+        if kind == "device":
+            from flink_tpu.streaming.device_window_operator import (
+                DeviceWindowOperator,
+            )
             env = self._keyed.env
             mesh, mesh_axis = env.mesh, env.mesh_axis
             from flink_tpu.streaming.windowing import (
                 TumblingEventTimeWindows as _Tumbling,
             )
             if mesh is not None and not isinstance(assigner, _Tumbling):
-                mesh = None  # only tumbling has a sharded engine so far
+                # sliding and session windows have sharded engines too
+                # (window_engines), but no DataStream job has been given
+                # a mesh for them yet: ROADMAP Design 1
+                mesh = None
 
             def factory():
                 return DeviceWindowOperator(assigner, aggregate_function,
                                             window_function,
                                             mesh=mesh, mesh_axis=mesh_axis)
-            from flink_tpu.streaming.device_window_operator import (
-                is_mesh_factory,
-            )
             if mesh is not None and not is_mesh_factory(mesh):
                 # the mesh IS the parallelism: one host subtask drives
                 # the SPMD program over all devices; upstream edges
@@ -990,21 +997,14 @@ class WindowedStream:
             # exchange spans processes, each subtask's own mesh spans
             # its local devices)
             return self._keyed._add_keyed_op(name, factory, chaining="head")
-        # arbitrary Python aggregates with the same eligible window
-        # shapes ride the generic vectorized log tier (sort + diagonal
-        # -round fold of the user's add over numpy columns) instead of
-        # the per-record scalar WindowOperator
-        from flink_tpu.streaming.generic_agg import (
-            GenericWindowOperator,
-            is_generic_eligible,
-        )
-        if (self._device_enabled
-                and self._keyed.env.time_characteristic == "event"
-                and is_generic_eligible(
-                    self._assigner, aggregate_function, self._trigger,
-                    self._evictor, self._allowed_lateness,
-                    self._late_tag, window_function)):
-            assigner = self._assigner
+        if kind == "generic":
+            # arbitrary Python aggregates ride the generic vectorized
+            # log tier (sort + diagonal-round fold of the user's add
+            # over numpy columns) instead of the per-record scalar
+            # WindowOperator
+            from flink_tpu.streaming.generic_agg import (
+                GenericWindowOperator,
+            )
 
             def gfactory():
                 return GenericWindowOperator(assigner,

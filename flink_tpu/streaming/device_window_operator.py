@@ -13,12 +13,14 @@ the rows do not columnarize.  Checkpoints snapshot the engine (device arrays
 DMA'd to host + host indexes) so barrier checkpointing, recovery, and
 restarts work identically to the scalar path.
 
-Eligibility is decided by the graph builder (see
-WindowedStream._build): DeviceAggregateFunction + event-time
-tumbling/sliding/session assigner + default trigger, no evictor,
-lateness 0.  Anything else stays on the scalar WindowOperator — same
-split the reference drew between its (removed) aligned-window fast
-operators and the general WindowOperator (WindowOperator.java:192-195).
+Which jobs get this operator (DeviceAggregateFunction + an aligned
+event-time assigner + default trigger, no evictor, lateness 0) and
+which engine it hosts are decided in flink_tpu.streaming.window_engines;
+anything else stays on the scalar WindowOperator — same split the
+reference drew between its (removed) aligned-window fast operators and
+the general WindowOperator (WindowOperator.java:192-195).  What is
+this operator's own: the two doors, the watermark policy and the emit
+tail.
 """
 
 from __future__ import annotations
@@ -32,165 +34,36 @@ from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.streaming import columnar
 from flink_tpu.streaming.elements import (MAX_TIMESTAMP, RecordBatch,
     StreamRecord, Watermark)
-from flink_tpu.streaming.operators import StreamOperator
-from flink_tpu.streaming.vectorized import (
-    VectorizedSlidingWindows,
-    VectorizedTumblingWindows,
-    hash_keys_np,
+from flink_tpu.streaming.vectorized import hash_keys_np
+from flink_tpu.streaming.window_engines import (
+    WindowEngineHost,
+    string_sum_fits,
 )
-from flink_tpu.streaming.vectorized_sessions import VectorizedSessionWindows
 from flink_tpu.streaming.window_operator import _FireBufferOutput
-from flink_tpu.streaming.windowing import (
-    EventTimeSessionWindows,
-    SlidingEventTimeWindows,
-    TimeWindow,
-    TumblingEventTimeWindows,
-)
+from flink_tpu.streaming.windowing import TimeWindow
 
 
-def assigner_supported(assigner) -> bool:
-    """Shape check shared by the fail-fast open() and the planner: the
-    assigners the single-device engines (either tier) cover."""
-    if isinstance(assigner, TumblingEventTimeWindows):
-        return assigner.offset == 0
-    if isinstance(assigner, SlidingEventTimeWindows):
-        return assigner.offset == 0 and assigner.size % assigner.slide == 0
-    return isinstance(assigner, EventTimeSessionWindows)
-
-
-def string_sum_engine_for_assigner(assigner, agg: DeviceAggregateFunction):
-    """Fused intern+sum engine for STRING-keyed tumbling sums, or None
-    when the shape doesn't fit.  Floating accumulation only: the C++
-    kernel sums in double, so integer value dtypes (exact beyond 2^53)
-    must stay on the exact tiers."""
-    from flink_tpu.ops.device_agg import SumAggregate
-    from flink_tpu.streaming.log_windows import StringSumTumblingWindows
-    if (isinstance(agg, SumAggregate)
-            and np.issubdtype(agg.value_dtype, np.floating)
-            and isinstance(assigner, TumblingEventTimeWindows)
-            and assigner.offset == 0):
-        return StringSumTumblingWindows(agg, assigner.size)
-    return None
-
-
-def log_engine_for_assigner(assigner, agg: DeviceAggregateFunction):
-    """Log-structured combiner tier for this assigner+aggregate, or
-    None when the cell decomposition / assigner shape doesn't fit
-    (streaming/log_windows.py scope: integer keys, HLL/Sum/Quantile
-    cells, Count-Min sessions).  A missing native runtime is an error
-    (the engines raise RuntimeError), never a reason to hand the job
-    to another engine."""
-    from flink_tpu.streaming import log_windows as lw
-    try:
-        if isinstance(assigner, TumblingEventTimeWindows) \
-                and assigner.offset == 0:
-            return lw.LogStructuredTumblingWindows(agg, assigner.size)
-        if (isinstance(assigner, SlidingEventTimeWindows)
-                and assigner.offset == 0
-                and assigner.size % assigner.slide == 0):
-            return lw.LogStructuredSlidingWindows(agg, assigner.size,
-                                                  assigner.slide)
-        if isinstance(assigner, EventTimeSessionWindows):
-            return lw.LogStructuredSessionWindows(agg, assigner.gap)
-    except (TypeError, ValueError):
-        pass  # unsupported cell decomposition / params
-    return None
-
-
-def engine_for_assigner(assigner, agg: DeviceAggregateFunction,
-                        initial_capacity: int = 1 << 14, mesh=None,
-                        mesh_axis: str = "kg", max_parallelism: int = 128):
-    """Assigner → engine, or None when no device engine applies.  With
-    a mesh, tumbling windows run on the sharded multi-window engine
-    (SPMD over the mesh axis, flink_tpu.parallel.mesh_windows); other
-    assigners fall back to the single-device engines."""
-    if isinstance(assigner, TumblingEventTimeWindows) and assigner.offset == 0:
-        if mesh is not None:
-            from flink_tpu.parallel.mesh_windows import MeshTumblingWindows
-            return MeshTumblingWindows(
-                agg, assigner.size, mesh, axis=mesh_axis,
-                max_parallelism=max_parallelism,
-                capacity_per_window_shard=max(
-                    1 << 8, initial_capacity // mesh.shape[mesh_axis]))
-        return VectorizedTumblingWindows(agg, assigner.size,
-                                         initial_capacity=initial_capacity)
-    if isinstance(assigner, SlidingEventTimeWindows):
-        if assigner.size % assigner.slide == 0 and assigner.offset == 0:
-            if mesh is not None:
-                from flink_tpu.parallel.mesh_windows import (
-                    MeshSlidingWindows,
-                )
-                return MeshSlidingWindows(
-                    agg, assigner.size, assigner.slide, mesh,
-                    axis=mesh_axis, max_parallelism=max_parallelism,
-                    capacity_per_window_shard=max(
-                        1 << 8, initial_capacity // mesh.shape[mesh_axis]))
-            return VectorizedSlidingWindows(agg, assigner.size,
-                                            assigner.slide,
-                                            initial_capacity=initial_capacity)
-        return None
-    if isinstance(assigner, EventTimeSessionWindows):
-        return VectorizedSessionWindows(agg, assigner.gap,
-                                        initial_capacity=initial_capacity)
-    return None
-
-
-def is_mesh_factory(mesh) -> bool:
-    """True for a callable that BUILDS a mesh (the pod-topology
-    per-process factory) as opposed to a Mesh instance — jax's Mesh is
-    itself callable (a context decorator), so `callable` alone cannot
-    discriminate; factories have no device grid `.shape`."""
-    return callable(mesh) and not hasattr(mesh, "shape")
-
-
-def resolve_mesh(mesh):
-    """Mesh | mesh-factory | None → Mesh | None (factories resolve in
-    the CURRENT process; device handles cannot ride a pickled graph)."""
-    return mesh() if is_mesh_factory(mesh) else mesh
-
-
-def is_device_eligible(assigner, aggregate_function, trigger, evictor,
-                       allowed_lateness, late_tag, window_function) -> bool:
-    """The graph-builder gate for the device fast path."""
-    if not isinstance(aggregate_function, DeviceAggregateFunction):
-        return False
-    if trigger is not None or evictor is not None:
-        return False
-    if allowed_lateness != 0 or late_tag is not None:
-        return False
-    if window_function is not None and not callable(window_function):
-        return False
-    if isinstance(assigner, SlidingEventTimeWindows):
-        return assigner.size % assigner.slide == 0 and assigner.offset == 0
-    if isinstance(assigner, TumblingEventTimeWindows):
-        return assigner.offset == 0
-    return isinstance(assigner, EventTimeSessionWindows)
-
-
-class DeviceWindowOperator(StreamOperator):
+class DeviceWindowOperator(WindowEngineHost):
     """Batched, device-backed twin of WindowOperator for the eligible
     aggregate path.  The key selector is applied per record at buffer
     time, per column at the batch door (the operator IS the keyed
     state; no keyed backend needed)."""
 
+    engine_key, tier_key = "device_engine", "device_tier"
+    mesh_scatter = True
+
     def __init__(self, assigner, aggregate_function: DeviceAggregateFunction,
                  window_function=None, flush_batch: int = 8192,
                  initial_capacity: int = 1 << 14, mesh=None,
                  mesh_axis: str = "kg"):
-        super().__init__()
-        self.assigner = assigner
-        self.agg = aggregate_function
+        super().__init__(assigner, aggregate_function, initial_capacity,
+                         mesh, mesh_axis)
         self.window_function = window_function
         self.flush_batch = flush_batch
-        self.initial_capacity = initial_capacity
-        self.mesh = mesh
-        self.mesh_axis = mesh_axis
-        self.engine = None
         self._keys: List[Any] = []
         self._ts: List[int] = []
         self._values: List[Any] = []
         self._last_fireable = None
-        self.num_late_records_dropped = 0  # metric parity
         #: rows the fires handed on with no StreamRecord of their own
         #: / wrapped in one on their way (no window function this
         #: operator takes writes through a collector: always 0)
@@ -206,7 +79,7 @@ class DeviceWindowOperator(StreamOperator):
 
     # ---- lifecycle --------------------------------------------------
     def open(self):
-        if not assigner_supported(self.assigner):
+        if self.shape is None:
             # fail fast at open, not at the first flush
             raise ValueError(
                 f"no device engine for assigner {self.assigner!r}")
@@ -220,9 +93,6 @@ class DeviceWindowOperator(StreamOperator):
             self._emit_batch_hist = self.metrics.histogram("emitBatchSize")
 
     # ---- input ------------------------------------------------------
-    def set_key_context(self, record):
-        pass  # no keyed backend; keys resolve vectorized at flush
-
     def process_element(self, record: StreamRecord):
         if record.timestamp is None:
             raise ValueError(
@@ -282,68 +152,14 @@ class DeviceWindowOperator(StreamOperator):
                     rows if rows is not None else batch.row_values())
         return self._maybe_intern(keys), vals
 
-    def _wants_fused_string_sum(self) -> bool:
-        from flink_tpu.ops.device_agg import SumAggregate
-        from flink_tpu.streaming.log_windows import StringSumTumblingWindows
+    def _feeds_raw_strings(self) -> bool:
+        """The fused string-sum engine consumes raw strings (intern +
+        dense sum in one C++ pass) and emits the original words itself:
+        no interning here where the ladder will pick it — or has, and
+        later batches must keep feeding it raw strings."""
         if self.engine is not None:
-            # locked at first flush; later batches must keep feeding
-            # the fused engine raw strings
-            return isinstance(self.engine, StringSumTumblingWindows)
-        return (self.mesh is None
-                and isinstance(self.agg, SumAggregate)
-                and np.issubdtype(self.agg.value_dtype, np.floating)
-                and isinstance(self.assigner, TumblingEventTimeWindows)
-                and self.assigner.offset == 0)
-
-    def _ensure_engine(self, keys_arr: np.ndarray):
-        """Tier selection on the first flush: integer-keyed streams get
-        the log-structured combiner tier when the aggregate has a cell
-        decomposition (string keys reach it through the interner);
-        string-keyed tumbling sums get the fused wordcount engine;
-        everything else (and every aggregate the log tier doesn't
-        cover) runs the device-resident scatter tier.  With a mesh,
-        the sharded twins take over: the mesh log tier (all_to_all
-        keyBy exchange + per-shard log fires, parallel/mesh_log.py)
-        when eligible, else the sharded scatter engines."""
-        if self.engine is not None:
-            return
-        self.mesh = resolve_mesh(self.mesh)
-        if self.mesh is not None:
-            if np.issubdtype(keys_arr.dtype, np.integer):
-                from flink_tpu.parallel.mesh_log import (
-                    mesh_log_engine_for_assigner,
-                )
-                self.engine = mesh_log_engine_for_assigner(
-                    self.assigner, self.agg, self.mesh,
-                    axis=self.mesh_axis,
-                    max_parallelism=self.max_parallelism)
-            if self.engine is None:
-                self.engine = engine_for_assigner(
-                    self.assigner, self.agg, self.initial_capacity,
-                    mesh=self.mesh, mesh_axis=self.mesh_axis,
-                    max_parallelism=self.max_parallelism)
-            if self.engine is None:
-                raise ValueError(
-                    f"no device engine for assigner {self.assigner!r}")
-        if self.engine is None \
-                and keys_arr.dtype.kind in "US" and keys_arr.ndim == 1 \
-                and self._wants_fused_string_sum():
-            self.engine = string_sum_engine_for_assigner(self.assigner,
-                                                         self.agg)
-        if self.engine is None and np.issubdtype(keys_arr.dtype, np.integer):
-            self.engine = log_engine_for_assigner(self.assigner, self.agg)
-        if self.engine is None:
-            self.engine = engine_for_assigner(self.assigner, self.agg,
-                                              self.initial_capacity)
-        if self.engine is None:
-            raise ValueError(
-                f"no device engine for assigner {self.assigner!r}")
-        # fast-forward a lazily created engine to the operator's
-        # watermark — records behind it must count as LATE, not be
-        # aggregated into windows that already passed downstream
-        wm = getattr(self, "current_watermark", None)
-        if wm is not None and wm > -(2 ** 63):
-            self.engine.advance_watermark(wm)
+            return self.tier == "string_sum"
+        return self.mesh is None and string_sum_fits(self.shape, self.agg)
 
     def _flush_buffer(self):
         if not self._keys:
@@ -373,7 +189,11 @@ class DeviceWindowOperator(StreamOperator):
         """One batch into the engine, whichever door it came by; the
         value column is hashed here, once, where the aggregate is a
         distinct-count sketch."""
-        self._ensure_engine(keys_arr)
+        if self.engine is None:
+            # composite keys coerce to 2-D string arrays whose rows
+            # are tuples: not a string column
+            self._build_engine(keys_arr.dtype if keys_arr.ndim == 1
+                               else np.dtype(object))
         hashes = None
         if self.agg.needs_value_hash:
             with get_tracer().phase("columnar.ingest.hash"):
@@ -395,10 +215,7 @@ class DeviceWindowOperator(StreamOperator):
             import flink_tpu.native as nat
             if not nat.available():
                 return keys_arr
-            if self._wants_fused_string_sum():
-                # the fused wordcount engine consumes raw strings
-                # (intern + dense sum in one C++ pass) and emits the
-                # original words itself
+            if self._feeds_raw_strings():
                 return keys_arr
             self._interner = nat.NativeStringInterner()
         elif keys_arr.dtype.kind not in "US":
@@ -417,7 +234,7 @@ class DeviceWindowOperator(StreamOperator):
         # can fire, so the watermark forwards without touching the
         # engine.
         wm = watermark.timestamp
-        grid = self._fire_grid()
+        grid = self.shape.grid
         if grid is not None and wm != MAX_TIMESTAMP:
             fireable = ((wm + 1) // grid) * grid if wm >= 0 else None
             if fireable is not None and fireable == self._last_fireable:
@@ -434,11 +251,7 @@ class DeviceWindowOperator(StreamOperator):
 
     def _fire(self, wm: int) -> None:
         engine = self.engine
-        # engines that can hand a fire over as arrays do; the rest
-        # (VectorizedSessionWindows) deliver one tuple per result
-        as_arrays = hasattr(engine, "fired")
-        if as_arrays:
-            engine.emit_arrays = True
+        as_arrays = getattr(engine, "emit_arrays", False)
         with get_tracer().phase("device_window.fire", watermark=wm):
             engine.advance_watermark(wm)
         if as_arrays:
@@ -451,15 +264,6 @@ class DeviceWindowOperator(StreamOperator):
         if self.metrics is not None:
             self.metrics.counter(
                 "numLateRecordsDropped").count = engine.num_late_dropped
-
-    def _fire_grid(self):
-        """Window-end alignment grid of the assigner, or None when
-        fires can happen at arbitrary times (sessions)."""
-        if isinstance(self.assigner, SlidingEventTimeWindows):
-            return self.assigner.slide
-        if isinstance(self.assigner, TumblingEventTimeWindows):
-            return self.assigner.size
-        return None
 
     def _emit_fires(self, fires) -> None:
         """Each fire — (keys, results, window start, window end), the
@@ -514,19 +318,6 @@ class DeviceWindowOperator(StreamOperator):
     def snapshot_state(self, checkpoint_id: Optional[int] = None) -> dict:
         self._flush_buffer()
         snap = super().snapshot_state(checkpoint_id)
-        if self.engine is not None:
-            from flink_tpu.parallel.mesh_log import _MeshShardedLogEngine
-            from flink_tpu.streaming import log_windows as lw
-            snap["device_engine"] = self.engine.snapshot()
-            if isinstance(self.engine, lw.StringSumTumblingWindows):
-                snap["device_tier"] = "string_sum"
-            elif isinstance(self.engine, _MeshShardedLogEngine):
-                snap["device_tier"] = "mesh_log"
-            elif isinstance(self.engine, (lw.LogStructuredTumblingWindows,
-                                          lw.LogStructuredSessionWindows)):
-                snap["device_tier"] = "log"
-            else:
-                snap["device_tier"] = "vectorized"
         if self._interner is not None:
             # ids are dense first-seen: the directory alone rebuilds
             # the interner on restore (re-interning in order
@@ -534,100 +325,21 @@ class DeviceWindowOperator(StreamOperator):
             snap["string_key_directory"] = list(self._id_to_key)
         return snap
 
-    def _kg_keep_fn(self):
-        """Key-group filter for rescaled restores (the shared
-        definition, so re-split engine state lands where the runtime's
-        keyBy partitioner routes live records)."""
-        from flink_tpu.core.keygroups import make_key_group_keep_fn
-        return make_key_group_keep_fn(self.max_parallelism,
-                                      self.num_subtasks,
-                                      self.subtask_index)
-
     def restore_state(self, snapshots) -> None:
+        directories = [s["string_key_directory"] for s in snapshots
+                       if s.get("string_key_directory") is not None]
+        if directories and self._resplits(snapshots):
+            raise ValueError(
+                "device window operator cannot re-split "
+                "dictionary-encoded string-keyed engine state "
+                "across a parallelism change; restore at the "
+                "checkpointed parallelism")
         super().restore_state(snapshots)
-        engine_snaps = [s for s in snapshots if "device_engine" in s]
-        rescaled = any(
-            s.get("restore_old_parallelism", self.num_subtasks)
-            != self.num_subtasks for s in snapshots)
-        if rescaled or len(engine_snaps) > 1:
-            if any(s.get("string_key_directory") is not None
-                   for s in snapshots):
-                raise ValueError(
-                    "device window operator cannot re-split "
-                    "dictionary-encoded string-keyed engine state "
-                    "across a parallelism change; restore at the "
-                    "checkpointed parallelism")
-            tiers = {s.get("device_tier") for s in engine_snaps}
-            if len(tiers) > 1:
-                raise ValueError(
-                    f"snapshots span engine tiers {sorted(tiers)}")
-            if engine_snaps:
-                tier = tiers.pop()
-                if self.engine is None:
-                    if tier == "log":
-                        self.engine = log_engine_for_assigner(
-                            self.assigner, self.agg)
-                    elif tier == "string_sum":
-                        self.engine = string_sum_engine_for_assigner(
-                            self.assigner, self.agg)
-                    if self.engine is None \
-                            or not hasattr(self.engine, "restore_many"):
-                        raise ValueError(
-                            f"the {tier!r} engine tier cannot re-split "
-                            "its state across a parallelism change; "
-                            "restore at the checkpointed parallelism")
-                self.engine.restore_many(
-                    [s["device_engine"] for s in engine_snaps],
-                    keep_fn=self._kg_keep_fn())
-            return
-        for s in snapshots:
-            if s.get("string_key_directory") is not None:
-                import flink_tpu.native as nat
-                directory = s["string_key_directory"]
-                self._interner = nat.NativeStringInterner(
-                    max(16, 2 * len(directory)))
-                self._id_to_key = list(directory)
-                if directory:
-                    ids, _ = self._interner.intern(np.asarray(directory))
-                    assert int(ids[-1]) == len(directory) - 1
-            if "device_engine" in s:
-                if self.engine is None:
-                    if s.get("device_tier") == "string_sum":
-                        from flink_tpu.streaming.log_windows import (
-                            StringSumTumblingWindows,
-                        )
-                        self.engine = StringSumTumblingWindows(
-                            self.agg, self.assigner.size)
-                    elif s.get("device_tier") == "log":
-                        self.engine = log_engine_for_assigner(
-                            self.assigner, self.agg)
-                        if self.engine is None:
-                            raise RuntimeError(
-                                "checkpoint was taken on the log engine "
-                                "tier, which is unavailable here (native "
-                                "runtime required)")
-                    elif s.get("device_tier") == "mesh_log":
-                        from flink_tpu.parallel.mesh_log import (
-                            mesh_log_engine_for_assigner,
-                        )
-                        self.mesh = resolve_mesh(self.mesh)
-                        if self.mesh is None:
-                            raise RuntimeError(
-                                "checkpoint was taken on the mesh log "
-                                "tier; restoring requires a mesh "
-                                "(env.set_mesh)")
-                        self.engine = mesh_log_engine_for_assigner(
-                            self.assigner, self.agg, self.mesh,
-                            axis=self.mesh_axis,
-                            max_parallelism=self.max_parallelism)
-                        if self.engine is None:
-                            raise RuntimeError(
-                                "checkpoint was taken on the mesh log "
-                                "tier, which is unavailable here "
-                                "(native runtime required)")
-                    else:
-                        self.engine = engine_for_assigner(
-                            self.assigner, self.agg, self.initial_capacity,
-                            mesh=self.mesh, mesh_axis=self.mesh_axis,
-                            max_parallelism=self.max_parallelism)
-                self.engine.restore(s["device_engine"])
+        import flink_tpu.native as nat
+        for directory in directories:
+            self._interner = nat.NativeStringInterner(
+                max(16, 2 * len(directory)))
+            self._id_to_key = list(directory)
+            if directory:
+                ids, _ = self._interner.intern(np.asarray(directory))
+                assert int(ids[-1]) == len(directory) - 1

@@ -60,7 +60,6 @@ __all__ = [
     "GenericLogSessionWindows",
     "GenericWindowOperator",
     "generic_engine_for_assigner",
-    "is_generic_eligible",
 ]
 
 _NUMERIC = (int, float, bool, np.integer, np.floating, np.bool_)
@@ -1518,32 +1517,6 @@ class GenericLogSessionWindows(_GenericLogEngine):
                         else [c[k2] for c in nc])
 
 
-def is_generic_eligible(assigner, aggregate_function, trigger, evictor,
-                        allowed_lateness, late_tag,
-                        window_function) -> bool:
-    """Graph-builder gate for the generic vectorized tier: same shape
-    constraints as the device gate (event-time aligned assigners,
-    default trigger, no evictor, zero lateness) but for ANY Python
-    AggregateFunction (ref: the one-operator-serves-all contract of
-    WindowOperator.java:291-421)."""
-    from flink_tpu.streaming.windowing import (
-        EventTimeSessionWindows,
-        SlidingEventTimeWindows,
-        TumblingEventTimeWindows,
-    )
-    if trigger is not None or evictor is not None:
-        return False
-    if allowed_lateness != 0 or late_tag is not None:
-        return False
-    if window_function is not None and not callable(window_function):
-        return False
-    if isinstance(assigner, SlidingEventTimeWindows):
-        return assigner.size % assigner.slide == 0 and assigner.offset == 0
-    if isinstance(assigner, TumblingEventTimeWindows):
-        return assigner.offset == 0
-    return isinstance(assigner, EventTimeSessionWindows)
-
-
 class GenericWindowOperator(StreamOperator):
     """Batched window operator for ARBITRARY Python AggregateFunctions
     — the DataStream-facing face of the generic log engines.  Buffers
@@ -1781,21 +1754,8 @@ def generic_engine_for_assigner(assigner, aggregate,
                                 compact_threshold: int = 1 << 21):
     """Assigner → generic log engine, or None when the assigner shape
     has no generic tier (custom assigners stay on the scalar path)."""
-    from flink_tpu.streaming.windowing import (
-        EventTimeSessionWindows,
-        SlidingEventTimeWindows,
-        TumblingEventTimeWindows,
-    )
-    if isinstance(assigner, TumblingEventTimeWindows) \
-            and assigner.offset == 0:
-        return GenericLogTumblingWindows(
-            aggregate, assigner.size, compact_threshold)
-    if isinstance(assigner, SlidingEventTimeWindows) \
-            and assigner.offset == 0 \
-            and assigner.size % assigner.slide == 0:
-        return GenericLogSlidingWindows(
-            aggregate, assigner.size, assigner.slide, compact_threshold)
-    if isinstance(assigner, EventTimeSessionWindows):
-        return GenericLogSessionWindows(
-            aggregate, assigner.gap, compact_threshold)
-    return None
+    from flink_tpu.streaming.window_engines import aligned_shape
+    shape = aligned_shape(assigner)
+    return shape and shape.build(
+        GenericLogTumblingWindows, GenericLogSlidingWindows,
+        GenericLogSessionWindows, aggregate, compact_threshold)

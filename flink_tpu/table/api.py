@@ -573,9 +573,7 @@ def _try_columnar_windowed_agg(table: Table, keys: List[Expr],
     # topology) keeps the env parallelism: the split exchange shards
     # keys across subtasks/processes and each subtask's own mesh
     # shards its range (same contract as the DataStream path).
-    from flink_tpu.streaming.device_window_operator import (
-        is_mesh_factory,
-    )
+    from flink_tpu.streaming.window_engines import is_mesh_factory
     env = table.stream.env
     mesh = (env.mesh if env.parallelism == 1
             or is_mesh_factory(env.mesh) else None)

@@ -236,8 +236,10 @@ def test_columnar_string_key_wordcount_matches_rowpath():
     op = ColumnarWindowOperator(
         TumblingEventTimeWindows.of(1000), SumAggregate(np.float64),
         "k", "u", [("k", "key"), ("c", "agg")])
-    assert isinstance(op._make_engine(words.dtype),
-                      StringSumTumblingWindows)
+    from flink_tpu.streaming.window_engines import select_engine
+    engine, tier = select_engine(op, words.dtype)
+    assert isinstance(engine, StringSumTumblingWindows)
+    assert tier == "string_sum"
 
 
 def test_columnar_interval_join_matches_rowpath():

@@ -9,10 +9,8 @@ from flink_tpu.core.functions import MapFunction
 from flink_tpu.ops.device_agg import CountAggregate, SumAggregate
 from flink_tpu.ops.sketches import HyperLogLogAggregate
 from flink_tpu.streaming.datastream import StreamExecutionEnvironment
-from flink_tpu.streaming.device_window_operator import (
-    DeviceWindowOperator,
-    is_device_eligible,
-)
+from flink_tpu.streaming.device_window_operator import DeviceWindowOperator
+from flink_tpu.streaming.window_engines import batched_operator_kind
 from flink_tpu.streaming.sources import CollectSink
 from flink_tpu.streaming.windowing import (
     CountTrigger,
@@ -70,23 +68,23 @@ def test_device_path_matches_scalar_through_api(assigner_factory):
 def test_eligibility_gate():
     tumbling = TumblingEventTimeWindows.of(Time.seconds(1))
     dev_agg = SumAggregate(np.float32)
-    assert is_device_eligible(tumbling, dev_agg, None, None, 0, None, None)
+    kind = batched_operator_kind
+    assert kind(tumbling, dev_agg, None, None, 0, None, None) == "device"
     # custom trigger → scalar
-    assert not is_device_eligible(tumbling, dev_agg, CountTrigger(5),
-                                  None, 0, None, None)
+    assert kind(tumbling, dev_agg, CountTrigger(5), None, 0, None,
+                None) is None
     # lateness → scalar
-    assert not is_device_eligible(tumbling, dev_agg, None, None, 100,
-                                  None, None)
+    assert kind(tumbling, dev_agg, None, None, 100, None, None) is None
 
-    # plain (non-device) AggregateFunction → scalar
+    # plain (non-device) AggregateFunction → the generic operator
     class Plain:
         pass
-    assert not is_device_eligible(tumbling, Plain(), None, None, 0,
-                                  None, None)
-    # unaligned sliding → scalar
+    assert kind(tumbling, Plain(), None, None, 0, None, None) == "generic"
+    # unaligned sliding → scalar, whatever the aggregate
     s = SlidingEventTimeWindows.of(Time.milliseconds_of(2500),
                                    Time.seconds(1))
-    assert not is_device_eligible(s, dev_agg, None, None, 0, None, None)
+    assert kind(s, dev_agg, None, None, 0, None, None) is None
+    assert kind(s, Plain(), None, None, 0, None, None) is None
 
 
 def test_graph_selects_device_operator():

@@ -306,8 +306,9 @@ def test_columnar_operator_selects_mesh_tier():
     op = ColumnarWindowOperator(
         TumblingEventTimeWindows.of(1000), HyperLogLogAggregate(10),
         "k", "u", [("k", "key"), ("d", "agg")], mesh=mesh)
-    eng = op._make_engine(np.dtype(np.int64))
-    assert isinstance(eng, _MeshShardedLogEngine)
+    from flink_tpu.streaming.window_engines import select_engine
+    eng, tier = select_engine(op, np.dtype(np.int64))
+    assert isinstance(eng, _MeshShardedLogEngine) and tier == "mesh_log"
 
 
 def test_datastream_session_job_on_mesh():

@@ -288,7 +288,11 @@ def test_minicluster_checkpoint_gauges_and_latency():
     env.enable_checkpointing(1)
     env.set_latency_tracking_interval(5)
     sink = CollectSink()
-    (env.from_collection(records, timestamped=True)
+    # the source holds its tail until a checkpoint has completed: on a
+    # loaded host the 8,000 records were through before the first one
+    from flink_tpu.runtime.chaos import CheckpointGatedSource
+    CheckpointGatedSource.completed = False
+    (env.add_source(CheckpointGatedSource(records, timestamped=True))
         .key_by(lambda v: v[0])
         .time_window(Time.milliseconds_of(500))
         .aggregate(SumAgg())
@@ -572,8 +576,7 @@ def test_mesh_sliding_blocked_window_fires_on_later_call(mesh):
 def test_mesh_sliding_window_job_on_minicluster(mesh):
     """keyBy().window(Sliding...).aggregate(device_agg) over the mesh,
     executed from a JobGraph — the sliding twin of the tumbling mesh
-    job (engine_for_assigner routes sliding+mesh to
-    MeshSlidingWindows)."""
+    job."""
     from flink_tpu.streaming.windowing import SlidingEventTimeWindows
     events = _sorted_events(n=500, n_keys=30, horizon=5000, seed=13)
     env = StreamExecutionEnvironment()

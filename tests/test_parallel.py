@@ -7,14 +7,15 @@ import pytest
 from jax.sharding import Mesh
 
 from flink_tpu.core.keygroups import assign_key_groups_np, splitmix64_np
-from flink_tpu.ops.device_agg import CountAggregate, SumAggregate
+from flink_tpu.ops.device_agg import CountAggregate
 from flink_tpu.ops.device_table import (
     insert_or_lookup,
     lookup_np,
     make_table,
 )
 from flink_tpu.ops.sketches import HyperLogLogAggregate
-from flink_tpu.parallel import MeshWindowAggregation
+from flink_tpu.parallel import MeshTumblingWindows
+from flink_tpu.streaming.vectorized import hash_keys_np
 
 
 # ---------------------------------------------------------------------
@@ -93,141 +94,79 @@ def mesh():
     return Mesh(devices, ("kg",))
 
 
-def _prepare(keys, values, n_shards):
-    """Host-side batch prep: hash keys, split lanes, pad to shards."""
-    h64 = splitmix64_np(np.asarray(keys, np.uint64))
-    hi, lo = _lanes(h64)
-    n = len(keys)
-    per = -(-n // n_shards)
-    total = per * n_shards
-    pad = total - n
-
-    def padded(a, dtype):
-        out = np.zeros(total, dtype)
-        out[:n] = a
-        return out
-
-    mask = np.zeros(total, bool)
-    mask[:n] = True
-    return (padded(hi, np.uint32), padded(lo, np.uint32),
-            padded(values, np.float32), padded(np.zeros(n), np.uint32),
-            padded(np.zeros(n), np.uint32), mask, h64)
+def _owner_shard(h64, n_shards):
+    """key hash → key group → shard, on the host."""
+    return (assign_key_groups_np(h64, 128).astype(np.int64)
+            * n_shards) // 128
 
 
-def test_mesh_sum_matches_host(mesh):
-    agg = SumAggregate(np.float32)
-    mwa = MeshWindowAggregation(mesh, "kg", agg, max_parallelism=128,
-                                capacity_per_shard=256)
-    rng = np.random.default_rng(3)
-    keys = rng.integers(0, 100, 1000)
-    vals = rng.random(1000).astype(np.float32)
-    hi, lo, v, vhi, vlo, mask, h64 = _prepare(keys, vals, mesh.shape["kg"])
-    mwa.step(hi, lo, v, vhi, vlo, mask)
-    assert mwa.overflowed == 0
-
-    khi, klo, res, occ = mwa.fire()
-    got = {}
-    for i in np.nonzero(occ)[0]:
-        got[(int(khi[i]), int(klo[i]))] = float(res[i])
-
-    expect = {}
-    for k, val in zip(keys, vals):
-        h = int(splitmix64_np(np.array([k], np.uint64))[0])
-        lane = (h >> 32, h & 0xFFFFFFFF)
-        expect[lane] = expect.get(lane, 0.0) + float(val)
-    assert set(got) == set(expect)
-    for lane in expect:
-        assert got[lane] == pytest.approx(expect[lane], rel=1e-4)
+def _table_lanes(eng):
+    """[(shard, key hash64)] of every occupied slot of the engine's
+    sharded table."""
+    t = eng.table
+    hi, lo, occ = (np.asarray(a) for a in (t.key_hi, t.key_lo, t.occupied))
+    shard, slot = np.nonzero(occ)
+    h64 = (hi[shard, slot].astype(np.uint64) << np.uint64(32)) \
+        | lo[shard, slot].astype(np.uint64)
+    return shard, h64
 
 
 def test_mesh_keys_land_on_owner_shard(mesh):
-    """Each key's state must live on the shard its key group maps to."""
-    agg = CountAggregate()
+    """Each key's state must live on the shard its key group maps to
+    (the device twin `_target_shard` against the host arithmetic)."""
     n_shards = mesh.shape["kg"]
-    cap = 128
-    mwa = MeshWindowAggregation(mesh, "kg", agg, max_parallelism=128,
-                                capacity_per_shard=cap)
+    eng = MeshTumblingWindows(CountAggregate(), 1000, mesh,
+                              capacity_per_window_shard=128, step_batch=64)
     keys = np.arange(200)
-    hi, lo, v, vhi, vlo, mask, h64 = _prepare(keys, np.zeros(200), n_shards)
-    mwa.step(hi, lo, v, vhi, vlo, mask)
-    khi, klo, res, occ = mwa.fire()
-    kgs = assign_key_groups_np(h64, 128)
-    expected_shard = (kgs.astype(np.int64) * n_shards) // 128
-    lane_to_shard = {}
-    for i in np.nonzero(occ)[0]:
-        lane_to_shard[(int(khi[i]), int(klo[i]))] = i // cap
-    for h, s in zip(h64, expected_shard):
-        lane = (int(h >> np.uint64(32)), int(h & np.uint64(0xFFFFFFFF)))
-        assert lane_to_shard[lane] == s
+    eng.process_batch(keys, np.full(200, 100))
+    eng.flush()
+    shard, h64 = _table_lanes(eng)
+    assert sorted(h64.tolist()) == sorted(hash_keys_np(keys).tolist())
+    np.testing.assert_array_equal(shard, _owner_shard(h64, n_shards))
+    eng.advance_watermark(999)
+    assert sorted(k for k, _, _, _ in eng.emitted) == keys.tolist()
 
 
 def test_mesh_hll(mesh):
-    agg = HyperLogLogAggregate(precision=9)
-    mwa = MeshWindowAggregation(mesh, "kg", agg, max_parallelism=128,
-                                capacity_per_shard=64)
+    """A sketch with value hashes through the sharded scatter step."""
+    eng = MeshTumblingWindows(HyperLogLogAggregate(precision=9), 1000, mesh,
+                              capacity_per_window_shard=64, step_batch=512)
     n = 4000
     keys = np.repeat(np.arange(4), n // 4)
     users = np.arange(n)  # 1000 distinct per key
-    h64u = splitmix64_np(users.astype(np.uint64))
-    hi, lo, v, _, _, mask, h64 = _prepare(keys, np.zeros(n), mesh.shape["kg"])
-    vhi = np.zeros(len(mask), np.uint32)
-    vlo = np.zeros(len(mask), np.uint32)
-    vhi[:n] = (h64u >> np.uint64(32)).astype(np.uint32)
-    vlo[:n] = (h64u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    mwa.step(hi, lo, v, vhi, vlo, mask)
-    khi, klo, res, occ = mwa.fire()
-    ests = res[occ]
-    assert len(ests) == 4
-    for est in ests:
+    eng.process_batch(keys, np.full(n, 100), users,
+                      value_hashes=splitmix64_np(users.astype(np.uint64)))
+    eng.advance_watermark(999)
+    assert sorted(k for k, _, _, _ in eng.emitted) == [0, 1, 2, 3]
+    for _, est, start, end in eng.emitted:
+        assert (start, end) == (0, 1000)
         assert abs(est - 1000) / 1000 < 0.10
-
-
-def test_mesh_multiple_steps_accumulate(mesh):
-    agg = CountAggregate()
-    mwa = MeshWindowAggregation(mesh, "kg", agg, max_parallelism=128,
-                                capacity_per_shard=64)
-    keys = np.arange(16)
-    for _ in range(3):
-        hi, lo, v, vhi, vlo, mask, _ = _prepare(keys, np.zeros(16),
-                                                mesh.shape["kg"])
-        mwa.step(hi, lo, v, vhi, vlo, mask)
-    khi, klo, res, occ = mwa.fire()
-    assert (res[occ] == 3).all()
-    # after fire, state reset
-    hi, lo, v, vhi, vlo, mask, _ = _prepare(keys, np.zeros(16),
-                                            mesh.shape["kg"])
-    mwa.step(hi, lo, v, vhi, vlo, mask)
-    _, _, res2, occ2 = mwa.fire()
-    assert (res2[occ2] == 1).all()
 
 
 def test_mesh_padding_does_not_clobber_shard0(mesh):
     """Regression: padded (mask=False) records used to scatter to bucket
     row 0 during _bucketize, colliding with real shard-0 records at the
     same [0, rank] positions and silently dropping them."""
-    agg = CountAggregate()
     n_shards = mesh.shape["kg"]
-    mwa = MeshWindowAggregation(mesh, "kg", agg, max_parallelism=128,
-                                capacity_per_shard=128)
+    per = 8  # slice length per device
+    total = per * n_shards
+    eng = MeshTumblingWindows(CountAggregate(), 1000, mesh,
+                              capacity_per_window_shard=128,
+                              step_batch=total)
     # pick n_shards keys that all target shard 0, and place exactly one
     # at the FRONT of each device's slice so every device holds a real
     # shard-0 record followed by padding — the layout where padding's
-    # bucket-row-0 writes used to collide with the real entry
-    def shard_of(k):
-        h64 = splitmix64_np(np.array([k], np.uint64))
-        kg = int(assign_key_groups_np(h64, 128)[0])
-        return (kg * n_shards) // 128
-
+    # bucket-row-0 writes used to collide with the real entry.  The
+    # engine pads only the tail of a batch, so the step program is
+    # called as `_run_step` calls it, with this mask.
     keys = []
     k = 0
     while len(keys) < n_shards:
-        if shard_of(k) == 0:
+        if _owner_shard(splitmix64_np(np.array([k], np.uint64)),
+                        n_shards)[0] == 0:
             keys.append(k)
         k += 1
-    keys = np.array(keys, np.uint64)
-    per = 8  # slice length per device
-    total = per * n_shards
-    h64 = splitmix64_np(keys)
+    h64 = splitmix64_np(np.array(keys, np.uint64))
     hi = np.zeros(total, np.uint32)
     lo = np.zeros(total, np.uint32)
     mask = np.zeros(total, bool)
@@ -235,12 +174,12 @@ def test_mesh_padding_does_not_clobber_shard0(mesh):
     hi[idx] = (h64 >> np.uint64(32)).astype(np.uint32)
     lo[idx] = (h64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     mask[idx] = True
-    mwa.step(hi, lo, np.zeros(total, np.float32),
-             np.zeros(total, np.uint32), np.zeros(total, np.uint32), mask)
-    assert mwa.overflowed == 0
-    khi, klo, res, occ = mwa.fire()
-    got = {(int(khi[i]), int(klo[i])) for i in np.nonzero(occ)[0]}
-    expect = {(int(h >> np.uint64(32)), int(h & np.uint64(0xFFFFFFFF)))
-              for h in h64}
-    assert got == expect  # every key survives, including shard-0 ones
-    assert (res[occ] == 1).all()
+    zeros = np.zeros(total, np.uint32)
+    (eng.table, eng.state), overflow = eng._step(
+        eng.table, eng.state, hi, lo, np.zeros(total, np.int32),
+        np.zeros(total, np.float32), zeros, zeros, mask)
+    assert int(np.asarray(overflow).sum()) == 0
+    got, res = eng._fire_region(0)
+    # every key survives, including shard-0 ones
+    assert sorted(got.tolist()) == sorted(h64.tolist())
+    assert (res == 1).all()

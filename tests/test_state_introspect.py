@@ -405,16 +405,31 @@ def test_live_state_route_top_param_limits_hot_keys():
         monitor.stop()
 
 
-def test_live_and_history_state_payload_parity(tmp_path):
+def test_live_and_history_state_payload_parity(tmp_path, monkeypatch):
     """The acceptance invariant: a finished job's archived `/state`
     payload is byte-identical to what the live route served at archive
-    time (accounting frozen at dispose, trackers process-global)."""
+    time (trackers process-global).
+
+    No executor disposes a keyed backend, so nothing freezes a finished
+    job's accounting: both payloads walk the backends that are still
+    alive, and the archive is written by the executor's thread after
+    `wait()` has returned.  A collection between the two reads took the
+    job's backend (or one an earlier test left behind) out of one
+    payload only.  So: what earlier tests left is collected first, and
+    this job's backends are held until the archive has been read."""
+    import gc
+
     from flink_tpu.streaming.datastream import StreamExecutionEnvironment
     from flink_tpu.streaming.sources import CollectSink
     from flink_tpu.streaming.windowing import TumblingEventTimeWindows
 
     archive = str(tmp_path / "archive")
     t = get_introspection()
+    gc.collect()
+    held = []
+    register = t.register_backend
+    monkeypatch.setattr(
+        t, "register_backend", lambda b: (held.append(b), register(b)))
     t.enable()
     env = StreamExecutionEnvironment()
     env.use_mini_cluster(2)

@@ -44,10 +44,22 @@ class TupleAvg(AvgAggregate):
         return value[1]
 
 
-def _run_window_job(env, n=4000, agg=None, name="trace-job"):
+def _run_window_job(env, n=4000, agg=None, name="trace-job",
+                    until_checkpoint=False):
+    """``until_checkpoint``: the source holds its tail back until a
+    checkpoint has COMPLETED (runtime/chaos.py's gate), so a test that
+    reads checkpoint spans does not depend on the 4,000 records
+    outlasting the checkpoint timer."""
     sink = CollectSink()
     recs = [((i % 7, 1.0), i * 10) for i in range(n)]
-    (env.from_collection(recs, timestamped=True)
+    if until_checkpoint:
+        from flink_tpu.runtime.chaos import CheckpointGatedSource
+        CheckpointGatedSource.completed = False
+        stream = env.add_source(
+            CheckpointGatedSource(recs, timestamped=True))
+    else:
+        stream = env.from_collection(recs, timestamped=True)
+    (stream
         .key_by(lambda t: t[0])
         .window(TumblingEventTimeWindows.of(Time.seconds(1)))
         .aggregate(agg or TupleSum(),
@@ -202,7 +214,8 @@ def test_minicluster_trace_prometheus_and_rest(tmp_path):
     env.use_mini_cluster(2)
     env.enable_checkpointing(20)
     env.enable_tracing()
-    sink = _run_window_job(env, n=4000, name="accept-trace")
+    sink = _run_window_job(env, n=4000, name="accept-trace",
+                           until_checkpoint=True)
     assert sink.values
 
     # ---- Chrome trace: operator + checkpoint (+ native) spans ------

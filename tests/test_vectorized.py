@@ -186,64 +186,3 @@ def test_string_keys():
     vec.advance_watermark(999)
     out = {k: int(r) for k, r, _, _ in vec.emitted}
     assert out == {"alpha": 2, "beta": 1, "gamma": 1}
-
-
-# ---------------------------------------------------------------------
-# fully device-resident engine (on-device key index)
-# ---------------------------------------------------------------------
-
-def test_device_windows_matches_heap():
-    from flink_tpu.streaming.device_windows import (
-        DeviceTumblingWindows, lanes_from_int_keys)
-
-    rng = np.random.default_rng(5)
-    n = 4000
-    keys = rng.integers(0, 300, n).astype(np.uint64)
-    ts = rng.integers(0, 3000, n)
-    vals = rng.random(n).astype(np.float32)
-
-    dev = DeviceTumblingWindows(SumAggregate(np.float32), 1000,
-                                capacity=1024)
-    heap = ScalarHeapTumblingWindows(SumAggregate(np.float32), 1000)
-    hi, lo = lanes_from_int_keys(keys)
-    dev.process_batch(hi, lo, ts, values=vals)
-    for i in range(n):
-        heap.process(int(keys[i]), int(ts[i]), float(vals[i]))
-    dev.advance_watermark(2999)
-    heap.advance_watermark(2999)
-    assert dev.overflowed == 0
-
-    got = {}
-    for karr, res, s, e in dev.fired:
-        for k, r in zip(karr, res):
-            got[(int(k), s)] = float(r)
-    want = {(int(k), s): float(r) for k, r, s, e in heap.emitted}
-    assert set(got) == set(want)
-    for kk in want:
-        assert got[kk] == pytest.approx(want[kk], rel=1e-4), kk
-    assert dev.num_late_dropped == heap.num_late_dropped
-
-
-def test_device_windows_hll_and_late():
-    from flink_tpu.streaming.device_windows import (
-        DeviceTumblingWindows, lanes_from_int_keys)
-    from flink_tpu.core.keygroups import splitmix64_np
-
-    dev = DeviceTumblingWindows(HyperLogLogAggregate(9), 1000, capacity=64)
-    keys = np.arange(4, dtype=np.uint64).repeat(500)
-    users = np.arange(2000).astype(np.uint64)
-    uh = splitmix64_np(users)
-    hi, lo = lanes_from_int_keys(keys)
-    ts = np.full(2000, 100)
-    dev.process_batch(hi, lo, ts,
-                      vh_hi=(uh >> np.uint64(32)).astype(np.uint32),
-                      vh_lo=(uh & np.uint64(0xFFFFFFFF)).astype(np.uint32))
-    dev.advance_watermark(999)
-    (karr, res, s, e), = dev.fired
-    assert sorted(karr.tolist()) == [0, 1, 2, 3]
-    for r in res:
-        assert abs(r - 500) / 500 < 0.15
-    # late record dropped
-    dev.process_batch(*lanes_from_int_keys(np.array([1], np.uint64)),
-                      np.array([500]))
-    assert dev.num_late_dropped == 1
