@@ -384,6 +384,16 @@ def fire_tail_problems(op, result_rows):
             f"StreamRecord"]
 
 
+def timer_run_problems(op, windows, result_rows):
+    """Every fired key's one timer must have left the store as part
+    of its window's run: a sweep hands over whole runs, one per
+    fired window (tumbling, lateness 0)."""
+    if op.timers_swept == result_rows and op.timer_runs == windows:
+        return []
+    return [f"timers_swept {op.timers_swept} of {result_rows} result "
+            f"rows in {op.timer_runs} runs, {windows} windows fired"]
+
+
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
     ops, sink = run_window_job("chip-smoke-state-backend", events,
@@ -395,6 +405,7 @@ def leg_state_backend(cfg, events, ref):
     problems, facts = check_hll(*cols, ref, cfg["precision"])
     problems += boxed_problems(wop, len(keys))
     problems += fire_tail_problems(wop, len(cols[0]))
+    problems += timer_run_problems(wop, len(ref), len(cols[0]))
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -404,6 +415,7 @@ def leg_state_backend(cfg, events, ref):
         "boxed_fallbacks": wop.boxed_fallbacks,
         "fire_rows_direct": wop.fire_rows_direct,
         "fire_rows_via_records": wop.fire_rows_via_records,
+        "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
         "evictions": state.evictions, **facts}

@@ -3,8 +3,10 @@
 Re-designs flink-streaming-java/.../api/operators/
 HeapInternalTimerService.java:43 (two priority queues of
 InternalTimer(timestamp, key, namespace), advanceWatermark :276-288
-draining event-time timers) and runtime/tasks/
-SystemProcessingTimeService.java / TestProcessingTimeService.java.
+draining event-time timers; here the queues order the RUNS of keys
+that share a (timestamp, namespace), :class:`_TimerStore`) and
+runtime/tasks/SystemProcessingTimeService.java /
+TestProcessingTimeService.java.
 
 Timers are exactly-once: registering the same (key, namespace,
 timestamp) twice is a no-op; they are part of operator snapshots, keyed
@@ -15,6 +17,8 @@ from __future__ import annotations
 
 import abc
 import heapq
+import itertools
+import operator
 import threading
 import time as _time
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
@@ -189,9 +193,286 @@ class InternalTimer:
         return f"Timer({self.timestamp}, {self.key!r}, {self.namespace!r})"
 
 
+def _extend(spans: list, how_many: int, seq: int) -> None:
+    last = spans[-1]
+    if last[0] + last[1] == seq:
+        last[0] += how_many
+    else:
+        spans.append([how_many, seq])
+
+
+def _keys(run) -> list:
+    return [run[0]] if type(run) is tuple else list(run)
+
+
+class _TimerStore:
+    """The timers of one time domain, kept by run: the keys registered
+    for ONE (timestamp, namespace) are one dict, in registration
+    order.  The dict is the exactly-once contract (a key is in it
+    once) and the firing order among them; the heap orders RUNS, one
+    node each, not timers.  A tumbling window's 230k fire timers are
+    one run and one heap node.  A process function that arms a timer
+    per event has runs of one key: such a run is kept as the pair
+    ``(key, number)`` until a second key joins it, which spares the
+    store a dict for each.
+
+    Every timer has a registration number, and the timers of one
+    timestamp fire in the order of their numbers, across namespaces.
+    A run that only ever grew by the bulk call holds its numbers in
+    `spans`, ``[how many, first number]`` per stretch of consecutive
+    numbers, so a bulk registration is one ``dict.update`` however
+    many keys it brings (the dict's values mean nothing then).  The
+    first removal of a key, a per-timer drain or a tie that has to be
+    merged numbers every key on its own: the run then maps key ->
+    number and has no `spans`.
+
+    Removing a key is a real removal and an emptied run goes, so every
+    run here has a key and a heap node.  The node of a run that went
+    stays behind only until such nodes outnumber the runs (then the
+    heap is rebuilt from the runs), so deletes never pile up."""
+
+    __slots__ = ("runs", "spans", "heap", "seq")
+
+    def __init__(self):
+        #: (timestamp, namespace) -> {key: number} | (key, number)
+        self.runs: Dict[Tuple[int, Any], Any] = {}
+        #: (timestamp, namespace) -> [[how many, first number], ...]
+        self.spans: Dict[Tuple[int, Any], list] = {}
+        #: (timestamp, the run's first number, the run's key in `runs`):
+        #: the number keeps two nodes from ever comparing beyond it
+        self.heap: List[Tuple[int, int, Tuple[int, Any]]] = []
+        #: the next registration number
+        self.seq = 0
+
+    def clear(self) -> None:
+        self.runs.clear()
+        self.spans.clear()
+        del self.heap[:]
+
+    def __len__(self) -> int:
+        return sum(1 if type(run) is tuple else len(run)
+                   for run in self.runs.values())
+
+    def __iter__(self):
+        """Live (timestamp, key, namespace), runs in the order they
+        were first seen, keys in registration order."""
+        for (timestamp, namespace), run in self.runs.items():
+            for key in _keys(run):
+                yield timestamp, key, namespace
+
+    def _dict(self, at, run) -> dict:
+        if type(run) is tuple:
+            run = self.runs[at] = {run[0]: run[1]}
+        return run
+
+    def add(self, timestamp: int, namespace, key) -> bool:
+        """Register one timer; False if it is registered already."""
+        at = (timestamp, namespace)
+        run = self.runs.get(at)
+        seq = self.seq
+        if run is None:
+            self.runs[at] = (key, seq)
+            heapq.heappush(self.heap, (timestamp, seq, at))
+        else:
+            run = self._dict(at, run)
+            if key in run:
+                return False
+            run[key] = seq
+            spans = self.spans.get(at)
+            if spans is not None:
+                _extend(spans, 1, seq)
+        self.seq = seq + 1
+        return True
+
+    def add_many(self, timestamp: int, namespace, keys) -> None:
+        """`keys` in row order, repeats allowed (a dict is taken for
+        its keys).  Keys that are in the run keep their place, the
+        others append in first-occurrence order under consecutive
+        numbers."""
+        if not isinstance(keys, dict):
+            keys = dict.fromkeys(keys)
+        if not keys:
+            return
+        at = (timestamp, namespace)
+        run = self.runs.get(at)
+        seq = self.seq
+        if run is None:
+            self.runs[at] = dict(keys)
+            self.spans[at] = [[len(keys), seq]]
+            heapq.heappush(self.heap, (timestamp, seq, at))
+            self.seq = seq + len(keys)
+            return
+        spans = self.spans.get(at)
+        if spans is None:
+            run = self._dict(at, run)
+            for key in keys:
+                if key not in run:
+                    run[key] = seq
+                    seq += 1
+            self.seq = seq
+            return
+        before = len(run)
+        run.update(keys)
+        added = len(run) - before
+        if added:
+            _extend(spans, added, seq)
+            self.seq = seq + added
+
+    def _numbered(self, at, run) -> dict:
+        """`run` as the dict key -> registration number, which it is
+        from here on."""
+        spans = self.spans.pop(at, None)
+        if spans is not None:
+            run.update(zip(list(run), itertools.chain.from_iterable(
+                range(first, first + n) for n, first in spans)))
+        return self._dict(at, run)
+
+    def _number_range(self, at, run) -> Tuple[int, int]:
+        """First and last registration number of a run (numbers only
+        grow, so they are those of the first and the last key)."""
+        if type(run) is tuple:
+            return run[1], run[1]
+        spans = self.spans.get(at)
+        if spans is not None:
+            return spans[0][1], spans[-1][1] + spans[-1][0] - 1
+        numbers = run.values()
+        return next(iter(numbers)), next(reversed(numbers))
+
+    def discard(self, timestamp: int, namespace, key) -> None:
+        self.discard_many(timestamp, namespace, (key,))
+
+    def discard_many(self, timestamp: int, namespace, keys) -> None:
+        at = (timestamp, namespace)
+        run = self.runs.get(at)
+        if run is None:
+            return
+        if type(run) is tuple:
+            if run[0] not in keys:
+                return
+        else:
+            for key in keys:
+                if key in run:
+                    del self._numbered(at, run)[key]
+            if run:
+                return
+        del self.runs[at]  # its heap node is stale now
+        heap = self.heap
+        if len(heap) > 2 * len(self.runs) + 32:
+            heap[:] = [(at[0], self._number_range(at, run)[0], at)
+                       for at, run in self.runs.items()]
+            heapq.heapify(heap)
+
+    def first(self) -> Optional[int]:
+        """The earliest timestamp that has a timer."""
+        heap = self.heap
+        while heap:
+            timestamp, _, at = heap[0]
+            if at in self.runs:
+                return timestamp
+            heapq.heappop(heap)
+        return None
+
+    def _pop_tied(self, timestamp: int, keep_nodes: bool) -> dict:
+        """Take the heap nodes of `timestamp` out and return the runs
+        they stand for, ``{(timestamp, namespace): run}``; with
+        `keep_nodes`, put one node back for each."""
+        heap = self.heap
+        runs = self.runs
+        nodes = {}
+        while heap and heap[0][0] == timestamp:
+            node = heapq.heappop(heap)
+            at = node[2]
+            if at in runs:
+                nodes[at] = node
+        if keep_nodes:
+            for node in nodes.values():
+                heapq.heappush(heap, node)
+        return {at: runs[at] for at in nodes}
+
+    def _by_number(self, tied: dict) -> list:
+        """``(registration number, key, (timestamp, namespace), run)``
+        of every timer of the runs in `tied`, in firing order."""
+        rows = [(seq, key, at, run) for at, run in
+                [(at, self._numbered(at, run)) for at, run in tied.items()]
+                for key, seq in run.items()]
+        if len(tied) > 1:
+            rows.sort(key=operator.itemgetter(0))
+        return rows
+
+    def pop_runs(self, limit: int) -> List[Tuple[int, Any, list]]:
+        """Take every timer <= limit out: ``(timestamp, namespace,
+        keys)`` runs in firing order.  A timestamp with one namespace
+        is its run, whole; runs that tie on a timestamp are cut where
+        their registration numbers interleave."""
+        out = []
+        heap = self.heap
+        while heap and heap[0][0] <= limit:
+            timestamp = heap[0][0]
+            tied = self._pop_tied(timestamp, keep_nodes=False)
+            ranges = sorted(self._number_range(at, run) + (at,)
+                            for at, run in tied.items())
+            if any(a[1] > b[0] for a, b in zip(ranges, ranges[1:])):
+                out.extend(
+                    (timestamp, at[1], [row[1] for row in rows])
+                    for at, rows in itertools.groupby(
+                        self._by_number(tied), key=operator.itemgetter(2)))
+            else:
+                out.extend((timestamp, at[1], _keys(tied[at]))
+                           for _, _, at in ranges)
+            for at in tied:
+                del self.runs[at]
+                self.spans.pop(at, None)
+        return out
+
+    def drain(self, limit: int, backend, on_timer) -> None:
+        """Fire every timer <= limit, one ``on_timer(InternalTimer)``
+        each under its key's context, in (timestamp, registration
+        number) order.  The callback may register and delete timers:
+        one registered <= limit fires in this drain, at the place its
+        timestamp and number give it; one deleted before its turn does
+        not fire."""
+        heap = self.heap
+        runs = self.runs
+        set_current_key = backend.set_current_key
+        while heap and heap[0][0] <= limit:
+            node = heapq.heappop(heap)
+            timestamp, _, at = node
+            run = runs.get(at)
+            if run is None:
+                continue  # the node of a run that went
+            tied = bool(heap) and heap[0][0] == timestamp
+            if not tied and type(run) is tuple:
+                # a timer of its own
+                del runs[at]
+                set_current_key(run[0])
+                on_timer(InternalTimer(timestamp, run[0], at[1]))
+                continue
+            # the callbacks find every run with its node in the heap
+            heapq.heappush(heap, node)
+            rows = self._by_number(
+                self._pop_tied(timestamp, True) if tied else {at: run})
+            for seq, key, at, run in rows:
+                if run.get(key) != seq:
+                    continue  # deleted; registered anew: a later number
+                del run[key]
+                if not run:
+                    del runs[at]
+                set_current_key(key)
+                on_timer(InternalTimer(timestamp, key, at[1]))
+                if heap and heap[0][0] < timestamp:
+                    break  # an earlier timer was registered: it goes first
+
+
 class InternalTimerService:
     """Keyed event-time + processing-time timers for one operator
-    (ref: HeapInternalTimerService.java)."""
+    (ref: HeapInternalTimerService.java), both kept in a
+    :class:`_TimerStore`.
+
+    A timer deleted and registered again is a new timer: it fires
+    after every timer of its timestamp that was registered before the
+    second registration (where the heap of one node per timer that
+    this replaced took the stale node for the live one and fired it
+    at its first place)."""
 
     def __init__(self, name: str, keyed_backend, processing_time_service: ProcessingTimeService,
                  triggerable):
@@ -201,89 +482,75 @@ class InternalTimerService:
         #: the operator: has on_event_time(timer) / on_processing_time(timer)
         self._triggerable = triggerable
         self.current_watermark = MIN_TIMESTAMP
-        # heaps of (timestamp, seq, key, namespace); set for dedup
-        self._event_heap: List[Tuple[int, int, Any, Any]] = []
-        self._event_set: Set[Tuple[int, Any, Any]] = set()
-        self._proc_heap: List[Tuple[int, int, Any, Any]] = []
-        self._proc_set: Set[Tuple[int, Any, Any]] = set()
-        self._seq = 0
+        self._event = _TimerStore()
+        self._proc = _TimerStore()
         self._next_proc_registered: Optional[int] = None
 
     # ---- registration (key = backend's current key) -----------------
     def register_event_time_timer(self, namespace, timestamp: int) -> None:
-        key = self._backend.current_key
-        entry = (timestamp, key, namespace)
-        if entry in self._event_set:
-            return
-        self._event_set.add(entry)
-        heapq.heappush(self._event_heap, (timestamp, self._seq, key, namespace))
-        self._seq += 1
+        self._event.add(timestamp, namespace, self._backend.current_key)
 
     def register_event_time_timers_bulk(self, namespace, timestamp: int,
                                         keys) -> None:
         """Register the same (namespace, timestamp) timer for MANY keys
-        without touching the backend's current-key context — the
-        batched window path registers one trigger/cleanup timer per
-        distinct key in a sub-batch.  Semantics per key are identical
-        to register_event_time_timer."""
-        push = heapq.heappush
-        heap = self._event_heap
-        seen = self._event_set
-        for key in keys:
-            entry = (timestamp, key, namespace)
-            if entry in seen:
-                continue
-            seen.add(entry)
-            push(heap, (timestamp, self._seq, key, namespace))
-            self._seq += 1
+        without touching the backend's current-key context: one update
+        of that run with the keys in row order (repeats allowed; a
+        dict is taken for its keys).  Per key as
+        register_event_time_timer: a key that has the timer keeps its
+        place, the others follow in first-occurrence order."""
+        self._event.add_many(timestamp, namespace, keys)
 
     def delete_event_time_timer(self, namespace, timestamp: int) -> None:
-        # lazy deletion: remove from the set; heap entries are skipped
-        self._event_set.discard((timestamp, self._backend.current_key, namespace))
+        self._event.discard(timestamp, namespace, self._backend.current_key)
+
+    def delete_event_time_timers_bulk(self, namespace, timestamp: int,
+                                      keys) -> None:
+        """delete_event_time_timer for many keys of one (namespace,
+        timestamp), the backend's current-key context untouched: the
+        batched fire drops every cleaned window's trigger timers in
+        one call, which finds no run where they fired already."""
+        self._event.discard_many(timestamp, namespace, keys)
 
     def register_processing_time_timer(self, namespace, timestamp: int) -> None:
-        key = self._backend.current_key
-        entry = (timestamp, key, namespace)
-        if entry in self._proc_set:
-            return
-        self._proc_set.add(entry)
-        heapq.heappush(self._proc_heap, (timestamp, self._seq, key, namespace))
-        self._seq += 1
+        if self._proc.add(timestamp, namespace, self._backend.current_key):
+            self._arm_processing_time(timestamp)
+
+    def _arm_processing_time(self, timestamp: int) -> None:
         if self._next_proc_registered is None or timestamp < self._next_proc_registered:
             self._next_proc_registered = timestamp
             self._pts.register_timer(timestamp, self._on_processing_time)
 
     def delete_processing_time_timer(self, namespace, timestamp: int) -> None:
-        self._proc_set.discard((timestamp, self._backend.current_key, namespace))
+        self._proc.discard(timestamp, namespace, self._backend.current_key)
 
     def num_event_time_timers(self) -> int:
-        return len(self._event_set)
+        return len(self._event)
 
     def num_processing_time_timers(self) -> int:
-        return len(self._proc_set)
+        return len(self._proc)
+
+    def event_time_timers(self):
+        """Every live event-time timer as (timestamp, key, namespace)."""
+        return iter(self._event)
+
+    def processing_time_timers(self):
+        return iter(self._proc)
 
     # ---- firing -----------------------------------------------------
     def advance_watermark(self, watermark: int) -> None:
         """Fire all event-time timers <= watermark
         (ref: HeapInternalTimerService.advanceWatermark :276-288)."""
         self.current_watermark = watermark
-        while self._event_heap and self._event_heap[0][0] <= watermark:
-            ts, _, key, namespace = heapq.heappop(self._event_heap)
-            entry = (ts, key, namespace)
-            if entry not in self._event_set:
-                continue  # deleted
-            self._event_set.remove(entry)
-            self._backend.set_current_key(key)
-            self._triggerable.on_event_time(InternalTimer(ts, key, namespace))
+        self._event.drain(watermark, self._backend,
+                          self._triggerable.on_event_time)
 
     def pop_due_event_time_timers(
-            self, watermark: int) -> Tuple[List[int], List[Any], List[Any]]:
-        """Bulk sweep: pop EVERY due event-time timer <= watermark and
-        return (timestamps, keys, namespaces) as parallel columns in
-        the exact per-row order advance_watermark would have fired
-        them (heap (timestamp, seq) order; lazily-deleted entries
-        skipped).  The watermark advances exactly as advance_watermark
-        does; FIRING is the caller's job.
+            self, watermark: int) -> List[Tuple[int, Any, list]]:
+        """Bulk sweep: take EVERY due event-time timer <= watermark
+        and return them as runs ``(timestamp, namespace, keys)``; the
+        runs end to end are the exact order advance_watermark would
+        have fired them in.  The watermark advances exactly as
+        advance_watermark does; FIRING is the caller's job.
 
         Contract: only valid when the caller's timer callbacks would
         not have registered NEW timers <= watermark mid-drain (the
@@ -292,81 +559,41 @@ class InternalTimerService:
         timer registered during the sweep's processing fires on the
         NEXT watermark instead of the current one."""
         self.current_watermark = watermark
-        heap = self._event_heap
-        live = self._event_set
-        timestamps: List[int] = []
-        keys: List[Any] = []
-        namespaces: List[Any] = []
-        pop = heapq.heappop
-        while heap and heap[0][0] <= watermark:
-            ts, _, key, namespace = pop(heap)
-            entry = (ts, key, namespace)
-            if entry not in live:
-                continue  # deleted
-            live.remove(entry)
-            timestamps.append(ts)
-            keys.append(key)
-            namespaces.append(namespace)
-        return timestamps, keys, namespaces
-
-    def delete_event_time_timers_bulk(self, entries) -> None:
-        """Bulk lazy delete: `entries` yields (timestamp, key,
-        namespace) triples.  Semantics per entry are identical to
-        delete_event_time_timer (set removal; stale heap nodes are
-        skipped on pop) without touching the backend's current-key
-        context — the batched fire path drops every cleaned window's
-        trigger timer in one call."""
-        self._event_set.difference_update(entries)
+        return self._event.pop_runs(watermark)
 
     def _on_processing_time(self, fired_at: int) -> None:
         self._next_proc_registered = None
-        now = self._pts.get_current_processing_time()
-        while self._proc_heap and self._proc_heap[0][0] <= now:
-            ts, _, key, namespace = heapq.heappop(self._proc_heap)
-            entry = (ts, key, namespace)
-            if entry not in self._proc_set:
-                continue
-            self._proc_set.remove(entry)
-            self._backend.set_current_key(key)
-            self._triggerable.on_processing_time(InternalTimer(ts, key, namespace))
-        if self._proc_heap:
-            nxt = self._proc_heap[0][0]
-            self._next_proc_registered = nxt
-            self._pts.register_timer(nxt, self._on_processing_time)
+        self._proc.drain(self._pts.get_current_processing_time(),
+                         self._backend, self._triggerable.on_processing_time)
+        nxt = self._proc.first()
+        if nxt is not None:
+            self._arm_processing_time(nxt)
 
     # ---- snapshot (timers are state, keyed per key group) -----------
     def snapshot(self) -> dict:
-        per_kg_event: Dict[int, list] = {}
-        per_kg_proc: Dict[int, list] = {}
-        mp = self._backend.max_parallelism
-        for ts, key, namespace in self._event_set:
-            per_kg_event.setdefault(assign_to_key_group(key, mp), []).append(
-                (ts, key, namespace))
-        for ts, key, namespace in self._proc_set:
-            per_kg_proc.setdefault(assign_to_key_group(key, mp), []).append(
-                (ts, key, namespace))
         return {"watermark": self.current_watermark,
-                "event": per_kg_event, "proc": per_kg_proc}
+                "event": self._by_key_group(self._event),
+                "proc": self._by_key_group(self._proc)}
+
+    def _by_key_group(self, store: _TimerStore) -> Dict[int, list]:
+        mp = self._backend.max_parallelism
+        per_kg: Dict[int, list] = {}
+        for ts, key, namespace in store:
+            per_kg.setdefault(assign_to_key_group(key, mp), []).append(
+                (ts, key, namespace))
+        return per_kg
 
     def restore(self, snapshots: List[dict]) -> None:
-        self._event_heap.clear()
-        self._event_set.clear()
-        self._proc_heap.clear()
-        self._proc_set.clear()
+        self._event.clear()
+        self._proc.clear()
         rng = self._backend.key_group_range
-        saved_key = self._backend.current_key
         for snap in snapshots:
             for kg, timers in snap.get("event", {}).items():
-                if not rng.contains(kg):
-                    continue
-                for ts, key, namespace in timers:
-                    self._backend.set_current_key(key)
-                    self.register_event_time_timer(namespace, ts)
+                if rng.contains(kg):
+                    for ts, key, namespace in timers:
+                        self._event.add(ts, namespace, key)
             for kg, timers in snap.get("proc", {}).items():
-                if not rng.contains(kg):
-                    continue
-                for ts, key, namespace in timers:
-                    self._backend.set_current_key(key)
-                    self.register_processing_time_timer(namespace, ts)
-        if saved_key is not None:
-            self._backend.set_current_key(saved_key)
+                if rng.contains(kg):
+                    for ts, key, namespace in timers:
+                        if self._proc.add(ts, namespace, key):
+                            self._arm_processing_time(ts)

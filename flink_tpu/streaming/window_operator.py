@@ -417,6 +417,18 @@ class _FireBufferOutput(Output):
         self._inner.emit_latency_marker(marker)
 
 
+def _run_columns(runs):
+    """``[(namespace, keys)]`` end to end: the key column with the one
+    namespace they share (a sweep of one run hands its key list on as
+    it is), or with a namespace per key."""
+    if len(runs) == 1:
+        namespace, keys = runs[0]
+        return keys, namespace, None
+    chain = itertools.chain.from_iterable
+    return (list(chain(keys for _, keys in runs)), None,
+            list(chain(itertools.repeat(ns, len(keys)) for ns, keys in runs)))
+
+
 class WindowOperator(AbstractUdfStreamOperator):
     """One-input keyed window operator."""
 
@@ -456,6 +468,10 @@ class WindowOperator(AbstractUdfStreamOperator):
         #: their own / wrapped in one by a collector on their way
         self.fire_rows_direct = 0
         self.fire_rows_via_records = 0
+        #: timers the batched fires swept / the (timestamp, window)
+        #: runs they came in: a tumbling window's fire is one run
+        self.timers_swept = 0
+        self.timer_runs = 0
 
     # ---- lifecycle --------------------------------------------------
     def open(self):
@@ -684,21 +700,22 @@ class WindowOperator(AbstractUdfStreamOperator):
             else:
                 backend.add_batch(state, gkeys, ns,
                                   [values[i] for i in gidx])
-            with tracer.phase("timers.register"):
+            with tracer.phase("timers.register") as phase:
                 # first-occurrence order, NOT a set: same-timestamp
                 # timers fire in registration order, and the scalar
                 # path registers them in row order
                 dkeys = dict.fromkeys(gkeys)
+                phase.set_attr("keys", len(dkeys))
                 maxt = start + size - 1
                 # trigger timer (what EventTimeTrigger.on_element
-                # registers on CONTINUE) + GC timer; the dedup set
-                # makes re-registration free
-                self.timer_service.register_event_time_timers_bulk(
-                    ns, maxt, dkeys)
+                # registers on CONTINUE) + GC timer, which with
+                # lateness 0 is the trigger timer; keys that have
+                # theirs keep their place
+                register = self.timer_service.register_event_time_timers_bulk
+                register(ns, maxt, dkeys)
                 cleanup = maxt + lateness
-                if cleanup < MAX_TIMESTAMP:
-                    self.timer_service.register_event_time_timers_bulk(
-                        ns, cleanup, dkeys)
+                if maxt < cleanup < MAX_TIMESTAMP:
+                    register(ns, cleanup, dkeys)
         if immediate.any():
             tlist = ts.tolist()
             for i in np.nonzero(immediate)[0]:
@@ -881,8 +898,8 @@ class WindowOperator(AbstractUdfStreamOperator):
             self.output.emit_watermark(watermark)
 
     def on_watermark_batch(self, watermark: int) -> None:
-        """Columnar fire: ONE timer sweep → vectorized
-        EventTimeTrigger decision → ONE backend gather for every
+        """Columnar fire: ONE timer sweep → one EventTimeTrigger
+        decision per swept run → ONE backend gather for every
         firing (key, window) → in-pop-order emit (one RecordBatch when
         the results columnarize) → ONE batch state clear + bulk
         cleanup-timer delete.
@@ -899,80 +916,83 @@ class WindowOperator(AbstractUdfStreamOperator):
         paths bit-equal."""
         svc = self.timer_service
         lateness = self.allowed_lateness
-        with get_tracer().phase("timers.sweep"):
-            ts_col, key_col, ns_col = svc.pop_due_event_time_timers(
-                watermark)
-            n = len(ts_col)
-            if n == 0:
-                return
-            tarr = np.fromiter(ts_col, np.int64, n)
-            maxts = np.fromiter((ns[1] for ns in ns_col), np.int64, n) - 1
-        # EventTimeTrigger.on_event_time: FIRE iff time == maxTimestamp
-        fire = tarr == maxts
-        if lateness == 0:
-            cleanup = fire  # fire and cleanup are the SAME dedup'd timer
-        else:
-            # a cleanup timer at/after MAX_TIMESTAMP is never
-            # registered, so int64 wraparound on an astronomical
-            # lateness yields False — exactly "no cleanup timer"
-            with np.errstate(over="ignore"):
-                cleanup = tarr == maxts + lateness
+        with get_tracer().phase("timers.sweep") as phase:
+            runs = svc.pop_due_event_time_timers(watermark)
+            n = sum(len(keys) for _, _, keys in runs)
+            phase.set_attr("timers", n)
+            phase.set_attr("runs", len(runs))
+        if not runs:
+            return
+        self.timers_swept += n
+        self.timer_runs += len(runs)
+        # the timers of a run share timestamp and window, so the
+        # trigger decides once per run.  EventTimeTrigger.on_event_time:
+        # FIRE iff time == maxTimestamp; cleanup as _is_cleanup_time
+        fired = []
+        cleaned = []
+        for timestamp, ns, keys in runs:
+            maxt = ns[1] - 1
+            if timestamp == maxt:
+                fired.append((ns, keys))
+            if lateness and timestamp == min(maxt + lateness, MAX_TIMESTAMP):
+                cleaned.append((ns, keys))
+        if not lateness:
+            cleaned = fired  # fire and cleanup are the SAME dedup'd timer
         backend = self.keyed_backend
         emitted = 0
-        fired_idx = np.nonzero(fire)[0]
-        if fired_idx.size:
-            rows = fired_idx.tolist()
+        if fired:
+            columns = _run_columns(fired)
             contents_col, found_mask, _path = backend.get_batch(
-                self.window_state, [key_col[i] for i in rows], None,
-                namespaces=[ns_col[i] for i in rows])
+                self.window_state, columns[0], columns[1],
+                namespaces=columns[2])
             emitted = self._emit_fired_columns(
-                rows, key_col, ns_col, contents_col, found_mask)
+                *columns, contents_col, found_mask)
         if TELEMETRY.enabled and emitted:
             TELEMETRY.note_windows_fired(emitted)
-        cleanup_idx = np.nonzero(cleanup)[0]
-        if cleanup_idx.size:
-            rows = cleanup_idx.tolist()
-            backend.clear_batch(
-                self.window_state, [key_col[i] for i in rows], None,
-                namespaces=[ns_col[i] for i in rows])
+        if cleaned:
+            if cleaned is not fired:
+                columns = _run_columns(cleaned)
+            backend.clear_batch(self.window_state, columns[0], columns[1],
+                                namespaces=columns[2])
             if lateness:
                 # EventTimeTrigger.clear: drop the max_timestamp fire
                 # timer (with lateness 0 that timer IS the one just
                 # swept — nothing left to delete)
-                svc.delete_event_time_timers_bulk(
-                    (int(maxts[i]), key_col[i], ns_col[i]) for i in rows)
+                for ns, keys in cleaned:
+                    svc.delete_event_time_timers_bulk(ns, ns[1] - 1, keys)
             if isinstance(self._internal_fn.fn, ProcessWindowFunction):
                 wt = self.assigner.window_type()
-                for i in rows:
-                    backend.set_current_key(key_col[i])
-                    self._internal_fn.clear(
-                        key_col[i], wt.from_namespace(ns_col[i]), self)
+                for ns, keys in cleaned:
+                    window = wt.from_namespace(ns)
+                    for key in keys:
+                        backend.set_current_key(key)
+                        self._internal_fn.clear(key, window, self)
 
-    def _emit_fired_columns(self, rows, key_col, ns_col, contents_col,
+    def _emit_fired_columns(self, keys, namespace, namespaces, contents_col,
                             found_mask) -> int:
         """Run the window function over the gathered contents in pop
         order, buffering the emissions; flush as ONE RecordBatch when
         the rows columnarize (per-row records otherwise, same order).
-        Returns the number of windows that emitted — the scalar path's
-        windowsFired increments, applied in one note."""
+        The fired keys are of one `namespace`, or each of its own in
+        `namespaces`.  Returns the number of windows that emitted —
+        the scalar path's windowsFired increments, applied in one
+        note."""
         buf = _FireBufferOutput(self.output)
-        with get_tracer().phase("window.fire.batch", keys=len(rows)) as phase:
-            if self._internal_fn.takes_collector:
-                fired = self._fire_through_collector(
-                    buf, rows, key_col, ns_col, contents_col, found_mask)
-            else:
-                fired = self._fire_over_columns(
-                    buf, rows, key_col, ns_col, contents_col, found_mask)
+        fire = self._fire_through_collector \
+            if self._internal_fn.takes_collector else self._fire_over_columns
+        with get_tracer().phase("window.fire.batch", keys=len(keys)) as phase:
+            fired = fire(buf, keys, namespace, namespaces, contents_col,
+                         found_mask)
             buf.book(self, phase)
         buf.flush()
         return fired
 
-    def _fire_through_collector(self, buf, rows, key_col, ns_col,
+    def _fire_through_collector(self, buf, keys, namespace, namespaces,
                                 contents_col, found_mask) -> int:
         """A ProcessWindowFunction or WindowFunction, key by key: each
         is called under its key's context (it may read per-window
         keyed state) and writes through a collector."""
-        wt = self.assigner.window_type()
+        from_namespace = self.assigner.window_type().from_namespace
         backend = self.keyed_backend
         hist = self._emit_batch_hist
         # a device gather hands back an ndarray: unbox 0-d rows exactly
@@ -980,37 +1000,36 @@ class WindowOperator(AbstractUdfStreamOperator):
         # heap results are python objects and pass through untouched
         unbox = isinstance(contents_col, np.ndarray)
         collector = TimestampedCollector(buf)
+        if namespaces is None:
+            namespaces = itertools.repeat(namespace)
         fired = 0
-        for j, i in enumerate(rows):
-            if not found_mask[j]:
+        for key, ns, found, contents in zip(keys, namespaces, found_mask,
+                                            contents_col):
+            if not found:
                 continue
-            contents = contents_col[j]
             if unbox:
                 if np.ndim(contents) == 0:
                     contents = contents.item()
             elif contents is None:
                 continue
-            window = wt.from_namespace(ns_col[i])
-            backend.set_current_key(key_col[i])
+            window = from_namespace(ns)
+            backend.set_current_key(key)
             if hist is not None:
                 hist.update(len(contents)
                             if hasattr(contents, "__len__") else 1)
             collector.set_absolute_timestamp(window.max_timestamp())
-            self._internal_fn.process(key_col[i], window, self,
-                                      contents, collector)
+            self._internal_fn.process(key, window, self, contents, collector)
             fired += 1
         return fired
 
-    def _fire_over_columns(self, buf, rows, key_col, ns_col, contents_col,
-                           found_mask) -> int:
+    def _fire_over_columns(self, buf, keys, namespace, namespaces,
+                           contents_col, found_mask) -> int:
         """A plain callable, or no window function: it is handed
         (key, window, elements) and nothing else, so nothing is set up
         per key — absent rows are dropped once, the gather is unboxed
         once, every distinct window is built once, and the backend's
         key context is set once, to the last fired key, where the
         per-key loop leaves it."""
-        keys = [key_col[i] for i in rows]
-        namespaces = [ns_col[i] for i in rows]
         keep = np.asarray(found_mask, bool)
         scalars = False
         if isinstance(contents_col, np.ndarray):
@@ -1027,8 +1046,9 @@ class WindowOperator(AbstractUdfStreamOperator):
         if not keep.all():
             keep = keep.tolist()
             keys = list(itertools.compress(keys, keep))
-            namespaces = list(itertools.compress(namespaces, keep))
             contents = list(itertools.compress(contents, keep))
+            if namespaces is not None:
+                namespaces = list(itertools.compress(namespaces, keep))
         fired = len(keys)
         if not fired:
             return 0
@@ -1037,12 +1057,12 @@ class WindowOperator(AbstractUdfStreamOperator):
                 [1] * fired if scalars else
                 [len(c) if hasattr(c, "__len__") else 1 for c in contents])
         from_namespace = self.assigner.window_type().from_namespace
-        made = {ns: from_namespace(ns) for ns in set(namespaces)}
         fn = self._internal_fn
-        if len(made) == 1:
+        if namespaces is None:
             buf.emit_fired(fn.fn, keys, contents, fn.single_value,
-                           window=made[namespaces[0]])
+                           window=from_namespace(namespace))
         else:
+            made = {ns: from_namespace(ns) for ns in set(namespaces)}
             buf.emit_fired(fn.fn, keys, contents, fn.single_value,
                            windows=[made[ns] for ns in namespaces])
         self.keyed_backend.set_current_key(keys[-1])

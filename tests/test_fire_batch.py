@@ -1,14 +1,17 @@
 """Differential suite for the batched window FIRE path.
 
 `WindowOperator.batch_fires` toggles the columnar watermark fire
-(bulk timer sweep → vectorized trigger decision → one backend gather →
-RecordBatch emit → batch clear) against the per-timer scalar drain.
+(bulk timer sweep → one trigger decision per swept run → one backend
+gather → RecordBatch emit → batch clear) against the per-timer scalar
+drain.
 Every combination of assigner {tumbling, sliding} x allowed lateness
 {0, positive} x backend {heap, tpu} x ingest {batched, per-row} must
 produce BIT-EQUAL output: values, timestamps, and emission order —
 including when a watermark fires windows whose timers straddle a
 checkpoint barrier (registered before the snapshot, fired after the
 restore)."""
+
+import itertools
 
 import numpy as np
 import pytest
@@ -121,7 +124,7 @@ def _run(kind, lateness, backend, batch_fires, snapshot_at=None,
 
 
 @pytest.mark.parametrize("backend", ["heap", "tpu"])
-@pytest.mark.parametrize("lateness", [0, 150])
+@pytest.mark.parametrize("lateness", [0, 100, 150])
 @pytest.mark.parametrize("kind", ["tumbling", "sliding"])
 def test_batch_fire_bit_equal(kind, lateness, backend):
     scalar = _run(kind, lateness, backend, batch_fires=False)
@@ -363,15 +366,26 @@ def _tail_harness(kind, backend, fn, batch_fires, lateness=0):
     op.register_standard_metrics(MetricRegistry().job_group("tail"))
     spy = op.output = FireSpy()  # before open(): the collector binds it
     h.open()
+    #: (timestamp, namespace) of every timer the per-timer drain fires
+    op.timers_fired = []
+    op.fired_at_watermark = []
+    drain_fires = op.on_event_time
+
+    def on_event_time(timer):
+        op.timers_fired.append((timer.timestamp, timer.namespace))
+        op.fired_at_watermark.append(op.timer_service.current_watermark)
+        drain_fires(timer)
+
+    op.on_event_time = on_event_time
     return op, h, spy
 
 
-def _drive_fires(kind, backend, fn, batch_fires, lateness=0):
+def _drive_fires(kind, backend, fn, batch_fires, lateness=0, chunks=None):
     """One list of what left the operator per watermark; the sweeps
     take in four tumbling (or five sliding) windows each."""
     op, h, spy = _tail_harness(kind, backend, fn, batch_fires, lateness)
     per_watermark = []
-    for keys, vals, ts, wm in _chunks():
+    for keys, vals, ts, wm in chunks or _chunks():
         h.process_batch(RecordBatch({"f0": keys, "f1": vals}, ts=ts))
         h.process_watermark(wm)
         per_watermark.append(spy.take())
@@ -404,6 +418,92 @@ def test_a_fire_leaves_as_the_record_tail_left_it(shape, kind, backend):
     hist, want = op._emit_batch_hist, scalar_op._emit_batch_hist
     assert hist.total_count == want.total_count > 0
     assert (hist._values, hist._pos) == (want._values, want._pos)
+
+
+def _timers_by_watermark(op):
+    """The drain's firing order, one list per watermark."""
+    fired = zip(op.fired_at_watermark, op.timers_fired)
+    return [[timer for _, timer in group] for _, group in
+            itertools.groupby(fired, key=lambda pair: pair[0])]
+
+
+def _maximal_runs(timers):
+    """How many stretches of one (timestamp, namespace) a firing
+    order is made of."""
+    return sum(1 for a, b in zip([None] + timers, timers) if a != b)
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("kind, lateness", [
+    ("tumbling", 0), ("sliding", 0),
+    # lateness = size: window A's cleanup timers tie with window B's
+    # fire timers, and the chunks' out-of-order rows interleave them
+    ("tumbling", 100),
+    # lateness = slide, = size: ties among three sliding windows
+    ("sliding", 100), ("sliding", 200), ("tumbling", 150)])
+@pytest.mark.parametrize("shape", ["one_row", "several_rows"])
+def test_swept_runs_fire_as_the_per_timer_drain(shape, kind, lateness,
+                                                backend):
+    """The batched fire over the sweep's runs against the per-timer
+    drain, cell for cell, where timers of several windows tie on a
+    timestamp; and the counters that say how the timers came: every
+    timer the drain fires is swept once, in as many runs as the
+    drain's order has stretches of one (timestamp, window)."""
+    fn = WINDOW_FUNCTIONS[shape]
+    scalar_op, scalar = _drive_fires(kind, backend, fn, False, lateness)
+    op, batched = _drive_fires(kind, backend, fn, True, lateness)
+    assert len(batched) == len(scalar)
+    for events, reference in zip(batched, scalar):
+        assert_fire_left_as(events, [what for _, what in reference])
+    assert sum(len(reference) for reference in scalar)
+    timers = scalar_op.timers_fired
+    assert op.timers_fired == [] and scalar_op.timers_swept == 0
+    assert op.timers_swept == len(timers) > 0
+    # fired + cleaned: with lateness every (key, window) has two timers
+    fire_timers = sum(ts == ns[1] - 1 for ts, ns in timers)
+    assert len(timers) == fire_timers * (2 if lateness else 1)
+    distinct = len(set(timers))
+    assert op.timer_runs >= distinct
+    if lateness == 0:
+        assert op.timer_runs == distinct  # one run per fired window
+    # a run per stretch, counted within each watermark's sweep
+    sweeps = _timers_by_watermark(scalar_op)
+    assert op.timer_runs == sum(_maximal_runs(sweep) for sweep in sweeps)
+    assert op.keyed_backend.current_key == scalar_op.keyed_backend.current_key
+
+
+def _rows(keys, ts, wm):
+    return (np.array(keys, np.int64), np.ones(len(keys)),
+            np.array(ts, np.int64), wm)
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+def test_tied_runs_are_cut_where_their_registrations_interleave(backend):
+    """Tumbling 100 with lateness 100: at 199 window [0, 100)'s
+    cleanup timers tie with window [100, 200)'s fire timers, and keys
+    reached the two windows in turns, batch after batch.  The sweep
+    hands the tie over in registration order, cut into five runs, and
+    the batched fire emits and clears as the per-timer drain."""
+    chunks = [_rows([1, 2], [50, 150], 0), _rows([3, 4, 1], [60, 160, 170], 10),
+              _rows([5], [70], 99), _rows([6], [250], 199)]
+    scalar_op, scalar = _drive_fires("tumbling", backend, _one_row, False,
+                                     100, chunks)
+    op, batched = _drive_fires("tumbling", backend, _one_row, True, 100,
+                               chunks)
+    for events, reference in zip(batched, scalar):
+        assert_fire_left_as(events, [what for _, what in reference])
+    tie = [sweep for sweep in _timers_by_watermark(scalar_op)
+           if sweep[0][0] == 199]
+    assert tie == [[(199, (0, 100)), (199, (100, 200)), (199, (0, 100)),
+                    (199, (100, 200)), (199, (100, 200)), (199, (0, 100))]]
+    assert [[value[0] for _, (value, _) in reference]
+            for reference in scalar] \
+        == [[], [], [1, 3, 5], [2, 4, 1], [6]]
+    assert op.timers_swept == len(scalar_op.timers_fired) == 14
+    # [0,100) fires: 1; the tie: 5; [100,200) cleans, [200,300) fires
+    # and cleans at the last watermark: 3
+    assert op.timer_runs == 9
+    assert len(set(scalar_op.timers_fired)) == 6
 
 
 def test_a_sweep_over_several_windows_holds_one_run_per_window():
@@ -509,6 +609,30 @@ def test_a_plain_callable_fire_builds_no_stream_record(fn, direct,
     else:
         assert (op.fire_rows_direct, op.fire_rows_via_records) == (0, rows)
         assert CountedRecord.made == rows
+
+
+def test_timer_phases_carry_keys_timers_and_runs(monkeypatch):
+    """`timers.register` says how many distinct keys a (window, batch)
+    handed to the store, `timers.sweep` how many timers a watermark
+    took out and in how many runs: 300 timers in one run for a
+    tumbling window, whatever the number of batches that brought them."""
+    op, h, spy = _tail_harness("tumbling", "heap", _one_row, True)
+    tr = get_tracer()
+    tr.reset()
+    monkeypatch.setattr(tr, "enabled", True)
+    for lo in (0, 100):
+        keys = np.arange(lo, lo + 200, dtype=np.int64).repeat(2)
+        h.process_batch(RecordBatch(
+            {"f0": keys, "f1": np.ones(400)}, ts=np.full(400, 50, np.int64)))
+    h.process_watermark(98)
+    h.process_watermark(99)
+    events = tr.recent()
+    assert [e["args"] for e in events if e["name"] == "timers.register"] \
+        == [{"keys": 200}, {"keys": 200}]
+    assert [e["args"] for e in events if e["name"] == "timers.sweep"] \
+        == [{"timers": 0, "runs": 0}, {"timers": 300, "runs": 1}]
+    assert (op.timers_swept, op.timer_runs) == (300, 1)
+    assert len(spy.rows()) == 300
 
 
 def test_rows_that_do_not_fit_become_records_at_the_flush(monkeypatch):
