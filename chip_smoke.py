@@ -16,6 +16,12 @@ Legs:
   2  state backend      env.set_state_backend("tpu"): WindowOperator →
                         TpuKeyedStateBackend → DeviceAggregatingState,
                         ~4 GiB of registers live in HBM per window
+  2b spill tier         the same route at the north star's 10,000,000
+                        keys under state.backend.tpu.max-device-slots =
+                        2^20, set in the environment's Configuration:
+                        2^21 events a window, ~1.89M live keys, so the
+                        coldest rows go to host RAM in bulk, come back
+                        on access and fire from there
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -35,6 +41,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import gc
 import json
 import sys
 import time
@@ -51,6 +58,7 @@ except ImportError as e:  # alone in a directory: nothing to smoke
     sys.exit(2)
 
 import flink_tpu.native as nat  # noqa: E402
+from flink_tpu.core.config import Configuration  # noqa: E402
 from flink_tpu.ops import link_probe  # noqa: E402
 from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
 from flink_tpu.ops.sketches import HyperLogLogAggregate  # noqa: E402
@@ -81,10 +89,17 @@ BATCH_ROWS = 8192
 
 FULL = dict(keys=1_000_000, events_per_window=1 << 22, windows=3,
             precision=12, side_events=1 << 20, fused_events=1 << 18,
-            fused_keys=4096)
+            fused_keys=4096,
+            spill=dict(keys=10_000_000, events_per_window=1 << 21,
+                       budget=1 << 20, microbatch=None))
 TINY = dict(keys=256, events_per_window=4096, windows=3,
             precision=12, side_events=4096, fused_events=8192,
-            fused_keys=64)
+            fused_keys=64,
+            # the microbatch bounds the LRU's protected stamp window
+            # (2 x microbatch + 16 touches): the default's would cover
+            # every slot of a tiny budget, and nothing could be evicted
+            spill=dict(keys=6000, events_per_window=4096, budget=1024,
+                       microbatch=64))
 
 
 # ---------------------------------------------------------------------
@@ -203,12 +218,14 @@ def one_of(ops, cls):
     return found[0]
 
 
-def run_window_job(name, arrays, agg, on_state_backend=False):
+def run_window_job(name, arrays, agg, on_state_backend=False,
+                   configuration=None):
     """source → keyBy(field 0) → 1 s tumbling window → aggregate →
     sink, through env.execute().  `on_state_backend` takes the scalar
     WindowOperator with its state in the `tpu` backend; otherwise the
-    default aggregate() picks the operator.  Returns (operators, sink)."""
-    env = StreamExecutionEnvironment()
+    default aggregate() picks the operator.  `configuration` is the
+    environment's.  Returns (operators, sink)."""
+    env = StreamExecutionEnvironment(configuration)
     windowed = (env.add_source(EventSource(*arrays), name="events")
                 .key_by(0)
                 .window(TumblingEventTimeWindows.of(WINDOW_MS)))
@@ -248,7 +265,7 @@ def make_events(seed, n_keys, events_per_window, windows):
 def exact_distinct(keys, users, ts):
     """{window start: (sorted keys, exact distinct users per key)} by
     np.unique — independent of every hash and sketch under test."""
-    assert int(keys.max()) < (1 << 23) and int(users.max()) < (1 << 40)
+    assert int(keys.max()) < (1 << 24) and int(users.max()) < (1 << 40)
     starts = ts - ts % WINDOW_MS
     ref = {}
     for w in np.unique(starts).tolist():
@@ -419,6 +436,49 @@ def leg_state_backend(cfg, events, ref):
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
         "evictions": state.evictions, **facts}
+
+
+def leg_state_spill(cfg, seed):
+    """Leg 2's route under a device-slot budget the window's live keys
+    outgrow.  The budget is set where docs/state.md tells a user to
+    set it: in the environment's Configuration."""
+    spill = cfg["spill"]
+    events = make_events(seed + 1, spill["keys"],
+                         spill["events_per_window"], cfg["windows"])
+    ref = exact_distinct(*events)
+    conf = Configuration().set("state.backend.tpu.max-device-slots",
+                               spill["budget"])
+    if spill["microbatch"] is not None:
+        conf.set("state.backend.tpu.microbatch-size", spill["microbatch"])
+    ops, sink = run_window_job("chip-smoke-state-spill", events,
+                               UserHll(cfg["precision"]),
+                               on_state_backend=True, configuration=conf)
+    wop = one_of(ops, WindowOperator)
+    state = wop.window_state
+    cols = sink.columns()
+    problems, facts = check_hll(*cols, ref, cfg["precision"])
+    problems += boxed_problems(wop, len(events[0]))
+    problems += fire_tail_problems(wop, len(cols[0]))
+    if state.max_device_slots != spill["budget"]:
+        problems.append(f"the backend's budget is {state.max_device_slots}, "
+                        f"the Configuration says {spill['budget']}")
+    if state.capacity > spill["budget"] or state.budget_overruns:
+        problems.append(f"capacity {state.capacity} of a budget of "
+                        f"{spill['budget']}, {state.budget_overruns} "
+                        f"overruns")
+    if not (state.evictions and state.promotions):
+        problems.append(f"{state.evictions} evictions, "
+                        f"{state.promotions} promotions: the tier did "
+                        f"nothing")
+    regs = state.device_state["regs"]
+    return problems, {
+        "keys": spill["keys"], "events": len(events[0]),
+        "budget": spill["budget"], "slots": state.capacity,
+        "register_bytes": int(regs.size) * regs.dtype.itemsize,
+        "evictions": state.evictions, "promotions": state.promotions,
+        "budget_overruns": state.budget_overruns,
+        "live_keys_per_window": [len(k) for k, _ in ref.values()],
+        **facts}
 
 
 def leg_sql(cfg, events, ref, mesh=None):
@@ -711,6 +771,9 @@ def main(argv=None) -> int:
             problems, facts = [f"{type(e).__name__}: {e}"], {}
         facts["wall_s"] = round(time.perf_counter() - t0, 2)
         facts["passed"] = not problems
+        # a leg's job is a web of reference cycles: without this its
+        # device state (4 GiB after leg 2) is still there in the next
+        gc.collect()
         if problems:
             facts["problems"] = problems
             if required:
@@ -730,6 +793,7 @@ def main(argv=None) -> int:
                          cfg["windows"])
     ref = exact_distinct(*events)
     run("2 state backend", leg_state_backend, cfg, events, ref)
+    run("2b spill tier", leg_state_spill, cfg, args.seed)
     sql = run("3a sql", leg_sql, cfg, events, ref)
     run("3b datastream", leg_datastream_default, cfg, events, ref)
     run("4a entry step", leg_entry_step, cfg)

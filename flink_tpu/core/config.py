@@ -281,8 +281,15 @@ class StateBackendOptions:
         "state.backend.tpu.max-device-slots").int_type().no_default_value(
         ).with_description(
         "Per-state HBM slot budget for the TPU backend; beyond it the "
-        "LRU-coldest slots spill to host RAM and are promoted back on "
-        "access. Unset = uncapped (grow-doubling device tables).")
+        "LRU-coldest slots spill to host RAM, a quarter of the slots "
+        "at a time, are promoted back on access and fire from there. "
+        "Set it in the Configuration the StreamExecutionEnvironment is "
+        "built with (or env.config.set(...) before execute()): every "
+        "executor hands that Configuration to the backend. A slot of "
+        "HLL precision 12 is 4 KiB; the device scatter holds a second "
+        "copy of the table while it runs, so a chip takes a budget of "
+        "about half its HBM (2^20 such slots on 16 GB). Unset = "
+        "uncapped (grow-doubling device tables).")
     TPU_MICROBATCH_SIZE = ConfigOptions.key(
         "state.backend.tpu.microbatch-size").int_type().no_default_value(
         ).with_description(

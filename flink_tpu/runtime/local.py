@@ -469,7 +469,7 @@ class SubtaskInstance:
     OperatorChain + BarrierBuffer)."""
 
     def __init__(self, vertex: JobVertex, subtask_index: int,
-                 state_backend_name: str, max_parallelism: int,
+                 state_backend, max_parallelism: int,
                  processing_time_service,
                  channel_capacity: int = DEFAULT_CHANNEL_CAPACITY,
                  metrics_group=None, latency_stats=None):
@@ -573,9 +573,8 @@ class SubtaskInstance:
                 rng = compute_key_group_range_for_operator_index(
                     max_parallelism, vertex.parallelism, subtask_index)
                 keyed = load_state_backend(
-                    state_backend_name if node.state_backend is None
-                    else node.state_backend,
-                    rng, max_parallelism)
+                    state_backend, rng, max_parallelism,
+                    name=node.state_backend)
             op.setup(
                 output,
                 keyed_backend=keyed,

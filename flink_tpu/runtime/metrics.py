@@ -636,6 +636,7 @@ def register_state_gauges(metrics: MetricRegistry) -> None:
     d.gauge("spilledEntries", lambda: _dev("spilled_entries"))
     d.gauge("evictions", lambda: _dev("evictions"))
     d.gauge("promotions", lambda: _dev("promotions"))
+    d.gauge("budgetOverruns", lambda: _dev("budget_overruns"))
     d.gauge("pendingDepth", lambda: _dev("pending_depth"))
 
     # per-state attribution of the batch/fallback split (the aggregate

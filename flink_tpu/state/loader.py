@@ -31,10 +31,16 @@ def load_state_backend(
     config_or_name,
     key_group_range: KeyGroupRange,
     max_parallelism: int,
+    name: str | None = None,
     **kwargs,
 ) -> KeyedStateBackend:
+    """`config_or_name` is a `Configuration` (the backend's name under
+    `state.backend`, its tuning keys beside it) or a bare name, which
+    carries no tuning.  `name` overrides the configured name (a graph
+    node that pins its own backend) and leaves the tuning keys be."""
     if isinstance(config_or_name, Configuration):
-        name = config_or_name.get_string(STATE_BACKEND_KEY, "heap")
+        if name is None:
+            name = config_or_name.get_string(STATE_BACKEND_KEY, "heap")
         # HBM budget: beyond it, cold device slots spill to host RAM
         if config_or_name.contains("state.backend.tpu.max-device-slots"):
             cap = config_or_name.get_integer(
@@ -53,10 +59,8 @@ def load_state_backend(
                     "state.backend.tpu.microbatch-size must be > 0 "
                     f"(got {mb}); omit it for the built-in default")
             kwargs.setdefault("microbatch", mb)
-    elif config_or_name is None:
-        name = "heap"
-    else:
-        name = str(config_or_name)
+    elif name is None:
+        name = "heap" if config_or_name is None else str(config_or_name)
     name = name.lower()
     if name in _HEAP_NAMES:
         return HeapKeyedStateBackend(key_group_range, max_parallelism)

@@ -23,6 +23,8 @@ class StateStats:
         "row_fallback_calls", "flush_batches", "flush_rows",
         "flush_sizes", "result_rows", "result_padded_rows",
         "snapshot_columns", "snapshot_rows",
+        "evicted_rows", "promoted_rows", "spill_fired_rows",
+        "budget_overruns",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
     )
@@ -49,6 +51,13 @@ class StateStats:
         #: snapshot rows serialized as columns vs boxed per-row
         self.snapshot_columns = 0
         self.snapshot_rows = 0
+        #: the tpu backend's spill tier: rows evicted to host RAM, rows
+        #: promoted back, rows a batched fire finalised from there, and
+        #: times the device budget was overrun (nothing cold to evict)
+        self.evicted_rows = 0
+        self.promoted_rows = 0
+        self.spill_fired_rows = 0
+        self.budget_overruns = 0
         #: the same batch/fallback split ATTRIBUTED by state name, so a
         #: fallback is traceable to the state that caused it; the
         #: aggregate counters above stay authoritative for the
@@ -110,10 +119,11 @@ def register_device_state(state) -> None:
 
 def device_state_summary() -> dict:
     """Aggregate live device-state pressure: slots in use, capacity,
-    host-spill entries, evictions, host→device promotions, pending-ring
-    depth.  Safe to call from a gauge thread."""
+    host-spill entries, evictions, host→device promotions, overruns of
+    the device budget, pending-ring depth.  Safe to call from a gauge
+    thread."""
     slots = capacity = spilled = evictions = promotions = pending = 0
-    states = 0
+    states = overruns = 0
     with _LIVE_LOCK:
         live = list(_LIVE_DEVICE_STATES)
     for st in live:
@@ -124,6 +134,7 @@ def device_state_summary() -> dict:
             spilled += len(st.host_tier)
             evictions += st.evictions
             promotions += st.promotions
+            overruns += st.budget_overruns
             pending += len(st._pending_slots)
         except Exception:  # noqa: BLE001 — racing dispose
             continue
@@ -134,5 +145,6 @@ def device_state_summary() -> dict:
         "spilled_entries": spilled,
         "evictions": evictions,
         "promotions": promotions,
+        "budget_overruns": overruns,
         "pending_depth": pending,
     }

@@ -66,6 +66,7 @@ from flink_tpu.state.heap_backend import (
 )
 from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
 from flink_tpu.runtime.tracing import get_tracer, traced_jit
+from flink_tpu.state.host_tier import HostTier
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
 _perf_ns = time.perf_counter_ns
@@ -77,6 +78,13 @@ DEFAULT_MICROBATCH = 16384
 #: reduces it, so the fire's tile is sized by bytes, not by slots (as
 #: vectorized.py sizes FIRE_TILE: 2^16 slots for HLL p12)
 RESULT_SCRATCH_BYTES = 256 << 20
+#: rows one `state.promote` dispatch uploads, in bytes: the spilled
+#: rows a batch touches go back up together, in tiles of this one shape
+PROMOTE_TILE_BYTES = 4 << 20
+#: an eviction's one gather comes back in pieces of this many bytes,
+#: their copies to the host all in flight at once (one copy of 1 GiB
+#: ran at half the rate of sixteen of 64 MiB on a v5e's host link)
+EVICT_PIECE_BYTES = 64 << 20
 
 
 def _round_up_pow2(n: int) -> int:
@@ -113,6 +121,9 @@ class DeviceAggregatingState(AggregatingState):
         self._descriptor = descriptor
         self.agg: DeviceAggregateFunction = agg
         self._namespace = VOID_NAMESPACE
+        if max_device_slots is not None:
+            # the budget holds from the first slot on
+            initial_capacity = min(initial_capacity, max_device_slots)
         self.capacity = initial_capacity
         self.device_state: Dict[str, jnp.ndarray] = agg.init_state(initial_capacity)
         #: (key, namespace) → slot
@@ -125,9 +136,12 @@ class DeviceAggregatingState(AggregatingState):
         # the role RocksDB's disk residency plays in the reference) ----
         #: device-slot budget; None = unbounded (grow-on-demand)
         self.max_device_slots = max_device_slots
-        #: (key, namespace) → {component: numpy row} for entries
-        #: evicted out of HBM; promoted back on access
-        self.host_tier: Dict[Tuple[Any, Any], Dict[str, np.ndarray]] = {}
+        #: entries evicted out of HBM, in the blocks their evictions
+        #: gathered them in; promoted back on access
+        self.host_tier = HostTier()
+        #: its index, (key, namespace) → row id: a plain dict for the
+        #: per-key probes
+        self._spilled = self.host_tier.index
         #: per-slot last-access stamps (approximate LRU clock)
         self._access_stamp: List[int] = [0] * initial_capacity
         #: per-slot flag: some update has actually LANDED on device —
@@ -138,18 +152,37 @@ class DeviceAggregatingState(AggregatingState):
         #: observability: spill/promotion counters
         self.evictions = 0
         self.promotions = 0
+        #: times nothing was cold enough to evict and the capacity grew
+        #: past the budget instead (the soft cap)
+        self.budget_overruns = 0
+        #: (tile width, two sets of host buffers) of the spilled fire
+        self._fire_buffers = (0, [])
         self._pending_slots: List[int] = []
         self._pending_values: List[Any] = []
         self._pending_hi: List[int] = []
         self._pending_lo: List[int] = []
         # jit-compiled entry points (cached per state object; XLA caches
-        # per padded batch shape), under labels jit_stats() keeps
-        self._jit_update = traced_jit(self._update_fn, name="state.update",
+        # per padded batch shape), under labels jit_stats() keeps.  None
+        # of them may close over this object: jax keeps a jitted
+        # function, and with it the registers, long after the job
+        self._jit_update = traced_jit(self.agg.update, name="state.update",
                                       donate_argnums=0)
         self._jit_upload = traced_jit(
             lambda st, slot, row: {k: st[k].at[slot].set(row[k])
                                    for k in st},
             name="state.upload", donate_argnums=0)
+        # the spill tier's two bulk programs: one gather of an
+        # eviction's rows (in pieces, see _evict_piece_rows), one
+        # scatter of a batch's promoted rows
+        piece = self._evict_piece_rows()
+        self._jit_evict = traced_jit(
+            lambda st, slots: [{k: st[k][slots[i:i + piece]] for k in st}
+                               for i in range(0, slots.shape[0], piece)],
+            name="state.evict")
+        self._jit_promote = traced_jit(
+            lambda st, slots, rows: {k: st[k].at[slots].set(rows[k])
+                                     for k in st},
+            name="state.promote", donate_argnums=0)
         self._jit_merge = traced_jit(self.agg.merge_slots,
                                      name="state.merge", donate_argnums=0)
         #: the jit(vmap(merge)) pairwise kernel — unique-dst dispatches
@@ -169,8 +202,14 @@ class DeviceAggregatingState(AggregatingState):
         self._device_lock = threading.RLock()
         register_device_state(self)
 
-    def _update_fn(self, state, slots, values, hi, lo, mask):
-        return self.agg.update(state, slots, values, hi, lo, mask)
+    def _bytes_per_slot(self) -> int:
+        return max(1, tree_nbytes(self.device_state) // self.capacity)
+
+    def _evict_piece_rows(self) -> int:
+        """Rows of one piece of an eviction's gather: `state.evict`
+        returns the rows of its slots, each component in pieces of
+        EVICT_PIECE_BYTES that travel to the host side by side."""
+        return max(1, EVICT_PIECE_BYTES // self._bytes_per_slot())
 
     # ---- namespace / key context ------------------------------------
     def set_current_namespace(self, namespace) -> None:
@@ -180,7 +219,7 @@ class DeviceAggregatingState(AggregatingState):
     def _slot_for(self, key, namespace, create: bool = True) -> Optional[int]:
         entry = (key, namespace)
         slot = self.slot_index.get(entry)
-        if slot is None and entry in self.host_tier:
+        if slot is None and entry in self._spilled:
             slot = self._promote(entry)
         if slot is None and create:
             if not self._free:
@@ -205,6 +244,9 @@ class DeviceAggregatingState(AggregatingState):
         self._evict_cold(max(1, self.capacity // 4))
 
     def _evict_cold(self, n: int) -> None:
+        """Spill the (up to) `n` coldest slots: ONE device gather of
+        their rows, at a shape `n` fixes, filed in the host tier as
+        the block it came back as."""
         self._flush()
         # never evict recently touched slots: a batch mid-assembly
         # references up to `microbatch` freshly assigned slots (the
@@ -213,67 +255,84 @@ class DeviceAggregatingState(AggregatingState):
         # allocating the target — the +16 margin covers the merge's
         # source set
         protected = self._clock - (2 * self.microbatch + 16)
-        candidates = [(self._access_stamp[s], s)
-                      for s, meta in enumerate(self.slot_meta)
-                      if meta is not None
-                      and self._access_stamp[s] < protected]
-        if not candidates:
+        stamps = np.asarray(self._access_stamp, np.int64)
+        cold = stamps < protected
+        cold[np.asarray(self._free, np.int64)] = False
+        cand = np.flatnonzero(cold)
+        if cand.size > n:
+            # the n smallest stamps, ties by slot
+            s = stamps[cand]
+            kth = np.partition(s, n - 1)[n - 1]
+            below = cand[s < kth]
+            cand = np.concatenate(
+                [below, cand[s == kth][:n - below.size]])
+        meta = self.slot_meta
+        victims = [v for v in cand[np.argsort(stamps[cand],
+                                              kind="stable")].tolist()
+                   if meta[v] is not None]  # a merge's sources mid-flight
+        if not victims:
             # everything is hot: grow past the budget rather than
             # corrupt in-flight batches (soft cap)
+            self.budget_overruns += 1
+            STATE_STATS.budget_overruns += 1
             self._grow(self.capacity * 2)
             return
-        candidates.sort()
-        victims = [s for _, s in candidates[:n]]
-        idx = np.array(victims, np.int32)
-        if TELEMETRY.enabled:
+        m = len(victims)
+        with get_tracer().phase("state.evict", rows=m):
+            idx = jnp.asarray(_pad_slots(victims, n))
             t0 = _perf_ns()
-            host_rows = {name: np.asarray(arr[jnp.asarray(idx)])
-                         for name, arr in self.device_state.items()}
-            TELEMETRY.record_transfer(
-                "d2h", sum(a.nbytes for a in host_rows.values()),
-                t0, _perf_ns(), "state.evict")
-        else:
-            host_rows = {name: np.asarray(arr[jnp.asarray(idx)])
-                         for name, arr in self.device_state.items()}
-        for i, s in enumerate(victims):
-            entry = self.slot_meta[s]
-            self.host_tier[entry] = {name: host_rows[name][i]
-                                     for name in host_rows}
-            del self.slot_index[entry]
-            self.slot_meta[s] = None
-        with self._device_lock:
-            self.device_state = self._jit_clear(self.device_state,
-                                                jnp.asarray(idx))
-            for s_ in victims:
-                self._slot_flushed[s_] = 0
-        self._free.extend(victims)
-        self.evictions += len(victims)
+            pieces = self._jit_evict(self.device_state, idx)
+            for piece in pieces:
+                for arr in piece.values():
+                    arr.copy_to_host_async()
+            entries = [meta[v] for v in victims]
+            # filed before it leaves the slot index: a concurrent
+            # query finds the entry in one tier or the other
+            step = self._evict_piece_rows()
+            for i, piece in zip(range(0, m, step), pieces):
+                self.host_tier.put(
+                    entries[i:i + step],
+                    {name: np.asarray(arr)[:m - i]
+                     for name, arr in piece.items()})
+            if TELEMETRY.enabled:
+                TELEMETRY.record_transfer(
+                    "d2h", m * self._bytes_per_slot(), t0, _perf_ns(),
+                    "state.evict")
+            slot_index = self.slot_index
+            for entry in entries:
+                del slot_index[entry]
+            for v in victims:
+                meta[v] = None
+            with self._device_lock:
+                self.device_state = self._jit_clear(self.device_state, idx)
+                flushed = self._slot_flushed
+                for v in victims:
+                    flushed[v] = 0
+            self._free.extend(victims)
+        self.evictions += m
+        STATE_STATS.evicted_rows += m
 
     def _promote(self, entry) -> int:
-        """Host-tier entry accessed: lift its row back into HBM
-        (donated single-row upload — in-place, no full-array copy).
-        The index entry publishes only AFTER the upload, inside the
-        lock: a concurrent query must see either the spilled row or
-        the uploaded slot, never a zeroed in-between slot."""
+        """Host-tier entry accessed through the scalar path: lift its
+        row back into HBM (donated single-row upload — in-place, no
+        full-array copy).  The index entry publishes only AFTER the
+        upload, inside the lock: a concurrent query must see either
+        the spilled row or the uploaded slot, never a zeroed
+        in-between slot."""
         if not self._free:
             self._make_room()
         slot = self._free.pop()
-        row = self.host_tier[entry]
+        row = self.host_tier.get(entry)
         with self._device_lock:
+            t0 = _perf_ns()
+            self.device_state = self._jit_upload(
+                self.device_state, jnp.int32(slot),
+                {name: jnp.asarray(val) for name, val in row.items()})
             if TELEMETRY.enabled:
-                t0 = _perf_ns()
-                self.device_state = self._jit_upload(
-                    self.device_state, jnp.int32(slot),
-                    {name: jnp.asarray(val) for name, val in row.items()})
                 TELEMETRY.record_transfer(
-                    "h2d",
-                    sum(getattr(v, "nbytes", 0) for v in row.values()),
+                    "h2d", sum(v.nbytes for v in row.values()),
                     t0, _perf_ns(), "state.promote")
-            else:
-                self.device_state = self._jit_upload(
-                    self.device_state, jnp.int32(slot),
-                    {name: jnp.asarray(val) for name, val in row.items()})
-            del self.host_tier[entry]
+            self.host_tier.discard(entry)
             self.slot_index[entry] = slot
             self._slot_flushed[slot] = 1
         self.slot_meta[slot] = entry
@@ -282,7 +341,78 @@ class DeviceAggregatingState(AggregatingState):
         self._clock += 1
         self._access_stamp[slot] = self._clock
         self.promotions += 1
+        STATE_STATS.promoted_rows += 1
         return slot
+
+    def _promote_spilled(self, keys, namespace, namespaces) -> None:
+        """The spilled entries a batch is about to touch go back into
+        HBM together, before its slot loop: one `state.promote`
+        scatter per tile of `_promote_tile()` rows, whatever their
+        number."""
+        spilled = self._spilled
+        if namespaces is None:
+            entries = [(k, namespace) for k in keys]
+        else:
+            entries = list(zip(keys, namespaces))
+        entries = [e for e in entries if e in spilled]
+        if not entries:
+            return
+        entries = list(dict.fromkeys(entries))  # a key twice: one row
+        m = len(entries)
+        with get_tracer().phase("state.promote", rows=m):
+            while len(self._free) < m:
+                self._make_room()
+            free = self._free
+            slots = [free.pop() for _ in range(m)]
+            ids = np.fromiter((spilled[e] for e in entries), np.int64, m)
+            tile = self._promote_tile()
+            with self._device_lock:
+                t0 = _perf_ns()
+                for i in range(0, m, tile):
+                    part = ids[i:i + tile]
+                    rows = self._host_rows(tile)
+                    self.host_tier.gather(part, rows)
+                    for arr in rows.values():
+                        arr[len(part):] = arr[0]  # pad: slot 0's row again
+                    self.device_state = self._jit_promote(
+                        self.device_state,
+                        jnp.asarray(_pad_slots(slots[i:i + tile], tile)),
+                        {name: jnp.asarray(arr)
+                         for name, arr in rows.items()})
+                if TELEMETRY.enabled:
+                    TELEMETRY.record_transfer(
+                        "h2d", m * self._bytes_per_slot(), t0, _perf_ns(),
+                        "state.promote")
+                slot_index, meta = self.slot_index, self.slot_meta
+                flushed, stamp = self._slot_flushed, self._access_stamp
+                clock = self._clock
+                for entry, slot in zip(entries, slots):
+                    del spilled[entry]
+                    slot_index[entry] = slot
+                    meta[slot] = entry
+                    flushed[slot] = 1
+                    # promoted slots are HOT, as in _promote
+                    clock += 1
+                    stamp[slot] = clock
+                self._clock = clock
+            self.host_tier.release(ids)
+        self.promotions += m
+        STATE_STATS.promoted_rows += m
+
+    def _host_rows(self, n: int) -> Dict[str, np.ndarray]:
+        """Host buffers for `n` rows of every component, to be filled
+        and uploaded.  An upload may alias them: they are filled again
+        only after what was computed from them came back."""
+        return {name: np.empty((n, *arr.shape[1:]), arr.dtype)
+                for name, arr in self.device_state.items()}
+
+    def _promote_tile(self) -> int:
+        """Rows of one `state.promote` dispatch: the power of two
+        whose rows fit PROMOTE_TILE_BYTES, and no more than a chunk of
+        `add_batch` can hold."""
+        return min(_round_up_pow2(self.microbatch),
+                   1 << max(0, (PROMOTE_TILE_BYTES
+                                // self._bytes_per_slot()).bit_length() - 1))
 
     def _grow(self, new_capacity: int) -> None:
         self._flush()
@@ -336,6 +466,8 @@ class DeviceAggregatingState(AggregatingState):
             return
         tracer = get_tracer()
         with tracer.phase("state.add.slots"):
+            if self._spilled:
+                self._promote_spilled(keys, namespace, namespaces)
             slot_for = self._slot_for
             if namespaces is None:
                 slots = [slot_for(k, namespace) for k in keys]
@@ -446,11 +578,11 @@ class DeviceAggregatingState(AggregatingState):
             keys = list(keys)
             n = len(keys)
             slot_index = self.slot_index
-            host_tier = self.host_tier
+            spilled = self._spilled
             slots = np.zeros(n, np.int32)
             found = np.zeros(n, bool)
             spill_idx: List[int] = []
-            spill_rows: List[Dict[str, np.ndarray]] = []
+            spill_ids: List[int] = []
             for i, k in enumerate(keys):
                 entry = (k, namespace if namespaces is None
                          else namespaces[i])
@@ -462,10 +594,10 @@ class DeviceAggregatingState(AggregatingState):
                     self._clock += 1
                     self._access_stamp[s] = self._clock
                     continue
-                row = host_tier.get(entry)
-                if row is not None:
+                rid = spilled.get(entry)
+                if rid is not None:
                     spill_idx.append(i)
-                    spill_rows.append(row)
+                    spill_ids.append(rid)
                     found[i] = True
         self._flush()  # ONE flush for the whole sweep
         if n == 0:  # nothing to gather, and no program for int32[0]
@@ -491,44 +623,61 @@ class DeviceAggregatingState(AggregatingState):
                 TELEMETRY.note_fire_read()
         STATE_STATS.note_result(n, padded)
         if spill_idx:
-            res[spill_idx] = self._finalize_spilled(spill_rows)
+            res[spill_idx] = self._finalize_spilled(
+                np.array(spill_ids, np.int64))
         return res, found
 
     def _result_tile(self) -> int:
         """Most rows one `state.result` dispatch gathers: the power of
         two whose [rows, *slot_shape] fits RESULT_SCRATCH_BYTES."""
-        bytes_per_slot = tree_nbytes(self.device_state) // self.capacity
-        return 1 << max(
-            0, (RESULT_SCRATCH_BYTES // bytes_per_slot).bit_length() - 1)
+        return 1 << max(0, (RESULT_SCRATCH_BYTES
+                            // self._bytes_per_slot()).bit_length() - 1)
 
-    def _finalize_spilled(self, rows: List[Dict[str, np.ndarray]]) -> np.ndarray:
+    def _finalize_spilled(self, ids: np.ndarray) -> np.ndarray:
         """Result extraction for spill-tier rows without promotion:
-        stack the host-resident accumulator rows into a pow2-padded
-        [m, ...] state and run the SAME jit result kernel over it —
-        bit-identical finalization (query_by_key's single-row idiom,
-        batched), zero HBM slot traffic."""
-        m = len(rows)
-        padded = _round_up_pow2(m)
-        state = {}
-        nbytes_in = 0
-        for name in self.device_state:
-            col = np.stack([r[name] for r in rows])
-            if padded != m:
-                pad = np.zeros((padded - m,) + col.shape[1:], col.dtype)
-                col = np.concatenate([col, pad])
-            nbytes_in += col.nbytes
-            state[name] = jnp.asarray(col)
-        idx = jnp.asarray(np.arange(padded, dtype=np.int32))
-        if TELEMETRY.enabled:
+        the host-resident accumulator rows go up in tiles of the
+        width `get_batch` gathers device rows at and through the SAME
+        jit result kernel — bit-identical finalization, zero HBM slot
+        traffic, never more than two tiles of rows on the device."""
+        m = len(ids)
+        width = min(_round_up_pow2(m), self._result_tile())
+        tiles = -(-m // width)
+        with get_tracer().phase("state.fire.spill", rows=m, tiles=tiles):
             t0 = _perf_ns()
-            out = np.asarray(self._jit_result(state, idx))
-            TELEMETRY.record_transfer("h2d", nbytes_in, t0, t0,
-                                      "state.fire.spill")
-            TELEMETRY.record_transfer("d2h", out.nbytes, t0, _perf_ns(),
-                                      "state.fire.spill")
-        else:
-            out = np.asarray(self._jit_result(state, idx))
-        return out[:m]
+            order = np.argsort(ids, kind="stable")  # block by block
+            ids = ids[order]
+            every_row = jnp.asarray(np.arange(width, dtype=np.int32))
+            # two sets of host buffers, kept from fire to fire (fresh
+            # pages cost ten times the copy into them): a tile's set is
+            # filled again only after its result came back
+            if self._fire_buffers[0] != width:
+                self._fire_buffers = (width, [self._host_rows(width),
+                                              self._host_rows(width)])
+            buffers = self._fire_buffers[1]
+            in_flight, done = [], []
+            for i in range(0, m, width):
+                part = ids[i:i + width]
+                rows = buffers[(i // width) % 2]
+                self.host_tier.gather(part, rows)
+                for arr in rows.values():
+                    arr[len(part):] = arr[0]  # pad rows, cut off below
+                in_flight.append(self._jit_result(
+                    {name: jnp.asarray(arr) for name, arr in rows.items()},
+                    every_row))
+                if len(in_flight) > 1:
+                    done.append(np.asarray(in_flight.pop(0)))
+            done.extend(np.asarray(p) for p in in_flight)
+            sorted_res = np.concatenate(done)[:m]
+            res = np.empty_like(sorted_res)
+            res[order] = sorted_res
+            if TELEMETRY.enabled:
+                TELEMETRY.record_transfer(
+                    "h2d", m * self._bytes_per_slot(), t0, t0,
+                    "state.fire.spill")
+                TELEMETRY.record_transfer("d2h", res.nbytes, t0,
+                                          _perf_ns(), "state.fire.spill")
+        STATE_STATS.spill_fired_rows += m
+        return res
 
     def query_by_key(self, key, namespace):
         """Queryable-state read from a FOREIGN thread (ref:
@@ -564,7 +713,7 @@ class DeviceAggregatingState(AggregatingState):
     # ---- lifecycle --------------------------------------------------
     def clear(self) -> None:
         entry = (self._backend.current_key, self._namespace)
-        self.host_tier.pop(entry, None)
+        self.host_tier.discard(entry)
         slot = self.slot_index.pop(entry, None)
         if slot is None:
             return
@@ -579,14 +728,22 @@ class DeviceAggregatingState(AggregatingState):
     def clear_batch(self, keys, namespace, namespaces=None) -> None:
         tracer = get_tracer()
         slots = []
+        spilled_ids = []
         with tracer.phase("state.clear.slots"):
+            spilled = self._spilled
             for i, k in enumerate(keys):
                 ns = namespace if namespaces is None else namespaces[i]
-                self.host_tier.pop((k, ns), None)
                 s = self.slot_index.pop((k, ns), None)
                 if s is not None:
                     slots.append(s)
                     self.slot_meta[s] = None
+                elif spilled:
+                    rid = spilled.pop((k, ns), None)
+                    if rid is not None:
+                        spilled_ids.append(rid)
+        if spilled_ids:
+            with tracer.phase("state.clear.spill", rows=len(spilled_ids)):
+                self.host_tier.release(spilled_ids)
         if not slots:
             return
         self._flush()
@@ -727,9 +884,11 @@ class DeviceAggregatingState(AggregatingState):
             row = {name: host[name][slot] for name in host}
             per_kg[kg].append((key, namespace, row))
         # spilled entries are part of the state too
-        for (key, namespace), row in self.host_tier.items():
+        entries, comps = self.host_tier.columns()
+        for i, (key, namespace) in enumerate(entries):
             kg = assign_to_key_group(key, mp)
-            per_kg[kg].append((key, namespace, dict(row)))
+            per_kg[kg].append((key, namespace,
+                               {name: arr[i] for name, arr in comps.items()}))
         return per_kg
 
     def restore_entries(self, entries: List[Tuple[Any, Any, Dict[str, np.ndarray]]]) -> None:
@@ -741,8 +900,11 @@ class DeviceAggregatingState(AggregatingState):
             # beyond the device budget: the overflow restores straight
             # into the host tier (promoted lazily on first access)
             budget = max(self.max_device_slots - len(self.slot_index), 0)
-            for key, namespace, row in entries[budget:]:
-                self.host_tier[(key, namespace)] = dict(row)
+            over = entries[budget:]
+            self.host_tier.put(
+                [(key, namespace) for key, namespace, _ in over],
+                {name: np.stack([row[name] for _, _, row in over])
+                 for name in over[0][2]})
             entries = entries[:budget]
             if not entries:
                 return
@@ -793,12 +955,10 @@ class DeviceAggregatingState(AggregatingState):
         idx = np.array(slots, np.int32)
         comps = {name: arr[idx] for name, arr in host.items()}
         if self.host_tier:
-            spilled = list(self.host_tier.items())
-            for (key, namespace), _ in spilled:
+            spilled, spill_cols = self.host_tier.columns()
+            for key, namespace in spilled:
                 keys.append(key)
                 nss.append(namespace)
-            spill_cols = {name: np.stack([row[name] for _, row in spilled])
-                          for name in host}
             comps = {name: np.concatenate([comps[name], spill_cols[name]])
                      for name in host}
         out: Dict[int, Tuple[list, list, Dict[str, np.ndarray]]] = {}
@@ -821,9 +981,10 @@ class DeviceAggregatingState(AggregatingState):
             # beyond the device budget: the overflow restores straight
             # into the host tier (promoted lazily on first access)
             budget = max(self.max_device_slots - len(self.slot_index), 0)
-            for i in range(budget, n):
-                self.host_tier[(keys[i], namespaces[i])] = {
-                    name: np.asarray(arr[i]) for name, arr in comps.items()}
+            self.host_tier.put(
+                list(zip(keys[budget:], namespaces[budget:])),
+                {name: np.array(arr[budget:])
+                 for name, arr in comps.items()})
             keys = keys[:budget]
             namespaces = namespaces[:budget]
             comps = {name: arr[:budget] for name, arr in comps.items()}

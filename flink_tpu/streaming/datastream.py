@@ -361,7 +361,11 @@ class StreamExecutionEnvironment:
     def _make_executor(self):
         from flink_tpu.core.config import HistoryServerOptions, MetricOptions
         kw = dict(
-            state_backend=self.state_backend,
+            # the whole configuration under the backend this job chose:
+            # the executors hand it to load_state_backend, which reads
+            # the backend's tuning keys (state.backend.tpu.*) off it
+            state_backend=self.config.clone().set("state.backend",
+                                                  self.state_backend),
             max_parallelism=self.max_parallelism,
             restart_strategy=self.restart_strategy,
             processing_time_service=self.processing_time_service,
