@@ -3,47 +3,47 @@ evicted out of HBM, kept as the blocks one eviction's device gather
 delivered them in, with one index over all of them.
 
 A row has an id that is never reused; a block covers a run of ids.
-Nothing here is per row but the index itself: an eviction files its
-block with one ``dict.update``, a fire slices rows out by id, a clear
-or a promotion releases ids in one call, and a block whose rows are
-all released is dropped whole.  When released rows outnumber live
-ones the live rows are copied into one fresh block.
+Nothing here is per row but the index itself, which is keyed by
+namespace first (`slot_index.NamespaceIndex`, as the device's): an
+eviction's rows enter it with one ``dict.update`` per namespace, a fire
+slices rows out by id, a clear or a promotion releases ids in one
+call, and a block whose rows are all released is dropped whole.  When
+released rows outnumber live ones the live rows are copied into one
+fresh block.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-Entry = Tuple[Any, Any]  # (key, namespace)
+from flink_tpu.state.slot_index import NamespaceIndex, cut_by_namespace
 
 #: released rows tolerated before a compaction is considered at all
 COMPACT_SLACK_ROWS = 1024
 
 
 class _Block:
-    __slots__ = ("base", "entries", "comps", "alive", "live")
+    __slots__ = ("base", "comps", "alive", "live")
 
-    def __init__(self, base, entries, comps):
+    def __init__(self, base, comps, n):
         self.base = base
-        #: entry of each row, in row order (for a compaction's re-index)
-        self.entries = entries
         #: {component: ndarray [rows, ...]}
         self.comps = comps
-        self.alive = np.ones(len(entries), bool)
-        self.live = len(entries)
+        self.alive = np.ones(n, bool)
+        self.live = n
 
 
 class HostTier:
     """(key, namespace) → accumulator row, for rows that left HBM.
-    Reads like a mapping of entries to ``{component: row}`` dicts; the
-    bulk calls (`put`, `gather`, `release`) are what the backend's
-    batch paths use."""
+    `get` / `discard` serve the per-key doors; the bulk calls (`put`,
+    `gather`, `release`, and `index.tables` read directly) are what
+    the backend's batch paths use."""
 
     def __init__(self) -> None:
-        #: entry → row id
-        self.index: Dict[Entry, int] = {}
+        #: namespace → {key → row id}
+        self.index = NamespaceIndex()
         self._blocks: List[_Block] = []
         #: first id of each block, ascending (ids grow with time)
         self._bases = np.zeros(0, np.int64)
@@ -58,26 +58,24 @@ class HostTier:
         return bool(self.index)
 
     def __iter__(self):
+        """Entries as ``(key, namespace)``."""
         return iter(self.index)
 
     def __contains__(self, entry) -> bool:
         return entry in self.index
 
-    def keys(self):
-        return self.index.keys()
-
-    def get(self, entry) -> Optional[Dict[str, np.ndarray]]:
+    def get(self, key, namespace) -> Optional[Dict[str, np.ndarray]]:
         """One row as ``{component: row}``, or None."""
-        rid = self.index.get(entry)
+        rid = self.index.get(key, namespace)
         if rid is None:
             return None
         block = self._blocks[self._block_of(np.array([rid]))[0]]
         return {name: arr[rid - block.base]
                 for name, arr in block.comps.items()}
 
-    def discard(self, entry) -> None:
+    def discard(self, key, namespace) -> None:
         """The entry's row, if it has one here, is gone."""
-        rid = self.index.pop(entry, None)
+        rid = self.index.pop(key, namespace)
         if rid is not None:
             self.release([rid])
 
@@ -88,19 +86,28 @@ class HostTier:
         self._rows = 0
 
     # ---- bulk -------------------------------------------------------
-    def put(self, entries: List[Entry],
-            comps: Dict[str, np.ndarray]) -> None:
-        """File one block: row i of every component belongs to
-        entries[i].  The arrays are kept as they are, not copied."""
-        n = len(entries)
-        if n == 0:
-            return
+    def file(self, comps: Dict[str, np.ndarray]) -> int:
+        """Keep one block of rows, the arrays as they are, not copied;
+        returns the id of its first row (the next ones follow).  The
+        caller enters the rows in `index`."""
+        n = len(next(iter(comps.values())))
         base = self._next_id
-        self.index.update(zip(entries, range(base, base + n)))
-        self._blocks.append(_Block(base, entries, comps))
+        self._blocks.append(_Block(base, comps, n))
         self._bases = np.append(self._bases, base)
         self._next_id = base + n
         self._rows += n
+        return base
+
+    def put(self, keys, namespaces, comps: Dict[str, np.ndarray]) -> None:
+        """File one block and index it: row i of every component
+        belongs to (keys[i], namespaces[i])."""
+        if len(keys) == 0:
+            return
+        base = self.file(comps)
+        for namespace, rows, part in cut_by_namespace(list(keys), None,
+                                                      namespaces):
+            self.index.table(namespace).update(
+                zip(part, (rows + base).tolist()))
 
     def _block_of(self, ids: np.ndarray) -> np.ndarray:
         return np.searchsorted(self._bases, ids, side="right") - 1
@@ -136,38 +143,39 @@ class HostTier:
             dead_blocks |= block.live == 0
         if dead_blocks:
             self._keep([b for b in self._blocks if b.live])
-        released = self._rows - len(self.index)
-        if released > COMPACT_SLACK_ROWS and released > len(self.index):
+        live = len(self.index)
+        released = self._rows - live
+        if released > COMPACT_SLACK_ROWS and released > live:
             self._compact()
 
     def _keep(self, blocks: List[_Block]) -> None:
         self._blocks = blocks
         self._bases = np.array([b.base for b in blocks], np.int64)
-        self._rows = sum(len(b.entries) for b in blocks)
+        self._rows = sum(len(b.alive) for b in blocks)
 
     def _compact(self) -> None:
-        """Copy the live rows of every block into one new block."""
+        """Copy the live rows of every block into one new block; a
+        row's new id follows from its rank among the live ones."""
         blocks, self._blocks = self._blocks, []
         self._bases = np.zeros(0, np.int64)
         self._rows = 0
-        entries: List[Entry] = []
-        parts: Dict[str, list] = {}
-        for block in blocks:
-            live = np.flatnonzero(block.alive)
-            entries.extend(block.entries[i] for i in live.tolist())
-            for name, arr in block.comps.items():
-                parts.setdefault(name, []).append(arr[live])
-        self.put(entries, {name: np.concatenate(p)
-                           for name, p in parts.items()})
+        old = np.concatenate([b.base + np.flatnonzero(b.alive)
+                              for b in blocks])
+        base = self.file(
+            {name: np.concatenate([b.comps[name][b.alive] for b in blocks])
+             for name in blocks[0].comps})
+        for table in self.index.tables.values():
+            ids = np.fromiter(table.values(), np.int64, len(table))
+            table.update(zip(list(table),
+                             (base + np.searchsorted(old, ids)).tolist()))
 
-    def columns(self) -> Tuple[List[Entry], Dict[str, np.ndarray]]:
-        """Every live row, for a snapshot: entries and their stacked
-        components, in index order."""
-        entries = list(self.index)
-        if not entries:
-            return entries, {}
-        ids = np.fromiter(self.index.values(), np.int64, len(entries))
+    def columns(self) -> Tuple[list, list, Dict[str, np.ndarray]]:
+        """Every live row, for a snapshot: keys, namespaces and their
+        stacked components, in index order."""
+        keys, namespaces, ids = self.index.columns()
+        if not keys:
+            return keys, namespaces, {}
         out = {name: np.empty((len(ids),) + arr.shape[1:], arr.dtype)
                for name, arr in self._blocks[0].comps.items()}
         self.gather(ids, out)
-        return entries, out
+        return keys, namespaces, out

@@ -24,7 +24,7 @@ class StateStats:
         "flush_sizes", "result_rows", "result_padded_rows",
         "snapshot_columns", "snapshot_rows",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
-        "budget_overruns",
+        "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
     )
@@ -58,6 +58,11 @@ class StateStats:
         self.promoted_rows = 0
         self.spill_fired_rows = 0
         self.budget_overruns = 0
+        #: rows whose slot the tpu backend resolved by a bulk probe of
+        #: a namespace's table (add_batch / get_batch / clear_batch) /
+        #: by the per-key door (`_slot_for`, scalar clear)
+        self.bulk_probe_rows = 0
+        self.per_key_probe_rows = 0
         #: the same batch/fallback split ATTRIBUTED by state name, so a
         #: fallback is traceable to the state that caused it; the
         #: aggregate counters above stay authoritative for the

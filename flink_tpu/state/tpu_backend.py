@@ -24,6 +24,7 @@ KeyGroupRangeAssignment.java:47-56, StateAssignmentOperation.java).
 
 from __future__ import annotations
 
+import itertools
 import pickle
 import threading
 import time
@@ -67,6 +68,11 @@ from flink_tpu.state.heap_backend import (
 from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
 from flink_tpu.runtime.tracing import get_tracer, traced_jit
 from flink_tpu.state.host_tier import HostTier
+from flink_tpu.state.slot_index import (
+    NamespaceIndex,
+    cut_by_namespace,
+    object_column,
+)
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
 _perf_ns = time.perf_counter_ns
@@ -100,14 +106,55 @@ def _pad_slots(slots, width: int) -> np.ndarray:
     return arr
 
 
+class _SlotRing:
+    """Slots of the pending micro-batch, in the order of its values:
+    `add_batch`'s slot vectors as they are, the single slots of `add`
+    gathered between them."""
+
+    __slots__ = ("_parts", "_tail", "_n")
+
+    def __init__(self) -> None:
+        self._parts: List[np.ndarray] = []
+        self._tail: List[int] = []
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def append(self, slot: int) -> None:
+        self._tail.append(slot)
+        self._n += 1
+
+    def _seal_tail(self) -> None:
+        if self._tail:
+            self._parts.append(np.array(self._tail, np.int64))
+            self._tail = []
+
+    def extend(self, slots: np.ndarray) -> None:
+        self._seal_tail()
+        self._parts.append(slots)
+        self._n += len(slots)
+
+    def take(self) -> np.ndarray:
+        """All of them as one vector (there is at least one)."""
+        self._seal_tail()
+        return np.concatenate(self._parts)
+
+    def clear(self) -> None:
+        self._parts = []
+        self._tail = []
+        self._n = 0
+
+
 class DeviceAggregatingState(AggregatingState):
     """Slot-indexed, micro-batched device aggregation state.
 
     The device twin of RocksDBAggregatingState / HeapAggregatingState:
     identical observable semantics through the AggregatingState
     interface, but `add` enqueues into a pending batch and `get`
-    flushes + gathers, so the per-record cost is a few Python list ops
-    and the per-batch cost is one XLA scatter over the whole key group.
+    flushes + gathers, so the per-batch cost is one bulk probe of the
+    slot index per namespace and one XLA scatter over the whole key
+    group.
     """
 
     def __init__(self, backend: "TpuKeyedStateBackend",
@@ -124,13 +171,7 @@ class DeviceAggregatingState(AggregatingState):
         if max_device_slots is not None:
             # the budget holds from the first slot on
             initial_capacity = min(initial_capacity, max_device_slots)
-        self.capacity = initial_capacity
         self.device_state: Dict[str, jnp.ndarray] = agg.init_state(initial_capacity)
-        #: (key, namespace) → slot
-        self.slot_index: Dict[Tuple[Any, Any], int] = {}
-        #: slot → (key, namespace) (None = free)
-        self.slot_meta: List[Optional[Tuple[Any, Any]]] = [None] * initial_capacity
-        self._free: List[int] = list(range(initial_capacity - 1, -1, -1))
         self.microbatch = microbatch
         # ---- host-RAM spill tier (SURVEY §7 hard-part: state > HBM;
         # the role RocksDB's disk residency plays in the reference) ----
@@ -139,15 +180,8 @@ class DeviceAggregatingState(AggregatingState):
         #: entries evicted out of HBM, in the blocks their evictions
         #: gathered them in; promoted back on access
         self.host_tier = HostTier()
-        #: its index, (key, namespace) → row id: a plain dict for the
-        #: per-key probes
+        #: its index, namespace → {key → row id}
         self._spilled = self.host_tier.index
-        #: per-slot last-access stamps (approximate LRU clock)
-        self._access_stamp: List[int] = [0] * initial_capacity
-        #: per-slot flag: some update has actually LANDED on device —
-        #: queryable reads must not surface the init accumulator of a
-        #: slot whose first adds are still pending (heap returns None)
-        self._slot_flushed = bytearray(initial_capacity)
         self._clock = 0
         #: observability: spill/promotion counters
         self.evictions = 0
@@ -157,10 +191,11 @@ class DeviceAggregatingState(AggregatingState):
         self.budget_overruns = 0
         #: (tile width, two sets of host buffers) of the spilled fire
         self._fire_buffers = (0, [])
-        self._pending_slots: List[int] = []
+        self._pending_slots = _SlotRing()
         self._pending_values: List[Any] = []
         self._pending_hi: List[int] = []
         self._pending_lo: List[int] = []
+        self._reset_slots(initial_capacity)
         # jit-compiled entry points (cached per state object; XLA caches
         # per padded batch shape), under labels jit_stats() keeps.  None
         # of them may close over this object: jax keeps a jitted
@@ -202,6 +237,39 @@ class DeviceAggregatingState(AggregatingState):
         self._device_lock = threading.RLock()
         register_device_state(self)
 
+    def _reset_slots(self, capacity: int) -> None:
+        """Every slot of `capacity` free, the index empty."""
+        self.capacity = capacity
+        #: namespace → {key → slot}: the ONE index of the device tier;
+        #: `_slot_for` reads and writes it a key at a time, the batch
+        #: doors a namespace's table at a time
+        self.slot_index = NamespaceIndex()
+        #: slot → key / namespace / whether it holds an entry at all
+        #: (what an eviction files its victims under)
+        self.slot_key = np.full(capacity, None, object)
+        self.slot_ns = np.full(capacity, None, object)
+        self._slot_live = np.zeros(capacity, bool)
+        self._free: List[int] = list(range(capacity - 1, -1, -1))
+        #: per-slot last-access stamps (approximate LRU clock)
+        self._access_stamp = np.zeros(capacity, np.int64)
+        #: per-slot flag: some update has actually LANDED on device —
+        #: queryable reads must not surface the init accumulator of a
+        #: slot whose first adds are still pending (heap returns None)
+        self._slot_flushed = np.zeros(capacity, bool)
+
+    def reset(self) -> None:
+        """Before a restore: the device state back to its initial
+        accumulators in place (descriptor bindings survive), nothing
+        indexed, nothing spilled.  Pending micro-batches are
+        pre-failure writes: dropped, the checkpoint supersedes them."""
+        self.device_state = self.agg.init_state(self.capacity)
+        self._reset_slots(self.capacity)
+        self.host_tier.clear()
+        self._pending_slots.clear()
+        self._pending_values.clear()
+        self._pending_hi.clear()
+        self._pending_lo.clear()
+
     def _bytes_per_slot(self) -> int:
         return max(1, tree_nbytes(self.device_state) // self.capacity)
 
@@ -217,22 +285,54 @@ class DeviceAggregatingState(AggregatingState):
 
     # ---- slot management --------------------------------------------
     def _slot_for(self, key, namespace, create: bool = True) -> Optional[int]:
-        entry = (key, namespace)
-        slot = self.slot_index.get(entry)
-        if slot is None and entry in self._spilled:
-            slot = self._promote(entry)
+        """The per-key door into the slot index (scalar add / get,
+        merges)."""
+        STATE_STATS.per_key_probe_rows += 1
+        slot = self.slot_index.get(key, namespace)
+        if slot is None and self._spilled \
+                and self._spilled.get(key, namespace) is not None:
+            slot = self._promote(key, namespace)
         if slot is None and create:
             if not self._free:
                 self._make_room()
             slot = self._free.pop()
-            self.slot_index[entry] = slot
-            self.slot_meta[slot] = entry
+            self.slot_index.put(key, namespace, slot)
+            self._claim_one(slot, key, namespace)
         if slot is not None:
             self._clock += 1
             self._access_stamp[slot] = self._clock
         return slot
 
-    def _make_room(self) -> None:
+    def _claim_one(self, slot: int, key, namespace) -> None:
+        self.slot_key[slot] = key
+        self.slot_ns[slot] = namespace
+        self._slot_live[slot] = True
+
+    def _claim(self, slots: np.ndarray, keys, namespace) -> None:
+        """`slots` hold `keys` of one namespace from now on."""
+        self.slot_key[slots] = object_column(keys, len(slots))
+        boxed = np.empty(1, object)  # a tuple would broadcast its fields
+        boxed[0] = namespace
+        self.slot_ns[slots] = boxed
+        self._slot_live[slots] = True
+
+    def _release(self, slots) -> None:
+        """`slots` hold no entry any more (their rows on the device
+        are the caller's to clear)."""
+        self.slot_key[slots] = None
+        self.slot_ns[slots] = None
+        self._slot_live[slots] = False
+
+    def _stamp(self, slots: np.ndarray) -> None:
+        """Touch `slots` in order: each row gets the stamp a per-key
+        loop would have given it (of a slot that comes twice, the
+        last)."""
+        n = len(slots)
+        self._access_stamp[slots] = np.arange(self._clock + 1,
+                                              self._clock + 1 + n)
+        self._clock += n
+
+    def _make_room(self, ahead: int = 0) -> None:
         """No free slots: grow HBM state, or — at the device budget —
         spill the coldest quarter of slots to the host tier (the
         RocksDB-disk-residency role; SURVEY §7 'state larger than
@@ -241,12 +341,15 @@ class DeviceAggregatingState(AggregatingState):
                 or self.capacity * 2 <= self.max_device_slots):
             self._grow(self.capacity * 2)
             return
-        self._evict_cold(max(1, self.capacity // 4))
+        self._evict_cold(max(1, self.capacity // 4), ahead)
 
-    def _evict_cold(self, n: int) -> None:
+    def _evict_cold(self, n: int, ahead: int = 0) -> None:
         """Spill the (up to) `n` coldest slots: ONE device gather of
         their rows, at a shape `n` fixes, filed in the host tier as
-        the block it came back as."""
+        the block it came back as.  `ahead`: rows the caller is about
+        to stamp — the batch door makes room before it resolves a
+        chunk, and what is cold is judged as of the chunk's last row,
+        as when the per-key loop ran into the full table mid-chunk."""
         self._flush()
         # never evict recently touched slots: a batch mid-assembly
         # references up to `microbatch` freshly assigned slots (the
@@ -254,11 +357,10 @@ class DeviceAggregatingState(AggregatingState):
         # merge mid-flight re-stamps its sources just before
         # allocating the target — the +16 margin covers the merge's
         # source set
-        protected = self._clock - (2 * self.microbatch + 16)
-        stamps = np.asarray(self._access_stamp, np.int64)
-        cold = stamps < protected
-        cold[np.asarray(self._free, np.int64)] = False
-        cand = np.flatnonzero(cold)
+        protected = self._clock + ahead - (2 * self.microbatch + 16)
+        stamps = self._access_stamp
+        # (free slots and a merge's sources mid-flight are not live)
+        cand = np.flatnonzero((stamps < protected) & self._slot_live)
         if cand.size > n:
             # the n smallest stamps, ties by slot
             s = stamps[cand]
@@ -266,18 +368,15 @@ class DeviceAggregatingState(AggregatingState):
             below = cand[s < kth]
             cand = np.concatenate(
                 [below, cand[s == kth][:n - below.size]])
-        meta = self.slot_meta
-        victims = [v for v in cand[np.argsort(stamps[cand],
-                                              kind="stable")].tolist()
-                   if meta[v] is not None]  # a merge's sources mid-flight
-        if not victims:
+        victims = cand[np.argsort(stamps[cand], kind="stable")]
+        m = len(victims)
+        if not m:
             # everything is hot: grow past the budget rather than
             # corrupt in-flight batches (soft cap)
             self.budget_overruns += 1
             STATE_STATS.budget_overruns += 1
             self._grow(self.capacity * 2)
             return
-        m = len(victims)
         with get_tracer().phase("state.evict", rows=m):
             idx = jnp.asarray(_pad_slots(victims, n))
             t0 = _perf_ns()
@@ -285,34 +384,33 @@ class DeviceAggregatingState(AggregatingState):
             for piece in pieces:
                 for arr in piece.values():
                     arr.copy_to_host_async()
-            entries = [meta[v] for v in victims]
+            # (while the copies are on their way)
+            entries = list(cut_by_namespace(
+                self.slot_key[victims].tolist(), None,
+                self.slot_ns[victims].tolist()))
             # filed before it leaves the slot index: a concurrent
             # query finds the entry in one tier or the other
             step = self._evict_piece_rows()
-            for i, piece in zip(range(0, m, step), pieces):
-                self.host_tier.put(
-                    entries[i:i + step],
-                    {name: np.asarray(arr)[:m - i]
-                     for name, arr in piece.items()})
+            bases = [self.host_tier.file(
+                {name: np.asarray(arr)[:m - i] for name, arr in piece.items()})
+                for i, piece in zip(range(0, m, step), pieces)]
             if TELEMETRY.enabled:
                 TELEMETRY.record_transfer(
                     "d2h", m * self._bytes_per_slot(), t0, _perf_ns(),
                     "state.evict")
-            slot_index = self.slot_index
-            for entry in entries:
-                del slot_index[entry]
-            for v in victims:
-                meta[v] = None
+            for namespace, rows, keys in entries:
+                self._spilled.table(namespace).update(
+                    zip(keys, (rows + bases[0]).tolist()))
+                self.slot_index.lookup(keys, namespace, len(keys), take=True)
+            self._release(victims)
             with self._device_lock:
                 self.device_state = self._jit_clear(self.device_state, idx)
-                flushed = self._slot_flushed
-                for v in victims:
-                    flushed[v] = 0
-            self._free.extend(victims)
+                self._slot_flushed[victims] = False
+            self._free.extend(victims.tolist())
         self.evictions += m
         STATE_STATS.evicted_rows += m
 
-    def _promote(self, entry) -> int:
+    def _promote(self, key, namespace) -> int:
         """Host-tier entry accessed through the scalar path: lift its
         row back into HBM (donated single-row upload — in-place, no
         full-array copy).  The index entry publishes only AFTER the
@@ -322,7 +420,7 @@ class DeviceAggregatingState(AggregatingState):
         if not self._free:
             self._make_room()
         slot = self._free.pop()
-        row = self.host_tier.get(entry)
+        row = self.host_tier.get(key, namespace)
         with self._device_lock:
             t0 = _perf_ns()
             self.device_state = self._jit_upload(
@@ -332,10 +430,10 @@ class DeviceAggregatingState(AggregatingState):
                 TELEMETRY.record_transfer(
                     "h2d", sum(v.nbytes for v in row.values()),
                     t0, _perf_ns(), "state.promote")
-            self.host_tier.discard(entry)
-            self.slot_index[entry] = slot
-            self._slot_flushed[slot] = 1
-        self.slot_meta[slot] = entry
+            self.host_tier.discard(key, namespace)
+            self.slot_index.put(key, namespace, slot)
+            self._slot_flushed[slot] = True
+        self._claim_one(slot, key, namespace)
         # freshly promoted slots are HOT: stamp them or a later
         # promotion in the same batch could evict them right back
         self._clock += 1
@@ -344,57 +442,52 @@ class DeviceAggregatingState(AggregatingState):
         STATE_STATS.promoted_rows += 1
         return slot
 
-    def _promote_spilled(self, keys, namespace, namespaces) -> None:
-        """The spilled entries a batch is about to touch go back into
-        HBM together, before its slot loop: one `state.promote`
-        scatter per tile of `_promote_tile()` rows, whatever their
-        number."""
-        spilled = self._spilled
-        if namespaces is None:
-            entries = [(k, namespace) for k in keys]
-        else:
-            entries = list(zip(keys, namespaces))
-        entries = [e for e in entries if e in spilled]
-        if not entries:
+    def _promote_spilled(self, keys, namespace) -> None:
+        """The spilled entries a batch of one namespace is about to
+        touch go back into HBM together, before its slots are
+        resolved: one bulk probe of the host index's table for that
+        namespace, one `state.promote` scatter per tile of
+        `_promote_tile()` rows, whatever their number.  The caller
+        has made the room."""
+        ids = self._spilled.lookup(keys, namespace, len(keys))
+        hit = ids >= 0
+        if not hit.any():
             return
-        entries = list(dict.fromkeys(entries))  # a key twice: one row
-        m = len(entries)
+        # a key twice: one row; in order of first appearance
+        rows = dict(zip(itertools.compress(keys, hit.tolist()),
+                        ids[hit].tolist()))
+        m = len(rows)
         with get_tracer().phase("state.promote", rows=m):
-            while len(self._free) < m:
-                self._make_room()
             free = self._free
-            slots = [free.pop() for _ in range(m)]
-            ids = np.fromiter((spilled[e] for e in entries), np.int64, m)
+            assert len(free) >= m
+            slots = np.array(free[:-m - 1:-1], np.int64)
+            del free[-m:]
+            ids = np.fromiter(rows.values(), np.int64, m)
             tile = self._promote_tile()
             with self._device_lock:
                 t0 = _perf_ns()
                 for i in range(0, m, tile):
                     part = ids[i:i + tile]
-                    rows = self._host_rows(tile)
-                    self.host_tier.gather(part, rows)
-                    for arr in rows.values():
+                    host_rows = self._host_rows(tile)
+                    self.host_tier.gather(part, host_rows)
+                    for arr in host_rows.values():
                         arr[len(part):] = arr[0]  # pad: slot 0's row again
                     self.device_state = self._jit_promote(
                         self.device_state,
                         jnp.asarray(_pad_slots(slots[i:i + tile], tile)),
                         {name: jnp.asarray(arr)
-                         for name, arr in rows.items()})
+                         for name, arr in host_rows.items()})
                 if TELEMETRY.enabled:
                     TELEMETRY.record_transfer(
                         "h2d", m * self._bytes_per_slot(), t0, _perf_ns(),
                         "state.promote")
-                slot_index, meta = self.slot_index, self.slot_meta
-                flushed, stamp = self._slot_flushed, self._access_stamp
-                clock = self._clock
-                for entry, slot in zip(entries, slots):
-                    del spilled[entry]
-                    slot_index[entry] = slot
-                    meta[slot] = entry
-                    flushed[slot] = 1
-                    # promoted slots are HOT, as in _promote
-                    clock += 1
-                    stamp[slot] = clock
-                self._clock = clock
+                self._spilled.lookup(rows, namespace, m, take=True)
+                self.slot_index.table(namespace).update(
+                    zip(rows, slots.tolist()))
+                self._claim(slots, rows, namespace)
+                self._slot_flushed[slots] = True
+                # promoted slots are HOT, as in _promote
+                self._stamp(slots)
             self.host_tier.release(ids)
         self.promotions += m
         STATE_STATS.promoted_rows += m
@@ -419,11 +512,112 @@ class DeviceAggregatingState(AggregatingState):
         with self._device_lock:
             self.device_state = self.agg.grow_state(self.device_state,
                                                     new_capacity)
+        extra = new_capacity - self.capacity
         self._free.extend(range(new_capacity - 1, self.capacity - 1, -1))
-        self._access_stamp.extend([0] * (new_capacity - self.capacity))
-        self._slot_flushed.extend(bytes(new_capacity - self.capacity))
-        self.slot_meta.extend([None] * (new_capacity - self.capacity))
+        for name in ("slot_key", "slot_ns", "_slot_live", "_access_stamp",
+                     "_slot_flushed"):
+            column = getattr(self, name)
+            fill = None if column.dtype == object else 0
+            setattr(self, name, np.concatenate(
+                [column, np.full(extra, fill, column.dtype)]))
         self.capacity = new_capacity
+
+    def _resolve(self, keys: list, namespace) -> Tuple[np.ndarray, int]:
+        """The batch door into the slot index: the slots of one
+        namespace's `keys` as int64[n], new keys taking theirs in the
+        same pass, and how many were new.  Room first, then the
+        chunk's spilled entries come up, then ONE probe of the
+        namespace's table; nothing is evicted between the last two, so
+        no entry the probe misses has a row in the host tier."""
+        n = len(keys)
+        if n == 0:
+            return np.zeros(0, np.int64), 0
+        free = self._free
+        tables = self.slot_index.tables
+        if len(free) < n:
+            # fewer free slots than rows: count the keys that have no
+            # slot before room is made for them, so the capacity
+            # doubles (or the cold quarter leaves) when the per-key
+            # door would have done it, not for rows that need nothing
+            distinct = set(keys)
+            while len(free) < len(distinct.difference(
+                    tables.get(namespace, ()))):
+                self._make_room(ahead=n)
+        if self._spilled:
+            self._promote_spilled(keys, namespace)
+        # (taken after the room was made: an eviction that empties a
+        # namespace's table drops it)
+        table = self.slot_index.table(namespace)
+        if len(free) >= n:
+            # every row offers its key the slot `free.pop()` would
+            # hand out n-th: a candidate that comes back as its own
+            # key's slot was taken, the rest return to the free list
+            offered = free[:-n - 1:-1]
+            del free[-n:]
+            slots = np.fromiter(map(table.setdefault, keys, offered),
+                                np.int64, n)
+            offered = np.array(offered, np.int64)
+            took = slots == offered
+            fresh = offered[took]
+            if len(fresh) < n:
+                free.extend(offered[~took][::-1].tolist())
+            new_keys = itertools.compress(keys, took.tolist())
+        else:
+            # a table about to fill up has no candidate for every
+            # row: find the keys without a slot, give each one, probe
+            # again
+            slots = self.slot_index.lookup(keys, namespace, n)
+            new_keys = dict.fromkeys(
+                itertools.compress(keys, (slots < 0).tolist()))
+            m = len(new_keys)
+            fresh = np.array(free[:-m - 1:-1], np.int64)
+            if m:
+                del free[-m:]
+                table.update(zip(new_keys, fresh.tolist()))
+                slots = self.slot_index.lookup(keys, namespace, n)
+        if len(fresh):
+            self._claim(fresh, new_keys, namespace)
+        self._stamp(slots)
+        STATE_STATS.bulk_probe_rows += n
+        return slots, len(fresh)
+
+    def _resolve_column(self, keys: list, namespace,
+                        namespaces) -> Tuple[np.ndarray, int]:
+        """`_resolve` for a column of rows of ONE namespace, or
+        (`namespaces=`) each of its own: the rows of a namespace go
+        through the batch door together."""
+        slots = np.empty(len(keys), np.int64)
+        new = 0
+        for namespace, rows, part in cut_by_namespace(keys, namespace,
+                                                      namespaces):
+            slots[rows], made = self._resolve(part, namespace)
+            new += made
+        return slots, new
+
+    def _find(self, keys: list, namespace, namespaces, take: bool = False):
+        """Where a column of entries lives, one bulk probe per
+        namespace and tier: their device slots as int64[n] (-1: none),
+        and of those rows the host tier holds, positions and row ids.
+        `take` takes what it finds out of both indexes.  Allocates
+        nothing, promotes nothing."""
+        n = len(keys)
+        STATE_STATS.bulk_probe_rows += n
+        slots = np.empty(n, np.int64)
+        spill_rows = [np.zeros(0, np.int64)]
+        spill_ids = [np.zeros(0, np.int64)]
+        spilled = self._spilled
+        for namespace, rows, part in cut_by_namespace(keys, namespace,
+                                                      namespaces):
+            got = self.slot_index.lookup(part, namespace, len(part), take)
+            slots[rows] = got
+            if namespace in spilled.tables:
+                miss = got < 0
+                ids = spilled.lookup(
+                    itertools.compress(part, miss.tolist()), namespace,
+                    int(miss.sum()), take)
+                spill_rows.append(rows[miss][ids >= 0])
+                spill_ids.append(ids[ids >= 0])
+        return slots, np.concatenate(spill_rows), np.concatenate(spill_ids)
 
     # ---- write path -------------------------------------------------
     def add(self, value) -> None:
@@ -441,20 +635,21 @@ class DeviceAggregatingState(AggregatingState):
 
     def add_batch(self, keys: Iterable[Any], namespace, values,
                   namespaces=None, pre_extracted: bool = False) -> None:
-        """Vectorized write: one slot lookup loop, no per-record method
-        dispatch.  `namespace` is ONE namespace shared by the whole
-        batch (a window tuple is a single namespace); pass a parallel
-        sequence via `namespaces=` to override per record.  `values` is
-        a sequence/ndarray parallel to keys; `pre_extracted=True` means
-        the caller already ran extract_value/extract_column over it (a
-        numeric column straight off a RecordBatch)."""
-        keys = list(keys)
+        """Vectorized write: one bulk probe of the slot index per
+        namespace, no per-record method dispatch.  `namespace` is ONE
+        namespace shared by the whole batch (a window tuple is a
+        single namespace); pass a parallel sequence via `namespaces=`
+        to override per record.  `values` is a sequence/ndarray
+        parallel to keys; `pre_extracted=True` means the caller
+        already ran extract_value/extract_column over it (a numeric
+        column straight off a RecordBatch)."""
+        if not isinstance(keys, list):
+            keys = list(keys)
         if self.max_device_slots is not None \
                 and len(keys) > self.microbatch:
-            # capped backend: resolve slots in microbatch-sized chunks
-            # so an eviction triggered late in the loop can never take
-            # a slot resolved earlier in the SAME chunk (chunk size <=
-            # the eviction-protected stamp window)
+            # capped backend: resolve slots in microbatch-sized chunks,
+            # so the room one chunk can need is bounded (and a chunk's
+            # slots lie inside the eviction-protected stamp window)
             for i in range(0, len(keys), self.microbatch):
                 sl = slice(i, i + self.microbatch)
                 self.add_batch(
@@ -465,15 +660,9 @@ class DeviceAggregatingState(AggregatingState):
                     pre_extracted=pre_extracted)
             return
         tracer = get_tracer()
-        with tracer.phase("state.add.slots"):
-            if self._spilled:
-                self._promote_spilled(keys, namespace, namespaces)
-            slot_for = self._slot_for
-            if namespaces is None:
-                slots = [slot_for(k, namespace) for k in keys]
-            else:
-                slots = [slot_for(k, namespaces[i])
-                         for i, k in enumerate(keys)]
+        with tracer.phase("state.add.slots", rows=len(keys)) as phase:
+            slots, new = self._resolve_column(keys, namespace, namespaces)
+            phase.set_attr("new", new)
             self._pending_slots.extend(slots)
         with tracer.phase("state.add.hash"):
             extract = self.agg.extract_value
@@ -504,8 +693,9 @@ class DeviceAggregatingState(AggregatingState):
 
     def _flush_locked(self, n: int) -> None:
         padded = _round_up_pow2(n)
+        pending = self._pending_slots.take()
         slots = np.zeros(padded, np.int32)
-        slots[:n] = self._pending_slots
+        slots[:n] = pending
         mask = np.zeros(padded, bool)
         mask[:n] = True
         if self.agg.needs_value:
@@ -535,8 +725,7 @@ class DeviceAggregatingState(AggregatingState):
             self.device_state = self._jit_update(
                 self.device_state, slots, values, hi, lo, mask)
         STATE_STATS.note_flush(n)
-        for s_ in self._pending_slots:
-            self._slot_flushed[s_] = 1
+        self._slot_flushed[pending] = True
         self._pending_slots.clear()
         self._pending_values.clear()
         self._pending_hi.clear()
@@ -565,8 +754,9 @@ class DeviceAggregatingState(AggregatingState):
 
     def get_batch(self, keys, namespace, namespaces=None) -> Tuple[np.ndarray, np.ndarray]:
         """Gather results for many (key, namespace) pairs in ONE device
-        round-trip: one pending-ring flush, one fused jit gather per
-        tile of slots, one wait — the batched window-fire read.  Spill-tier
+        round-trip: one bulk probe of each tier's index per namespace,
+        one pending-ring flush, one fused jit gather per tile of
+        slots, one wait — the batched window-fire read.  Spill-tier
         rows are finalized from their host-resident accumulators
         WITHOUT promotion (a fire is a read; lifting cold rows into
         HBM per fired window would re-pay the per-row transfer tax
@@ -575,30 +765,16 @@ class DeviceAggregatingState(AggregatingState):
         (results, found_mask); namespace semantics as in `add_batch`."""
         tracer = get_tracer()
         with tracer.phase("state.get.lookup"):
-            keys = list(keys)
+            if not isinstance(keys, (list, tuple)):
+                keys = list(keys)
             n = len(keys)
-            slot_index = self.slot_index
-            spilled = self._spilled
-            slots = np.zeros(n, np.int32)
-            found = np.zeros(n, bool)
-            spill_idx: List[int] = []
-            spill_ids: List[int] = []
-            for i, k in enumerate(keys):
-                entry = (k, namespace if namespaces is None
-                         else namespaces[i])
-                s = slot_index.get(entry)
-                if s is not None:
-                    slots[i] = s
-                    found[i] = True
-                    # reads stamp the LRU clock exactly as scalar get()
-                    self._clock += 1
-                    self._access_stamp[s] = self._clock
-                    continue
-                rid = spilled.get(entry)
-                if rid is not None:
-                    spill_idx.append(i)
-                    spill_ids.append(rid)
-                    found[i] = True
+            slots, spill_idx, spill_ids = self._find(keys, namespace,
+                                                     namespaces)
+            found = slots >= 0
+            # reads stamp the LRU clock exactly as scalar get()
+            self._stamp(slots[found])
+            slots[~found] = 0  # a row not found reads slot 0, cut off
+            found[spill_idx] = True
         self._flush()  # ONE flush for the whole sweep
         if n == 0:  # nothing to gather, and no program for int32[0]
             none = jax.eval_shape(self.agg.result, self.device_state,
@@ -622,9 +798,8 @@ class DeviceAggregatingState(AggregatingState):
                                           _perf_ns(), "state.fire")
                 TELEMETRY.note_fire_read()
         STATE_STATS.note_result(n, padded)
-        if spill_idx:
-            res[spill_idx] = self._finalize_spilled(
-                np.array(spill_ids, np.int64))
+        if len(spill_idx):
+            res[spill_idx] = self._finalize_spilled(spill_ids)
         return res, found
 
     def _result_tile(self) -> int:
@@ -687,9 +862,8 @@ class DeviceAggregatingState(AggregatingState):
         owner-side structures mutate (no promotion, no access-stamp
         touch).  The device gather serializes against state swaps via
         the device lock."""
-        entry = (key, namespace)
         with self._device_lock:
-            slot = self.slot_index.get(entry)
+            slot = self.slot_index.get(key, namespace)
             if slot is not None and not self._slot_flushed[slot]:
                 # the key's first adds are still pending: invisible
                 # (matches the heap path's None-for-absent contract)
@@ -699,7 +873,7 @@ class DeviceAggregatingState(AggregatingState):
                     self.device_state,
                     jnp.asarray(np.array([slot], np.int32))))[0]
                 return out.item() if np.ndim(out) == 0 else out
-        row = self.host_tier.get(entry)
+        row = self.host_tier.get(key, namespace)
         if row is not None:
             # spilled entry: finalize its single row host-side (lift
             # to a 1-slot state; compiles once per aggregate)
@@ -712,39 +886,33 @@ class DeviceAggregatingState(AggregatingState):
 
     # ---- lifecycle --------------------------------------------------
     def clear(self) -> None:
-        entry = (self._backend.current_key, self._namespace)
-        self.host_tier.discard(entry)
-        slot = self.slot_index.pop(entry, None)
+        key = self._backend.current_key
+        STATE_STATS.per_key_probe_rows += 1
+        self.host_tier.discard(key, self._namespace)
+        slot = self.slot_index.pop(key, self._namespace)
         if slot is None:
             return
         self._flush()
         with self._device_lock:
             self.device_state = self._jit_clear(
                 self.device_state, jnp.asarray(np.array([slot], np.int32)))
-            self._slot_flushed[slot] = 0
-        self.slot_meta[slot] = None
+            self._slot_flushed[slot] = False
+        self._release(slot)
         self._free.append(slot)
 
     def clear_batch(self, keys, namespace, namespaces=None) -> None:
         tracer = get_tracer()
-        slots = []
-        spilled_ids = []
         with tracer.phase("state.clear.slots"):
-            spilled = self._spilled
-            for i, k in enumerate(keys):
-                ns = namespace if namespaces is None else namespaces[i]
-                s = self.slot_index.pop((k, ns), None)
-                if s is not None:
-                    slots.append(s)
-                    self.slot_meta[s] = None
-                elif spilled:
-                    rid = spilled.pop((k, ns), None)
-                    if rid is not None:
-                        spilled_ids.append(rid)
-        if spilled_ids:
+            if not isinstance(keys, (list, tuple)):
+                keys = list(keys)
+            slots, _, spilled_ids = self._find(keys, namespace, namespaces,
+                                               take=True)
+            slots = slots[slots >= 0]
+            self._release(slots)
+        if len(spilled_ids):
             with tracer.phase("state.clear.spill", rows=len(spilled_ids)):
                 self.host_tier.release(spilled_ids)
-        if not slots:
+        if not len(slots):
             return
         self._flush()
         with tracer.phase("state.clear.device"):
@@ -752,46 +920,56 @@ class DeviceAggregatingState(AggregatingState):
             with self._device_lock:
                 self.device_state = self._jit_clear(self.device_state,
                                                     jnp.asarray(arr))
-                for s_ in slots:
-                    self._slot_flushed[s_] = 0
-            self._free.extend(slots)
+                self._slot_flushed[slots] = False
+            self._free.extend(slots.tolist())
+
+    def _merge_plan(self, key, target, sources) -> Tuple[int, List[int]]:
+        """One session merge's slots: the target's (made if it has
+        none) and its live sources', which leave the index here; (-1,
+        []) if no source holds state.  The caller folds and clears
+        them on the device and frees them."""
+        spilled = self._spilled
+        # spilled sources participate in the merge: promote them first
+        for src in sources:
+            if spilled.get(key, src) is not None:
+                self._promote(key, src)
+        if spilled.get(key, target) is not None:
+            self._promote(key, target)
+        # touch every source slot BEFORE any allocation below: the
+        # target slot allocation may need to make room, and eviction
+        # must not take a slot this merge still references (fresh
+        # stamps fall inside _evict_cold's protected window; slots
+        # stay fully registered in the index until after the
+        # allocation, so eviction bookkeeping stays consistent)
+        live = []
+        for src in sources:
+            s = self.slot_index.get(key, src)
+            if s is not None:
+                self._clock += 1
+                self._access_stamp[s] = self._clock
+                live.append((src, s))
+        # don't materialize a target slot unless some source has state
+        # (matches heap: merging all-empty namespaces leaves no state)
+        if not live:
+            return -1, []  # nothing to fold in; target (if any) stays
+        dst = self._slot_for(key, target)
+        src_slots = []
+        for src, s in live:
+            self.slot_index.pop(key, src)
+            if s != dst:
+                src_slots.append(s)
+                # (not live, not yet free: an eviction a later merge
+                # of the batch triggers passes it by)
+                self._release(s)
+        return dst, src_slots
 
     def merge_namespaces(self, target, sources) -> None:
         """Session-window merge: device merge_slots(dst, src), then
         free source slots (ref: mergeNamespaces,
         WindowOperator.java:338 / MergingWindowSet.java:156)."""
-        key = self._backend.current_key
         self._flush()
-        # spilled sources participate in the merge: promote them first
-        for src in sources:
-            if (key, src) in self.host_tier:
-                self._promote((key, src))
-        if (key, target) in self.host_tier:
-            self._promote((key, target))
-        # touch every source slot BEFORE any allocation below: the
-        # target slot allocation may need to make room, and eviction
-        # must not take a slot this merge still references (fresh
-        # stamps fall inside _evict_cold's protected window; slots
-        # stay fully registered in slot_index/slot_meta until after
-        # the allocation, so eviction bookkeeping stays consistent)
-        live_sources = []
-        for src in sources:
-            s = self.slot_index.get((key, src))
-            if s is not None:
-                self._clock += 1
-                self._access_stamp[s] = self._clock
-                live_sources.append((src, s))
-        # don't materialize a target slot unless some source has state
-        # (matches heap: merging all-empty namespaces leaves no state)
-        if not live_sources:
-            return  # nothing to fold in; target (if any) stays as-is
-        dst = self._slot_for(key, target)
-        src_slots = []
-        for src, s in live_sources:
-            del self.slot_index[(key, src)]
-            if s != dst:
-                src_slots.append(s)
-                self.slot_meta[s] = None
+        dst, src_slots = self._merge_plan(self._backend.current_key,
+                                          target, sources)
         if not src_slots:
             return
         dsts = np.full(len(src_slots), dst, np.int32)
@@ -801,9 +979,8 @@ class DeviceAggregatingState(AggregatingState):
                 self.device_state, jnp.asarray(dsts), jnp.asarray(srcs))
             self.device_state = self._jit_clear(self.device_state,
                                                 jnp.asarray(srcs))
-            self._slot_flushed[dst] = 1
-            for s_ in src_slots:
-                self._slot_flushed[s_] = 0
+            self._slot_flushed[dst] = True
+            self._slot_flushed[srcs] = False
         self._free.extend(src_slots)
 
     def merge_namespaces_batch(self, merges) -> None:
@@ -819,27 +996,7 @@ class DeviceAggregatingState(AggregatingState):
         self._flush()
         plans = []  # (dst_slot, [src_slots])
         for key, target, sources in merges:
-            for src in sources:
-                if (key, src) in self.host_tier:
-                    self._promote((key, src))
-            if (key, target) in self.host_tier:
-                self._promote((key, target))
-            live = []
-            for src in sources:
-                s = self.slot_index.get((key, src))
-                if s is not None:
-                    self._clock += 1
-                    self._access_stamp[s] = self._clock
-                    live.append((src, s))
-            if not live:
-                continue
-            dst = self._slot_for(key, target)
-            srcs = []
-            for src, s in live:
-                del self.slot_index[(key, src)]
-                if s != dst:
-                    srcs.append(s)
-                    self.slot_meta[s] = None
+            dst, srcs = self._merge_plan(key, target, sources)
             if srcs:
                 plans.append((dst, srcs))
         if not plans:
@@ -857,108 +1014,39 @@ class DeviceAggregatingState(AggregatingState):
                 all_srcs.extend(srcs)
             self.device_state = self._jit_clear(
                 self.device_state, jnp.asarray(np.array(all_srcs, np.int32)))
-            for dst, _ in plans:
-                self._slot_flushed[dst] = 1
-            for s_ in all_srcs:
-                self._slot_flushed[s_] = 0
+            self._slot_flushed[[dst for dst, _ in plans]] = True
+            self._slot_flushed[all_srcs] = False
         self._free.extend(all_srcs)
 
     # ---- snapshot ---------------------------------------------------
-    def snapshot_entries(self) -> Dict[int, List[Tuple[Any, Any, Dict[str, np.ndarray]]]]:
-        """Per key group: [(key, namespace, {component: row})]."""
-        self._flush()
-        if TELEMETRY.enabled:
-            t0 = _perf_ns()
-            host = {name: np.asarray(arr)
-                    for name, arr in self.device_state.items()}
-            TELEMETRY.record_transfer(
-                "d2h", sum(a.nbytes for a in host.values()),
-                t0, _perf_ns(), "state.snapshot")
-        else:
-            host = {name: np.asarray(arr)
-                    for name, arr in self.device_state.items()}
-        per_kg: Dict[int, List[Tuple[Any, Any, Dict[str, np.ndarray]]]] = defaultdict(list)
-        mp = self._backend.max_parallelism
-        for (key, namespace), slot in self.slot_index.items():
-            kg = assign_to_key_group(key, mp)
-            row = {name: host[name][slot] for name in host}
-            per_kg[kg].append((key, namespace, row))
-        # spilled entries are part of the state too
-        entries, comps = self.host_tier.columns()
-        for i, (key, namespace) in enumerate(entries):
-            kg = assign_to_key_group(key, mp)
-            per_kg[kg].append((key, namespace,
-                               {name: arr[i] for name, arr in comps.items()}))
-        return per_kg
-
     def restore_entries(self, entries: List[Tuple[Any, Any, Dict[str, np.ndarray]]]) -> None:
         if not entries:
             return
-        needed = len(self.slot_index) + len(entries)
-        if self.max_device_slots is not None \
-                and needed > self.max_device_slots:
-            # beyond the device budget: the overflow restores straight
-            # into the host tier (promoted lazily on first access)
-            budget = max(self.max_device_slots - len(self.slot_index), 0)
-            over = entries[budget:]
-            self.host_tier.put(
-                [(key, namespace) for key, namespace, _ in over],
-                {name: np.stack([row[name] for _, _, row in over])
-                 for name in over[0][2]})
-            entries = entries[:budget]
-            if not entries:
-                return
-            needed = len(self.slot_index) + len(entries)
-        if needed > self.capacity - len(self._pending_slots):
-            self._grow(max(self.capacity * 2, _round_up_pow2(needed)))
-        slots = []
-        rows: Dict[str, List[np.ndarray]] = defaultdict(list)
-        for key, namespace, row in entries:
-            slot = self._slot_for(key, namespace)
-            slots.append(slot)
-            for name, val in row.items():
-                rows[name].append(val)
-        idx = jnp.asarray(np.array(slots, np.int32))
-        with self._device_lock:
-            new_state = dict(self.device_state)
-            for name, vals in rows.items():
-                new_state[name] = new_state[name].at[idx].set(
-                    jnp.asarray(np.stack(vals)))
-            self.device_state = new_state
-            for s_ in slots:
-                self._slot_flushed[s_] = 1
+        self.restore_columns(
+            [key for key, _, _ in entries],
+            [namespace for _, namespace, _ in entries],
+            {name: np.stack([row[name] for _, _, row in entries])
+             for name in entries[0][2]})
 
     def snapshot_columns(self) -> Dict[int, Tuple[list, list, Dict[str, np.ndarray]]]:
         """Columnar snapshot: per key group, (keys, namespaces,
         {component: stacked rows}) — ONE host transfer per component,
         ONE fancy-index gather, and the key-group split done in one
-        vectorized hash pass (replaces snapshot_entries' per-row dict
-        building + per-row assign_to_key_group)."""
+        vectorized hash pass."""
         self._flush()
-        keys: List[Any] = []
-        nss: List[Any] = []
-        slots: List[int] = []
-        for (key, namespace), slot in self.slot_index.items():
-            keys.append(key)
-            nss.append(namespace)
-            slots.append(slot)
+        keys, nss, slots = self.slot_index.columns()
+        t0 = _perf_ns()
+        host = {name: np.asarray(arr)
+                for name, arr in self.device_state.items()}
         if TELEMETRY.enabled:
-            t0 = _perf_ns()
-            host = {name: np.asarray(arr)
-                    for name, arr in self.device_state.items()}
             TELEMETRY.record_transfer(
                 "d2h", sum(a.nbytes for a in host.values()),
                 t0, _perf_ns(), "state.snapshot")
-        else:
-            host = {name: np.asarray(arr)
-                    for name, arr in self.device_state.items()}
-        idx = np.array(slots, np.int32)
-        comps = {name: arr[idx] for name, arr in host.items()}
+        comps = {name: arr[slots] for name, arr in host.items()}
         if self.host_tier:
-            spilled, spill_cols = self.host_tier.columns()
-            for key, namespace in spilled:
-                keys.append(key)
-                nss.append(namespace)
+            spilled_keys, spilled_nss, spill_cols = self.host_tier.columns()
+            keys += spilled_keys
+            nss += spilled_nss
             comps = {name: np.concatenate([comps[name], spill_cols[name]])
                      for name in host}
         out: Dict[int, Tuple[list, list, Dict[str, np.ndarray]]] = {}
@@ -970,19 +1058,20 @@ class DeviceAggregatingState(AggregatingState):
 
     def restore_columns(self, keys: list, namespaces: list,
                         comps: Dict[str, np.ndarray]) -> None:
-        """Columnar restore: one slot-resolve loop, ONE device upload
-        per component (no per-row dict boxing)."""
+        """Columnar restore: the rows' slots through the batch door,
+        ONE device upload per component (no per-row dict boxing)."""
         n = len(keys)
         if n == 0:
             return
-        needed = len(self.slot_index) + n
+        live = len(self.slot_index)
+        needed = live + n
         if self.max_device_slots is not None \
                 and needed > self.max_device_slots:
             # beyond the device budget: the overflow restores straight
             # into the host tier (promoted lazily on first access)
-            budget = max(self.max_device_slots - len(self.slot_index), 0)
+            budget = max(self.max_device_slots - live, 0)
             self.host_tier.put(
-                list(zip(keys[budget:], namespaces[budget:])),
+                keys[budget:], namespaces[budget:],
                 {name: np.array(arr[budget:])
                  for name, arr in comps.items()})
             keys = keys[:budget]
@@ -991,25 +1080,22 @@ class DeviceAggregatingState(AggregatingState):
             n = budget
             if n == 0:
                 return
-            needed = len(self.slot_index) + n
+            needed = live + n
         if needed > self.capacity - len(self._pending_slots):
             self._grow(max(self.capacity * 2, _round_up_pow2(needed)))
-        slots = np.empty(n, np.int32)
-        for i in range(n):
-            slots[i] = self._slot_for(keys[i], namespaces[i])
-        idx = jnp.asarray(slots)
+        slots, _ = self._resolve_column(list(keys), None, namespaces)
+        idx = jnp.asarray(slots.astype(np.int32))
         with self._device_lock:
             new_state = dict(self.device_state)
             for name, arr in comps.items():
                 new_state[name] = new_state[name].at[idx].set(
                     jnp.asarray(np.ascontiguousarray(arr)))
             self.device_state = new_state
-            for s_ in slots:
-                self._slot_flushed[int(s_)] = 1
+            self._slot_flushed[slots] = True
 
     def active_entries(self) -> Iterable[Tuple[Any, Any]]:
-        yield from self.slot_index.keys()
-        yield from self.host_tier.keys()
+        yield from self.slot_index
+        yield from self.host_tier
 
 
 class TpuKeyedStateBackend(KeyedStateBackend):
@@ -1212,19 +1298,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         for table in self._tables.values():
             table.clear_all()
         for dstate in self._device_states.values():
-            # reset device state in place (descriptor bindings survive);
-            # pending micro-batches are pre-failure writes — drop them,
-            # the restored checkpoint supersedes them
-            dstate.device_state = dstate.agg.init_state(dstate.capacity)
-            dstate.slot_index.clear()
-            dstate.slot_meta = [None] * dstate.capacity
-            dstate._free = list(range(dstate.capacity - 1, -1, -1))
-            dstate._slot_flushed = bytearray(dstate.capacity)
-            dstate.host_tier.clear()
-            dstate._pending_slots.clear()
-            dstate._pending_values.clear()
-            dstate._pending_hi.clear()
-            dstate._pending_lo.clear()
+            dstate.reset()
         pending_device: Dict[str, list] = defaultdict(list)
         pending_cols: Dict[str, list] = {}
         for snap in snapshots:

@@ -1,18 +1,25 @@
-"""The `tpu` backend's spill tier as it was before the bulk tier: one
-dict of numpy rows per spilled key, one `state.upload` dispatch per
-promotion, one Python walk of every slot per eviction.  Kept as the
-reference `tests/test_spill_tier.py` holds the bulk paths to, bit for
-bit: the method bodies are the old ones, word for word.
+"""The `tpu` backend's device state as it was before its bulk paths,
+kept as the reference the tests hold them to, bit for bit; the method
+bodies are the old ones, word for word.
+
+The spill tier before the bulk tier (PR 31): one dict of numpy rows
+per spilled key, one `state.upload` dispatch per promotion, one Python
+walk of every slot per eviction (`tests/test_spill_tier.py`).  The
+slot index before it was keyed by namespace (PR 32): one flat
+``(key, namespace) → slot`` dict, `slot_meta` a list of those tuples,
+the stamps a list, and one `_slot_for` call, one tuple and one probe
+per event, per fired key and per cleared key
+(`tests/test_slot_index_bulk.py`).
 """
 
 from collections import defaultdict
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flink_tpu.core.keygroups import assign_to_key_group
+from flink_tpu.core.keygroups import assign_to_key_group, stable_hash64
 from flink_tpu.ops.device_agg import DeviceAggregateFunction
 from flink_tpu.runtime.device_stats import TELEMETRY
 from flink_tpu.runtime.tracing import get_tracer
@@ -32,12 +39,310 @@ class PerKeySpillState(DeviceAggregatingState):
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
+        #: (key, namespace) → slot
+        self.slot_index: Dict[Tuple[Any, Any], int] = {}
+        #: slot → (key, namespace) (None = free)
+        self.slot_meta: List[Optional[Tuple[Any, Any]]] = \
+            [None] * self.capacity
+        del self.slot_key, self.slot_ns, self._slot_live
+        self._access_stamp: List[int] = [0] * self.capacity
+        self._slot_flushed = bytearray(self.capacity)
+        self._pending_slots: List[int] = []
         #: (key, namespace) → {component: numpy row}
         self.host_tier: Dict[Tuple[Any, Any], Dict[str, np.ndarray]] = {}
         self._spilled = self.host_tier
 
+    def reset(self) -> None:
+        dstate = self
+        dstate.device_state = dstate.agg.init_state(dstate.capacity)
+        dstate.slot_index.clear()
+        dstate.slot_meta = [None] * dstate.capacity
+        dstate._free = list(range(dstate.capacity - 1, -1, -1))
+        dstate._slot_flushed = bytearray(dstate.capacity)
+        dstate.host_tier.clear()
+        dstate._pending_slots.clear()
+        dstate._pending_values.clear()
+        dstate._pending_hi.clear()
+        dstate._pending_lo.clear()
+
+    def _slot_for(self, key, namespace, create: bool = True) -> Optional[int]:
+        entry = (key, namespace)
+        slot = self.slot_index.get(entry)
+        if slot is None and entry in self._spilled:
+            slot = self._promote(entry)
+        if slot is None and create:
+            if not self._free:
+                self._make_room()
+            slot = self._free.pop()
+            self.slot_index[entry] = slot
+            self.slot_meta[slot] = entry
+        if slot is not None:
+            self._clock += 1
+            self._access_stamp[slot] = self._clock
+        return slot
+
+    def _grow(self, new_capacity: int) -> None:
+        self._flush()
+        with self._device_lock:
+            self.device_state = self.agg.grow_state(self.device_state,
+                                                    new_capacity)
+        self._free.extend(range(new_capacity - 1, self.capacity - 1, -1))
+        self._access_stamp.extend([0] * (new_capacity - self.capacity))
+        self._slot_flushed.extend(bytes(new_capacity - self.capacity))
+        self.slot_meta.extend([None] * (new_capacity - self.capacity))
+        self.capacity = new_capacity
+
+    def add_batch(self, keys: Iterable[Any], namespace, values,
+                  namespaces=None, pre_extracted: bool = False) -> None:
+        """Vectorized write: one slot lookup loop, no per-record method
+        dispatch.  `namespace` is ONE namespace shared by the whole
+        batch (a window tuple is a single namespace); pass a parallel
+        sequence via `namespaces=` to override per record.  `values` is
+        a sequence/ndarray parallel to keys; `pre_extracted=True` means
+        the caller already ran extract_value/extract_column over it (a
+        numeric column straight off a RecordBatch)."""
+        keys = list(keys)
+        if self.max_device_slots is not None \
+                and len(keys) > self.microbatch:
+            # capped backend: resolve slots in microbatch-sized chunks
+            # so an eviction triggered late in the loop can never take
+            # a slot resolved earlier in the SAME chunk (chunk size <=
+            # the eviction-protected stamp window)
+            for i in range(0, len(keys), self.microbatch):
+                sl = slice(i, i + self.microbatch)
+                self.add_batch(
+                    keys[sl], namespace,
+                    values[sl] if values is not None else None,
+                    namespaces=None if namespaces is None
+                    else namespaces[sl],
+                    pre_extracted=pre_extracted)
+            return
+        tracer = get_tracer()
+        with tracer.phase("state.add.slots"):
+            if self._spilled:
+                self._promote_spilled(keys, namespace, namespaces)
+            slot_for = self._slot_for
+            if namespaces is None:
+                slots = [slot_for(k, namespace) for k in keys]
+            else:
+                slots = [slot_for(k, namespaces[i])
+                         for i, k in enumerate(keys)]
+            self._pending_slots.extend(slots)
+        with tracer.phase("state.add.hash"):
+            extract = self.agg.extract_value
+            # overridden on the class or per-instance (an
+            # instance-attached plain function has no __func__)
+            if not pre_extracted and getattr(
+                    extract, "__func__",
+                    None) is not DeviceAggregateFunction.extract_value:
+                values = [extract(v) for v in values]
+            if self.agg.needs_value:
+                self._pending_values.extend(values)
+            if self.agg.needs_value_hash:
+                hi = self._pending_hi
+                lo = self._pending_lo
+                for v in values:
+                    h = stable_hash64(v)
+                    hi.append(h >> 32)
+                    lo.append(h & 0xFFFFFFFF)
+        if len(self._pending_slots) >= self.microbatch:
+            self._flush()
+
+    def _flush_locked(self, n: int) -> None:
+        padded = _round_up_pow2(n)
+        slots = np.zeros(padded, np.int32)
+        slots[:n] = self._pending_slots
+        mask = np.zeros(padded, bool)
+        mask[:n] = True
+        if self.agg.needs_value:
+            values = np.zeros(padded, self.agg.value_dtype)
+            values[:n] = np.asarray(self._pending_values, self.agg.value_dtype)
+        else:
+            values = np.zeros(padded, self.agg.value_dtype)
+        if self.agg.needs_value_hash:
+            hi = np.zeros(padded, np.uint32)
+            lo = np.zeros(padded, np.uint32)
+            hi[:n] = np.asarray(self._pending_hi, np.uint64).astype(np.uint32)
+            lo[:n] = np.asarray(self._pending_lo, np.uint64).astype(np.uint32)
+        else:
+            hi = np.zeros(padded, np.uint32)
+            lo = np.zeros(padded, np.uint32)
+        if TELEMETRY.enabled:
+            t0 = _perf_ns()
+            self.device_state = self._jit_update(
+                self.device_state, slots, values, hi, lo, mask)
+            TELEMETRY.record_transfer(
+                "h2d",
+                slots.nbytes + mask.nbytes + values.nbytes
+                + hi.nbytes + lo.nbytes,
+                t0, _perf_ns(), "state.flush")
+            TELEMETRY.note_flush(n)
+        else:
+            self.device_state = self._jit_update(
+                self.device_state, slots, values, hi, lo, mask)
+        STATE_STATS.note_flush(n)
+        for s_ in self._pending_slots:
+            self._slot_flushed[s_] = 1
+        self._pending_slots.clear()
+        self._pending_values.clear()
+        self._pending_hi.clear()
+        self._pending_lo.clear()
+
+    def query_by_key(self, key, namespace):
+        """Queryable-state read from a FOREIGN thread (ref:
+        AbstractKeyedStateBackend.java:382-389 getPartitionedState for
+        queries + KvStateServerHandler).  Dirty-read semantics match
+        the heap path: pending (unflushed) adds are invisible; no
+        owner-side structures mutate (no promotion, no access-stamp
+        touch).  The device gather serializes against state swaps via
+        the device lock."""
+        entry = (key, namespace)
+        with self._device_lock:
+            slot = self.slot_index.get(entry)
+            if slot is not None and not self._slot_flushed[slot]:
+                # the key's first adds are still pending: invisible
+                # (matches the heap path's None-for-absent contract)
+                slot = None
+            if slot is not None:
+                out = np.asarray(self._jit_result(
+                    self.device_state,
+                    jnp.asarray(np.array([slot], np.int32))))[0]
+                return out.item() if np.ndim(out) == 0 else out
+        row = self.host_tier.get(entry)
+        if row is not None:
+            # spilled entry: finalize its single row host-side (lift
+            # to a 1-slot state; compiles once per aggregate)
+            state1 = {name: jnp.asarray(val)[None]
+                      for name, val in row.items()}
+            out = np.asarray(self._jit_result(
+                state1, jnp.asarray(np.zeros(1, np.int32))))[0]
+            return out.item() if np.ndim(out) == 0 else out
+        return None
+
+    def merge_namespaces(self, target, sources) -> None:
+        """Session-window merge: device merge_slots(dst, src), then
+        free source slots (ref: mergeNamespaces,
+        WindowOperator.java:338 / MergingWindowSet.java:156)."""
+        key = self._backend.current_key
+        self._flush()
+        # spilled sources participate in the merge: promote them first
+        for src in sources:
+            if (key, src) in self.host_tier:
+                self._promote((key, src))
+        if (key, target) in self.host_tier:
+            self._promote((key, target))
+        # touch every source slot BEFORE any allocation below: the
+        # target slot allocation may need to make room, and eviction
+        # must not take a slot this merge still references (fresh
+        # stamps fall inside _evict_cold's protected window; slots
+        # stay fully registered in slot_index/slot_meta until after
+        # the allocation, so eviction bookkeeping stays consistent)
+        live_sources = []
+        for src in sources:
+            s = self.slot_index.get((key, src))
+            if s is not None:
+                self._clock += 1
+                self._access_stamp[s] = self._clock
+                live_sources.append((src, s))
+        # don't materialize a target slot unless some source has state
+        # (matches heap: merging all-empty namespaces leaves no state)
+        if not live_sources:
+            return  # nothing to fold in; target (if any) stays as-is
+        dst = self._slot_for(key, target)
+        src_slots = []
+        for src, s in live_sources:
+            del self.slot_index[(key, src)]
+            if s != dst:
+                src_slots.append(s)
+                self.slot_meta[s] = None
+        if not src_slots:
+            return
+        dsts = np.full(len(src_slots), dst, np.int32)
+        srcs = np.array(src_slots, np.int32)
+        with self._device_lock:
+            self.device_state = self._jit_merge(
+                self.device_state, jnp.asarray(dsts), jnp.asarray(srcs))
+            self.device_state = self._jit_clear(self.device_state,
+                                                jnp.asarray(srcs))
+            self._slot_flushed[dst] = 1
+            for s_ in src_slots:
+                self._slot_flushed[s_] = 0
+        self._free.extend(src_slots)
+
+    def merge_namespaces_batch(self, merges) -> None:
+        """Batched session merge: `merges` is a list of
+        (key, target_namespace, [source_namespaces]).  One flush up
+        front, then the whole merge set runs in ROUNDS through the
+        jit(vmap(agg.merge)) pairwise kernel — round r folds each
+        target's r-th live source, so every dispatch has UNIQUE
+        destination slots (distinct merges own distinct (key, target)
+        slots) — and one clear frees every source slot at the end.
+        Observable state after this call is identical to running
+        merge_namespaces per (key, target)."""
+        self._flush()
+        plans = []  # (dst_slot, [src_slots])
+        for key, target, sources in merges:
+            for src in sources:
+                if (key, src) in self.host_tier:
+                    self._promote((key, src))
+            if (key, target) in self.host_tier:
+                self._promote((key, target))
+            live = []
+            for src in sources:
+                s = self.slot_index.get((key, src))
+                if s is not None:
+                    self._clock += 1
+                    self._access_stamp[s] = self._clock
+                    live.append((src, s))
+            if not live:
+                continue
+            dst = self._slot_for(key, target)
+            srcs = []
+            for src, s in live:
+                del self.slot_index[(key, src)]
+                if s != dst:
+                    srcs.append(s)
+                    self.slot_meta[s] = None
+            if srcs:
+                plans.append((dst, srcs))
+        if not plans:
+            return
+        rounds = max(len(srcs) for _, srcs in plans)
+        all_srcs: List[int] = []
+        with self._device_lock:
+            for r in range(rounds):
+                dsts = [dst for dst, srcs in plans if len(srcs) > r]
+                srcs = [srcs[r] for _, srcs in plans if len(srcs) > r]
+                self.device_state = self._jit_merge_rows(
+                    self.device_state,
+                    jnp.asarray(np.array(dsts, np.int32)),
+                    jnp.asarray(np.array(srcs, np.int32)))
+                all_srcs.extend(srcs)
+            self.device_state = self._jit_clear(
+                self.device_state, jnp.asarray(np.array(all_srcs, np.int32)))
+            for dst, _ in plans:
+                self._slot_flushed[dst] = 1
+            for s_ in all_srcs:
+                self._slot_flushed[s_] = 0
+        self._free.extend(all_srcs)
+
+    def active_entries(self) -> Iterable[Tuple[Any, Any]]:
+        yield from self.slot_index.keys()
+        yield from self.host_tier.keys()
+
     def _promote_spilled(self, keys, namespace, namespaces) -> None:
         """No batch pre-pass: `_slot_for` promotes key by key."""
+
+    def _make_room(self) -> None:
+        """No free slots: grow HBM state, or — at the device budget —
+        spill the coldest quarter of slots to the host tier (the
+        RocksDB-disk-residency role; SURVEY §7 'state larger than
+        HBM')."""
+        if (self.max_device_slots is None
+                or self.capacity * 2 <= self.max_device_slots):
+            self._grow(self.capacity * 2)
+            return
+        self._evict_cold(max(1, self.capacity // 4))
 
     def _evict_cold(self, n: int) -> None:
         self._flush()

@@ -164,8 +164,8 @@ def test_query_device_state_spilled_to_host_tier():
     assert v == float(spilled[1:])      # value == key index
     assert st.promotions == promotions_before  # read did not promote
     # a device-resident key answers too
-    resident = st.slot_meta[[s for s in range(st.capacity)
-                             if st.slot_meta[s] is not None][0]][0]
+    resident = st.slot_key[np.flatnonzero(st._slot_live)[0]]
+    assert st.slot_index.get(resident, ()) is not None
     assert client.get_kv_state("spill_sum", resident,
                                namespace=()) == float(resident[1:])
 

@@ -327,7 +327,7 @@ def test_eviction_gathers_at_one_shape(monkeypatch):
 # ---------------------------------------------------------------------
 
 def _block(lo, hi):
-    return ([(k, "w") for k in range(lo, hi)],
+    return (list(range(lo, hi)), ["w"] * (hi - lo),
             {"a": np.arange(lo, hi, dtype=np.float32),
              "b": (np.arange(lo, hi) % 256).astype(np.uint8).reshape(-1, 1)
              * np.ones((1, 4), np.uint8)})
@@ -338,29 +338,36 @@ def test_host_tier_files_slices_releases_and_compacts():
     tier.put(*_block(0, 3000))
     tier.put(*_block(3000, 5000))
     assert len(tier) == 5000 and (4999, "w") in tier
-    assert tier.get((4000, "w"))["a"] == 4000.0
-    assert tier.get((9, "x")) is None
-    ids = np.array([tier.index[(k, "w")] for k in (4500, 7, 3000, 2999)])
+    assert tier.get(4000, "w")["a"] == 4000.0
+    assert tier.get(9, "x") is None and tier.get(9000, "w") is None
+    ids = np.array([tier.index.get(k, "w") for k in (4500, 7, 3000, 2999)])
     out = {"a": np.empty(4, np.float32), "b": np.empty((4, 4), np.uint8)}
     tier.gather(ids, out)
     assert out["a"].tolist() == [4500.0, 7.0, 3000.0, 2999.0]
     assert out["b"][:, 0].tolist() == [4500 % 256, 7, 3000 % 256,
                                        2999 % 256]
     # a block whose rows are all released is dropped whole
-    tier.release([tier.index.pop((k, "w")) for k in range(3000, 5000)])
+    tier.release([tier.index.pop(k, "w") for k in range(3000, 5000)])
     assert len(tier._blocks) == 1 and tier._rows == 3000
     # released rows outnumber live ones: the live rows move together
-    tier.release([tier.index.pop((k, "w")) for k in range(0, 2000)])
+    tier.release(tier.index.lookup(range(0, 2000), "w", 2000, take=True))
     assert tier._rows == len(tier) == 1000
-    assert tier.get((2500, "w"))["a"] == 2500.0
-    entries, comps = tier.columns()
-    assert entries == [(k, "w") for k in range(2000, 3000)]
+    assert tier.get(2500, "w")["a"] == 2500.0
+    keys, namespaces, comps = tier.columns()
+    assert keys == list(range(2000, 3000)) and namespaces == ["w"] * 1000
     assert comps["a"].tolist() == list(map(float, range(2000, 3000)))
-    tier.discard((2000, "w"))
-    tier.discard((2000, "w"))
+    # rows of a second namespace share the blocks and nothing else
+    tier.put([2000, 7], ["w", "x"], {"a": np.array([-1.0, -2.0], np.float32),
+                                     "b": np.zeros((2, 4), np.uint8)})
+    assert tier.get(2000, "w")["a"] == -1.0 and tier.get(7, "x")["a"] == -2.0
+    assert len(tier) == 1001 and sorted(tier.index.tables) == ["w", "x"]
+    tier.discard(7, "x")
+    assert list(tier.index.tables) == ["w"]  # no empty table is kept
+    tier.discard(2000, "w")
+    tier.discard(2000, "w")
     assert len(tier) == 999 and (2000, "w") not in tier
     tier.clear()
-    assert not tier and tier.columns() == ([], {})
+    assert not tier and tier.columns() == ([], [], {})
 
 
 # ---------------------------------------------------------------------
