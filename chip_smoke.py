@@ -22,6 +22,11 @@ Legs:
                         2^21 events a window, ~1.89M live keys, so the
                         coldest rows go to host RAM in bulk, come back
                         on access and fire from there
+  2c sliding quantiles  config #3 on the same route: sliding 10 s / 1 s
+                        p50/p99 (QuantileSketchAggregate) over 10,000,000
+                        Zipf keys, 14 slides of 16,384 events under a
+                        budget of 2^19 slots: ten state rows an event,
+                        every window against exact order statistics
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -61,7 +66,10 @@ import flink_tpu.native as nat  # noqa: E402
 from flink_tpu.core.config import Configuration  # noqa: E402
 from flink_tpu.ops import link_probe  # noqa: E402
 from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
-from flink_tpu.ops.sketches import HyperLogLogAggregate  # noqa: E402
+from flink_tpu.ops.sketches import (  # noqa: E402
+    HyperLogLogAggregate,
+    QuantileSketchAggregate,
+)
 from flink_tpu.runtime import tracing  # noqa: E402
 from flink_tpu.runtime.device_stats import get_telemetry  # noqa: E402
 from flink_tpu.streaming import chain_fusion  # noqa: E402
@@ -78,7 +86,10 @@ from flink_tpu.streaming.device_window_operator import (  # noqa: E402
 from flink_tpu.streaming.elements import RecordBatch  # noqa: E402
 from flink_tpu.streaming.sources import SinkFunction  # noqa: E402
 from flink_tpu.streaming.window_operator import WindowOperator  # noqa: E402
-from flink_tpu.streaming.windowing import TumblingEventTimeWindows  # noqa: E402
+from flink_tpu.streaming.windowing import (  # noqa: E402
+    SlidingEventTimeWindows,
+    TumblingEventTimeWindows,
+)
 from flink_tpu.table import StreamTableEnvironment  # noqa: E402
 
 WINDOW_MS = 1000
@@ -91,7 +102,9 @@ FULL = dict(keys=1_000_000, events_per_window=1 << 22, windows=3,
             precision=12, side_events=1 << 20, fused_events=1 << 18,
             fused_keys=4096,
             spill=dict(keys=10_000_000, events_per_window=1 << 21,
-                       budget=1 << 20, microbatch=None))
+                       budget=1 << 20, microbatch=None),
+            sliding=dict(keys=10_000_000, events_per_slide=1 << 14,
+                         slides=14, budget=1 << 19))
 TINY = dict(keys=256, events_per_window=4096, windows=3,
             precision=12, side_events=4096, fused_events=8192,
             fused_keys=64,
@@ -99,7 +112,9 @@ TINY = dict(keys=256, events_per_window=4096, windows=3,
             # (2 x microbatch + 16 touches): the default's would cover
             # every slot of a tiny budget, and nothing could be evicted
             spill=dict(keys=6000, events_per_window=4096, budget=1024,
-                       microbatch=64))
+                       microbatch=64),
+            sliding=dict(keys=500, events_per_slide=512, slides=14,
+                         budget=1 << 14))
 
 
 # ---------------------------------------------------------------------
@@ -164,6 +179,16 @@ class ArraySink(SinkFunction):
 
 class UserHll(HyperLogLogAggregate):
     """COUNT DISTINCT over field 1 (the user) of a (key, user) row."""
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1]
+
+
+class ValueQuantiles(QuantileSketchAggregate):
+    """p50 / p99 over field 1 (the value) of a (key, value) row."""
 
     def extract_value(self, value):
         return value[1]
@@ -481,6 +506,105 @@ def leg_state_spill(cfg, seed):
         **facts}
 
 
+#: config #3's windows; the slide is the source period too
+SLIDING_SIZE_MS, SLIDING_SLIDE_MS = 10_000, 1_000
+
+
+def emit_quantiles(key, window, vals):
+    p50, p99 = vals[0]
+    return [(key, window.end - SLIDING_SLIDE_MS, float(p50), float(p99))]
+
+
+def quantile_reference():
+    """``tests/quantile_sliding_reference.py``, the plain reference of
+    the sliding quantile job, from the file beside this one."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "tests", "quantile_sliding_reference.py")
+    spec = importlib.util.spec_from_file_location(
+        "quantile_sliding_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def leg_state_sliding(cfg, seed):
+    """Leg 2's route under a sliding assigner and a value-carrying
+    aggregate: every event lands in ten (key, window) sketches, a
+    window fires every slide, and the budget holds all ten windows, so
+    the spill tier must stay idle."""
+    sl = cfg["sliding"]
+    rng = np.random.default_rng(seed + 2)
+    n = sl["events_per_slide"] * sl["slides"]
+    weights = np.arange(1, sl["keys"] + 1, dtype=np.float64) ** -0.99
+    cumulative = np.cumsum(weights)
+    ranks = np.searchsorted(cumulative, rng.random(n) * cumulative[-1],
+                            side="right")
+    keys = rng.permutation(sl["keys"])[np.minimum(ranks, sl["keys"] - 1)]
+    keys = keys.astype(np.int64)
+    values = np.exp(rng.normal(3.0, 1.0, n))
+    ts = (np.arange(n, dtype=np.int64) * SLIDING_SLIDE_MS) \
+        // sl["events_per_slide"]
+    env = StreamExecutionEnvironment(Configuration().set(
+        "state.backend.tpu.max-device-slots", sl["budget"]))
+    env.set_state_backend("tpu")
+    windowed = (env.add_source(EventSource(keys, values, ts), name="events")
+                .key_by(0)
+                .window(SlidingEventTimeWindows.of(SLIDING_SIZE_MS,
+                                                   SLIDING_SLIDE_MS)))
+    windowed.disable_device_operator()
+    sink = ArraySink()
+    agg = ValueQuantiles()
+    windowed.aggregate(agg, window_function=emit_quantiles).add_sink(sink)
+    ops = capture_operators(env)
+    env.execute("chip-smoke-state-sliding")
+    wop = one_of(ops, WindowOperator)
+    state = wop.window_state
+    got_keys, got_starts, p50, p99 = sink.columns()
+    order = np.argsort(got_starts, kind="stable")
+    cuts = np.flatnonzero(np.diff(got_starts[order])) + 1
+    results = {int(got_starts[part[0]]): (got_keys[part], got_starts[part],
+                                          p50[part], p99[part])
+               for part in np.split(order, cuts)}
+    per = sl["events_per_slide"]
+    emitted = [(p, None, lambda p=p: (keys[p * per:(p + 1) * per],
+                                      values[p * per:(p + 1) * per]))
+               for p in range(sl["slides"])]
+    verdict = quantile_reference().check(
+        {"slide_ms": SLIDING_SLIDE_MS, "window_size_ms": SLIDING_SIZE_MS,
+         "quantiles": list(agg.quantiles), "relative_accuracy": 0.01},
+        emitted, results)
+    problems = list(verdict["problems"])
+    if verdict["failed"]:
+        problems.append(f"{verdict['failed']} of {verdict['attempted']} "
+                        f"quantiles failed")
+    problems += boxed_problems(wop, n)
+    problems += fire_tail_problems(wop, len(got_keys))
+    if state.evictions or state.budget_overruns \
+            or state.capacity > sl["budget"]:
+        problems.append(f"{state.evictions} evictions, "
+                        f"{state.budget_overruns} overruns, capacity "
+                        f"{state.capacity} of a budget of {sl['budget']}: "
+                        f"ten windows were to fit the device")
+    panes = SLIDING_SIZE_MS // SLIDING_SLIDE_MS
+    if wop.window_rows != panes * n:
+        problems.append(f"{wop.window_rows} state rows for {n} events, "
+                        f"{panes} an event expected")
+    hist = state.device_state["hist"]
+    return problems, {
+        "keys": sl["keys"], "events": n, "budget": sl["budget"],
+        "slots": state.capacity,
+        "table_bytes": int(hist.size) * hist.dtype.itemsize,
+        "rows_per_event": wop.window_rows / n,
+        "windows_touched": wop.windows_touched,
+        "evictions": state.evictions,
+        "budget_overruns": state.budget_overruns,
+        "result_rows": len(got_keys), "windows_fired": len(results),
+        "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
+        **verdict["facts"]}
+
+
 def leg_sql(cfg, events, ref, mesh=None):
     keys, users, ts = events
     env = StreamExecutionEnvironment()
@@ -794,6 +918,7 @@ def main(argv=None) -> int:
     ref = exact_distinct(*events)
     run("2 state backend", leg_state_backend, cfg, events, ref)
     run("2b spill tier", leg_state_spill, cfg, args.seed)
+    run("2c sliding quantiles", leg_state_sliding, cfg, args.seed)
     sql = run("3a sql", leg_sql, cfg, events, ref)
     run("3b datastream", leg_datastream_default, cfg, events, ref)
     run("4a entry step", leg_entry_step, cfg)

@@ -472,6 +472,11 @@ class WindowOperator(AbstractUdfStreamOperator):
         #: runs they came in: a tumbling window's fire is one run
         self.timers_swept = 0
         self.timer_runs = 0
+        #: (batch, window) groups the vectorized ingest cut its batches
+        #: into / the rows those groups handed to the state backend: a
+        #: sliding assigner of size 10 x slide reads 10 rows per event
+        self.windows_touched = 0
+        self.window_rows = 0
 
     # ---- lifecycle --------------------------------------------------
     def open(self):
@@ -491,6 +496,7 @@ class WindowOperator(AbstractUdfStreamOperator):
             # accumulate into the previous attempt's count)
             self.metrics.counter("numLateRecordsDropped").count = 0
             self._emit_batch_hist = self.metrics.histogram("emitBatchSize")
+            self.metrics.gauge("windowRowsPerRow", self._window_rows_per_row)
         self.window_state = self.keyed_backend.get_or_create_keyed_state(
             self.state_descriptor)
         self.trigger_ctx = _WindowTriggerContext(self)
@@ -540,6 +546,13 @@ class WindowOperator(AbstractUdfStreamOperator):
                 self.num_late_records_dropped += 1
                 if self.metrics is not None:
                     self.metrics.counter("numLateRecordsDropped").inc()
+
+    def _window_rows_per_row(self):
+        """State rows written per columnar row taken in: the fan-out
+        of the window assigner (1 tumbling, size / slide sliding)."""
+        if not self.columnar_rows:
+            return None  # never saw a batch: undefined
+        return self.window_rows / self.columnar_rows
 
     # ---- batch path -------------------------------------------------
     def _batch_eligibility(self) -> Optional[str]:
@@ -672,8 +685,9 @@ class WindowOperator(AbstractUdfStreamOperator):
                 if vi.size:
                     idx_parts.append(vi)
                     start_parts.append(starts[vi])
-            #: (window start, row indexes, their keys) per touched window
-            groups = []
+        #: (window start, row indexes, their keys) per touched window
+        groups = []
+        with tracer.phase("window.ingest.group") as phase:
             if idx_parts:
                 all_idx = np.concatenate(idx_parts)
                 all_starts = np.concatenate(start_parts)
@@ -692,6 +706,9 @@ class WindowOperator(AbstractUdfStreamOperator):
                     groups.append((int(sstarts[lo]), gidx,
                                    [keys[i] for i in gidx]))
                     lo = hi
+                self.window_rows += len(sidx)
+            phase.set_attr("windows", len(groups))
+            self.windows_touched += len(groups)
         for start, gidx, gkeys in groups:
             ns = (start, start + size)
             if vcol is not None:

@@ -650,7 +650,7 @@ def _sql_tumble_job(rows):
 
 STATE_ROUTE_PHASES = {
     "window.ingest": 8, "window.ingest.box": 8, "window.ingest.assign": 8,
-    "state.add.slots": 8, "state.add.hash": 8, "timers.register": 8,
+    "window.ingest.group": 8, "state.add.slots": 8, "state.add.hash": 8, "timers.register": 8,
     "window.watermark": 1, "timers.sweep": 1, "state.get.lookup": 1,
     "state.flush": 1, "state.get.device": 1, "window.fire.batch": 1,
     "window.fire.columnarize": 1, "window.fire.downstream": 1,
