@@ -26,7 +26,9 @@ Legs:
                         p50/p99 (QuantileSketchAggregate) over 10,000,000
                         Zipf keys, 14 slides of 16,384 events under a
                         budget of 2^19 slots: ten state rows an event,
-                        every window against exact order statistics
+                        every window against exact order statistics,
+                        every flush against the full-grown table by the
+                        tile kernel (on the TPU; no cell scattered)
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -72,6 +74,7 @@ from flink_tpu.ops.sketches import (  # noqa: E402
 )
 from flink_tpu.runtime import tracing  # noqa: E402
 from flink_tpu.runtime.device_stats import get_telemetry  # noqa: E402
+from flink_tpu.state.stats import STATE_STATS  # noqa: E402
 from flink_tpu.streaming import chain_fusion  # noqa: E402
 from flink_tpu.streaming.columnar import (  # noqa: E402
     ColumnarSource,
@@ -175,6 +178,24 @@ class ArraySink(SinkFunction):
             return ()
         return tuple(np.concatenate([c[i] for c in chunks])
                      for i in range(len(chunks[0])))
+
+
+class FlushMarkingSource(EventSource):
+    """Before each batch, notes how large the state table of the job's
+    window operator has grown and what the flush counters read."""
+
+    def __init__(self, keys, values, ts):
+        super().__init__(keys, values, ts)
+        self.ops = []
+        self.marks = []
+
+    def emit_step(self, ctx, max_records):
+        for op in self.ops:
+            if type(op) is WindowOperator and op.columnar_rows:
+                self.marks.append((op.window_state.capacity,
+                                   STATE_STATS.flush_batches,
+                                   STATE_STATS.flush_row_form_batches))
+        return super().emit_step(ctx, max_records)
 
 
 class UserHll(HyperLogLogAggregate):
@@ -549,7 +570,8 @@ def leg_state_sliding(cfg, seed):
     env = StreamExecutionEnvironment(Configuration().set(
         "state.backend.tpu.max-device-slots", sl["budget"]))
     env.set_state_backend("tpu")
-    windowed = (env.add_source(EventSource(keys, values, ts), name="events")
+    source = FlushMarkingSource(keys, values, ts)
+    windowed = (env.add_source(source, name="events")
                 .key_by(0)
                 .window(SlidingEventTimeWindows.of(SLIDING_SIZE_MS,
                                                    SLIDING_SLIDE_MS)))
@@ -557,7 +579,7 @@ def leg_state_sliding(cfg, seed):
     sink = ArraySink()
     agg = ValueQuantiles()
     windowed.aggregate(agg, window_function=emit_quantiles).add_sink(sink)
-    ops = capture_operators(env)
+    source.ops = ops = capture_operators(env)
     env.execute("chip-smoke-state-sliding")
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
@@ -591,11 +613,28 @@ def leg_state_sliding(cfg, seed):
     if wop.window_rows != panes * n:
         problems.append(f"{wop.window_rows} state rows for {n} events, "
                         f"{panes} an event expected")
+    # the flushes since the table reached the size it ended at (a flush
+    # is two batches' rows in one window: small against that table,
+    # not against the 4,096 slots it started from) took the tile
+    # kernel, every one; off the TPU there is no such kernel to take
+    grown = [m for m in source.marks if m[0] == state.capacity][:1]
+    flushes, in_place = (
+        (STATE_STATS.flush_batches - grown[0][1],
+         STATE_STATS.flush_row_form_batches - grown[0][2])
+        if grown else (0, 0))
+    if not cfg["preflight"] and not 0 < flushes == in_place:
+        problems.append(
+            f"of {flushes} flushes of {2 * BATCH_ROWS} rows against the "
+            f"full-grown table of {state.capacity} slots {in_place} ran "
+            f"in place: state.update rewrites the whole table around "
+            f"the others (ops/sketches.py quantile_update_form)")
     hist = state.device_state["hist"]
     return problems, {
         "keys": sl["keys"], "events": n, "budget": sl["budget"],
         "slots": state.capacity,
         "table_bytes": int(hist.size) * hist.dtype.itemsize,
+        "batches_marked": len(source.marks),
+        "flushes_at_full_size": flushes, "flushes_in_place": in_place,
         "rows_per_event": wop.window_rows / n,
         "windows_touched": wop.windows_touched,
         "evictions": state.evictions,

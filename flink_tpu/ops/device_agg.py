@@ -112,6 +112,15 @@ class DeviceAggregateFunction(AggregateFunction):
     ) -> Dict[str, jnp.ndarray]:
         ...
 
+    def update_runs_in_place(self, rows: int, capacity: int) -> bool:
+        """Whether `update` of a `rows`-row batch against `capacity`
+        slots, on the platform the backend's programs run on, adds to
+        the table where it lies and not by a scatter of single cells:
+        what the state backend notes per flush
+        (`STATE_STATS.flush_row_form_batches`).  Only an aggregate
+        whose update has such a form says yes."""
+        return False
+
     @abc.abstractmethod
     def result(self, state: Dict[str, jnp.ndarray], slots: jnp.ndarray) -> jnp.ndarray:
         """Finalize: gather `slots` and compute per-slot results

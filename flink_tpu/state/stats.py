@@ -21,8 +21,8 @@ class StateStats:
     __slots__ = (
         "batch_rows", "row_fallback_rows", "batch_calls",
         "row_fallback_calls", "flush_batches", "flush_rows",
-        "flush_sizes", "result_rows", "result_padded_rows",
-        "snapshot_columns", "snapshot_rows",
+        "flush_row_form_batches", "flush_sizes", "result_rows",
+        "result_padded_rows", "snapshot_columns", "snapshot_rows",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
         "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
         "per_state_batch_rows", "per_state_batch_calls",
@@ -42,6 +42,9 @@ class StateStats:
         #: device micro-batch flushes and the rows they carried
         self.flush_batches = 0
         self.flush_rows = 0
+        #: flushes whose `state.update` ran in place (the quantile
+        #: sketch's tile kernel, on a TPU) where the others scatter cells
+        self.flush_row_form_batches = 0
         #: recent flush batch sizes (for mean/max gauges)
         self.flush_sizes = deque(maxlen=512)
         #: rows batched fire reads asked `state.result` for, and rows it
@@ -91,9 +94,10 @@ class StateStats:
         self.per_state_fallback_rows[name] = \
             self.per_state_fallback_rows.get(name, 0) + n
 
-    def note_flush(self, n: int) -> None:
+    def note_flush(self, n: int, row_form: bool = False) -> None:
         self.flush_batches += 1
         self.flush_rows += n
+        self.flush_row_form_batches += row_form
         self.flush_sizes.append(n)
 
     def note_result(self, n: int, padded: int) -> None:

@@ -724,7 +724,8 @@ class DeviceAggregatingState(AggregatingState):
         else:
             self.device_state = self._jit_update(
                 self.device_state, slots, values, hi, lo, mask)
-        STATE_STATS.note_flush(n)
+        STATE_STATS.note_flush(
+            n, self.agg.update_runs_in_place(padded, self.capacity))
         self._slot_flushed[pending] = True
         self._pending_slots.clear()
         self._pending_values.clear()

@@ -165,6 +165,30 @@ def test_tpu_backend_matches_exact_order_statistics(chunk):
     assert op.fire_rows_via_records == 0
 
 
+@pytest.mark.parametrize("tile_form_runs", [False, True])
+def test_the_job_notes_the_flushes_that_ran_in_place(tile_form_runs,
+                                                     monkeypatch):
+    """Flushes of 64 rows against a table of 4,096 slots and more: the
+    shapes give every one to the tile kernel.  That kernel is the
+    TPU's, so here each flush scatters cells and none is noted; where
+    the kernel runs (stood in for: the answer, not the program) every
+    flush of the job is."""
+    from flink_tpu.ops import sketches
+    if tile_form_runs:
+        monkeypatch.setattr(sketches, "_tile_form_runs", lambda: True)
+    before = (STATE_STATS.flush_batches, STATE_STATS.flush_row_form_batches)
+    op, results = run_job(EVENTS, 64, configuration=Configuration().set(
+        "state.backend.tpu.microbatch-size", 64))
+    flushed = STATE_STATS.flush_batches - before[0]
+    assert flushed == PANES * len(EVENTS[0]) // 64
+    assert STATE_STATS.flush_row_form_batches - before[1] \
+        == (flushed if tile_form_runs else 0)
+    assert op.window_state.capacity >= 4096
+    verdict = reference.check(CONFIG, emitted_panes(EVENTS, PER_SLIDE),
+                              results)
+    assert verdict["problems"] == [] and verdict["failed"] == 0
+
+
 @pytest.mark.parametrize("budget, microbatch", [(1024, 64), (2048, 128)])
 def test_under_a_small_budget_the_tier_evicts_and_fires_from_host_ram(
         budget, microbatch):

@@ -399,6 +399,39 @@ def _small_tile_state(agg, monkeypatch, **backend_kw):
     return backend, st
 
 
+@pytest.mark.parametrize("agg, tile_form_runs, in_place", [
+    ("quantile", True, True), ("quantile", False, False),
+    ("hll_p12", True, False), ("countmin", True, False),
+    ("sum", True, False)])
+def test_a_flush_notes_whether_it_ran_in_place(agg, tile_form_runs, in_place,
+                                               monkeypatch):
+    """`flush_row_form_batches` beside `flush_batches`: every flush of
+    a quantile sketch that is small against the table where the tile
+    kernel runs (a TPU; stood in for here, where each flush scatters
+    cells and none is noted), none of any other aggregate's, and none
+    while the batch is not small."""
+    from flink_tpu.ops import sketches
+    monkeypatch.setattr(sketches, "_tile_form_runs", lambda: tile_form_runs)
+    backend = TpuKeyedStateBackend(FULL_RANGE, MAX_PAR,
+                                   initial_capacity=1024, microbatch=64)
+    st = backend.get_or_create_keyed_state(
+        AggregatingStateDescriptor("noted", RESULT_AGGS[agg]()))
+    rng = np.random.default_rng(3)
+    before = (STATE_STATS.flush_batches, STATE_STATS.flush_row_form_batches)
+    for window in range(5):
+        st.add_batch(rng.integers(0, 50, 64).tolist(), window,
+                     rng.integers(1, 1000, 64).astype(np.float32))
+    flushed = STATE_STATS.flush_batches - before[0]
+    noted = STATE_STATS.flush_row_form_batches - before[1]
+    assert flushed == 5 and st.capacity == 1024
+    assert noted == (flushed if in_place else 0)
+    # a batch that is not small against the table scatters cells
+    st.add_batch(list(range(512)) * 2, 9, np.ones(1024, np.float32))
+    assert STATE_STATS.flush_batches - before[0] == 6 \
+        and st.capacity == 1024
+    assert STATE_STATS.flush_row_form_batches - before[1] == noted
+
+
 @pytest.mark.parametrize("n, padded", [
     (1, 1), (7, 8), (8, 8), (9, 16), (RESULT_TILE, RESULT_TILE),
     (RESULT_TILE + 1, 2 * RESULT_TILE),
