@@ -71,16 +71,6 @@ def sample_backpressure(subtasks_by_vertex: Dict[int, List],
     return out
 
 
-def sample_client(client, num_samples: int = 20,
-                  delay_s: float = 0.005) -> Dict[int, dict]:
-    """Sample a running job via its JobClient (executor_state)."""
-    state = client.executor_state or {}
-    subtasks = state.get("subtasks")
-    if not subtasks:
-        return {}
-    return sample_backpressure(subtasks, num_samples, delay_s)
-
-
 def router_blocked(router, now: Optional[float] = None) -> bool:
     """The sticky-window blocked predicate shared by the gauge read
     and time attribution: out of capacity right now, or a producer

@@ -48,7 +48,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = [
-    "ON_CPU", "OFF_CPU", "BACKPRESSURED", "CLASS_NAMES", "MODES",
+    "ON_CPU", "OFF_CPU", "BACKPRESSURED", "MODES",
     "SamplingProfiler", "get_profiler", "PROFILER",
     "classify_subtask", "fold_stack", "sample_windowed",
     "empty_export", "merge_export", "flamegraph_payload",

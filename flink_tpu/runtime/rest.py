@@ -357,7 +357,10 @@ class WebMonitor:
             # while the named job is tracked
             return ({"enabled": tracer.enabled,
                      "spans": tracer.recent(200),
-                     "stats": tracer.stats()}, "application/json")
+                     "stats": tracer.stats(),
+                     "periods": tracer.periods(),
+                     "dropped_periods": tracer.dropped_periods},
+                    "application/json")
         if path.startswith("/jobs/") and path.endswith("/bottleneck"):
             job = urllib.parse.unquote(
                 path[len("/jobs/"):-len("/bottleneck")])

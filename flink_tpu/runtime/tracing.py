@@ -15,7 +15,11 @@ surface:
   at batch or fire granularity: it feeds the same per-name stats with
   the tracer off, and enters a ``jax.profiler.TraceAnnotation`` named
   ``flink/<name>`` so a profiler trace shows the phase on the clock of
-  the device ops.
+  the device ops.  A cyclic collection of CPython's is booked like a
+  backend compile: on the innermost phase it interrupted, out of that
+  phase's self time, as ``flink/py.gc`` in a trace
+  (:func:`gc_totals`).  Every watermark that fired a window cuts a
+  *period* out of these books (:meth:`Tracer.periods`).
 
 * **Kernel profiling** — ``record_kernel(name, t0_ns, t1_ns)`` called
   by the wrappers in :mod:`flink_tpu.native` around every
@@ -41,6 +45,7 @@ full picture.
 from __future__ import annotations
 
 import functools
+import gc
 import json
 import os
 import threading
@@ -55,7 +60,6 @@ __all__ = [
     "get_tracer",
     "set_tracer",
     "make_trace_context",
-    "clock_anchor",
     "estimate_clock_offset",
     "build_cluster_trace",
     "traced_jit",
@@ -64,8 +68,8 @@ __all__ = [
     "kernel_stats",
     "jit_stats",
     "backend_compile_totals",
+    "gc_totals",
     "phase_annotation",
-    "reset_kernel_stats",
     "reset_jit_stats",
     "register_runtime_profile_gauges",
 ]
@@ -74,6 +78,12 @@ _perf_ns = time.perf_counter_ns
 
 #: every phase's profiler annotation is named PHASE_PREFIX + its name
 PHASE_PREFIX = "flink/"
+#: the collector's pseudo-phase, as ``jax.compile`` is the compiler's
+GC_PHASE = "py.gc"
+#: the window operators' watermark entry: one that fired cuts a period
+PERIOD_PHASE = "window.watermark"
+#: periods the ring keeps
+MAX_PERIODS = 512
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # one lock guards the aggregate stores (kernel + jit + span stats and
@@ -128,7 +138,8 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("tracer", "name", "attrs", "start_ns", "child_ns",
-                 "parent", "compile_ns", "compiles")
+                 "parent", "compile_ns", "compiles", "gc_ns", "gcs",
+                 "gc_under_ns", "native_ns")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self.tracer = tracer
@@ -140,6 +151,16 @@ class _Span:
         #: open one (booked by the jax.monitoring listener)
         self.compile_ns = 0
         self.compiles = 0
+        #: cyclic collections that interrupted this span while it was
+        #: the innermost open one (booked by the gc.callbacks hook),
+        #: and the time of those that ran anywhere under it
+        self.gc_ns = 0
+        self.gcs = 0
+        self.gc_under_ns = 0
+        #: time in native kernels called while this span was the
+        #: innermost open one (``record_kernel``): part of its self
+        #: time, and named
+        self.native_ns = 0
 
     def set_attr(self, key: str, value: Any) -> None:
         if self.attrs is None:
@@ -161,6 +182,7 @@ class _Span:
         dur_ns = end_ns - self.start_ns
         if self.parent is not None:
             self.parent.child_ns += dur_ns
+            self.parent.gc_under_ns += self.gc_under_ns
         self.tracer._finish(self, dur_ns)
         return False
 
@@ -178,6 +200,9 @@ class _Phase(_Span):
     def __exit__(self, *exc):
         _Span.__exit__(self)
         self.annotation.__exit__(*exc)
+        if self.name == PERIOD_PHASE and self.attrs \
+                and self.attrs.get("fired"):
+            self.tracer._cut_period(self.attrs)
         return False
 
     def set_attr(self, key: str, value: Any) -> None:
@@ -189,7 +214,11 @@ class _Phase(_Span):
 
 class _SpanStat:
     __slots__ = ("count", "total_ms", "self_ms", "compile_ms", "compiles",
-                 "reservoir")
+                 "gc_ms", "gcs", "gc_under_ms", "native_ms", "reservoir")
+    #: what stats() gives of a name beside the percentiles, and what a
+    #: period holds of it: the growth of these
+    CUT = ("count", "total_ms", "self_ms", "gc_ms", "gcs", "gc_under_ms",
+           "compiles", "compile_ms", "native_ms")
 
     def __init__(self):
         self.count = 0
@@ -197,7 +226,48 @@ class _SpanStat:
         self.self_ms = 0.0
         self.compile_ms = 0.0
         self.compiles = 0
+        self.gc_ms = 0.0
+        self.gcs = 0
+        self.gc_under_ms = 0.0
+        self.native_ms = 0.0
         self.reservoir = _Reservoir()
+
+
+class _GcBooks:
+    """Cyclic collections since the tracer was made or reset: all of
+    them, by generation, and those that found no span open."""
+
+    __slots__ = ("collections", "ns", "by_generation", "unphased")
+
+    def __init__(self):
+        self.collections = 0
+        self.ns = 0
+        #: generation -> [collections, ns]
+        self.by_generation = [[0, 0], [0, 0], [0, 0]]
+        self.unphased = [0, 0]
+
+    def totals(self) -> dict:
+        return {"collections": self.collections, "gc_ms": self.ns / 1e6,
+                "by_generation": {
+                    g: {"collections": n, "gc_ms": ns / 1e6}
+                    for g, (n, ns) in enumerate(self.by_generation)},
+                "unphased": {"collections": self.unphased[0],
+                             "gc_ms": self.unphased[1] / 1e6}}
+
+
+def _growth(now: dict, base: dict) -> dict:
+    """``now - base``, key by key, through nested dicts; what did not
+    grow is left out."""
+    out = {}
+    for key, value in now.items():
+        before = base.get(key)
+        if isinstance(value, dict):
+            grown = _growth(value, before or {})
+        else:
+            grown = value - (before or 0)
+        if grown:
+            out[key] = grown
+    return out
 
 
 class Tracer:
@@ -220,6 +290,17 @@ class Tracer:
         self._seq = 0
         # metric groups (weakrefs) that want per-span-name gauges
         self._metric_groups: List[weakref.ref] = []
+        self._new_books()
+
+    def _new_books(self) -> None:
+        self._gc = _GcBooks()
+        #: the last MAX_PERIODS fire periods, and how many left the ring
+        self._periods: deque = deque(maxlen=MAX_PERIODS)
+        self.dropped_periods = 0
+        self._period_seq = 0
+        #: the books at the last cut, and its host time
+        self._cut_base: dict = {}
+        self._cut_s = time.perf_counter()
 
     # ---- recording --------------------------------------------------
     def span(self, name: str, **attrs):
@@ -302,7 +383,9 @@ class Tracer:
             if span.attrs:
                 event["args"] = span.attrs
         total_ms = dur_ns / 1e6
-        self_ms = (dur_ns - span.child_ns - span.compile_ns) / 1e6
+        # neither a compile nor a collection is the span's own work
+        self_ms = (dur_ns - span.child_ns - span.compile_ns
+                   - span.gc_ns) / 1e6
         with self._lock:
             if event is not None:
                 self._append_locked(event)
@@ -316,7 +399,82 @@ class Tracer:
             if span.compiles:
                 stat.compiles += span.compiles
                 stat.compile_ms += span.compile_ns / 1e6
+            if span.gc_under_ns:
+                stat.gcs += span.gcs
+                stat.gc_ms += span.gc_ns / 1e6
+                stat.gc_under_ms += span.gc_under_ns / 1e6
+            if span.native_ns:
+                stat.native_ms += span.native_ns / 1e6
             stat.reservoir.update(total_ms)
+
+    # ---- fire periods -------------------------------------------------
+    def note_fire(self, operator: str, windows: int, keys: int,
+                  newest_window_end: int) -> None:
+        """Called by a window operator under its ``window.watermark``
+        phase once it knows what the watermark fired: the phase's exit
+        then cuts a period (:meth:`periods`).  Several calls under one
+        watermark add up."""
+        if not windows:
+            return
+        for span in reversed(self._stack()):
+            if span.name == PERIOD_PHASE:
+                attrs = span.attrs or {}
+                if attrs.get("fired"):
+                    windows += attrs["fired"]
+                    keys += attrs["fired_keys"]
+                    newest_window_end = max(newest_window_end,
+                                            attrs["newest_window_end"])
+                for key, value in (("operator", operator),
+                                   ("fired", windows), ("fired_keys", keys),
+                                   ("newest_window_end", newest_window_end)):
+                    span.set_attr(key, value)
+                return
+
+    def _cut_period(self, attrs: dict) -> None:
+        """One walk over the books (tens of names), once per fire."""
+        now_s = time.perf_counter()
+        with _LOCK:
+            kernels = {name: st.total_ms
+                       for name, st in _kernel_stats.items()}
+        with self._lock:  # two operators' threads may cut at once
+            books = {"phases": {name: {f: getattr(st, f)
+                                       for f in _SpanStat.CUT}
+                                for name, st in self._stats.items()},
+                     "gc": self._gc.totals(), "kernels": kernels}
+            period = {"seq": self._period_seq, "start_s": self._cut_s,
+                      "end_s": now_s, "operator": attrs.get("operator"),
+                      "watermark": attrs.get("watermark"),
+                      "windows": attrs["fired"],
+                      "keys": attrs["fired_keys"],
+                      "newest_window_end": attrs["newest_window_end"],
+                      **{k: _growth(v, self._cut_base.get(k, {}))
+                         for k, v in books.items()}}
+            if len(self._periods) == MAX_PERIODS:
+                self.dropped_periods += 1
+            self._periods.append(period)
+            self._period_seq += 1
+            self._cut_base, self._cut_s = books, now_s
+
+    def periods(self) -> List[dict]:
+        """The last ``MAX_PERIODS`` fire periods, oldest first.  A
+        period ends where a ``window.watermark`` phase that fired at
+        least one window ends, and holds the growth of the process's
+        books since the period before it (since the tracer was made or
+        reset, for the first): per phase ``count``, ``total_ms``,
+        ``self_ms``, ``gc_ms``, ``gcs``, ``gc_under_ms``, ``compiles``,
+        ``compile_ms``, ``native_ms``; ``gc`` as :func:`gc_totals` gives it;
+        ``kernels`` as ``kernel_stats()``' ``total_ms``; names that did
+        not grow are left out.  Beside them the host times of the two
+        cuts (``time.perf_counter``), the operator that fired, its
+        watermark, the windows and keys it fired and the end timestamp
+        of the newest of those windows.  ``dropped_periods`` counts
+        what left the ring."""
+        with self._lock:
+            return list(self._periods)
+
+    def gc_totals(self) -> dict:
+        with self._lock:
+            return self._gc.totals()
 
     def record_instant(self, name: str, **attrs) -> None:
         """Record a zero-duration marker event (checkpoint triggers,
@@ -404,17 +562,20 @@ class Tracer:
         return len(trace["traceEvents"])
 
     def stats(self) -> Dict[str, dict]:
-        """Aggregated per-span-name stats."""
+        """Aggregated per-span-name stats.  ``self_ms`` is the span's
+        own work: the time in its children, in backend compiles
+        (``compile_ms``) and in cyclic collections (``gc_ms``, ``gcs``
+        of them) that ran while it was the innermost open span is left
+        out.  ``gc_under_ms`` is the collector's time anywhere under
+        the span, its children's included; ``native_ms`` the part of
+        ``self_ms`` spent in native kernels called straight from the
+        span (``kernel_stats()`` names them)."""
         out = {}
         with self._lock:
             for name, st in self._stats.items():
                 vals = sorted(st.reservoir.values)
                 out[name] = {
-                    "count": st.count,
-                    "total_ms": st.total_ms,
-                    "self_ms": st.self_ms,
-                    "compiles": st.compiles,
-                    "compile_ms": st.compile_ms,
+                    **{f: getattr(st, f) for f in _SpanStat.CUT},
                     "p50_ms": _percentile(vals, 0.50),
                     "p99_ms": _percentile(vals, 0.99),
                 }
@@ -425,6 +586,7 @@ class Tracer:
             self._events.clear()
             self._stats.clear()
             self.dropped = 0
+            self._new_books()
 
     # ---- metric registry feed --------------------------------------
     def install_metrics(self, group) -> None:
@@ -455,6 +617,8 @@ class Tracer:
         g.gauge("selfMs", lambda s=stat: s.self_ms)
         g.gauge("compiles", lambda s=stat: s.compiles)
         g.gauge("compileMs", lambda s=stat: s.compile_ms)
+        g.gauge("gcs", lambda s=stat: s.gcs)
+        g.gauge("gcMs", lambda s=stat: s.gc_ms)
         g.gauge("p50Ms", lambda s=stat: s.reservoir.quantile(0.50))
         g.gauge("p99Ms", lambda s=stat: s.reservoir.quantile(0.99))
 
@@ -468,9 +632,15 @@ _TraceAnnotation = None
 _backend_compiles = [0, 0]
 
 
+#: (annotation, start ns) of the collection that is running: CPython
+#: runs one at a time, on the thread whose allocation triggered it
+_gc_open = None
+
+
 def _hook_jax() -> None:
-    """Once per process: the annotation class phases enter, and the
-    one listener that books backend compiles where they happen."""
+    """Once per process: the annotation class phases enter, the one
+    listener that books backend compiles where they happen, and the
+    one ``gc.callbacks`` hook that books cyclic collections there."""
     global _TraceAnnotation
     import jax
     with _LOCK:
@@ -478,6 +648,42 @@ def _hook_jax() -> None:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_jax_duration)
             _TraceAnnotation = jax.profiler.TraceAnnotation
+            gc.callbacks.append(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # a collection runs on the thread whose allocation set it off,
+    # inside whatever span is open there, and is not that span's work
+    global _gc_open
+    if phase == "start":
+        annotation = _TraceAnnotation(PHASE_PREFIX + GC_PHASE,
+                                      generation=info["generation"])
+        annotation.__enter__()
+        _gc_open = (annotation, _perf_ns())
+        return
+    if _gc_open is None:  # hooked in the middle of a collection
+        return
+    end_ns = _perf_ns()
+    annotation, start_ns = _gc_open
+    _gc_open = None
+    annotation.__exit__(None, None, None)
+    ns = end_ns - start_ns
+    tracer = _tracer
+    books = tracer._gc
+    books.collections += 1
+    books.ns += ns
+    generation = books.by_generation[info["generation"]]
+    generation[0] += 1
+    generation[1] += ns
+    stack = getattr(tracer._tls, "stack", None)
+    if stack:
+        span = stack[-1]
+        span.gcs += 1
+        span.gc_ns += ns
+        span.gc_under_ns += ns
+    else:
+        books.unphased[0] += 1
+        books.unphased[1] += ns
 
 
 def _on_jax_duration(event: str, secs: float, **_kw) -> None:
@@ -511,6 +717,16 @@ def backend_compile_totals() -> Dict[str, float]:
     with _LOCK:
         return {"compiles": _backend_compiles[0],
                 "compile_ms": _backend_compiles[1] / 1e6}
+
+
+def gc_totals() -> Dict[str, Any]:
+    """Every cyclic collection of CPython's since the process's tracer
+    was made or reset (the hook goes in with the first phase or
+    ``traced_jit``): ``collections`` and ``gc_ms``, the same
+    ``by_generation``, and ``unphased``, the ones that found no span
+    open on their thread.  Those that found one are in
+    ``stats()[name]["gcs"]`` / ``["gc_ms"]``."""
+    return _tracer.gc_totals()
 
 
 def get_tracer() -> Tracer:
@@ -638,6 +854,9 @@ def record_kernel(name: str, t0_ns: int, t1_ns: int) -> None:
         stat.total_ms += ms
         stat.reservoir.update(ms)
     tracer = _tracer
+    stack = getattr(tracer._tls, "stack", None)
+    if stack:
+        stack[-1].native_ns += t1_ns - t0_ns
     if tracer.enabled:
         event = {
             "name": "native." + name,
@@ -667,11 +886,6 @@ def kernel_stats() -> Dict[str, dict]:
                 "p99_ms": _percentile(vals, 0.99),
             }
     return out
-
-
-def reset_kernel_stats() -> None:
-    with _LOCK:
-        _kernel_stats.clear()
 
 
 # ---------------------------------------------------------------------
@@ -812,8 +1026,12 @@ def jit_stats() -> Dict[str, dict]:
 
 
 def reset_jit_stats() -> None:
+    """Zero every label's counts in place: a live ``traced_jit``
+    wrapper holds its stat object, and shows here again with its next
+    call."""
     with _LOCK:
-        _jit_stats.clear()
+        for stat in _jit_stats.values():
+            stat.__init__()
 
 
 # ---------------------------------------------------------------------

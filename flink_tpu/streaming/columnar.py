@@ -435,6 +435,8 @@ class ColumnarWindowOperator(WindowEngineHost):
         starts = np.asarray([e[2] for e in emitted], np.int64)
         ends = np.asarray([e[3] for e in emitted], np.int64)
         del emitted[:]
+        get_tracer().note_fire(self.operator_id or type(self).__name__,
+                               len(ends), len(ends), int(ends.max()))
         out = self._out_batch(keys_np, results, starts, ends)
         self.output.collect(StreamRecord(out, timestamp=int(ends.max()) - 1))
 
@@ -447,6 +449,11 @@ class ColumnarWindowOperator(WindowEngineHost):
     def _emit_fired(self):
         tracer = get_tracer()
         fired = self.engine.fired
+        if fired:
+            tracer.note_fire(
+                self.operator_id or type(self).__name__, len(fired),
+                sum(len(entry[0]) for entry in fired),
+                int(max(np.max(entry[3]) for entry in fired)))
         for entry in fired:
             keys_np, results, start, end = entry
             with tracer.phase("window.fire.batch", keys=len(keys_np)):

@@ -259,6 +259,11 @@ class DeviceWindowOperator(WindowEngineHost):
         else:
             fires = [tuple(zip(*engine.emitted))] if engine.emitted else []
             del engine.emitted[:]
+        if fires:
+            get_tracer().note_fire(
+                self.operator_id or type(self).__name__, len(fires),
+                sum(len(f[0]) for f in fires),
+                int(max(np.max(f[3]) for f in fires)))
         self._emit_fires(fires)
         self.num_late_records_dropped = engine.num_late_dropped
         if self.metrics is not None:
@@ -295,14 +300,18 @@ class DeviceWindowOperator(WindowEngineHost):
                     self.output.collect_batch(out)
                 continue
             buf = _FireBufferOutput(self.output)
+            owned = []  # the columns made here, freed in the release
             with tracer.phase("window.fire.batch", keys=n) as phase:
                 # python scalars, as the scalar operator hands them on
                 if isinstance(keys, np.ndarray) and keys.ndim == 1:
                     keys = keys.tolist()
+                    owned.append(keys)
                 if id_to_key is not None:
                     keys = [id_to_key[k] for k in keys]
+                    owned.append(keys)
                 if isinstance(results, np.ndarray) and results.ndim == 1:
                     results = results.tolist()
+                    owned.append(results)
                 if one_window:
                     buf.emit_fired(fn, keys, results, True,
                                    window=TimeWindow(starts, ends))
@@ -313,6 +322,7 @@ class DeviceWindowOperator(WindowEngineHost):
                             np.asarray(ends).tolist())))
                 buf.book(self, phase)
             buf.flush()
+            buf.release(*owned)
 
     # ---- checkpoint -------------------------------------------------
     def snapshot_state(self, checkpoint_id: Optional[int] = None) -> dict:

@@ -407,6 +407,17 @@ class _FireBufferOutput(Output):
                     for value in itertools.islice(rows, n):
                         collect(StreamRecord(value, timestamp))
 
+    def release(self, *owned: list) -> None:
+        """Free the fire's rows, and empty the `owned` lists the caller
+        built for this fire alone (its key and result columns as
+        python scalars).  A phase of its own: a million row tuples
+        take as long to free as a tenth of the loop that made them."""
+        with get_tracer().phase("window.fire.release"):
+            self.values.clear()
+            self.runs.clear()
+            for column in owned:
+                column.clear()
+
     def emit_watermark(self, watermark) -> None:
         self._inner.emit_watermark(watermark)
 
@@ -955,6 +966,11 @@ class WindowOperator(AbstractUdfStreamOperator):
                 cleaned.append((ns, keys))
         if not lateness:
             cleaned = fired  # fire and cleanup are the SAME dedup'd timer
+        if fired:
+            get_tracer().note_fire(
+                self.operator_id or type(self).__name__, len(fired),
+                sum(len(keys) for _, keys in fired),
+                max(ns[1] for ns, _ in fired))
         backend = self.keyed_backend
         emitted = 0
         if fired:
@@ -1002,6 +1018,7 @@ class WindowOperator(AbstractUdfStreamOperator):
                          found_mask)
             buf.book(self, phase)
         buf.flush()
+        buf.release()
         return fired
 
     def _fire_through_collector(self, buf, keys, namespace, namespaces,

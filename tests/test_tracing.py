@@ -4,6 +4,7 @@ the end-to-end MiniCluster acceptance path (operator/native/checkpoint
 spans + Prometheus watermark-lag/kernel metrics + jit recompile
 counts in the registry dump)."""
 
+import gc
 import json
 import time
 import urllib.request
@@ -168,6 +169,17 @@ def test_traced_jit_counts_compiles_and_hits():
     assert stats["recompiles"] == 2
     assert stats["cache_hits"] == 1
     assert stats["compile_time_ms"] > 0
+    # a reset zeroes the books in place: the wrapper made before it
+    # holds the same stat object and shows again with its next call
+    tracing.reset_jit_stats()
+    assert tracing.jit_stats()["test.add_one"] == {
+        "recompiles": 0, "compile_time_ms": 0.0, "cache_hits": 0,
+        "shape_variants": 0, "last_shape_sig": ""}
+    f(jnp.ones(8, jnp.float32))
+    f(jnp.ones(16, jnp.float32))
+    stats = tracing.jit_stats()["test.add_one"]
+    assert (stats["cache_hits"], stats["recompiles"]) == (1, 1)
+    assert stats["last_shape_sig"] == "(float32[16])"
 
 
 def test_record_compile_event_and_kernel_stats_reach_registry():
@@ -262,6 +274,11 @@ def test_minicluster_trace_prometheus_and_rest(tmp_path):
         assert payload["enabled"] is True
         assert payload["spans"] and payload["stats"]
         assert any(s["name"].startswith("op.") for s in payload["spans"])
+        # the history of fire periods rides along
+        assert payload["periods"] and payload["dropped_periods"] == 0
+        assert {"seq", "start_s", "end_s", "operator", "watermark",
+                "windows", "keys", "newest_window_end", "phases", "gc",
+                "kernels"} <= set(payload["periods"][0])
     finally:
         monitor.stop()
 
@@ -654,6 +671,7 @@ STATE_ROUTE_PHASES = {
     "window.watermark": 1, "timers.sweep": 1, "state.get.lookup": 1,
     "state.flush": 1, "state.get.device": 1, "window.fire.batch": 1,
     "window.fire.columnarize": 1, "window.fire.downstream": 1,
+    "window.fire.release": 1,
     "state.clear.slots": 1, "state.clear.device": 1}
 SQL_ROUTE_PHASES = {
     "window.ingest": 8, "columnar.ingest.hash": 8, "log.append": 8,
@@ -666,20 +684,32 @@ DEFAULT_DOOR_PHASES = {
     "columnar.ingest.hash": 8, "log.append": 8,
     "window.watermark": 1, "device_window.fire": 1, "log.concat": 2,
     "log.finish.pad": 2, "log.finish.device": 2, "window.fire.batch": 2,
-    "window.fire.columnarize": 2, "window.fire.downstream": 2}
+    "window.fire.columnarize": 2, "window.fire.downstream": 2,
+    "window.fire.release": 2}
+#: per route: (operator uid, windows, newest window end) of every period.
+#: A period is cut where a watermark that fired ends: one per firing
+#: watermark, so two on the SQL route and one where the stream's last
+#: watermark fires both windows
+STATE_ROUTE_PERIODS = [("op-2-window_aggregate", 2, 2000)]
+SQL_ROUTE_PERIODS = [
+    ("columnar-window-agg:0:k:APPROX_COUNT_DISTINCT:u", 1, 1000),
+    ("columnar-window-agg:0:k:APPROX_COUNT_DISTINCT:u", 1, 2000)]
+DEFAULT_DOOR_PERIODS = [("op-2-window_aggregate", 2, 2000)]
 
-@pytest.mark.parametrize("job, expected", [
-    (_state_backend_job, STATE_ROUTE_PHASES),
-    (_sql_tumble_job, SQL_ROUTE_PHASES),
-    (_default_door_job, DEFAULT_DOOR_PHASES)],
+@pytest.mark.parametrize("job, expected, expected_periods", [
+    (_state_backend_job, STATE_ROUTE_PHASES, STATE_ROUTE_PERIODS),
+    (_sql_tumble_job, SQL_ROUTE_PHASES, SQL_ROUTE_PERIODS),
+    (_default_door_job, DEFAULT_DOOR_PHASES, DEFAULT_DOOR_PERIODS)],
     ids=["state_backend", "sql", "default_door"])
 def test_phase_counts_follow_batches_and_fires_never_rows(
-        job, expected, monkeypatch):
+        job, expected, expected_periods, monkeypatch):
     """The guard against a span per record, key or timer: exactly the
     documented phase names, and the same number of each when every
     batch carries four times the rows and every fire four times the
-    keys.  (The finish tier is the device's, as on the chip; here the
-    link probe would pick the host.)"""
+    keys; `window.fire.release` once a flushed fire; and as many
+    periods cut as watermarks fired, whatever the rows and keys.  (The
+    finish tier is the device's, as on the chip; here the link probe
+    would pick the host.)"""
     import flink_tpu.native as nat
     from flink_tpu.ops import link_probe
     if not nat.available():
@@ -693,6 +723,19 @@ def test_phase_counts_follow_batches_and_fires_never_rows(
         job(rows)
         counts = {name: s["count"] for name, s in tr.stats().items()}
         assert counts == expected, rows
+        periods = tr.periods()
+        assert [(p["operator"], p["windows"],
+                 p["newest_window_end"]) for p in periods] \
+            == expected_periods, rows
+        assert sum(p["keys"] for p in periods) > rows // 2
+        assert tr.dropped_periods == 0
+        # the periods' counts add up to the books, but for a watermark
+        # after the last cut that fired nothing (the SQL route's last)
+        for name, count in expected.items():
+            cut = sum(p["phases"].get(name, {}).get("count", 0)
+                      for p in periods)
+            assert cut == count or (name, cut) == (
+                "window.watermark", count - 1), name
 
 
 @pytest.mark.parametrize("n", [9_000, 15_000])
@@ -727,7 +770,10 @@ def test_record_door_phases_follow_buffer_flushes_and_fires(n, monkeypatch):
         "columnar.ingest.hash": 2, "log.append": 2,
         "window.watermark": 1, "device_window.fire": 1, "log.concat": 3,
         "log.finish.pad": 3, "log.finish.device": 3, "window.fire.batch": 3,
-        "window.fire.columnarize": 3, "window.fire.downstream": 3}
+        "window.fire.columnarize": 3, "window.fire.downstream": 3,
+        "window.fire.release": 3}
+    [period] = tr.periods()
+    assert (period["windows"], period["keys"]) == (3, 150)
 
 
 def test_a_compile_is_booked_on_the_phase_and_the_label_that_needed_it():
@@ -763,3 +809,221 @@ def test_dispatch_spans_a_watermark_when_the_tracer_is_on():
     for e in watermarks:
         assert e["parent"].startswith("op.")
         assert e["parent"].endswith(".process")
+
+
+# ---------------------------------------------------------------------
+# the cyclic collector on the books
+# ---------------------------------------------------------------------
+
+@pytest.fixture
+def forced_collections_only():
+    """Only the collections a test forces: an automatic one would land
+    in some phase by chance, which is the thing under test."""
+    gc.collect()
+    gc.disable()
+    tr = get_tracer()
+    with tr.phase("hook"):  # the hook goes in with the first phase
+        pass
+    tr.reset()
+    try:
+        yield tr
+    finally:
+        gc.enable()
+
+
+def test_a_collection_is_booked_on_the_phase_it_interrupted(
+        forced_collections_only):
+    tr = forced_collections_only
+    before = sum(g["collections"] for g in gc.get_stats())
+    with tr.phase("fire"):
+        time.sleep(0.005)
+        with tr.phase("fire.emit"):
+            junk = [[i] for i in range(20_000)]  # something to walk
+            gc.collect()
+    stats = tr.stats()
+    emit, fire = stats["fire.emit"], stats["fire"]
+    assert emit["gcs"] == 1 and emit["gc_ms"] > 0
+    assert emit["gc_under_ms"] == emit["gc_ms"]
+    # out of the phase's own time, and not into its parent's
+    assert emit["self_ms"] == pytest.approx(
+        emit["total_ms"] - emit["gc_ms"], abs=1e-6)
+    assert (fire["gcs"], fire["gc_ms"]) == (0, 0.0)
+    assert fire["gc_under_ms"] == emit["gc_ms"]
+    assert fire["self_ms"] == pytest.approx(
+        fire["total_ms"] - emit["total_ms"], abs=1e-6)
+    totals = tracing.gc_totals()
+    assert totals["collections"] == 1
+    assert totals["gc_ms"] == pytest.approx(emit["gc_ms"])
+    assert totals["by_generation"][2] == {"collections": 1,
+                                          "gc_ms": totals["gc_ms"]}
+    assert totals["unphased"] == {"collections": 0, "gc_ms": 0.0}
+    # one with no phase open counts in the totals alone
+    del junk
+    gc.collect(0)
+    totals = tracing.gc_totals()
+    assert totals["collections"] == 2
+    assert totals["unphased"]["collections"] == 1
+    assert totals["by_generation"][0]["collections"] == 1
+    assert tr.stats()["fire.emit"]["gcs"] == 1
+    # the books close: every collection is in a phase or unphased, and
+    # the interpreter counted as many
+    stats = tr.stats()
+    assert sum(s["gcs"] for s in stats.values()) \
+        + totals["unphased"]["collections"] == totals["collections"]
+    assert sum(s["gc_ms"] for s in stats.values()) \
+        + totals["unphased"]["gc_ms"] == pytest.approx(totals["gc_ms"])
+    assert sum(g["collections"] for g in gc.get_stats()) - before \
+        == totals["collections"]
+
+
+def test_a_collection_is_a_profiler_event_inside_the_phase(
+        forced_collections_only, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = forced_collections_only
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tr.phase("window.fire.batch"):
+            gc.collect(1)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {e.name: e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith(tracing.PHASE_PREFIX)}
+    assert set(events) == {"flink/window.fire.batch", "flink/py.gc"}
+    collection, phase = events["flink/py.gc"], events["flink/window.fire.batch"]
+    assert dict(collection.stats)["generation"] == 1
+    assert phase.start_ns <= collection.start_ns
+    assert collection.start_ns + collection.duration_ns \
+        <= phase.start_ns + phase.duration_ns
+
+
+def test_the_hook_is_installed_once_however_many_tracers_are_set():
+    old = get_tracer()
+    try:
+        for _ in range(3):
+            tr = tracing.set_tracer(Tracer())
+            with tr.phase("x"):
+                pass
+            tr.reset()
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        # and books on the tracer that is set when a collection ends
+        with tr.phase("x"):
+            gc.collect()
+        assert tr.stats()["x"]["gcs"] >= 1
+        assert tr.gc_totals()["collections"] >= 1
+        assert old.gc_totals()["collections"] == 0 or old is tr
+    finally:
+        tracing.set_tracer(old)
+
+
+def test_an_inert_collection_callback_pair_costs_under_5_microseconds():
+    """What the hook adds to a collection while no profiler session
+    runs, beside the 5 us bound of an inert phase."""
+    with get_tracer().phase("hook"):
+        pass
+    info = {"generation": 0, "collected": 0, "uncollectable": 0}
+    on_gc = tracing._on_gc
+    n = 100_000
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            on_gc("start", info)
+            on_gc("stop", info)
+        best = min(best, time.perf_counter() - t0)
+    assert best / n < 5e-6, f"{best / n * 1e6:.2f} us per callback pair"
+
+
+# ---------------------------------------------------------------------
+# the history of fire periods
+# ---------------------------------------------------------------------
+
+def _watermark(tr, fired, end=1000, work=("state.flush",)):
+    with tr.phase("window.ingest", rows=8):
+        for name in work:
+            with tr.phase(name):
+                pass
+    with tr.phase("window.watermark", watermark=end - 1):
+        with tr.phase("timers.sweep"):
+            pass
+        if fired:
+            tr.note_fire("WindowOperator", fired, 10 * fired, end)
+            with tr.phase("window.fire.batch"):
+                pass
+
+
+def test_period_deltas_sum_to_the_stats_and_only_a_fire_cuts(
+        forced_collections_only):
+    tr = forced_collections_only
+    tracing.record_kernel("test.period_kernel", 0, 3_000_000)
+    _watermark(tr, 0)
+    _watermark(tr, 0)
+    assert tr.periods() == []  # a watermark that fires nothing cuts nothing
+    _watermark(tr, 1, end=1000)
+    tracing.record_kernel("test.period_kernel", 0, 2_000_000)
+    with tr.phase("window.ingest"):
+        gc.collect()
+        # a native kernel called straight from a phase is named time
+        # inside that phase's own
+        tracing.record_kernel("test.period_kernel", 0, 1_000_000)
+    _watermark(tr, 2, end=3000, work=("state.flush", "state.add.hash"))
+    first, second = tr.periods()
+    assert (first["seq"], first["windows"], first["keys"],
+            first["newest_window_end"], first["watermark"]) \
+        == (0, 1, 10, 1000, 999)
+    assert (second["seq"], second["windows"], second["keys"],
+            second["newest_window_end"], second["operator"]) \
+        == (1, 2, 20, 3000, "WindowOperator")
+    assert first["end_s"] == second["start_s"] < second["end_s"]
+    # the first holds the two idle watermarks before it
+    assert first["phases"]["window.watermark"]["count"] == 3
+    assert first["phases"]["window.ingest"]["count"] == 3
+    assert second["phases"]["window.ingest"]["count"] == 2
+    assert "state.add.hash" not in first["phases"]  # did not grow
+    assert second["phases"]["window.ingest"]["gcs"] == 1
+    assert second["gc"]["collections"] == 1 and "gc" in first
+    assert first["gc"] == {}
+    assert first["kernels"]["test.period_kernel"] == pytest.approx(3.0)
+    assert second["kernels"]["test.period_kernel"] == pytest.approx(3.0)
+    assert second["phases"]["window.ingest"]["native_ms"] \
+        == pytest.approx(1.0)
+    assert "native_ms" not in first["phases"]["window.ingest"]
+    stats = tr.stats()
+    for name, stat in stats.items():
+        for field in ("count", "total_ms", "self_ms", "gc_ms", "gcs",
+                      "compiles", "compile_ms", "native_ms"):
+            assert sum(p["phases"].get(name, {}).get(field, 0)
+                       for p in (first, second)) \
+                == pytest.approx(stat[field]), (name, field)
+    assert second["gc"]["gc_ms"] == pytest.approx(
+        tracing.gc_totals()["gc_ms"])
+    # several calls under one watermark add up
+    with tr.phase("window.watermark", watermark=5):
+        tr.note_fire("a", 1, 5, 4000)
+        tr.note_fire("a", 2, 7, 3000)
+    assert [(p["windows"], p["keys"], p["newest_window_end"])
+            for p in tr.periods()][-1] == (3, 12, 4000)
+    # a reset empties the ring, the base of the next cut and the books
+    tr.reset()
+    assert tr.periods() == [] and tr.gc_totals()["collections"] == 0
+    _watermark(tr, 1)
+    [only] = tr.periods()
+    assert only["seq"] == 0
+    assert only["phases"]["window.watermark"]["count"] == 1
+
+
+def test_the_period_ring_stops_at_512_and_counts_what_it_dropped():
+    tr = Tracer()
+    for i in range(tracing.MAX_PERIODS + 8):
+        with tr.phase("window.watermark", watermark=i):
+            tr.note_fire("op", 1, 1, i)
+    periods = tr.periods()
+    assert len(periods) == tracing.MAX_PERIODS == 512
+    assert tr.dropped_periods == 8
+    assert [periods[0]["seq"], periods[-1]["seq"]] == [8, 519]
