@@ -457,8 +457,26 @@ def timer_run_problems(op, windows, result_rows):
             f"rows in {op.timer_runs} runs, {windows} windows fired"]
 
 
+def hash_counts():
+    return STATE_STATS.hash_column_rows, STATE_STATS.hash_per_value_rows
+
+
+def hash_column_problems(before, events):
+    """Every event's user must have been hashed as part of its batch's
+    column since `before` (`hash_counts()` then); returns (problems,
+    facts)."""
+    column, per_value = (now - then
+                         for now, then in zip(hash_counts(), before))
+    facts = {"hash_column_rows": column, "hash_per_value_rows": per_value}
+    if column == events and not per_value:
+        return [], facts
+    return [f"hash_column_rows {column} of {events} events, "
+            f"{per_value} hashed a value at a time"], facts
+
+
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
+    hashed = hash_counts()
     ops, sink = run_window_job("chip-smoke-state-backend", events,
                                UserHll(cfg["precision"]),
                                on_state_backend=True)
@@ -469,6 +487,8 @@ def leg_state_backend(cfg, events, ref):
     problems += boxed_problems(wop, len(keys))
     problems += fire_tail_problems(wop, len(cols[0]))
     problems += timer_run_problems(wop, len(ref), len(cols[0]))
+    hash_problems, hash_facts = hash_column_problems(hashed, len(keys))
+    problems += hash_problems
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -481,7 +501,7 @@ def leg_state_backend(cfg, events, ref):
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
-        "evictions": state.evictions, **facts}
+        "evictions": state.evictions, **hash_facts, **facts}
 
 
 def leg_state_spill(cfg, seed):
@@ -496,6 +516,7 @@ def leg_state_spill(cfg, seed):
                                spill["budget"])
     if spill["microbatch"] is not None:
         conf.set("state.backend.tpu.microbatch-size", spill["microbatch"])
+    hashed = hash_counts()
     ops, sink = run_window_job("chip-smoke-state-spill", events,
                                UserHll(cfg["precision"]),
                                on_state_backend=True, configuration=conf)
@@ -505,6 +526,8 @@ def leg_state_spill(cfg, seed):
     problems, facts = check_hll(*cols, ref, cfg["precision"])
     problems += boxed_problems(wop, len(events[0]))
     problems += fire_tail_problems(wop, len(cols[0]))
+    hash_problems, hash_facts = hash_column_problems(hashed, len(events[0]))
+    problems += hash_problems
     if state.max_device_slots != spill["budget"]:
         problems.append(f"the backend's budget is {state.max_device_slots}, "
                         f"the Configuration says {spill['budget']}")
@@ -524,7 +547,7 @@ def leg_state_spill(cfg, seed):
         "evictions": state.evictions, "promotions": state.promotions,
         "budget_overruns": state.budget_overruns,
         "live_keys_per_window": [len(k) for k, _ in ref.values()],
-        **facts}
+        **hash_facts, **facts}
 
 
 #: config #3's windows; the slide is the source period too

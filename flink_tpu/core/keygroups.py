@@ -89,6 +89,21 @@ def splitmix64_np(x: np.ndarray) -> np.ndarray:
     return x ^ (x >> np.uint64(31))
 
 
+def hash_int_column_np(col: np.ndarray) -> np.ndarray:
+    """``stable_hash64`` of every element of a one-dimensional integer
+    column, in one pass: the native splitmix64 when the host runtime
+    is loaded, ``splitmix64_np`` otherwise.  A signed column is
+    reinterpreted as uint64, which wraps a negative value as the
+    scalar hash's ``& 0xFFFFFFFFFFFFFFFF`` does."""
+    try:
+        import flink_tpu.native as nat
+        if nat.available():
+            return nat.splitmix64(col.astype(np.uint64, copy=False))
+    except Exception:  # noqa: BLE001 — numpy twin below
+        pass
+    return splitmix64_np(col.astype(np.uint64))
+
+
 def stable_hashes_np(keys) -> np.ndarray:
     """64-bit stable hash per key, EXACTLY matching ``stable_hash64`` —
     the scalar routing/assignment path.  All-int key columns vectorize

@@ -25,6 +25,7 @@ class StateStats:
         "result_padded_rows", "snapshot_columns", "snapshot_rows",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
         "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
+        "hash_column_rows", "hash_per_value_rows",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
     )
@@ -66,6 +67,11 @@ class StateStats:
         #: by the per-key door (`_slot_for`, scalar clear)
         self.bulk_probe_rows = 0
         self.per_key_probe_rows = 0
+        #: value hashes the tpu backend took over a whole integer
+        #: column of `add_batch` in one pass / from `stable_hash64` a
+        #: value at a time (any other column, and the scalar `add`)
+        self.hash_column_rows = 0
+        self.hash_per_value_rows = 0
         #: the same batch/fallback split ATTRIBUTED by state name, so a
         #: fallback is traceable to the state that caused it; the
         #: aggregate counters above stay authoritative for the

@@ -38,6 +38,7 @@ import numpy as np
 from flink_tpu.core.keygroups import (
     KeyGroupRange,
     assign_to_key_group,
+    hash_int_column_np,
     stable_hash64,
 )
 from flink_tpu.core.state import (
@@ -50,6 +51,7 @@ from flink_tpu.core.state import (
     ValueStateDescriptor,
 )
 from flink_tpu.ops.device_agg import DeviceAggregateFunction
+from flink_tpu.ops.hashing import split_hash64_np
 from flink_tpu.state.backend import (
     VOID_NAMESPACE,
     KeyedStateBackend,
@@ -106,34 +108,37 @@ def _pad_slots(slots, width: int) -> np.ndarray:
     return arr
 
 
-class _SlotRing:
-    """Slots of the pending micro-batch, in the order of its values:
-    `add_batch`'s slot vectors as they are, the single slots of `add`
-    gathered between them."""
+class _PendingRing:
+    """One column of the pending micro-batch (its slots, its values or
+    their hashes), in the order of its rows: `add_batch`'s columns as
+    the arrays they are, the single entries of `add` gathered between
+    them."""
 
-    __slots__ = ("_parts", "_tail", "_n")
+    __slots__ = ("_dtype", "_parts", "_tail", "_n")
 
-    def __init__(self) -> None:
+    def __init__(self, dtype) -> None:
+        self._dtype = dtype
         self._parts: List[np.ndarray] = []
-        self._tail: List[int] = []
+        self._tail: list = []
         self._n = 0
 
     def __len__(self) -> int:
         return self._n
 
-    def append(self, slot: int) -> None:
-        self._tail.append(slot)
+    def append(self, entry) -> None:
+        self._tail.append(entry)
         self._n += 1
 
     def _seal_tail(self) -> None:
         if self._tail:
-            self._parts.append(np.array(self._tail, np.int64))
+            self._parts.append(np.array(self._tail, self._dtype))
             self._tail = []
 
-    def extend(self, slots: np.ndarray) -> None:
+    def extend(self, column) -> None:
         self._seal_tail()
-        self._parts.append(slots)
-        self._n += len(slots)
+        column = np.asarray(column, self._dtype)
+        self._parts.append(column)
+        self._n += len(column)
 
     def take(self) -> np.ndarray:
         """All of them as one vector (there is at least one)."""
@@ -191,10 +196,12 @@ class DeviceAggregatingState(AggregatingState):
         self.budget_overruns = 0
         #: (tile width, two sets of host buffers) of the spilled fire
         self._fire_buffers = (0, [])
-        self._pending_slots = _SlotRing()
-        self._pending_values: List[Any] = []
-        self._pending_hi: List[int] = []
-        self._pending_lo: List[int] = []
+        #: the pending micro-batch, a column each: slots, values (an
+        #: aggregate that needs them) and 64-bit value hashes (one that
+        #: needs those), split into the device's two lanes at the flush
+        self._pending_slots = _PendingRing(np.int64)
+        self._pending_values = _PendingRing(agg.value_dtype)
+        self._pending_hash = _PendingRing(np.uint64)
         self._reset_slots(initial_capacity)
         # jit-compiled entry points (cached per state object; XLA caches
         # per padded batch shape), under labels jit_stats() keeps.  None
@@ -267,8 +274,7 @@ class DeviceAggregatingState(AggregatingState):
         self.host_tier.clear()
         self._pending_slots.clear()
         self._pending_values.clear()
-        self._pending_hi.clear()
-        self._pending_lo.clear()
+        self._pending_hash.clear()
 
     def _bytes_per_slot(self) -> int:
         return max(1, tree_nbytes(self.device_state) // self.capacity)
@@ -627,9 +633,8 @@ class DeviceAggregatingState(AggregatingState):
         if self.agg.needs_value:
             self._pending_values.append(value)
         if self.agg.needs_value_hash:
-            h = stable_hash64(value)
-            self._pending_hi.append(h >> 32)
-            self._pending_lo.append(h & 0xFFFFFFFF)
+            self._pending_hash.append(stable_hash64(value))
+            STATE_STATS.hash_per_value_rows += 1
         if len(self._pending_slots) >= self.microbatch:
             self._flush()
 
@@ -660,11 +665,12 @@ class DeviceAggregatingState(AggregatingState):
                     pre_extracted=pre_extracted)
             return
         tracer = get_tracer()
-        with tracer.phase("state.add.slots", rows=len(keys)) as phase:
+        n = len(keys)
+        with tracer.phase("state.add.slots", rows=n) as phase:
             slots, new = self._resolve_column(keys, namespace, namespaces)
             phase.set_attr("new", new)
             self._pending_slots.extend(slots)
-        with tracer.phase("state.add.hash"):
+        with tracer.phase("state.add.hash", rows=n) as phase:
             extract = self.agg.extract_value
             # overridden on the class or per-instance (an
             # instance-attached plain function has no __func__)
@@ -673,14 +679,25 @@ class DeviceAggregatingState(AggregatingState):
                     None) is not DeviceAggregateFunction.extract_value:
                 values = [extract(v) for v in values]
             if self.agg.needs_value:
-                self._pending_values.extend(values)
+                # (a copy: the ring outlives the caller's column)
+                self._pending_values.extend(
+                    np.array(values, self.agg.value_dtype))
             if self.agg.needs_value_hash:
-                hi = self._pending_hi
-                lo = self._pending_lo
-                for v in values:
-                    h = stable_hash64(v)
-                    hi.append(h >> 32)
-                    lo.append(h & 0xFFFFFFFF)
+                # an integer column hashes whole; whatever else a
+                # value may be (a list's ints beyond int64, floats,
+                # strings, tuples, objects) goes through the scalar
+                # hash, which alone defines theirs
+                column = (isinstance(values, np.ndarray)
+                          and values.ndim == 1 and values.dtype.kind in "iu")
+                if column:
+                    hashes = hash_int_column_np(values)
+                    STATE_STATS.hash_column_rows += n
+                else:
+                    hashes = np.fromiter(map(stable_hash64, values),
+                                         np.uint64, n)
+                    STATE_STATS.hash_per_value_rows += n
+                self._pending_hash.extend(hashes)
+                phase.set_attr("column", column)
         if len(self._pending_slots) >= self.microbatch:
             self._flush()
 
@@ -698,19 +715,13 @@ class DeviceAggregatingState(AggregatingState):
         slots[:n] = pending
         mask = np.zeros(padded, bool)
         mask[:n] = True
+        values = np.zeros(padded, self.agg.value_dtype)
         if self.agg.needs_value:
-            values = np.zeros(padded, self.agg.value_dtype)
-            values[:n] = np.asarray(self._pending_values, self.agg.value_dtype)
-        else:
-            values = np.zeros(padded, self.agg.value_dtype)
+            values[:n] = self._pending_values.take()
+        hi = np.zeros(padded, np.uint32)
+        lo = np.zeros(padded, np.uint32)
         if self.agg.needs_value_hash:
-            hi = np.zeros(padded, np.uint32)
-            lo = np.zeros(padded, np.uint32)
-            hi[:n] = np.asarray(self._pending_hi, np.uint64).astype(np.uint32)
-            lo[:n] = np.asarray(self._pending_lo, np.uint64).astype(np.uint32)
-        else:
-            hi = np.zeros(padded, np.uint32)
-            lo = np.zeros(padded, np.uint32)
+            hi[:n], lo[:n] = split_hash64_np(self._pending_hash.take())
         if TELEMETRY.enabled:
             t0 = _perf_ns()
             self.device_state = self._jit_update(
@@ -729,8 +740,7 @@ class DeviceAggregatingState(AggregatingState):
         self._slot_flushed[pending] = True
         self._pending_slots.clear()
         self._pending_values.clear()
-        self._pending_hi.clear()
-        self._pending_lo.clear()
+        self._pending_hash.clear()
 
     # ---- read path --------------------------------------------------
     def get(self):

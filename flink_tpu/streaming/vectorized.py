@@ -32,7 +32,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flink_tpu.core.keygroups import splitmix64_np, stable_hash64
+from flink_tpu.core.keygroups import (
+    hash_int_column_np,
+    splitmix64_np,
+    stable_hash64,
+)
 from flink_tpu.ops.device_agg import DeviceAggregateFunction
 from flink_tpu.ops.hashing import split_hash64_np
 from flink_tpu.runtime.device_stats import TELEMETRY
@@ -54,14 +58,7 @@ def hash_keys_np(keys) -> np.ndarray:
         arr = arr.astype(np.int64)
     if arr.dtype.kind in "iu":
         if arr.ndim == 1:
-            try:
-                import flink_tpu.native as nat
-                if nat.available():
-                    return nat.splitmix64(arr.astype(np.uint64,
-                                                     copy=False))
-            except Exception:  # noqa: BLE001 — numpy twin below
-                pass
-            return splitmix64_np(arr.astype(np.uint64))
+            return hash_int_column_np(arr)
         h = np.zeros(len(arr), np.uint64)
         for j in range(arr.shape[1]):
             h = splitmix64_np(

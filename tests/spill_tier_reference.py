@@ -9,7 +9,10 @@ slot index before it was keyed by namespace (PR 32): one flat
 ``(key, namespace) → slot`` dict, `slot_meta` a list of those tuples,
 the stamps a list, and one `_slot_for` call, one tuple and one probe
 per event, per fired key and per cleared key
-(`tests/test_slot_index_bulk.py`).
+(`tests/test_slot_index_bulk.py`).  The pending micro-batch before it
+was columns (PR 36): three lists of Python values, `stable_hash64` a
+value at a time, two lane lists turned into arrays at the flush
+(`tests/test_state_hash_column.py`).
 """
 
 from collections import defaultdict
@@ -48,6 +51,9 @@ class PerKeySpillState(DeviceAggregatingState):
         self._access_stamp: List[int] = [0] * self.capacity
         self._slot_flushed = bytearray(self.capacity)
         self._pending_slots: List[int] = []
+        self._pending_values: List[Any] = []
+        self._pending_hi: List[int] = []
+        self._pending_lo: List[int] = []
         #: (key, namespace) → {component: numpy row}
         self.host_tier: Dict[Tuple[Any, Any], Dict[str, np.ndarray]] = {}
         self._spilled = self.host_tier
@@ -80,6 +86,19 @@ class PerKeySpillState(DeviceAggregatingState):
             self._clock += 1
             self._access_stamp[slot] = self._clock
         return slot
+
+    def add(self, value) -> None:
+        slot = self._slot_for(self._backend.current_key, self._namespace)
+        self._pending_slots.append(slot)
+        value = self.agg.extract_value(value)
+        if self.agg.needs_value:
+            self._pending_values.append(value)
+        if self.agg.needs_value_hash:
+            h = stable_hash64(value)
+            self._pending_hi.append(h >> 32)
+            self._pending_lo.append(h & 0xFFFFFFFF)
+        if len(self._pending_slots) >= self.microbatch:
+            self._flush()
 
     def _grow(self, new_capacity: int) -> None:
         self._flush()
