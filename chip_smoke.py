@@ -29,6 +29,16 @@ Legs:
                         every window against exact order statistics,
                         every flush against the full-grown table by the
                         tile kernel (on the TPU; no cell scattered)
+  2d session Count-Min  config #4 on the same route: 10 s-gap session
+                        windows of Count-Min sketches over 1,000,000
+                        Zipf keys, read from a 4-partition replayable
+                        log by the program's own connector, 14 periods
+                        of 32,768 events under a budget of 2^17 slots,
+                        every session against tests/
+                        session_countmin_reference.py; fails on an
+                        eviction, a boxed batch, a late row or a
+                        per-key probe of the slot index beyond the
+                        merges' targets
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -68,7 +78,14 @@ import flink_tpu.native as nat  # noqa: E402
 from flink_tpu.core.config import Configuration  # noqa: E402
 from flink_tpu.ops import link_probe  # noqa: E402
 from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
+from flink_tpu.connectors.log_connector import (  # noqa: E402
+    ReplayableLogSource,
+)
+from flink_tpu.connectors.partitioned_log import (  # noqa: E402
+    ColumnarPartitionedLog,
+)
 from flink_tpu.ops.sketches import (  # noqa: E402
+    CountMinSketchAggregate,
     HyperLogLogAggregate,
     QuantileSketchAggregate,
 )
@@ -90,6 +107,7 @@ from flink_tpu.streaming.elements import RecordBatch  # noqa: E402
 from flink_tpu.streaming.sources import SinkFunction  # noqa: E402
 from flink_tpu.streaming.window_operator import WindowOperator  # noqa: E402
 from flink_tpu.streaming.windowing import (  # noqa: E402
+    EventTimeSessionWindows,
     SlidingEventTimeWindows,
     TumblingEventTimeWindows,
 )
@@ -107,7 +125,10 @@ FULL = dict(keys=1_000_000, events_per_window=1 << 22, windows=3,
             spill=dict(keys=10_000_000, events_per_window=1 << 21,
                        budget=1 << 20, microbatch=None),
             sliding=dict(keys=10_000_000, events_per_slide=1 << 14,
-                         slides=14, budget=1 << 19))
+                         slides=14, budget=1 << 19),
+            session=dict(keys=1_000_000, items=1 << 20,
+                         events_per_period=1 << 15, periods=14,
+                         gap_ms=10_000, budget=1 << 17))
 TINY = dict(keys=256, events_per_window=4096, windows=3,
             precision=12, side_events=4096, fused_events=8192,
             fused_keys=64,
@@ -117,7 +138,9 @@ TINY = dict(keys=256, events_per_window=4096, windows=3,
             spill=dict(keys=6000, events_per_window=4096, budget=1024,
                        microbatch=64),
             sliding=dict(keys=500, events_per_slide=512, slides=14,
-                         budget=1 << 14))
+                         budget=1 << 14),
+            session=dict(keys=3000, items=64, events_per_period=512,
+                         periods=14, gap_ms=3000, budget=1 << 14))
 
 
 # ---------------------------------------------------------------------
@@ -559,18 +582,27 @@ def emit_quantiles(key, window, vals):
     return [(key, window.end - SLIDING_SLIDE_MS, float(p50), float(p99))]
 
 
-def quantile_reference():
-    """``tests/quantile_sliding_reference.py``, the plain reference of
-    the sliding quantile job, from the file beside this one."""
+def load_reference(name):
+    """``tests/<name>.py``, a plain reference from the file beside
+    this one."""
     import importlib.util
     import os
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "tests", "quantile_sliding_reference.py")
-    spec = importlib.util.spec_from_file_location(
-        "quantile_sliding_reference", path)
+                        "tests", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def zipf_column(rng, n, space):
+    """Zipf 0.99 over `space` ids, rank -> id by a permutation."""
+    weights = np.arange(1, space + 1, dtype=np.float64) ** -0.99
+    cumulative = np.cumsum(weights)
+    ranks = np.searchsorted(cumulative, rng.random(n) * cumulative[-1],
+                            side="right")
+    return rng.permutation(space)[np.minimum(ranks, space - 1)] \
+        .astype(np.int64)
 
 
 def leg_state_sliding(cfg, seed):
@@ -581,12 +613,7 @@ def leg_state_sliding(cfg, seed):
     sl = cfg["sliding"]
     rng = np.random.default_rng(seed + 2)
     n = sl["events_per_slide"] * sl["slides"]
-    weights = np.arange(1, sl["keys"] + 1, dtype=np.float64) ** -0.99
-    cumulative = np.cumsum(weights)
-    ranks = np.searchsorted(cumulative, rng.random(n) * cumulative[-1],
-                            side="right")
-    keys = rng.permutation(sl["keys"])[np.minimum(ranks, sl["keys"] - 1)]
-    keys = keys.astype(np.int64)
+    keys = zipf_column(rng, n, sl["keys"])
     values = np.exp(rng.normal(3.0, 1.0, n))
     ts = (np.arange(n, dtype=np.int64) * SLIDING_SLIDE_MS) \
         // sl["events_per_slide"]
@@ -616,7 +643,7 @@ def leg_state_sliding(cfg, seed):
     emitted = [(p, None, lambda p=p: (keys[p * per:(p + 1) * per],
                                       values[p * per:(p + 1) * per]))
                for p in range(sl["slides"])]
-    verdict = quantile_reference().check(
+    verdict = load_reference("quantile_sliding_reference").check(
         {"slide_ms": SLIDING_SLIDE_MS, "window_size_ms": SLIDING_SIZE_MS,
          "quantiles": list(agg.quantiles), "relative_accuracy": 0.01},
         emitted, results)
@@ -663,6 +690,124 @@ def leg_state_sliding(cfg, seed):
         "evictions": state.evictions,
         "budget_overruns": state.budget_overruns,
         "result_rows": len(got_keys), "windows_fired": len(results),
+        "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
+        **verdict["facts"]}
+
+
+SESSION_PARTITIONS = 4
+SESSION_WATCH = 8
+
+
+class ItemCounts(CountMinSketchAggregate):
+    """A count of one per event over field 1 (the item)."""
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1]
+
+
+def emit_session(key, window, vals):
+    return [(key, (window.end - 1) // WINDOW_MS * WINDOW_MS, window.start,
+             window.end, *vals[0].tolist())]
+
+
+def leg_state_sessions(cfg, seed):
+    """Leg 2's route under the first merging assigner, fed by the
+    program's own log connector: a producer fills a 4-partition
+    columnar log period by period, round-robin, the bounded consumer
+    reads one chunk a partition a step and emits one watermark lagging
+    by a period; every open session is a Count-Min sketch in a slot of
+    its own, and the budget holds them all."""
+    se = cfg["session"]
+    rng = np.random.default_rng(seed + 3)
+    per, periods, parts = se["events_per_period"], se["periods"], \
+        SESSION_PARTITIONS
+    n = per * periods
+    keys = zipf_column(rng, n, se["keys"])
+    items = zipf_column(rng, n, se["items"])
+    counts = np.bincount(items, minlength=se["items"])
+    watch = tuple(int(i) for i in np.lexsort(
+        (np.arange(len(counts)), -counts))[:SESSION_WATCH])
+    ts = (np.arange(n, dtype=np.int64) // per) * WINDOW_MS \
+        + 1 + ((np.arange(n, dtype=np.int64) % per)
+               * (WINDOW_MS - 1)) // per
+    log = ColumnarPartitionedLog(parts)
+    for p in range(periods):
+        for part in range(parts):
+            rows = slice(p * per + part, (p + 1) * per, parts)
+            log.append_columns(part, {"f0": keys[rows], "f1": items[rows]},
+                               ts[rows])
+    env = StreamExecutionEnvironment(Configuration().set(
+        "state.backend.tpu.max-device-slots", se["budget"]))
+    env.set_state_backend("tpu")
+    source = ReplayableLogSource(log, bounded=True,
+                                 watermark_lag_ms=WINDOW_MS,
+                                 batch_per_partition=per // parts)
+    windowed = (env.add_source(source, name="log")
+                .key_by(0)
+                .window(EventTimeSessionWindows.with_gap(se["gap_ms"])))
+    windowed.disable_device_operator()
+    sink = ArraySink()
+    windowed.aggregate(
+        ItemCounts(unit_weights=True, queries=watch),
+        window_function=emit_session).add_sink(sink)
+    ops = capture_operators(env)
+    probes = STATE_STATS.per_key_probe_rows
+    env.execute("chip-smoke-state-sessions")
+    wop = one_of(ops, WindowOperator)
+    state = wop.window_state
+    cols = sink.columns()
+    # the consumer read partition by partition, a chunk each a period
+    arrival = np.concatenate([
+        np.arange(p * per + part, (p + 1) * per, parts)
+        for p in range(periods) for part in range(parts)])
+    verdict = load_reference("session_countmin_reference").check(
+        {"gap_ms": se["gap_ms"], "window_ms": WINDOW_MS, "depth": 4,
+         "width": 2048},
+        [(0, None, lambda: (keys[arrival], items[arrival], ts[arrival],
+                            watch))],
+        {0: cols} if cols else {})
+    problems = list(verdict["problems"])
+    if verdict["failed"]:
+        problems.append(f"{verdict['failed']} of {verdict['attempted']} "
+                        f"session facts failed")
+    problems += boxed_problems(wop, n)
+    problems += fire_tail_problems(wop, len(cols[0]) if cols else 0)
+    if state.evictions or state.budget_overruns \
+            or state.capacity > se["budget"]:
+        problems.append(f"{state.evictions} evictions, "
+                        f"{state.budget_overruns} overruns, capacity "
+                        f"{state.capacity} of a budget of {se['budget']}: "
+                        f"the open sessions were to fit the device")
+    if wop.num_late_records_dropped:
+        problems.append(f"{wop.num_late_records_dropped} rows dropped as "
+                        f"late under a watermark that lags by a period")
+    # a merge of two state windows finds its target's slot through
+    # the per-key door, once; nothing else of this job may
+    per_key = STATE_STATS.per_key_probe_rows - probes
+    if per_key > STATE_STATS.merged_rows:
+        problems.append(f"{per_key} rows resolved by the per-key door of "
+                        f"the slot index against "
+                        f"{STATE_STATS.merged_rows} state windows merged: "
+                        f"the batched session path probes in bulk")
+    if log.committed_offsets != {part: n // parts for part in range(parts)}:
+        problems.append(f"offsets committed at the end of the stream: "
+                        f"{log.committed_offsets}")
+    table = state.device_state["table"]
+    return problems, {
+        "keys": se["keys"], "events": n, "budget": se["budget"],
+        "slots": state.capacity,
+        "table_bytes": int(table.size) * table.dtype.itemsize,
+        "sessions_opened": wop.sessions_opened,
+        "sessions_extended": wop.sessions_extended,
+        "session_windows_merged": wop.session_windows_merged,
+        "state_windows_merged": STATE_STATS.merged_rows,
+        "per_key_probe_rows": per_key,
+        "evictions": state.evictions,
+        "budget_overruns": state.budget_overruns,
+        "result_rows": len(cols[0]) if cols else 0,
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         **verdict["facts"]}
 
@@ -981,6 +1126,7 @@ def main(argv=None) -> int:
     run("2 state backend", leg_state_backend, cfg, events, ref)
     run("2b spill tier", leg_state_spill, cfg, args.seed)
     run("2c sliding quantiles", leg_state_sliding, cfg, args.seed)
+    run("2d session count-min", leg_state_sessions, cfg, args.seed)
     sql = run("3a sql", leg_sql, cfg, events, ref)
     run("3b datastream", leg_datastream_default, cfg, events, ref)
     run("4a entry step", leg_entry_step, cfg)

@@ -24,6 +24,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from flink_tpu.connectors.partitioned_log import PartitionedLog
+from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.streaming.sources import RichParallelSourceFunction, SourceContext
 from flink_tpu.streaming.two_phase import TwoPhaseCommitSinkFunction
 
@@ -74,31 +75,41 @@ class ReplayableLogSource(RichParallelSourceFunction):
     def run(self, ctx: SourceContext):
         import time
         while True:
-            more = self.emit_step(ctx, self.batch_per_partition)
+            # emit_step shares its `max_records` out over the
+            # partitions
+            more = self.emit_step(
+                ctx, self.batch_per_partition
+                * max(1, len(self._my_partitions or [1])))
             if not more:
                 return
             time.sleep(0)  # thread-hosted fallback: stay preemptible
 
     def emit_step(self, ctx: SourceContext, max_records: int) -> bool:
+        """One step: up to ``max_records // partitions`` records from
+        every assigned partition, then ONE watermark lagging the newest
+        timestamp.  A log that answers `read_columns` hands each
+        partition's chunk over as one ``RecordBatch``; offsets and the
+        watermark move as they do record by record."""
         if self._cancelled:
             return False
-        per_part = max(1, max_records // max(1, len(self._my_partitions or [1])))
+        partitions = self._my_partitions or []
+        per_part = max(1, max_records // max(1, len(partitions)))
         emitted = 0
         exhausted = True
-        for p in self._my_partitions or []:
-            records = self.log.read(p, self.offsets[p], per_part)
-            for _off, ts, value in records:
-                if ts is None:
-                    ctx.collect(value)
+        with get_tracer().phase("source.log.read") as phase:
+            for p in partitions:
+                chunk = self.log.read_columns(p, self.offsets[p], per_part)
+                if chunk is not None:
+                    read = self._emit_columns(ctx, *chunk)
                 else:
-                    ctx.collect_with_timestamp(value, ts)
-                    if self._max_ts is None or ts > self._max_ts:
-                        self._max_ts = ts
-            if records:
-                self.offsets[p] = records[-1][0] + 1
-                emitted += len(records)
-            if self.offsets[p] < self.log.end_offset(p):
-                exhausted = False
+                    read = self._emit_records(
+                        ctx, self.log.read(p, self.offsets[p], per_part))
+                self.offsets[p] += read
+                emitted += read
+                if self.offsets[p] < self.log.end_offset(p):
+                    exhausted = False
+            phase.set_attr("rows", emitted)
+            phase.set_attr("partitions", len(partitions))
         if emitted and self.watermark_lag_ms is not None and self._max_ts is not None:
             wm = self._max_ts - self.watermark_lag_ms
             if self._last_wm is None or wm > self._last_wm:
@@ -108,6 +119,27 @@ class ReplayableLogSource(RichParallelSourceFunction):
         if self.bounded and exhausted:
             return False
         return not self._cancelled
+
+    def _emit_records(self, ctx: SourceContext, records) -> int:
+        for _off, ts, value in records:
+            if ts is None:
+                ctx.collect(value)
+            else:
+                ctx.collect_with_timestamp(value, ts)
+                if self._max_ts is None or ts > self._max_ts:
+                    self._max_ts = ts
+        return len(records)
+
+    def _emit_columns(self, ctx: SourceContext, first: int, ts, cols) -> int:
+        """A partition's chunk as one batch element."""
+        n = len(ts)
+        if n:
+            from flink_tpu.streaming.elements import RecordBatch
+            ctx.collect_batch(RecordBatch(dict(cols), ts))
+            newest = int(ts.max())
+            if self._max_ts is None or newest > self._max_ts:
+                self._max_ts = newest
+        return n
 
     def cancel(self):
         self._cancelled = True

@@ -8,18 +8,24 @@ offset, re-readable from any offset, with a committed-offsets side
 channel (the consumer-group offset commit that Flink performs on
 checkpoint completion, `pendingOffsetsToCommit` :160,756).
 
-Two implementations: in-memory (unit tests, single process) and
+Three implementations: in-memory (unit tests, single process),
 file-backed JSON-lines (survives process exit — the durability tier
-the recovery tests need).  Both are thread-safe: test feeders append
-from their own threads while the executor loop reads.
+the recovery tests need) and in-memory columnar (a producer appends
+array chunks, a consumer reads them back as zero-copy slices: the
+form a vectorized source hands on as one ``RecordBatch``).  All are
+thread-safe: test feeders append from their own threads while the
+executor loop reads.
 """
 
 from __future__ import annotations
 
+import bisect
 import json
 import os
 import threading
 from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
 
 
 class PartitionedLog:
@@ -48,6 +54,16 @@ class PartitionedLog:
              max_records: int) -> List[Tuple[int, Optional[int], Any]]:
         """Records from `offset` (inclusive), at most `max_records`."""
         raise NotImplementedError
+
+    def read_columns(self, partition: int, offset: int, max_records: int
+                     ) -> Optional[Tuple[int, np.ndarray,
+                                         Dict[str, np.ndarray]]]:
+        """The records `read` would return, as columns: ``(first
+        offset, int64 timestamps, {name: column})``, at most
+        `max_records` rows (none at the head of the log: empty
+        columns).  ``None`` from a log that holds no columns: the
+        consumer then reads records."""
+        return None
 
     def end_offset(self, partition: int) -> int:
         raise NotImplementedError
@@ -232,3 +248,113 @@ class FilePartitionedLog(PartitionedLog):
             parts = (self._cache if partition is None
                      else [self._cache[partition]])
             return [v for p in parts for (_ts, v) in p]
+
+
+class ColumnarPartitionedLog(PartitionedLog):
+    """In-memory log whose partitions are appended array chunks: a
+    record is one row of named columns with an int64 timestamp, its
+    value the tuple of its fields in column order (the cell itself
+    where the one column is named ``"v"``: ``RecordBatch``'s
+    convention).  `read_columns`
+    answers with slices of the chunks as they were appended (no copy;
+    the producer must not write into an array it has appended); `read`
+    boxes the same rows for a per-record consumer.  A read never
+    crosses a chunk: it returns the rows from `offset` to the end of
+    the chunk that holds it, `max_records` at most."""
+
+    def __init__(self, num_partitions: int = 1):
+        self._n = num_partitions
+        #: per partition: the chunks, and the offset each starts at
+        self._chunks: List[List[Tuple[np.ndarray, Dict[str, np.ndarray]]]] \
+            = [[] for _ in range(num_partitions)]
+        self._starts: List[List[int]] = [[] for _ in range(num_partitions)]
+        self._ends = [0] * num_partitions
+        self._names: Optional[Tuple[str, ...]] = None
+        self._committed: Dict[int, int] = {}
+        self._lock = threading.Lock()
+
+    @property
+    def num_partitions(self) -> int:
+        return self._n
+
+    def append_columns(self, partition: int, columns: Dict[str, np.ndarray],
+                       timestamps) -> int:
+        """Append one chunk of rows; returns the first row's offset."""
+        ts = np.asarray(timestamps, np.int64)
+        cols = {name: np.asarray(col) for name, col in columns.items()}
+        if any(len(col) != len(ts) for col in cols.values()):
+            raise ValueError("columns and timestamps differ in length")
+        with self._lock:
+            if self._names is None:
+                self._names = tuple(cols)
+            elif tuple(cols) != self._names:
+                raise ValueError(f"columns {tuple(cols)}, the log holds "
+                                 f"{self._names}")
+            first = self._ends[partition]
+            if len(ts):
+                self._chunks[partition].append((ts, cols))
+                self._starts[partition].append(first)
+                self._ends[partition] = first + len(ts)
+            return first
+
+    def append(self, partition, value, timestamp=None) -> int:
+        """One record: `value` is the tuple of its fields, or a
+        scalar (the one column ``"v"``)."""
+        if timestamp is None:
+            raise ValueError("a columnar log's records carry timestamps")
+        if isinstance(value, tuple):
+            fields = value
+            names = self._names or tuple(f"f{i}" for i in range(len(fields)))
+        else:
+            fields, names = (value,), self._names or ("v",)
+        return self.append_columns(
+            partition, {name: np.array([field])
+                        for name, field in zip(names, fields)}, [timestamp])
+
+    def read_columns(self, partition, offset, max_records):
+        with self._lock:
+            starts = self._starts[partition]
+            if offset >= self._ends[partition] or max_records <= 0:
+                names = self._names or ()
+                return offset, np.zeros(0, np.int64), \
+                    {name: np.zeros(0, np.int64) for name in names}
+            # the chunk that holds `offset`: the last that starts <= it
+            lo = bisect.bisect_right(starts, offset) - 1
+            ts, cols = self._chunks[partition][lo]
+            at = offset - starts[lo]
+            rows = slice(at, at + max_records)
+            return offset, ts[rows], {name: col[rows]
+                                      for name, col in cols.items()}
+
+    def read(self, partition, offset, max_records):
+        first, ts, cols = self.read_columns(partition, offset, max_records)
+        if not len(ts):
+            return []
+        fields = [col.tolist() for col in cols.values()]
+        values = fields[0] if tuple(cols) == ("v",) else list(zip(*fields))
+        return [(first + i, t, v)
+                for i, (t, v) in enumerate(zip(ts.tolist(), values))]
+
+    def end_offset(self, partition) -> int:
+        with self._lock:
+            return self._ends[partition]
+
+    def commit_offsets(self, offsets):
+        with self._lock:
+            self._committed.update(offsets)
+
+    @property
+    def committed_offsets(self):
+        with self._lock:
+            return dict(self._committed)
+
+    def all_values(self, partition: Optional[int] = None) -> List[Any]:
+        parts = range(self._n) if partition is None else [partition]
+        out: List[Any] = []
+        for p in parts:
+            offset = 0
+            while offset < self.end_offset(p):
+                records = self.read(p, offset, 1 << 30)
+                out.extend(v for _, _, v in records)
+                offset += len(records)
+        return out

@@ -155,7 +155,8 @@ class DeviceAggregateFunction(AggregateFunction):
         .at[dst].set.  Repeated dst entries would race under .set; the
         backend's batch-merge driver rounds multi-source merges so each
         dispatch is repeat-free (merge_slots stays the repeat-tolerant
-        scalar path)."""
+        scalar path).  A pair whose dst lies past the table is
+        padding: it reads a clamped row and its write is dropped."""
         specs = self.state_specs()
 
         def pair_merge(rows_a, rows_b):
@@ -170,7 +171,7 @@ class DeviceAggregateFunction(AggregateFunction):
         merged = jax.vmap(pair_merge)(rows_a, rows_b)
         out = dict(state)
         for k in specs:
-            out[k] = out[k].at[dst].set(merged[k])
+            out[k] = out[k].at[dst].set(merged[k], mode="drop")
         return out
 
     def clear_slots(self, state: Dict[str, jnp.ndarray], slots: jnp.ndarray) -> Dict[str, jnp.ndarray]:

@@ -36,8 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from flink_tpu.core.keygroups import hash_int_column_np
 from flink_tpu.ops.device_agg import DeviceAggregateFunction, StateSpec
-from flink_tpu.ops.hashing import countmin_rows, hll_register_and_rank
+from flink_tpu.ops.hashing import (
+    countmin_rows,
+    hll_register_and_rank,
+    split_hash64_np,
+)
 
 
 class HyperLogLogAggregate(DeviceAggregateFunction):
@@ -131,21 +136,43 @@ class CountMinSketchAggregate(DeviceAggregateFunction):
     :meth:`point_query` (a queryable-state style read).
     Guarantee: est ≤ true + eps*L1 with prob 1-delta, eps=e/width,
     delta=e^-depth.
+
+    As constructed by default, the weight and the item are the SAME
+    extracted value (a stream of weights that are their own identity).
+    ``unit_weights=True`` is the heavy-hitter deployment: every event
+    adds 1 and the extracted value is the item alone, hashed as a
+    column and never shipped as a value.  ``queries=(item, ...)``
+    makes ``result`` read the table: ``int32[N, 1 + W]``, the total,
+    then ``point_query`` of each of the W items in that slot (their
+    hashes taken once, here, by the function that hashes a column of
+    ``add_batch``).
     """
 
     needs_value = True        # weight (usually 1.0)
     needs_value_hash = True   # item identity
 
-    def __init__(self, depth: int = 4, width: int = 2048):
+    def __init__(self, depth: int = 4, width: int = 2048, *,
+                 unit_weights: bool = False,
+                 queries: Sequence[int] | None = None):
         self.depth = depth
         self.width = width
+        self.unit_weights = unit_weights
+        if unit_weights:
+            self.needs_value = False
+        self.queries = None if queries is None else tuple(queries)
+        if self.queries is not None:
+            hashes = hash_int_column_np(np.asarray(self.queries, np.int64))
+            self._query_hi, self._query_lo = split_hash64_np(hashes)
 
     def state_specs(self) -> Dict[str, StateSpec]:
         return {"table": StateSpec((self.depth, self.width), np.dtype(np.int32), 0),
                 "total": StateSpec((), np.dtype(np.int32), 0)}
 
     def update(self, state, slots, values, vh_hi, vh_lo, mask):
-        w = jnp.where(mask, values.astype(jnp.int32), 0)           # [N]
+        if self.unit_weights:
+            w = mask.astype(jnp.int32)                              # [N]
+        else:
+            w = jnp.where(mask, values.astype(jnp.int32), 0)
         cols = countmin_rows(vh_hi, vh_lo, self.depth, self.width)  # [d, N]
         slots_b = jnp.broadcast_to(slots.astype(jnp.int32)[None, :], cols.shape)
         rows_b = jnp.broadcast_to(
@@ -156,7 +183,16 @@ class CountMinSketchAggregate(DeviceAggregateFunction):
                 "total": state["total"].at[slots].add(w)}
 
     def result(self, state, slots):
-        return state["total"][slots]
+        total = state["total"][slots]
+        if self.queries is None:
+            return total
+        # every (slot, tracked item) pair as one point query
+        n, w = slots.shape[0], len(self.queries)
+        est = self.point_query(
+            state, jnp.repeat(slots, w),
+            jnp.tile(jnp.asarray(self._query_hi), n),
+            jnp.tile(jnp.asarray(self._query_lo), n))
+        return jnp.concatenate([total[:, None], est.reshape(n, w)], axis=1)
 
     def point_query(self, state, slots, qh_hi, qh_lo):
         """Estimate frequency of items (qh_hi, qh_lo) in slot `slots[i]`."""

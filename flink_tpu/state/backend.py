@@ -335,6 +335,24 @@ class KeyedStateBackend(abc.ABC):
                 state.clear()
         return "rows"
 
+    def merge_namespaces_batch(self, state, merges) -> str:
+        """Fold, for every ``(key, target, [sources])`` of `merges`,
+        the sources' namespaces of that key into its target — the
+        batched twin of ``state.merge_namespaces`` under each key, the
+        session windows' merge of state windows.  Dispatches to the
+        state object's native ``merge_namespaces_batch`` (the TPU
+        backend: one flush, the merges in rounds through one pairwise
+        kernel, one clear); otherwise key by key.  Returns the path
+        taken.  Leaves the backend's current key undefined."""
+        native = getattr(state, "merge_namespaces_batch", None)
+        if native is not None:
+            native(merges)
+            return "batch"
+        for key, target, sources in merges:
+            self.set_current_key(key)
+            state.merge_namespaces(target, sources)
+        return "rows"
+
     # ---- introspection ----------------------------------------------
     @abc.abstractmethod
     def get_keys(self, state_name: str, namespace) -> Iterable[Any]:

@@ -25,6 +25,7 @@ class StateStats:
         "result_padded_rows", "snapshot_columns", "snapshot_rows",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
         "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
+        "merged_rows",
         "hash_column_rows", "hash_per_value_rows",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
@@ -67,6 +68,9 @@ class StateStats:
         #: by the per-key door (`_slot_for`, scalar clear)
         self.bulk_probe_rows = 0
         self.per_key_probe_rows = 0
+        #: source slots the tpu backend's batched session merge
+        #: (`merge_namespaces_batch`) folded into their targets
+        self.merged_rows = 0
         #: value hashes the tpu backend took over a whole integer
         #: column of `add_batch` in one pass / from `stable_hash64` a
         #: value at a time (any other column, and the scalar `add`)

@@ -93,6 +93,9 @@ PROMOTE_TILE_BYTES = 4 << 20
 #: their copies to the host all in flight at once (one copy of 1 GiB
 #: ran at half the rate of sixteen of 64 MiB on a v5e's host link)
 EVICT_PIECE_BYTES = 64 << 20
+#: fewest (destination, source) pairs one `state.merge_rows` dispatch
+#: is shaped for: a session job merges a state window or two a batch
+MERGE_MIN_WIDTH = 8
 
 
 def _round_up_pow2(n: int) -> int:
@@ -1002,32 +1005,47 @@ class DeviceAggregatingState(AggregatingState):
         target's r-th live source, so every dispatch has UNIQUE
         destination slots (distinct merges own distinct (key, target)
         slots) — and one clear frees every source slot at the end.
+        Both programs run at a power of two of at least
+        MERGE_MIN_WIDTH pairs, so a batch with a merge or two more
+        than the last finds its program: a pad pair's destination is
+        the slot past the table, which the scatter drops.
         Observable state after this call is identical to running
         merge_namespaces per (key, target)."""
         self._flush()
-        plans = []  # (dst_slot, [src_slots])
-        for key, target, sources in merges:
-            dst, srcs = self._merge_plan(key, target, sources)
-            if srcs:
-                plans.append((dst, srcs))
-        if not plans:
-            return
-        rounds = max(len(srcs) for _, srcs in plans)
-        all_srcs: List[int] = []
-        with self._device_lock:
-            for r in range(rounds):
-                dsts = [dst for dst, srcs in plans if len(srcs) > r]
-                srcs = [srcs[r] for _, srcs in plans if len(srcs) > r]
-                self.device_state = self._jit_merge_rows(
-                    self.device_state,
-                    jnp.asarray(np.array(dsts, np.int32)),
-                    jnp.asarray(np.array(srcs, np.int32)))
-                all_srcs.extend(srcs)
-            self.device_state = self._jit_clear(
-                self.device_state, jnp.asarray(np.array(all_srcs, np.int32)))
-            self._slot_flushed[[dst for dst, _ in plans]] = True
-            self._slot_flushed[all_srcs] = False
-        self._free.extend(all_srcs)
+        with get_tracer().phase("state.merge") as phase:
+            plans = []  # (dst_slot, [src_slots])
+            for key, target, sources in merges:
+                dst, srcs = self._merge_plan(key, target, sources)
+                if srcs:
+                    plans.append((dst, srcs))
+            rows = sum(len(srcs) for _, srcs in plans)
+            phase.set_attr("rows", rows)
+            STATE_STATS.merged_rows += rows
+            if not plans:
+                return
+            rounds = max(len(srcs) for _, srcs in plans)
+            width = max(MERGE_MIN_WIDTH, _round_up_pow2(len(plans)))
+            all_srcs: List[int] = []
+            with self._device_lock:
+                for r in range(rounds):
+                    pairs = [(dst, srcs[r]) for dst, srcs in plans
+                             if len(srcs) > r]
+                    # (a pad pair reads slot 0 and writes nowhere)
+                    dsts = np.full(width, self.capacity, np.int32)
+                    srcs = np.zeros(width, np.int32)
+                    dsts[:len(pairs)] = [dst for dst, _ in pairs]
+                    srcs[:len(pairs)] = [src for _, src in pairs]
+                    self.device_state = self._jit_merge_rows(
+                        self.device_state, jnp.asarray(dsts),
+                        jnp.asarray(srcs))
+                    all_srcs.extend(src for _, src in pairs)
+                self.device_state = self._jit_clear(
+                    self.device_state, jnp.asarray(_pad_slots(
+                        all_srcs, max(MERGE_MIN_WIDTH,
+                                      _round_up_pow2(len(all_srcs))))))
+                self._slot_flushed[[dst for dst, _ in plans]] = True
+                self._slot_flushed[all_srcs] = False
+            self._free.extend(all_srcs)
 
     # ---- snapshot ---------------------------------------------------
     def restore_entries(self, entries: List[Tuple[Any, Any, Dict[str, np.ndarray]]]) -> None:

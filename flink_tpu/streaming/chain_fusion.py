@@ -156,6 +156,8 @@ def _window_stage_reason(op) -> Optional[str]:
         return "not a window operator"
     if isinstance(op, EvictingWindowOperator):
         return "evicting window operator is per-row"
+    if op.assigner.is_merging():
+        return "session windows have no pane column to fuse"
     reason = op._batch_eligibility()
     if reason is not None:
         return reason

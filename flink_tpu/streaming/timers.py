@@ -511,6 +511,22 @@ class InternalTimerService:
         one call, which finds no run where they fired already."""
         self._event.discard_many(timestamp, namespace, keys)
 
+    def register_event_time_timers_rows(self, rows) -> None:
+        """register_event_time_timer for `rows` of (namespace,
+        timestamp, key), in their order, the backend's current-key
+        context untouched: the batched session ingest registers every
+        window that grew under its key's own namespace."""
+        add = self._event.add
+        for namespace, timestamp, key in rows:
+            add(timestamp, namespace, key)
+
+    def delete_event_time_timers_rows(self, rows) -> None:
+        """delete_event_time_timer for `rows` of (namespace,
+        timestamp, key)."""
+        discard = self._event.discard
+        for namespace, timestamp, key in rows:
+            discard(timestamp, namespace, key)
+
     def register_processing_time_timer(self, namespace, timestamp: int) -> None:
         if self._proc.add(timestamp, namespace, self._backend.current_key):
             self._arm_processing_time(timestamp)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import abc
 import itertools
+import operator
 from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -41,11 +42,14 @@ from flink_tpu.streaming.operators import (
     TimestampedCollector,
 )
 from flink_tpu.streaming.windowing import (
+    EventTimeSessionWindows,
+    EventTimeTrigger,
     SlidingEventTimeWindows,
     Trigger,
     TriggerContext,
     TriggerResult,
     TumblingEventTimeWindows,
+    Window,
     WindowAssigner,
 )
 
@@ -159,15 +163,27 @@ class MergingWindowSet:
     kept as the merge target and the others' state is folded into it —
     so state never has to be re-namespaced (ref: MergingWindowSet.java:54)."""
 
-    def __init__(self, mapping_state):
+    def __init__(self, mapping_state, window_type=None):
         #: ValueState holding {window_namespace: state_window_namespace}
+        #: (plain tuples: of all a job's long-lived objects the open
+        #: sessions' mappings are the most, and tuples of ints are none
+        #: of the cyclic collector's business); a snapshot from before
+        #: the mappings were persisted so holds the windows themselves
         self._mapping_state = mapping_state
         m = mapping_state.value()
-        self.mapping: dict = dict(m) if m else {}
+        self.mapping: dict = {}
+        if m:
+            if window_type is None or isinstance(next(iter(m)), Window):
+                self.mapping = dict(m)
+            else:
+                make = window_type.from_namespace
+                self.mapping = {make(w): make(sw) for w, sw in m.items()}
 
     def persist(self) -> None:
         if self.mapping:
-            self._mapping_state.update(dict(self.mapping))
+            self._mapping_state.update(
+                {w.to_namespace(): sw.to_namespace()
+                 for w, sw in self.mapping.items()})
         else:
             self._mapping_state.clear()
 
@@ -222,6 +238,275 @@ class MergingWindowSet:
                 merge_callback(merge_result, merged_windows,
                                state_window_result, merged_state_windows)
         return merge_result
+
+
+class SessionBatchPlan:
+    """:class:`MergingWindowSet` worked for a whole batch: what the
+    per-row path does to the window -> state-window mappings of the
+    batch's keys, without touching state or timers, so that the
+    caller can write the rows with one ``add_batch``, fold the merged
+    state windows with one ``merge_namespaces_batch`` and move the
+    timers in bulk.  Windows are ``(start, end)`` tuples here.
+
+    The rows are sorted by (key, timestamp), stably, and cut into runs
+    where the key changes or two timestamps lie more than `gap` apart
+    (``TimeWindow.intersects`` is inclusive: abutting windows merge);
+    a run is the proto-session ``[first ts, last ts + gap)``.  A run
+    goes into its key's mapping as ONE step where that provably equals
+    its rows going in one at a time:
+
+    - the key's rows arrived in timestamp order (so the run's rows
+      came one after the other, each intersecting the window the rows
+      before it made), and
+    - the run touches no open window, or exactly one and that one
+      intersects the run's FIRST proto-window (so every row merges
+      into the same growing window, whose state window is the target
+      from the first row on).
+
+    Every other row goes in by itself, in arrival order: a key whose
+    rows arrived out of order (its session may grow at the front, and
+    a row may bridge two windows that rows after it made), a run that
+    bridges two open windows (their state windows merge, and which
+    one survives depends on the mapping's order at that row), a run
+    whose first rows are late.  A step is ``add_window`` on tuples:
+    the windows it intersects leave the mapping, their cover enters it
+    at the END under the state window of the first of them in the
+    mapping's order.
+
+    What the per-row path does and this plan leaves out is what a
+    later row of the batch undoes: a state window that is made and
+    merged away inside the batch never gets a slot (its rows are
+    written under the state window that survives), a timer that is
+    registered and deleted inside the batch is never registered.  A
+    surviving window's timer is registered in the place of the LAST
+    row that changed the window, which is where the per-row path
+    registered the one that survives (``EventTimeTrigger.on_merge``
+    registers the merged window's timer anew at every change)."""
+
+    __slots__ = ("gap", "watermark", "unit_sw", "unit_of_row", "merges",
+                 "deleted", "registered", "changed_keys", "changed_maps",
+                 "runs", "opened", "extended", "merged")
+
+    def __init__(self, gap: int, watermark: int):
+        self.gap = gap
+        self.watermark = watermark
+        #: unit (a run, or a row that went in by itself) -> state
+        #: window, None where the rows are late
+        self.unit_sw: list = []
+        #: int64[n]: the unit of every row
+        self.unit_of_row = None
+        #: (key, target state window, [source state windows]) of the
+        #: merges of two state windows that hold state from before
+        self.merges: list = []
+        #: timers to delete: (window, key); to register: (row that
+        #: places it, window, key)
+        self.deleted: list = []
+        self.registered: list = []
+        #: keys whose mapping changed (or was re-ordered), with it
+        self.changed_keys: list = []
+        self.changed_maps: list = []
+        self.runs = self.opened = self.extended = self.merged = 0
+
+    def state_windows(self) -> np.ndarray:
+        """object[n]: every row's state window, None for a late row."""
+        from flink_tpu.state.slot_index import object_column
+        units = object_column(self.unit_sw, len(self.unit_sw))
+        return units[self.unit_of_row]
+
+    def run(self, keys: list, ts: np.ndarray, load) -> "SessionBatchPlan":
+        """Plan the batch; ``load(distinct keys)`` returns each key's
+        stored mapping ``{(start, end): (start, end)}`` or None."""
+        n = len(keys)
+        gap = self.gap
+        # rows by (key, timestamp), stably; a key's code is the row it
+        # first appeared in, so keys sort in order of first appearance
+        # and `distinct` lists them in that order
+        codebook: dict = {}
+        codes = np.fromiter(
+            map(codebook.setdefault, keys, itertools.count()), np.int64, n)
+        distinct = list(codebook)
+        order = np.lexsort((ts, codes))
+        sorted_ts = ts[order]
+        new_key = np.ones(n, bool)
+        new_key[1:] = np.diff(codes[order]) != 0
+        cut = new_key.copy()
+        cut[1:] |= np.diff(sorted_ts) > gap
+        run_first = np.flatnonzero(cut)
+        run_end = np.append(run_first[1:], n)
+        nruns = self.runs = len(run_first)
+        self.unit_of_row = np.empty(n, np.int64)
+        self.unit_of_row[order] = np.cumsum(cut) - 1
+        self.unit_sw = [None] * nruns
+        # keys with a row that arrived before a row of an earlier
+        # timestamp: all their rows go in one at a time
+        stepped_back = np.zeros(n, bool)
+        stepped_back[1:] = (np.diff(order) < 0) & ~new_key[1:]
+        key_of_sorted = np.cumsum(new_key) - 1
+        unordered = np.zeros(len(distinct), bool)
+        unordered[key_of_sorted[stepped_back]] = True
+        # the row that places a run's timer where its window's end
+        # moves last: the first to carry the run's last timestamp
+        group = cut.copy()
+        group[1:] |= np.diff(sorted_ts) != 0
+        group_first = np.flatnonzero(group)
+        end_row = order[group_first[
+            np.searchsorted(group_first, run_end - 1, side="right") - 1]]
+        key_first = np.flatnonzero(new_key)
+        key_runs = np.searchsorted(run_first, np.append(key_first, n))
+        first_ts = sorted_ts[run_first].tolist()
+        last_ts = sorted_ts[run_end - 1].tolist()
+        start_row = order[run_first].tolist()
+        end_row = end_row.tolist()
+        run_first, run_end = run_first.tolist(), run_end.tolist()
+        key_runs = key_runs.tolist()
+        unordered = unordered.tolist()
+        rows_by_themselves: list = []  # (row, unit)
+        for j, (key, stored) in enumerate(zip(distinct, load(distinct))):
+            state = _KeySessions(self, key, stored)
+            lo, hi = key_runs[j], key_runs[j + 1]
+            if unordered[j]:
+                rows = np.sort(order[run_first[lo]:run_end[hi - 1]]).tolist()
+                for i, t in zip(rows, ts[rows].tolist()):
+                    rows_by_themselves.append((i, state.row(i, t)))
+            else:
+                for r in range(lo, hi):
+                    if state.whole_run(r, first_ts[r], last_ts[r],
+                                       run_end[r] - run_first[r],
+                                       start_row[r], end_row[r]):
+                        continue
+                    rows = order[run_first[r]:run_end[r]]
+                    for i, t in zip(rows.tolist(), ts[rows].tolist()):
+                        rows_by_themselves.append((i, state.row(i, t)))
+            state.close()
+        if rows_by_themselves:
+            rows, units = zip(*rows_by_themselves)
+            self.unit_of_row[list(rows)] = units
+        self.registered.sort(key=operator.itemgetter(0))
+        return self
+
+
+class _KeySessions:
+    """One key's working mapping inside a :class:`SessionBatchPlan`."""
+
+    __slots__ = ("plan", "key", "stored", "mapping", "alias", "placed",
+                 "units")
+
+    def __init__(self, plan: SessionBatchPlan, key, stored):
+        self.plan = plan
+        self.key = key
+        self.stored = stored or {}
+        #: the working copy, made at the first change
+        self.mapping = None
+        #: state window merged away -> the one it was folded into
+        self.alias = None
+        #: window made or changed here -> the row that places its timer
+        self.placed: dict = {}
+        #: the units of this key's rows
+        self.units: list = []
+
+    def _step(self, first: int, end: int, proto_end: int, start_row: int,
+              end_row: int, rows: int, whole_run: bool, unit: int) -> bool:
+        """``add_window`` of the span ``[first, end)`` whose first
+        proto-window ends at `proto_end`; `end_row` is the row that
+        moves a window's end where the span does, `start_row` the one
+        that moves its start.  False (and nothing done) where a
+        `whole_run` may not go in as one step."""
+        plan = self.plan
+        mapping = self.mapping if self.mapping is not None else self.stored
+        hits = [w for w in mapping if w[0] <= end and w[1] >= first]
+        if not hits:
+            if proto_end - 1 <= plan.watermark:
+                if whole_run:
+                    return False  # its first rows are late: row by row
+                return True       # late, and opens nothing
+            window, target = (first, end), (first, proto_end)
+            plan.opened += 1
+            plan.extended += rows - 1
+        else:
+            if whole_run and (len(hits) > 1 or hits[0][0] > proto_end):
+                return False
+            hit = hits[0]
+            target = mapping[hit]
+            if len(hits) == 1:
+                hit_start, hit_end = hit
+            else:
+                hit_start = min(w[0] for w in hits)
+                hit_end = max(w[1] for w in hits)
+            window = (first if first < hit_start else hit_start,
+                      end if end > hit_end else hit_end)
+            if end <= hit_end:
+                end_row = start_row  # the span moves a start at most
+            plan.extended += rows
+            plan.merged += len(hits) - 1
+        if self.mapping is None:
+            mapping = self.mapping = dict(self.stored)
+        for w in hits:
+            source = mapping.pop(w)
+            if source != target:
+                if self.alias is None:
+                    self.alias = {}
+                self.alias[source] = target
+        mapping[window] = target
+        if len(hits) != 1 or hits[0] != window:
+            # new, or grown: the per-row path registers its timer anew
+            # at the last row that changes it
+            self.placed[window] = end_row
+        plan.unit_sw[unit] = target
+        self.units.append(unit)
+        return True
+
+    def whole_run(self, unit: int, first_ts: int, last_ts: int, rows: int,
+                  start_row: int, end_row: int) -> bool:
+        gap = self.plan.gap
+        return self._step(first_ts, last_ts + gap, first_ts + gap,
+                          start_row, end_row, rows, True, unit)
+
+    def row(self, i: int, t: int) -> int:
+        """Row `i` by itself, as a unit of its own; returns the unit."""
+        plan = self.plan
+        unit = len(plan.unit_sw)
+        plan.unit_sw.append(None)
+        self._step(t, t + plan.gap, t + plan.gap, i, i, 1, False, unit)
+        return unit
+
+    def close(self) -> None:
+        """The key's rows are in: its merges, its timers, its mapping."""
+        mapping = self.mapping
+        if mapping is None:
+            return  # every row late
+        plan, key, stored = self.plan, self.key, self.stored
+        alias = self.alias
+        if alias:
+            def survivor(sw):
+                while sw in alias:
+                    sw = alias[sw]
+                return sw
+            held = set(stored.values())  # state windows that hold state
+            sources: dict = {}
+            for sw in alias:
+                if sw in held:
+                    sources.setdefault(survivor(sw), []).append(sw)
+            plan.merges.extend((key, target, merged)
+                               for target, merged in sources.items())
+            unit_sw = plan.unit_sw
+            for unit in self.units:
+                unit_sw[unit] = survivor(unit_sw[unit])
+        placed = self.placed
+        if len(stored) == 1 and len(mapping) == 1:
+            # the usual key: one open session, which grew or did not
+            (old,), (new,) = stored, mapping
+            if old != new:
+                plan.deleted.append((old, key))
+                plan.registered.append((placed[new], new, key))
+        else:
+            for w in stored:
+                if w not in mapping:
+                    plan.deleted.append((w, key))
+            for w in mapping:
+                if w not in stored:
+                    plan.registered.append((placed[w], w, key))
+        plan.changed_keys.append(key)
+        plan.changed_maps.append(mapping)
 
 
 # ---------------------------------------------------------------------
@@ -488,16 +773,25 @@ class WindowOperator(AbstractUdfStreamOperator):
         #: sliding assigner of size 10 x slide reads 10 rows per event
         self.windows_touched = 0
         self.window_rows = 0
+        #: the batched session ingest: rows that opened a session, rows
+        #: that joined an open one, windows a row's merge swallowed
+        #: beyond the first it joined
+        self.sessions_opened = 0
+        self.sessions_extended = 0
+        self.session_windows_merged = 0
 
     # ---- lifecycle --------------------------------------------------
     def open(self):
         super().open()
-        # structural demotions, known AOT: merging assigners, custom
-        # triggers and evictors are inherently per-row; plain
-        # tumbling/sliding event-time windows with their default
-        # trigger take the vectorized process_batch path (the
-        # columnar.ratio gauge and linter FT184 surface the reason)
+        # structural demotions, known AOT: custom triggers and
+        # evictors are inherently per-row; plain tumbling/sliding
+        # event-time windows and static-gap event-time sessions with
+        # their default trigger take the vectorized process_batch path
+        # (the columnar.ratio gauge and linter FT184 surface the reason)
         self._batch_demote_reason = self._batch_eligibility()
+        #: the batched MergingWindowSet is this operator's batch path
+        self._session_batches = (self._batch_demote_reason is None
+                                 and self.assigner.is_merging())
         self.columnar_fallback_reason = self._batch_demote_reason
         self._emit_batch_hist = None
         if self.metrics is not None:
@@ -571,7 +865,7 @@ class WindowOperator(AbstractUdfStreamOperator):
         or None when process_batch can vectorize.  Called at open();
         uses only constructor state."""
         if self.assigner.is_merging():
-            return "merging window assigner is per-row"
+            return self._session_batch_eligibility()
         if not isinstance(self.assigner,
                           (TumblingEventTimeWindows, SlidingEventTimeWindows)):
             return (f"no vectorized assignment for "
@@ -580,6 +874,56 @@ class WindowOperator(AbstractUdfStreamOperator):
             return (f"custom trigger {type(self.trigger).__name__} "
                     f"is per-row")
         return None
+
+    def _session_batch_eligibility(self) -> Optional[str]:
+        """A merging assigner takes batches as event-time sessions of
+        a static gap under the default trigger with no lateness, over
+        pre-aggregated state: the shape `SessionBatchPlan` is exact
+        for.  A dynamic gap, processing time, a custom trigger (it
+        may fire per element, or keep state per window), lateness (a
+        merged window may be due and fire row by row) and raw element
+        lists (a merge concatenates them in the order the rows came)
+        keep the per-row path."""
+        if type(self.assigner) is not EventTimeSessionWindows:
+            return "merging window assigner is per-row"
+        if type(self.trigger) is not EventTimeTrigger:
+            return (f"custom trigger {type(self.trigger).__name__} "
+                    f"is per-row")
+        if self.allowed_lateness:
+            return "session windows with allowed lateness are per-row"
+        if not isinstance(self.state_descriptor,
+                          (ReducingStateDescriptor,
+                           AggregatingStateDescriptor)):
+            return "session windows over raw elements are per-row"
+        return None
+
+    def _value_column(self, batch, n: int):
+        """The value column for a device state whose aggregate
+        extracts by column: it feeds the scatter as it is
+        (``pre_extracted=True``).  None where the rows have to be
+        boxed."""
+        agg = getattr(self.window_state, "agg", None)
+        if agg is not None and hasattr(agg, "extract_column"):
+            c = agg.extract_column(batch.value_arrays())
+            if isinstance(c, np.ndarray) and c.ndim == 1 and len(c) == n:
+                return c
+        return None
+
+    def _drop_late(self, batch, values, ts, late: np.ndarray) -> None:
+        """Rows `late` (indexes, in row order) of a batch are late: to
+        the late side output, or counted, as process_element does."""
+        if self.late_data_tag is not None:
+            if values is None:
+                values = batch.row_values()
+            tlist = ts.tolist()
+            for i in late.tolist():
+                self.output.collect_side(
+                    self.late_data_tag, StreamRecord(values[i], tlist[i]))
+        else:
+            cnt = int(late.size)
+            self.num_late_records_dropped += cnt
+            if self.metrics is not None:
+                self.metrics.counter("numLateRecordsDropped").inc(cnt)
 
     def _batch_keys(self, batch, values) -> list:
         """Key column for a batch as a python list — bit-identical to
@@ -625,7 +969,10 @@ class WindowOperator(AbstractUdfStreamOperator):
                     self.set_key_context(record)
                     self.process_element(record)
                 return
-            self._process_batch_vectorized(batch, n)
+            if self._session_batches:
+                self._process_batch_sessions(batch, n)
+            else:
+                self._process_batch_vectorized(batch, n)
             self._note_columnar(n)
 
     def process_batch_fused(self, batch, last_start=None) -> None:
@@ -640,6 +987,7 @@ class WindowOperator(AbstractUdfStreamOperator):
             return
         if (last_start is None
                 or self._batch_demote_reason is not None
+                or self._session_batches
                 or batch.ts is None
                 or (batch.ts_mask is not None and not batch.ts_mask.all())
                 or self.key_selector is None):
@@ -664,14 +1012,7 @@ class WindowOperator(AbstractUdfStreamOperator):
             wm = self.timer_service.current_watermark
             slide = getattr(self.assigner, "slide", size)
             offset = self.assigner.offset
-            # value column for device states: the aggregate's extract
-            # is identity, so the raw column feeds the scatter directly
-            vcol = None
-            agg = getattr(state, "agg", None)
-            if agg is not None and hasattr(agg, "extract_column"):
-                c = agg.extract_column(batch.value_arrays())
-                if isinstance(c, np.ndarray) and c.ndim == 1 and len(c) == n:
-                    vcol = c
+            vcol = self._value_column(batch, n)
             if last_start is None:
                 last_start = ts - ((ts - offset) % slide)
             else:
@@ -751,17 +1092,129 @@ class WindowOperator(AbstractUdfStreamOperator):
                 self._replay_immediate(values[i], tlist[i], wm)
         dropped = ~assigned & ~immediate & ((ts + lateness) <= wm)
         if dropped.any():
-            if self.late_data_tag is not None:
-                tlist = ts.tolist()
-                for i in np.nonzero(dropped)[0]:
-                    self.output.collect_side(
-                        self.late_data_tag,
-                        StreamRecord(values[i], tlist[i]))
+            self._drop_late(batch, values, ts, np.flatnonzero(dropped))
+
+    # ---- batch path, session windows ---------------------------------
+    def _mapping_state(self):
+        from flink_tpu.state.backend import VOID_NAMESPACE
+        return self.keyed_backend.get_partitioned_state(
+            VOID_NAMESPACE, self._mapping_desc)
+
+    def _load_mappings(self, keys: list) -> list:
+        """The stored window -> state-window mappings of `keys`, as
+        `MergingWindowSet.persist` writes them (``{(start, end):
+        (start, end)}``), None where a key has none.  The caller
+        copies before it changes one."""
+        state = self._mapping_state()
+        if hasattr(state, "values_batch"):
+            stored = state.values_batch(keys)
+        else:
+            stored = []
+            for key in keys:
+                self.keyed_backend.set_current_key(key)
+                stored.append(state.value())
+        # (a snapshot from before PR 37 holds the windows themselves)
+        return [m if not m or type(next(iter(m))) is tuple else
+                {w.to_namespace(): sw.to_namespace() for w, sw in m.items()}
+                for m in stored]
+
+    def _store_mappings(self, keys: list, mappings: list) -> None:
+        """`MergingWindowSet.persist` for many keys: a mapping that is
+        empty clears its key's."""
+        state = self._mapping_state()
+        mappings = [m or None for m in mappings]
+        if hasattr(state, "update_batch"):
+            state.update_batch(keys, mappings)
+            return
+        for key, mapping in zip(keys, mappings):
+            self.keyed_backend.set_current_key(key)
+            state.update(mapping)
+
+    def _process_batch_sessions(self, batch, n: int) -> None:
+        """Columnar ingest of event-time session windows: the batch's
+        rows go through `SessionBatchPlan` (the per-row path's
+        MergingWindowSet, worked for the batch), then ONE
+        ``merge_namespaces_batch`` for the state windows that merged,
+        ONE ``add_batch`` with every row's state window as its
+        namespace, the timers moved in bulk, the mappings persisted.
+
+        Exactness: the watermark is fixed for the batch; with allowed
+        lateness 0 a merged window that is due is late, so no row
+        fires on arrival and a row either joins a live session or is
+        dropped exactly where the per-row path drops it (the plan
+        decides that row by row where it matters).  The plan's
+        mappings, dict order included, and its surviving timers, in
+        the order of the rows that place them, are the per-row path's;
+        state is written under the state window that SURVIVES the
+        batch, so the rows of a state window the per-row path makes
+        and merges away reach the same accumulator without the detour.
+        That is the same state where the aggregate's merge is exact
+        (associative and commutative, as a sketch's or an integer
+        sum's is); a float sum may differ in its last bit, as it may
+        between two arrival orders.  tests/test_session_batch.py pins
+        the two paths bit-equal."""
+        tracer = get_tracer()
+        ts = np.asarray(batch.ts, np.int64)
+        state = self.window_state
+        backend = self.keyed_backend
+        values = None
+        with tracer.phase("window.ingest.box"):
+            from flink_tpu.streaming.columnar import field_key_column
+            col = field_key_column(self.key_selector, batch)
+            if col is not None:
+                keys = col.tolist()
             else:
-                cnt = int(dropped.sum())
-                self.num_late_records_dropped += cnt
-                if self.metrics is not None:
-                    self.metrics.counter("numLateRecordsDropped").inc(cnt)
+                values = batch.row_values()
+                keys = [self.key_selector.get_key(v) for v in values]
+            vcol = self._value_column(batch, n)
+            if vcol is None and values is None:
+                values = batch.row_values()
+        wm = self.timer_service.current_watermark
+        with tracer.phase("window.ingest.sessions", rows=n) as phase:
+            plan = SessionBatchPlan(self.assigner.gap, wm).run(
+                keys, ts, self._load_mappings)
+            namespaces = plan.state_windows()
+            self._store_mappings(plan.changed_keys, plan.changed_maps)
+            for name, count in (("runs", plan.runs),
+                                ("opened", plan.opened),
+                                ("extended", plan.extended),
+                                ("merged", plan.merged)):
+                phase.set_attr(name, count)
+            self.sessions_opened += plan.opened
+            self.sessions_extended += plan.extended
+            self.session_windows_merged += plan.merged
+            late = np.flatnonzero(namespaces == None)  # noqa: E711
+            if late.size:
+                keep = np.flatnonzero(namespaces != None)  # noqa: E711
+                namespaces = namespaces[keep]
+                keys = [keys[i] for i in keep.tolist()]
+                if vcol is not None:
+                    vcol = vcol[keep]
+            namespaces = namespaces.tolist()
+        if plan.merges:
+            backend.merge_namespaces_batch(state, plan.merges)
+        if keys:
+            if vcol is not None:
+                backend.add_batch(state, keys, None, vcol,
+                                  namespaces=namespaces, pre_extracted=True)
+            else:
+                rows = values if not late.size else \
+                    [values[i] for i in keep.tolist()]
+                backend.add_batch(state, keys, None, rows,
+                                  namespaces=namespaces)
+        svc = self.timer_service
+        if plan.deleted:
+            with tracer.phase("timers.delete", keys=len(plan.deleted)):
+                svc.delete_event_time_timers_rows(
+                    (w, w[1] - 1, key) for w, key in plan.deleted)
+        if plan.registered:
+            with tracer.phase("timers.register", keys=len(plan.registered)):
+                # the trigger's timer; with lateness 0 the cleanup
+                # timer is the same one
+                svc.register_event_time_timers_rows(
+                    (w, w[1] - 1, key) for _, w, key in plan.registered)
+        if late.size:
+            self._drop_late(batch, values, ts, late)
 
     def _replay_immediate(self, value, timestamp: int, wm: int) -> None:
         """Scalar replay for a row with >= 1 window already past the
@@ -793,7 +1246,8 @@ class WindowOperator(AbstractUdfStreamOperator):
         from flink_tpu.state.backend import VOID_NAMESPACE
         mapping_state = self.keyed_backend.get_partitioned_state(
             VOID_NAMESPACE, self._mapping_desc)
-        merging = MergingWindowSet(mapping_state)
+        merging = MergingWindowSet(mapping_state,
+                                   self.assigner.window_type())
 
         def on_merge(merge_result, merged_windows, state_window, merged_state_windows):
             # fold merged state windows into the surviving one
@@ -849,7 +1303,8 @@ class WindowOperator(AbstractUdfStreamOperator):
             from flink_tpu.state.backend import VOID_NAMESPACE
             mapping_state = self.keyed_backend.get_partitioned_state(
                 VOID_NAMESPACE, self._mapping_desc)
-            merging = MergingWindowSet(mapping_state)
+            merging = MergingWindowSet(mapping_state,
+                                       self.assigner.window_type())
             state_window = merging.get_state_window(window)
             if state_window is None:
                 return  # window was merged away; timer is stale
@@ -878,7 +1333,8 @@ class WindowOperator(AbstractUdfStreamOperator):
             from flink_tpu.state.backend import VOID_NAMESPACE
             mapping_state = self.keyed_backend.get_partitioned_state(
                 VOID_NAMESPACE, self._mapping_desc)
-            merging = MergingWindowSet(mapping_state)
+            merging = MergingWindowSet(mapping_state,
+                                       self.assigner.window_type())
             state_window = merging.get_state_window(window)
             if state_window is None:
                 return
@@ -922,7 +1378,10 @@ class WindowOperator(AbstractUdfStreamOperator):
                 super().process_watermark(watermark)
                 return
             self.current_watermark = watermark.timestamp
-            self.on_watermark_batch(watermark.timestamp)
+            if self._session_batches:
+                self.on_watermark_sessions(watermark.timestamp)
+            else:
+                self.on_watermark_batch(watermark.timestamp)
             self.output.emit_watermark(watermark)
 
     def on_watermark_batch(self, watermark: int) -> None:
@@ -1000,6 +1459,77 @@ class WindowOperator(AbstractUdfStreamOperator):
                     for key in keys:
                         backend.set_current_key(key)
                         self._internal_fn.clear(key, window, self)
+
+    def on_watermark_sessions(self, watermark: int) -> None:
+        """Columnar fire of session windows: ONE timer sweep, each
+        swept (window, key) mapped to its state window, ONE gather
+        under the state windows, the emit with each row's WINDOW, ONE
+        clear, the fired windows retired from their keys' mappings.
+
+        Exactness vs the per-timer loop: as `on_watermark_batch` (the
+        default EventTimeTrigger writes no state and registers no
+        timer from on_event_time; with lateness 0 the one timer of a
+        window fires and cleans; distinct (key, state window) slots
+        are independent, so gathering all before the one clear reads
+        what the interleaved drain read, in the same order).  A timer
+        whose window is not in its key's mapping is stale, as there
+        (``on_event_time`` returns at once): the ingest deletes the
+        timer of every window it merges away, so none is expected.
+        Retiring a window touches only its own key's mapping, and a
+        key's windows are retired in the order they fired."""
+        svc = self.timer_service
+        tracer = get_tracer()
+        with tracer.phase("timers.sweep") as phase:
+            runs = svc.pop_due_event_time_timers(watermark)
+            n = sum(len(keys) for _, _, keys in runs)
+            phase.set_attr("timers", n)
+            phase.set_attr("runs", len(runs))
+        if not runs:
+            return
+        self.timers_swept += n
+        self.timer_runs += len(runs)
+        with tracer.phase("window.fire.sessions", timers=n):
+            keys, window, windows = _run_columns(
+                [(ns, ks) for _, ns, ks in runs])
+            if windows is None:
+                windows = [window] * len(keys)
+            distinct = list(dict.fromkeys(keys))
+            held = dict(zip(distinct, self._load_mappings(distinct)))
+            fired_keys, fired_windows, state_windows = [], [], []
+            for key, ns in zip(keys, windows):
+                mapping = held[key]
+                sw = mapping.get(ns) if mapping else None
+                if sw is not None:  # else stale: merged away
+                    fired_keys.append(key)
+                    fired_windows.append(ns)
+                    state_windows.append(sw)
+        if not fired_keys:
+            return
+        tracer.note_fire(self.operator_id or type(self).__name__,
+                         len(fired_keys), len(fired_keys),
+                         max(ns[1] for ns in fired_windows))
+        backend = self.keyed_backend
+        contents_col, found_mask, _path = backend.get_batch(
+            self.window_state, fired_keys, None, namespaces=state_windows)
+        emitted = self._emit_fired_columns(
+            fired_keys, None, fired_windows, contents_col, found_mask)
+        if TELEMETRY.enabled and emitted:
+            TELEMETRY.note_windows_fired(emitted)
+        backend.clear_batch(self.window_state, fired_keys, None,
+                            namespaces=state_windows)
+        with tracer.phase("window.fire.sessions"):
+            retired: dict = {}
+            for key, ns in zip(fired_keys, fired_windows):
+                mapping = retired.get(key)
+                if mapping is None:
+                    mapping = retired[key] = dict(held[key])
+                del mapping[ns]
+            self._store_mappings(list(retired), list(retired.values()))
+        if isinstance(self._internal_fn.fn, ProcessWindowFunction):
+            from_namespace = self.assigner.window_type().from_namespace
+            for key, ns in zip(fired_keys, fired_windows):
+                backend.set_current_key(key)
+                self._internal_fn.clear(key, from_namespace(ns), self)
 
     def _emit_fired_columns(self, keys, namespace, namespaces, contents_col,
                             found_mask) -> int:

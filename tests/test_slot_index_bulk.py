@@ -27,6 +27,7 @@ from flink_tpu.streaming.columnar import RecordBatch
 from flink_tpu.streaming.harness import OneInputStreamOperatorTestHarness
 from flink_tpu.streaming.window_operator import WindowOperator
 from flink_tpu.streaming.windowing import (
+    DynamicEventTimeSessionWindows,
     EventTimeSessionWindows,
     TumblingEventTimeWindows,
 )
@@ -486,10 +487,15 @@ def test_phase_attributes_say_rows_and_new_slots():
     assert seen == [(4, 3), (2, 1)]
 
 
-def test_a_session_window_job_stays_on_the_per_key_door():
-    """Sessions merge namespaces row by row: every row of theirs is a
-    per-key probe, none a bulk one."""
-    op, h = _window_job(EventTimeSessionWindows.with_gap(100), "tpu")
+@pytest.mark.parametrize("gap", ["static", "per_element"])
+def test_a_session_window_job_stays_on_the_per_key_door(gap):
+    """Sessions with a gap per element merge namespaces row by row:
+    every row of theirs is a per-key probe, none a bulk one.  Sessions
+    of a static gap work their merges per batch since PR 37: every row
+    a bulk probe under its state window, none per key."""
+    assigner = EventTimeSessionWindows.with_gap(100) if gap == "static" \
+        else DynamicEventTimeSessionWindows(lambda v: 100)
+    op, h = _window_job(assigner, "tpu")
     STATE_STATS.reset()
     rng = np.random.default_rng(5)
     keys = rng.integers(0, 20, 300)
@@ -497,8 +503,14 @@ def test_a_session_window_job_stays_on_the_per_key_door():
     ts = np.sort(rng.integers(0, 5000, 300))
     h.process_batch(RecordBatch({"f0": keys, "f1": users}, ts=ts))
     h.process_watermark(10 ** 9)
-    assert len(h.get_output()) > 20
-    assert STATE_STATS.bulk_probe_rows == 0
-    assert STATE_STATS.per_key_probe_rows >= 300
+    fired = len(h.get_output())
+    assert fired > 20
+    if gap == "static":
+        assert STATE_STATS.per_key_probe_rows == 0
+        assert STATE_STATS.bulk_probe_rows == 300 + 2 * fired
+        assert op.boxed_fallbacks == 0
+    else:
+        assert STATE_STATS.bulk_probe_rows == 0
+        assert STATE_STATS.per_key_probe_rows >= 300
     assert not op.window_state.slot_index
     check_invariants(op.window_state)

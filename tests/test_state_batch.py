@@ -35,6 +35,7 @@ from flink_tpu.streaming.window_operator import (
 from flink_tpu.streaming.windowing import (
     CountEvictor,
     CountTrigger,
+    DynamicEventTimeSessionWindows,
     EventTimeSessionWindows,
     SlidingEventTimeWindows,
     TumblingEventTimeWindows,
@@ -264,9 +265,18 @@ def test_window_batch_demotions_and_eligibility():
     mode, reason = operator_batch_report(native)
     assert mode == NATIVE and native._batch_eligibility() is None
 
+    # event-time sessions of a static gap over pre-aggregated state
+    # take batches (PR 37); over raw elements, or with a gap per
+    # element, they stay per-row and say why
+    session = _window_op(EventTimeSessionWindows.with_gap(100))
+    mode, reason = operator_batch_report(session)
+    assert mode == NATIVE and session._batch_eligibility() is None
     session = WindowOperator(
         EventTimeSessionWindows.with_gap(100),
         ListStateDescriptor("w"), window_function=fn)
+    mode, reason = operator_batch_report(session)
+    assert mode == BOXED and "raw elements are per-row" in reason
+    session = _window_op(DynamicEventTimeSessionWindows(lambda v: 100))
     mode, reason = operator_batch_report(session)
     assert mode == BOXED and "merging" in reason
 
@@ -294,12 +304,26 @@ def test_window_batch_demotions_and_eligibility():
 def test_window_batch_demoted_path_still_correct(backend):
     """A demoted operator consumes batches through the boxed loop —
     same output as the row path, reason recorded."""
-    a, _, _ = _drive("row", backend, EventTimeSessionWindows.with_gap(400))
+    a, _, _ = _drive("row", backend,
+                     DynamicEventTimeSessionWindows(lambda v: 400))
     b, op_b, _ = _drive("batch", backend,
-                        EventTimeSessionWindows.with_gap(400))
+                        DynamicEventTimeSessionWindows(lambda v: 400))
     assert a == b
     assert op_b.boxed_fallbacks > 0
     assert "merging" in op_b.columnar_fallback_reason
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_window_batch_vs_row_sessions(backend):
+    """Static-gap event-time sessions take the batch path (PR 37): the
+    rows of the row path, no boxed fallback."""
+    a, op_a, _ = _drive("row", backend,
+                        EventTimeSessionWindows.with_gap(400), late_every=17)
+    b, op_b, _ = _drive("batch", backend,
+                        EventTimeSessionWindows.with_gap(400), late_every=17)
+    assert a == b
+    assert op_a.num_late_records_dropped == op_b.num_late_records_dropped > 0
+    assert op_b.boxed_fallbacks == 0 and op_b.columnar_rows == 300
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
