@@ -480,26 +480,41 @@ def timer_run_problems(op, windows, result_rows):
             f"rows in {op.timer_runs} runs, {windows} windows fired"]
 
 
-def hash_counts():
-    return STATE_STATS.hash_column_rows, STATE_STATS.hash_per_value_rows
+#: how the tpu backend took a leg's integer columns: the values hashed
+#: whole, the keys probed on a window's integer table
+COLUMN_COUNTS = ("hash_column_rows", "hash_per_value_rows",
+                 "bulk_probe_rows", "int_table_rows", "int_table_demotions")
 
 
-def hash_column_problems(before, events):
+def column_counts():
+    return tuple(getattr(STATE_STATS, name) for name in COLUMN_COUNTS)
+
+
+def column_problems(before, events):
     """Every event's user must have been hashed as part of its batch's
-    column since `before` (`hash_counts()` then); returns (problems,
-    facts)."""
-    column, per_value = (now - then
-                         for now, then in zip(hash_counts(), before))
-    facts = {"hash_column_rows": column, "hash_per_value_rows": per_value}
-    if column == events and not per_value:
-        return [], facts
-    return [f"hash_column_rows {column} of {events} events, "
-            f"{per_value} hashed a value at a time"], facts
+    column since `before` (`column_counts()` then), and every bulk probe
+    of the slot index (an event's, a fired key's, a cleared key's)
+    must have met an integer table; returns (problems, facts)."""
+    facts = {name: now - then for name, now, then
+             in zip(COLUMN_COUNTS, column_counts(), before)}
+    problems = []
+    if facts["hash_column_rows"] != events or facts["hash_per_value_rows"]:
+        problems.append(
+            f"hash_column_rows {facts['hash_column_rows']} of {events} "
+            f"events, {facts['hash_per_value_rows']} hashed a value at a "
+            f"time")
+    if facts["int_table_rows"] != facts["bulk_probe_rows"] \
+            or facts["int_table_demotions"]:
+        problems.append(
+            f"int_table_rows {facts['int_table_rows']} of "
+            f"{facts['bulk_probe_rows']} bulk-probed rows, "
+            f"{facts['int_table_demotions']} tables became dicts")
+    return problems, facts
 
 
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
-    hashed = hash_counts()
+    counted = column_counts()
     ops, sink = run_window_job("chip-smoke-state-backend", events,
                                UserHll(cfg["precision"]),
                                on_state_backend=True)
@@ -510,8 +525,8 @@ def leg_state_backend(cfg, events, ref):
     problems += boxed_problems(wop, len(keys))
     problems += fire_tail_problems(wop, len(cols[0]))
     problems += timer_run_problems(wop, len(ref), len(cols[0]))
-    hash_problems, hash_facts = hash_column_problems(hashed, len(keys))
-    problems += hash_problems
+    column_faults, column_facts = column_problems(counted, len(keys))
+    problems += column_faults
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -524,7 +539,7 @@ def leg_state_backend(cfg, events, ref):
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
-        "evictions": state.evictions, **hash_facts, **facts}
+        "evictions": state.evictions, **column_facts, **facts}
 
 
 def leg_state_spill(cfg, seed):
@@ -539,7 +554,7 @@ def leg_state_spill(cfg, seed):
                                spill["budget"])
     if spill["microbatch"] is not None:
         conf.set("state.backend.tpu.microbatch-size", spill["microbatch"])
-    hashed = hash_counts()
+    counted = column_counts()
     ops, sink = run_window_job("chip-smoke-state-spill", events,
                                UserHll(cfg["precision"]),
                                on_state_backend=True, configuration=conf)
@@ -549,8 +564,8 @@ def leg_state_spill(cfg, seed):
     problems, facts = check_hll(*cols, ref, cfg["precision"])
     problems += boxed_problems(wop, len(events[0]))
     problems += fire_tail_problems(wop, len(cols[0]))
-    hash_problems, hash_facts = hash_column_problems(hashed, len(events[0]))
-    problems += hash_problems
+    column_faults, column_facts = column_problems(counted, len(events[0]))
+    problems += column_faults
     if state.max_device_slots != spill["budget"]:
         problems.append(f"the backend's budget is {state.max_device_slots}, "
                         f"the Configuration says {spill['budget']}")
@@ -570,7 +585,7 @@ def leg_state_spill(cfg, seed):
         "evictions": state.evictions, "promotions": state.promotions,
         "budget_overruns": state.budget_overruns,
         "live_keys_per_window": [len(k) for k, _ in ref.values()],
-        **hash_facts, **facts}
+        **column_facts, **facts}
 
 
 #: config #3's windows; the slide is the source period too

@@ -41,6 +41,10 @@ _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 _COMPILE = ("g++", "-O3", "-march=native", "-shared", "-fPIC")
 
 _lib: Optional[ctypes.CDLL] = None
+#: the same library through a handle whose calls KEEP the interpreter
+#: lock: the integer table's entry points (`NativeIntTable`), which a
+#: foreign thread may read while its owner inserts
+_gil_lib: Optional[ctypes.PyDLL] = None
 _lib_path: Optional[str] = None
 _load_error: Optional[str] = None
 
@@ -83,7 +87,7 @@ def _build(out_path: str) -> None:
 
 
 def _ensure_loaded() -> Optional[ctypes.CDLL]:
-    global _lib, _lib_path, _load_error
+    global _lib, _gil_lib, _lib_path, _load_error
     if _lib is not None or _load_error is not None:
         return _lib
     try:
@@ -94,13 +98,15 @@ def _ensure_loaded() -> Optional[ctypes.CDLL]:
         if not os.path.exists(path):
             _build(path)
         lib = ctypes.CDLL(path)
+        gil_lib = ctypes.PyDLL(path)
     except (OSError, subprocess.CalledProcessError) as e:
         _load_error = (getattr(e, "stderr", None) or str(e)).strip()
         log.error("native host runtime unavailable (%s): %s",
                   type(e).__name__, _load_error)
         return None
     _declare(lib)
-    _lib, _lib_path = lib, path
+    _declare_int_table(gil_lib)
+    _lib, _gil_lib, _lib_path = lib, gil_lib, path
     return _lib
 
 
@@ -301,6 +307,39 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.ft_ivjoin_prune.argtypes = [c.c_void_p, c.c_int64]
 
 
+def _declare_int_table(lib: ctypes.PyDLL) -> None:
+    """argtypes/restype of the integer table's entry points."""
+    c = ctypes
+    i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    lib.ft_itab_new.argtypes = [c.c_int64]
+    lib.ft_itab_new.restype = c.c_void_p
+    lib.ft_itab_free.argtypes = [c.c_void_p]
+    lib.ft_itab_free.restype = None
+    for name in ("ft_itab_size", "ft_itab_peak"):
+        fn = getattr(lib, name)
+        fn.argtypes = [c.c_void_p]
+        fn.restype = c.c_int64
+    lib.ft_itab_probe.argtypes = [c.c_void_p, i64p, c.c_int64, i64p, i64p]
+    lib.ft_itab_probe.restype = c.c_int64
+    lib.ft_itab_assign.argtypes = [c.c_void_p, i64p, c.c_int64, i64p,
+                                   c.c_int64]
+    lib.ft_itab_assign.restype = None
+    for name in ("ft_itab_lookup", "ft_itab_take"):
+        fn = getattr(lib, name)
+        fn.argtypes = [c.c_void_p, i64p, c.c_int64, i64p]
+        fn.restype = None
+    lib.ft_itab_set.argtypes = [c.c_void_p, i64p, i64p, c.c_int64]
+    lib.ft_itab_set.restype = None
+    lib.ft_itab_export.argtypes = [c.c_void_p, i64p, i64p]
+    lib.ft_itab_export.restype = c.c_int64
+    for name in ("ft_itab_get1", "ft_itab_take1"):
+        fn = getattr(lib, name)
+        fn.argtypes = [c.c_void_p, c.c_int64]
+        fn.restype = c.c_int64
+    lib.ft_itab_set1.argtypes = [c.c_void_p, c.c_int64, c.c_int64]
+    lib.ft_itab_set1.restype = None
+
+
 def available() -> bool:
     return _ensure_loaded() is not None
 
@@ -406,6 +445,89 @@ class NativeSlotIndex:
         slots = np.empty(n, np.int64)
         k = _lib.ft_index_export(self._h, hashes, slots)
         return hashes[:k], slots[:k]
+
+
+class NativeIntTable:
+    """int64 key → int64 id (>= 0), the C++ `FtIntTable`: one
+    namespace's table of the `tpu` state backend's slot index
+    (`state/slot_index.py`).  Every int64 is a key; entries come back
+    from `export` in the order they were entered, as a dict's would;
+    a delete leaves nothing behind.  The caller owns the ids: `probe`
+    enters the new keys of a batch and says which rows brought them,
+    `assign` gives them their ids.  Key columns are int64, contiguous.
+
+    Every call keeps the interpreter lock (the library's second
+    handle, `ctypes.PyDLL`), so a call is as atomic as a dict's:
+    `get` from a foreign thread never meets a table mid-growth.  No
+    call is a `native.<kernel>` of its own in the books: its time is
+    the slot phase's that made it (`state.add.slots`, ...), as the
+    dict's was."""
+
+    __slots__ = ("_h",)
+
+    def __init__(self, room: int = 0) -> None:
+        """`room`: entries it takes before it has to grow."""
+        _ensure_loaded()
+        self._h = _gil_lib.ft_itab_new(room)
+
+    def __del__(self):
+        if _gil_lib is not None and getattr(self, "_h", None):
+            _gil_lib.ft_itab_free(self._h)
+            self._h = None
+
+    def __len__(self) -> int:
+        return _gil_lib.ft_itab_size(self._h)
+
+    def peak(self) -> int:
+        """The most entries it ever held at once."""
+        return _gil_lib.ft_itab_peak(self._h)
+
+    def probe(self, keys: np.ndarray):
+        """Phase one of probe-or-insert: ``(ids, first)``, `ids` the
+        id of every row and `first` the rows that brought a key the
+        table did not hold, in order; the rows of those keys read a
+        negative id until `assign`, which follows at once."""
+        n = len(keys)
+        ids = np.empty(n, np.int64)
+        first = np.empty(n, np.int64)
+        m = _gil_lib.ft_itab_probe(self._h, keys, n, ids, first)
+        return ids, first[:m]
+
+    def assign(self, new_ids: np.ndarray, ids: np.ndarray) -> None:
+        """Phase two: the k-th new key of the probe that returned
+        `ids` gets ``new_ids[k]``, in the table and in `ids`."""
+        _gil_lib.ft_itab_assign(self._h, new_ids, len(new_ids), ids,
+                                len(ids))
+
+    def lookup(self, keys: np.ndarray, take: bool = False) -> np.ndarray:
+        """The ids of `keys`, -1 where the table has none; `take`
+        removes what it finds (a key that comes twice is found once)."""
+        ids = np.empty(len(keys), np.int64)
+        (_gil_lib.ft_itab_take if take else _gil_lib.ft_itab_lookup)(
+            self._h, keys, len(keys), ids)
+        return ids
+
+    def set(self, keys: np.ndarray, ids: np.ndarray) -> None:
+        """``keys[i] → ids[i]``: new keys enter in row order, a key
+        the table holds keeps its place."""
+        _gil_lib.ft_itab_set(self._h, keys, ids, len(keys))
+
+    def export(self):
+        """``(keys, ids)`` of every entry, in the order of entry."""
+        n = len(self)
+        keys = np.empty(n, np.int64)
+        ids = np.empty(n, np.int64)
+        _gil_lib.ft_itab_export(self._h, keys, ids)
+        return keys, ids
+
+    def get(self, key: int) -> int:
+        return _gil_lib.ft_itab_get1(self._h, key)
+
+    def pop(self, key: int) -> int:
+        return _gil_lib.ft_itab_take1(self._h, key)
+
+    def put(self, key: int, id_: int) -> None:
+        _gil_lib.ft_itab_set1(self._h, key, id_)
 
 
 # ---- log-structured window engine kernels ---------------------------------

@@ -132,19 +132,24 @@ class _NullSpan:
     def set_attr(self, key: str, value: Any) -> None:
         pass
 
+    def add_count(self, key: str, n: int) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "attrs", "start_ns", "child_ns",
-                 "parent", "compile_ns", "compiles", "gc_ns", "gcs",
-                 "gc_under_ns", "native_ns")
+    __slots__ = ("tracer", "name", "attrs", "counts", "start_ns",
+                 "child_ns", "parent", "compile_ns", "compiles", "gc_ns",
+                 "gcs", "gc_under_ns", "native_ns")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        #: the attributes that are counts the books sum (`add_count`)
+        self.counts: Optional[dict] = None
         self.child_ns = 0
         self.parent: Optional[_Span] = None
         #: backend compiles that ran while this span was the innermost
@@ -166,6 +171,16 @@ class _Span:
         if self.attrs is None:
             self.attrs = {}
         self.attrs[key] = value
+
+    def add_count(self, key: str, n: int) -> None:
+        """An attribute that counts the span's work (rows, keys): on
+        the event like any other, and summed per span name in the
+        books (``stats()[name]["counts"]``, and its growth in every
+        fire period)."""
+        self.set_attr(key, n)
+        if self.counts is None:
+            self.counts = {}
+        self.counts[key] = n
 
     def __enter__(self):
         stack = self.tracer._stack()
@@ -214,7 +229,8 @@ class _Phase(_Span):
 
 class _SpanStat:
     __slots__ = ("count", "total_ms", "self_ms", "compile_ms", "compiles",
-                 "gc_ms", "gcs", "gc_under_ms", "native_ms", "reservoir")
+                 "gc_ms", "gcs", "gc_under_ms", "native_ms", "counts",
+                 "reservoir")
     #: what stats() gives of a name beside the percentiles, and what a
     #: period holds of it: the growth of these
     CUT = ("count", "total_ms", "self_ms", "gc_ms", "gcs", "gc_under_ms",
@@ -230,7 +246,17 @@ class _SpanStat:
         self.gcs = 0
         self.gc_under_ms = 0.0
         self.native_ms = 0.0
+        #: Σ of what the spans counted (`_Span.add_count`), by key
+        self.counts: Dict[str, int] = {}
         self.reservoir = _Reservoir()
+
+    def cut(self) -> dict:
+        """What stats() gives of a name beside the percentiles, and
+        what a period holds the growth of."""
+        out = {f: getattr(self, f) for f in self.CUT}
+        if self.counts:
+            out["counts"] = dict(self.counts)
+        return out
 
 
 class _GcBooks:
@@ -405,6 +431,9 @@ class Tracer:
                 stat.gc_under_ms += span.gc_under_ns / 1e6
             if span.native_ns:
                 stat.native_ms += span.native_ns / 1e6
+            if span.counts:
+                for key, n in span.counts.items():
+                    stat.counts[key] = stat.counts.get(key, 0) + n
             stat.reservoir.update(total_ms)
 
     # ---- fire periods -------------------------------------------------
@@ -437,8 +466,7 @@ class Tracer:
             kernels = {name: st.total_ms
                        for name, st in _kernel_stats.items()}
         with self._lock:  # two operators' threads may cut at once
-            books = {"phases": {name: {f: getattr(st, f)
-                                       for f in _SpanStat.CUT}
+            books = {"phases": {name: st.cut()
                                 for name, st in self._stats.items()},
                      "gc": self._gc.totals(), "kernels": kernels}
             period = {"seq": self._period_seq, "start_s": self._cut_s,
@@ -462,7 +490,8 @@ class Tracer:
         books since the period before it (since the tracer was made or
         reset, for the first): per phase ``count``, ``total_ms``,
         ``self_ms``, ``gc_ms``, ``gcs``, ``gc_under_ms``, ``compiles``,
-        ``compile_ms``, ``native_ms``; ``gc`` as :func:`gc_totals` gives it;
+        ``compile_ms``, ``native_ms``, and ``counts`` (what its spans
+        counted with ``add_count``, by key); ``gc`` as :func:`gc_totals` gives it;
         ``kernels`` as ``kernel_stats()``' ``total_ms``; names that did
         not grow are left out.  Beside them the host times of the two
         cuts (``time.perf_counter``), the operator that fired, its
@@ -575,7 +604,7 @@ class Tracer:
             for name, st in self._stats.items():
                 vals = sorted(st.reservoir.values)
                 out[name] = {
-                    **{f: getattr(st, f) for f in _SpanStat.CUT},
+                    **st.cut(),
                     "p50_ms": _percentile(vals, 0.50),
                     "p99_ms": _percentile(vals, 0.99),
                 }

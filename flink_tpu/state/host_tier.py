@@ -5,7 +5,7 @@ delivered them in, with one index over all of them.
 A row has an id that is never reused; a block covers a run of ids.
 Nothing here is per row but the index itself, which is keyed by
 namespace first (`slot_index.NamespaceIndex`, as the device's): an
-eviction's rows enter it with one ``dict.update`` per namespace, a fire
+eviction's rows enter it with one bulk call per namespace, a fire
 slices rows out by id, a clear or a promotion releases ids in one
 call, and a block whose rows are all released is dropped whole.  When
 released rows outnumber live ones the live rows are copied into one
@@ -38,11 +38,11 @@ class _Block:
 class HostTier:
     """(key, namespace) → accumulator row, for rows that left HBM.
     `get` / `discard` serve the per-key doors; the bulk calls (`put`,
-    `gather`, `release`, and `index.tables` read directly) are what
+    `gather`, `release`, and `index` read directly) are what
     the backend's batch paths use."""
 
     def __init__(self) -> None:
-        #: namespace → {key → row id}
+        #: namespace → table of key → row id
         self.index = NamespaceIndex()
         self._blocks: List[_Block] = []
         #: first id of each block, ascending (ids grow with time)
@@ -106,8 +106,7 @@ class HostTier:
         base = self.file(comps)
         for namespace, rows, part in cut_by_namespace(list(keys), None,
                                                       namespaces):
-            self.index.table(namespace).update(
-                zip(part, (rows + base).tolist()))
+            self.index.enter(part, namespace, rows + base)
 
     def _block_of(self, ids: np.ndarray) -> np.ndarray:
         return np.searchsorted(self._bases, ids, side="right") - 1
@@ -164,10 +163,7 @@ class HostTier:
         base = self.file(
             {name: np.concatenate([b.comps[name][b.alive] for b in blocks])
              for name in blocks[0].comps})
-        for table in self.index.tables.values():
-            ids = np.fromiter(table.values(), np.int64, len(table))
-            table.update(zip(list(table),
-                             (base + np.searchsorted(old, ids)).tolist()))
+        self.index.remap(lambda ids: base + np.searchsorted(old, ids))
 
     def columns(self) -> Tuple[list, list, Dict[str, np.ndarray]]:
         """Every live row, for a snapshot: keys, namespaces and their

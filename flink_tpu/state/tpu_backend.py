@@ -24,7 +24,6 @@ KeyGroupRangeAssignment.java:47-56, StateAssignmentOperation.java).
 
 from __future__ import annotations
 
-import itertools
 import pickle
 import threading
 import time
@@ -74,6 +73,7 @@ from flink_tpu.state.slot_index import (
     NamespaceIndex,
     cut_by_namespace,
     object_column,
+    pick,
 )
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
@@ -109,6 +109,14 @@ def _pad_slots(slots, width: int) -> np.ndarray:
     arr = np.full(width, slots[0], np.int32)
     arr[:len(slots)] = slots
     return arr
+
+
+def _count_probed(phase, rows: int, on_int_tables: int) -> None:
+    """On a slot phase's books: the rows it probed in bulk, and those
+    of them that went as a column since `on_int_tables`
+    (`STATE_STATS.int_table_rows` then)."""
+    phase.add_count("rows", rows)
+    phase.add_count("int_table", STATE_STATS.int_table_rows - on_int_tables)
 
 
 class _PendingRing:
@@ -188,7 +196,7 @@ class DeviceAggregatingState(AggregatingState):
         #: entries evicted out of HBM, in the blocks their evictions
         #: gathered them in; promoted back on access
         self.host_tier = HostTier()
-        #: its index, namespace → {key → row id}
+        #: its index, namespace → table of key → row id
         self._spilled = self.host_tier.index
         self._clock = 0
         #: observability: spill/promotion counters
@@ -250,9 +258,9 @@ class DeviceAggregatingState(AggregatingState):
     def _reset_slots(self, capacity: int) -> None:
         """Every slot of `capacity` free, the index empty."""
         self.capacity = capacity
-        #: namespace → {key → slot}: the ONE index of the device tier;
-        #: `_slot_for` reads and writes it a key at a time, the batch
-        #: doors a namespace's table at a time
+        #: namespace → table of key → slot: the ONE index of the device
+        #: tier; `_slot_for` reads and writes it a key at a time, the
+        #: batch doors a namespace's table at a time
         self.slot_index = NamespaceIndex()
         #: slot → key / namespace / whether it holds an entry at all
         #: (what an eviction files its victims under)
@@ -319,7 +327,9 @@ class DeviceAggregatingState(AggregatingState):
 
     def _claim(self, slots: np.ndarray, keys, namespace) -> None:
         """`slots` hold `keys` of one namespace from now on."""
-        self.slot_key[slots] = object_column(keys, len(slots))
+        # (an int64 column goes in as the Python ints it holds)
+        self.slot_key[slots] = keys if isinstance(keys, np.ndarray) \
+            else object_column(keys, len(slots))
         boxed = np.empty(1, object)  # a tuple would broadcast its fields
         boxed[0] = namespace
         self.slot_ns[slots] = boxed
@@ -408,9 +418,8 @@ class DeviceAggregatingState(AggregatingState):
                     "d2h", m * self._bytes_per_slot(), t0, _perf_ns(),
                     "state.evict")
             for namespace, rows, keys in entries:
-                self._spilled.table(namespace).update(
-                    zip(keys, (rows + bases[0]).tolist()))
-                self.slot_index.lookup(keys, namespace, len(keys), take=True)
+                self.slot_index.move(keys, namespace, self._spilled,
+                                     rows + bases[0])
             self._release(victims)
             with self._device_lock:
                 self.device_state = self._jit_clear(self.device_state, idx)
@@ -458,20 +467,21 @@ class DeviceAggregatingState(AggregatingState):
         namespace, one `state.promote` scatter per tile of
         `_promote_tile()` rows, whatever their number.  The caller
         has made the room."""
-        ids = self._spilled.lookup(keys, namespace, len(keys))
+        ids = self._spilled.lookup(keys, namespace)
         hit = ids >= 0
         if not hit.any():
             return
         # a key twice: one row; in order of first appearance
-        rows = dict(zip(itertools.compress(keys, hit.tolist()),
-                        ids[hit].tolist()))
-        m = len(rows)
+        ids, first = np.unique(ids[hit], return_index=True)
+        order = np.argsort(first)
+        ids = ids[order]
+        keys = pick(keys, np.flatnonzero(hit)[first[order]])
+        m = len(ids)
         with get_tracer().phase("state.promote", rows=m):
             free = self._free
             assert len(free) >= m
             slots = np.array(free[:-m - 1:-1], np.int64)
             del free[-m:]
-            ids = np.fromiter(rows.values(), np.int64, m)
             tile = self._promote_tile()
             with self._device_lock:
                 t0 = _perf_ns()
@@ -490,10 +500,8 @@ class DeviceAggregatingState(AggregatingState):
                     TELEMETRY.record_transfer(
                         "h2d", m * self._bytes_per_slot(), t0, _perf_ns(),
                         "state.promote")
-                self._spilled.lookup(rows, namespace, m, take=True)
-                self.slot_index.table(namespace).update(
-                    zip(rows, slots.tolist()))
-                self._claim(slots, rows, namespace)
+                self._spilled.move(keys, namespace, self.slot_index, slots)
+                self._claim(slots, keys, namespace)
                 self._slot_flushed[slots] = True
                 # promoted slots are HOT, as in _promote
                 self._stamp(slots)
@@ -531,66 +539,39 @@ class DeviceAggregatingState(AggregatingState):
                 [column, np.full(extra, fill, column.dtype)]))
         self.capacity = new_capacity
 
-    def _resolve(self, keys: list, namespace) -> Tuple[np.ndarray, int]:
+    def _resolve(self, keys, namespace) -> Tuple[np.ndarray, int]:
         """The batch door into the slot index: the slots of one
-        namespace's `keys` as int64[n], new keys taking theirs in the
-        same pass, and how many were new.  Room first, then the
-        chunk's spilled entries come up, then ONE probe of the
-        namespace's table; nothing is evicted between the last two, so
-        no entry the probe misses has a row in the host tier."""
+        namespace's `keys` (a list, or the int64 column they are) as
+        int64[n], new keys taking theirs in the same pass, and how many
+        were new.  Room first, then the chunk's spilled entries come
+        up, then ONE probe of the namespace's table; nothing is evicted
+        between the last two, so no entry the probe misses has a row in
+        the host tier."""
         n = len(keys)
         if n == 0:
             return np.zeros(0, np.int64), 0
         free = self._free
-        tables = self.slot_index.tables
+        index = self.slot_index
         if len(free) < n:
             # fewer free slots than rows: count the keys that have no
             # slot before room is made for them, so the capacity
             # doubles (or the cold quarter leaves) when the per-key
             # door would have done it, not for rows that need nothing
-            distinct = set(keys)
-            while len(free) < len(distinct.difference(
-                    tables.get(namespace, ()))):
+            while len(free) < index.missing(keys, namespace):
                 self._make_room(ahead=n)
         if self._spilled:
             self._promote_spilled(keys, namespace)
-        # (taken after the room was made: an eviction that empties a
-        # namespace's table drops it)
-        table = self.slot_index.table(namespace)
-        if len(free) >= n:
-            # every row offers its key the slot `free.pop()` would
-            # hand out n-th: a candidate that comes back as its own
-            # key's slot was taken, the rest return to the free list
-            offered = free[:-n - 1:-1]
-            del free[-n:]
-            slots = np.fromiter(map(table.setdefault, keys, offered),
-                                np.int64, n)
-            offered = np.array(offered, np.int64)
-            took = slots == offered
-            fresh = offered[took]
-            if len(fresh) < n:
-                free.extend(offered[~took][::-1].tolist())
-            new_keys = itertools.compress(keys, took.tolist())
-        else:
-            # a table about to fill up has no candidate for every
-            # row: find the keys without a slot, give each one, probe
-            # again
-            slots = self.slot_index.lookup(keys, namespace, n)
-            new_keys = dict.fromkeys(
-                itertools.compress(keys, (slots < 0).tolist()))
-            m = len(new_keys)
-            fresh = np.array(free[:-m - 1:-1], np.int64)
-            if m:
-                del free[-m:]
-                table.update(zip(new_keys, fresh.tolist()))
-                slots = self.slot_index.lookup(keys, namespace, n)
+        on_int_tables = index.int_rows
+        slots, fresh, new_keys = index.resolve(keys, namespace, free)
+        if index.int_rows != on_int_tables:
+            STATE_STATS.int_table_rows += index.int_rows - on_int_tables
         if len(fresh):
             self._claim(fresh, new_keys, namespace)
         self._stamp(slots)
         STATE_STATS.bulk_probe_rows += n
         return slots, len(fresh)
 
-    def _resolve_column(self, keys: list, namespace,
+    def _resolve_column(self, keys, namespace,
                         namespaces) -> Tuple[np.ndarray, int]:
         """`_resolve` for a column of rows of ONE namespace, or
         (`namespaces=`) each of its own: the rows of a namespace go
@@ -614,18 +595,18 @@ class DeviceAggregatingState(AggregatingState):
         slots = np.empty(n, np.int64)
         spill_rows = [np.zeros(0, np.int64)]
         spill_ids = [np.zeros(0, np.int64)]
-        spilled = self._spilled
+        index, spilled = self.slot_index, self._spilled
+        on_int_tables = index.int_rows
         for namespace, rows, part in cut_by_namespace(keys, namespace,
                                                       namespaces):
-            got = self.slot_index.lookup(part, namespace, len(part), take)
+            got = index.lookup(part, namespace, take)
             slots[rows] = got
             if namespace in spilled.tables:
                 miss = got < 0
-                ids = spilled.lookup(
-                    itertools.compress(part, miss.tolist()), namespace,
-                    int(miss.sum()), take)
+                ids = spilled.lookup(pick(part, miss), namespace, take)
                 spill_rows.append(rows[miss][ids >= 0])
                 spill_ids.append(ids[ids >= 0])
+        STATE_STATS.int_table_rows += index.int_rows - on_int_tables
         return slots, np.concatenate(spill_rows), np.concatenate(spill_ids)
 
     # ---- write path -------------------------------------------------
@@ -644,14 +625,14 @@ class DeviceAggregatingState(AggregatingState):
     def add_batch(self, keys: Iterable[Any], namespace, values,
                   namespaces=None, pre_extracted: bool = False) -> None:
         """Vectorized write: one bulk probe of the slot index per
-        namespace, no per-record method dispatch.  `namespace` is ONE
-        namespace shared by the whole batch (a window tuple is a
-        single namespace); pass a parallel sequence via `namespaces=`
-        to override per record.  `values` is a sequence/ndarray
-        parallel to keys; `pre_extracted=True` means the caller
-        already ran extract_value/extract_column over it (a numeric
-        column straight off a RecordBatch)."""
-        if not isinstance(keys, list):
+        namespace, no per-record method dispatch.  `keys` is a list or
+        an ndarray.  `namespace` is ONE namespace shared by the whole
+        batch (a window tuple is a single namespace); pass a parallel
+        sequence via `namespaces=` to override per record.  `values`
+        is a sequence/ndarray parallel to keys; `pre_extracted=True`
+        means the caller already ran extract_value/extract_column over
+        it (a numeric column straight off a RecordBatch)."""
+        if not isinstance(keys, (list, np.ndarray)):
             keys = list(keys)
         if self.max_device_slots is not None \
                 and len(keys) > self.microbatch:
@@ -669,9 +650,11 @@ class DeviceAggregatingState(AggregatingState):
             return
         tracer = get_tracer()
         n = len(keys)
-        with tracer.phase("state.add.slots", rows=n) as phase:
+        with tracer.phase("state.add.slots") as phase:
+            on_int_tables = STATE_STATS.int_table_rows
             slots, new = self._resolve_column(keys, namespace, namespaces)
-            phase.set_attr("new", new)
+            phase.add_count("new", new)
+            _count_probed(phase, n, on_int_tables)
             self._pending_slots.extend(slots)
         with tracer.phase("state.add.hash", rows=n) as phase:
             extract = self.agg.extract_value
@@ -778,12 +761,14 @@ class DeviceAggregatingState(AggregatingState):
         can happen here, so no chunking is needed.  Returns
         (results, found_mask); namespace semantics as in `add_batch`."""
         tracer = get_tracer()
-        with tracer.phase("state.get.lookup"):
-            if not isinstance(keys, (list, tuple)):
+        with tracer.phase("state.get.lookup") as phase:
+            if not isinstance(keys, (list, tuple, np.ndarray)):
                 keys = list(keys)
             n = len(keys)
+            on_int_tables = STATE_STATS.int_table_rows
             slots, spill_idx, spill_ids = self._find(keys, namespace,
                                                      namespaces)
+            _count_probed(phase, n, on_int_tables)
             found = slots >= 0
             # reads stamp the LRU clock exactly as scalar get()
             self._stamp(slots[found])
@@ -916,11 +901,13 @@ class DeviceAggregatingState(AggregatingState):
 
     def clear_batch(self, keys, namespace, namespaces=None) -> None:
         tracer = get_tracer()
-        with tracer.phase("state.clear.slots"):
-            if not isinstance(keys, (list, tuple)):
+        with tracer.phase("state.clear.slots") as phase:
+            if not isinstance(keys, (list, tuple, np.ndarray)):
                 keys = list(keys)
+            on_int_tables = STATE_STATS.int_table_rows
             slots, _, spilled_ids = self._find(keys, namespace, namespaces,
                                                take=True)
+            _count_probed(phase, len(keys), on_int_tables)
             slots = slots[slots >= 0]
             self._release(slots)
         if len(spilled_ids):

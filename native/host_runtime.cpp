@@ -21,6 +21,7 @@
 #include <cstring>
 #include <chrono>
 #include <memory>
+#include <utility>
 #include <vector>
 
 namespace {
@@ -132,6 +133,99 @@ struct FtIndex {
   }
 };
 
+// ---- integer-keyed table ---------------------------------------------------
+// int64 key -> int64 id (>= 0) for the tpu state backend's slot index
+// (flink_tpu/state/slot_index.py: one table per window namespace, in
+// both tiers).  Exact keys, every int64 is one (a cell is empty by its
+// id, never by its key); open addressing, linear probing from a
+// Fibonacci hash; a delete shifts the run behind it back, so there are
+// no tombstones to reclaim; every entry remembers when it was entered,
+// and export gives the entries back in that order, as a dict would.
+// Ids come from the caller (the Python side owns the free list), so an
+// insert has two phases like FtIndex's: probe marks the new keys, assign
+// gives them their ids.
+
+struct FtIntTable {
+  static constexpr int64_t kEmpty = -1;  // ids <= -2: entered, id pending
+  struct Cell { int64_t key; int64_t id; };
+  std::vector<Cell> cells;
+  std::vector<uint64_t> seq;  // entry order, parallel to cells
+  uint64_t mask;
+  int shift;
+  int64_t n = 0;
+  int64_t peak = 0;  // most entries it ever held at once
+  uint64_t next_seq = 0;
+  std::vector<int64_t> new_pos;  // probe -> assign: cells of the new keys
+
+  explicit FtIntTable(int64_t cap) { reset(cap); }
+
+  void reset(int64_t cap) {
+    cells.assign(cap, Cell{0, kEmpty});
+    seq.assign(cap, 0);
+    mask = static_cast<uint64_t>(cap) - 1;
+    shift = 64 - __builtin_ctzll(static_cast<uint64_t>(cap));
+  }
+
+  inline uint64_t home(int64_t key) const {
+    return (static_cast<uint64_t>(key) * 0x9E3779B97F4A7C15ull) >> shift;
+  }
+
+  inline void prefetch(int64_t key) const {
+    __builtin_prefetch(&cells[home(key)]);
+  }
+
+  // the cell that holds `key`, or the empty one where it would go
+  inline uint64_t slot_of(int64_t key) const {
+    uint64_t pos = home(key);
+    while (cells[pos].id != kEmpty && cells[pos].key != key)
+      pos = (pos + 1) & mask;
+    return pos;
+  }
+
+  void enter(uint64_t pos, int64_t key, int64_t id) {
+    cells[pos] = Cell{key, id};
+    seq[pos] = next_seq++;
+    if (++n > peak) peak = n;
+  }
+
+  // room for `incoming` more entries at a load of 3/5 at most
+  void reserve(int64_t incoming) {
+    size_t cap = cells.size();
+    if ((n + incoming) * 5 <= static_cast<int64_t>(cap) * 3) return;
+    while ((n + incoming) * 5 > static_cast<int64_t>(cap) * 3) cap *= 2;
+    std::vector<Cell> old_cells(std::move(cells));
+    std::vector<uint64_t> old_seq(std::move(seq));
+    reset(static_cast<int64_t>(cap));
+    for (size_t i = 0; i < old_cells.size(); ++i) {
+      if (old_cells[i].id == kEmpty) continue;
+      uint64_t pos = slot_of(old_cells[i].key);
+      cells[pos] = old_cells[i];
+      seq[pos] = old_seq[i];
+    }
+  }
+
+  // take the entry at `pos` out: every entry of the run behind it that
+  // may move up without passing its home cell does
+  void erase(uint64_t pos) {
+    uint64_t hole = pos;
+    uint64_t next = pos;
+    for (;;) {
+      next = (next + 1) & mask;
+      if (cells[next].id == kEmpty) break;
+      uint64_t h = home(cells[next].key);
+      // home cyclically in (hole, next]: it has to stay behind the hole
+      bool stays = hole <= next ? (hole < h && h <= next)
+                                : (hole < h || h <= next);
+      if (stays) continue;
+      cells[hole] = cells[next];
+      seq[hole] = seq[next];
+      hole = next;
+    }
+    cells[hole].id = kEmpty;
+    --n;
+  }
+};
+
 extern "C" {
 
 void* ft_index_new(int64_t capacity_pow2) {
@@ -231,6 +325,135 @@ int64_t ft_index_export(void* p, uint64_t* hashes_out, int64_t* slots_out) {
     }
   }
   return k;
+}
+
+// ---- integer-keyed table: entry points -------------------------------------
+// (flink_tpu/native loads these through a handle that keeps the GIL, so
+// a reader on another thread never sees a table mid-insert or mid-growth)
+
+constexpr int64_t kItabAhead = 8;  // keys a bulk loop prefetches ahead
+
+// A table with room for `room` entries before it grows.
+void* ft_itab_new(int64_t room) {
+  FtIntTable* t = new FtIntTable(16);
+  t->reserve(room);
+  return t;
+}
+
+void ft_itab_free(void* p) { delete static_cast<FtIntTable*>(p); }
+
+int64_t ft_itab_size(void* p) { return static_cast<FtIntTable*>(p)->n; }
+
+int64_t ft_itab_peak(void* p) { return static_cast<FtIntTable*>(p)->peak; }
+
+// Phase 1 of probe-or-insert: ids_out[i] is the id of keys[i]; a key the
+// table did not hold is entered with its id pending, ids_out of its rows
+// (all of them, a key may come twice) is -2 - k for the k-th new key, and
+// first_idx[k] the row it first came in.  Returns the number of new keys;
+// ft_itab_assign follows before any other call.
+int64_t ft_itab_probe(void* p, const int64_t* keys, int64_t n,
+                      int64_t* ids_out, int64_t* first_idx) {
+  FtIntTable& t = *static_cast<FtIntTable*>(p);
+  t.reserve(n);
+  t.new_pos.clear();
+  int64_t n_new = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + kItabAhead < n) t.prefetch(keys[i + kItabAhead]);
+    uint64_t pos = t.slot_of(keys[i]);
+    if (t.cells[pos].id == FtIntTable::kEmpty) {
+      t.enter(pos, keys[i], -2 - n_new);
+      t.new_pos.push_back(static_cast<int64_t>(pos));
+      first_idx[n_new++] = i;
+    }
+    ids_out[i] = t.cells[pos].id;
+  }
+  return n_new;
+}
+
+// Phase 2: the k-th new key of the probe gets new_ids[k], in the table
+// and in every row of ids_out that waited for it.
+void ft_itab_assign(void* p, const int64_t* new_ids, int64_t n_new,
+                    int64_t* ids_out, int64_t n) {
+  FtIntTable& t = *static_cast<FtIntTable*>(p);
+  for (int64_t k = 0; k < n_new; ++k)
+    t.cells[t.new_pos[k]].id = new_ids[k];
+  for (int64_t i = 0; i < n; ++i)
+    if (ids_out[i] < -1) ids_out[i] = new_ids[-2 - ids_out[i]];
+  t.new_pos.clear();
+}
+
+// ids_out[i] = id of keys[i], -1 where the table has none.
+void ft_itab_lookup(void* p, const int64_t* keys, int64_t n,
+                    int64_t* ids_out) {
+  const FtIntTable& t = *static_cast<FtIntTable*>(p);
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + kItabAhead < n) t.prefetch(keys[i + kItabAhead]);
+    int64_t id = t.cells[t.slot_of(keys[i])].id;
+    ids_out[i] = id < 0 ? -1 : id;
+  }
+}
+
+// Lookup that takes what it finds out of the table: a key that comes
+// twice is found once.
+void ft_itab_take(void* p, const int64_t* keys, int64_t n,
+                  int64_t* ids_out) {
+  FtIntTable& t = *static_cast<FtIntTable*>(p);
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + kItabAhead < n) t.prefetch(keys[i + kItabAhead]);
+    uint64_t pos = t.slot_of(keys[i]);
+    int64_t id = t.cells[pos].id;
+    if (id < 0) { ids_out[i] = -1; continue; }
+    ids_out[i] = id;
+    t.erase(pos);
+  }
+}
+
+// keys[i] -> ids[i]: a new key is entered (in the order of the rows), a
+// key the table holds keeps its place and gets the new id.
+void ft_itab_set(void* p, const int64_t* keys, const int64_t* ids,
+                 int64_t n) {
+  FtIntTable& t = *static_cast<FtIntTable*>(p);
+  t.reserve(n);
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + kItabAhead < n) t.prefetch(keys[i + kItabAhead]);
+    uint64_t pos = t.slot_of(keys[i]);
+    if (t.cells[pos].id == FtIntTable::kEmpty) t.enter(pos, keys[i], ids[i]);
+    else t.cells[pos].id = ids[i];
+  }
+}
+
+// Every entry in the order it was entered (buffers sized >= size).
+int64_t ft_itab_export(void* p, int64_t* keys_out, int64_t* ids_out) {
+  const FtIntTable& t = *static_cast<FtIntTable*>(p);
+  std::vector<std::pair<uint64_t, uint64_t>> order;  // (seq, cell)
+  order.reserve(static_cast<size_t>(t.n));
+  for (size_t i = 0; i < t.cells.size(); ++i)
+    if (t.cells[i].id != FtIntTable::kEmpty) order.emplace_back(t.seq[i], i);
+  std::sort(order.begin(), order.end());
+  int64_t k = 0;
+  for (const auto& e : order) {
+    keys_out[k] = t.cells[e.second].key;
+    ids_out[k] = t.cells[e.second].id;
+    ++k;
+  }
+  return k;
+}
+
+// One key: its id or -1 / its id, taken out / entered or given a new id.
+int64_t ft_itab_get1(void* p, int64_t key) {
+  int64_t id;
+  ft_itab_lookup(p, &key, 1, &id);
+  return id;
+}
+
+int64_t ft_itab_take1(void* p, int64_t key) {
+  int64_t id;
+  ft_itab_take(p, &key, 1, &id);
+  return id;
+}
+
+void ft_itab_set1(void* p, int64_t key, int64_t id) {
+  ft_itab_set(p, &key, &id, 1);
 }
 
 // ---- hot host-path kernels -------------------------------------------------

@@ -303,3 +303,129 @@ def test_session_fire_guard_demotes_predating_rows():
     # 700 joins [1000,1400,1650] because 1000-700 <= 500: one session
     # [700, 2150) of 4 events
     assert got == {(700, 2150): 4}, got
+
+
+# ---- the integer table (the tpu backend's slot index) ----------------
+
+_I64 = np.iinfo(np.int64)
+INT_KEY_PATTERNS = [
+    ("small", lambda rng, n: rng.integers(-50, 50, n)),
+    ("wide", lambda rng, n: rng.integers(_I64.min, _I64.max, n,
+                                         endpoint=True)),
+    ("ends", lambda rng, n: np.array([0, -1, _I64.min, _I64.max, 1])[
+        rng.integers(0, 5, n)]),
+    # keys that share their low bits, and keys that share their high ones
+    ("strided", lambda rng, n: rng.integers(0, 400, n) << 40),
+    ("dense", lambda rng, n: rng.integers(0, 5000, n) + (1 << 62)),
+]
+
+
+@pytest.mark.parametrize("name,gen", INT_KEY_PATTERNS)
+def test_int_table_is_a_dict_on_random_batches(name, gen):
+    """probe + assign, lookup, take, set and the three scalar calls in
+    random order against a Python dict: equal ids, `first` the rows of
+    first appearance, and `export` the dict's own order throughout."""
+    rng = np.random.default_rng([38, len(name)])
+    table, ref = nat.NativeIntTable(), {}
+    next_id = 0
+    for step in range(300):
+        n = int(rng.integers(1, 200))
+        keys = np.ascontiguousarray(gen(rng, n), np.int64)
+        call = int(rng.integers(0, 7))
+        if call <= 1:
+            ids, first = table.probe(keys)
+            new = list(dict.fromkeys(k for k in keys.tolist()
+                                     if k not in ref))
+            assert keys[first].tolist() == new
+            assert (ids < -1).sum() == sum(k not in ref
+                                           for k in keys.tolist())
+            # a reader between the two phases finds no pending key
+            assert all(table.get(k) == -1 for k in new[:3])
+            fresh = np.arange(next_id, next_id + len(new))
+            next_id += len(new)
+            table.assign(fresh, ids)
+            ref.update(zip(new, fresh.tolist()))
+            assert ids.tolist() == [ref[k] for k in keys.tolist()]
+        elif call == 2:
+            assert table.lookup(keys).tolist() == [
+                ref.get(k, -1) for k in keys.tolist()]
+        elif call == 3:
+            assert table.lookup(keys, take=True).tolist() == [
+                ref.pop(k, -1) for k in keys.tolist()]
+        elif call == 4:
+            ids = rng.integers(0, 1 << 40, n)
+            table.set(keys, ids)
+            ref.update(zip(keys.tolist(), ids.tolist()))
+        else:
+            key = int(keys[0])
+            if call == 5:
+                assert table.pop(key) == ref.pop(key, -1)
+                assert table.get(key) == -1
+            else:
+                table.put(key, step)
+                ref[key] = step
+                assert table.get(key) == step
+        assert len(table) == len(ref)
+        if step % 25 == 0 or step == 299:
+            got_keys, got_ids = table.export()
+            assert got_keys.tolist() == list(ref)
+            assert got_ids.tolist() == list(ref.values())
+
+
+def test_int_table_refills_after_it_was_emptied():
+    """A window's life: filled batch by batch through several
+    doublings, emptied by one take, filled again."""
+    rng = np.random.default_rng(7)
+    for table in (nat.NativeIntTable(), nat.NativeIntTable(room=200_000),
+                  nat.NativeIntTable(room=5)):
+        _fill_and_empty_twice(table, rng)
+
+
+def _fill_and_empty_twice(table, rng):
+    for _ in range(2):
+        keys = rng.permutation(200_000).astype(np.int64) - 100_000
+        for lo in range(0, len(keys), 8192):
+            ids, first = table.probe(keys[lo:lo + 8192])
+            assert len(first) == len(ids)
+            table.assign(np.arange(lo, lo + len(ids)), ids)
+        assert len(table) == 200_000
+        assert table.lookup(keys).tolist() == list(range(200_000))
+        assert table.export()[0].tolist() == keys.tolist()
+        taken = table.lookup(np.concatenate([keys[::-1], keys[:10]]),
+                             take=True)
+        assert taken[:200_000].tolist() == list(range(199_999, -1, -1))
+        assert (taken[200_000:] == -1).all() and len(table) == 0
+        assert table.peak() == 200_000
+
+
+def test_slot_index_of_the_vectorized_tier_behaves_as_before():
+    """`NativeSlotIndex` (the vectorized window tier, CEP) is keyed by
+    64-bit hash, gives its new hashes the caller's slots in order of
+    first appearance and exports by table position: unchanged by the
+    integer table beside it."""
+    rng = np.random.default_rng(11)
+    index, ref = nat.NativeSlotIndex(16), {}
+    handed = [0]
+
+    def alloc(n):
+        handed[0] += n
+        return np.arange(handed[0] - n, handed[0])
+
+    for _ in range(40):
+        hashes = rng.integers(2, 3000, 500).astype(np.uint64) \
+            * np.uint64(0x9E3779B97F4A7C15)
+        hashes[7] = 0  # the one hash the table stores under another
+        new = list(dict.fromkeys(h for h in hashes.tolist() if h not in ref))
+        slots, is_new, first = index.lookup_or_insert(hashes, alloc)
+        assert hashes[first].tolist() == new and is_new.all()
+        ref.update(zip(new, range(len(ref), len(ref) + len(new))))
+        assert slots.tolist() == [ref[h] for h in hashes.tolist()]
+    assert index.n == len(ref) == handed[0]
+    hashes, slots = index.export()
+    # (0 comes back as the constant it is stored under)
+    assert dict(zip(hashes.tolist(), slots.tolist())) \
+        == {h or 0x9E3779B97F4A7C15: slot for h, slot in ref.items()}
+    restored = nat.NativeSlotIndex(16)
+    restored.set_bulk(hashes, slots)
+    again = restored.lookup_or_insert(hashes, alloc)
+    assert again[0].tolist() == slots.tolist() and len(again[2]) == 0

@@ -4,8 +4,11 @@ probed in bulk, against the per-key index it replaced
 dict, one `_slot_for` call per row): the same programme of batch and
 scalar calls gives the same results, `found` masks, snapshot cells and
 active entries on both, and the bulk state's invariants hold after
-every step.  Then the count of work: which door resolved how many
-rows."""
+every step, whichever of its two forms a namespace's table has (a
+`dict`, or the native integer table a window of integer keys gets).
+Then the integer table alone against a dict-only index, and the count
+of work: which door resolved how many rows, how many of them on an
+integer table."""
 
 import os
 import pickle
@@ -19,6 +22,8 @@ from flink_tpu.core.state import AggregatingStateDescriptor
 from flink_tpu.ops.device_agg import SumAggregate
 from flink_tpu.ops.sketches import HyperLogLogAggregate
 from flink_tpu.runtime.tracing import get_tracer
+from flink_tpu import native
+from flink_tpu.state import slot_index
 from flink_tpu.state.backend import KeyedStateSnapshot, decode_obj_column
 from flink_tpu.state.slot_index import NamespaceIndex, group_rows
 from flink_tpu.state.stats import STATE_STATS
@@ -91,7 +96,10 @@ def check_invariants(st):
     assert set(live) | set(st._free) == set(range(st.capacity))
     # slot → entry agrees with the index
     for key, namespace, slot in zip(keys, namespaces, live):
-        assert st.slot_key[slot] is key and st.slot_ns[slot] == namespace
+        # (an integer table gives a key back as the int it equals)
+        held = st.slot_key[slot]
+        assert held is key or (type(key) is int and held == key)
+        assert st.slot_ns[slot] == namespace
     assert np.flatnonzero(st._slot_live).tolist() == sorted(live)
     for slot in st._free:
         assert st.slot_key[slot] is None and st.slot_ns[slot] is None
@@ -205,17 +213,37 @@ def _namespaces(shape, rng, n, round_):
     return None, [WINDOWS[i] for i in rng.integers(0, 3, n)]  # per row
 
 
+@pytest.fixture
+def dict_tables_only(monkeypatch):
+    """No native host runtime as far as the slot index can tell: every
+    table is a dict."""
+    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+
+
+#: the kinds of keys an integer table can take run on both forms
+KIND_FORMS = [(kind, form) for kind in sorted(KEYS)
+              for form in (("native", "dict") if kind in ("equal", "int")
+                           else ("native",))]
+
+
 @pytest.mark.parametrize("capped", [False, True],
                          ids=["uncapped", "capped"])
 @pytest.mark.parametrize("shape", ["one", "runs", "per_row"])
-@pytest.mark.parametrize("kind", sorted(KEYS))
+@pytest.mark.parametrize("kind,form", KIND_FORMS,
+                         ids=[f"{kind}-{form}" for kind, form in KIND_FORMS])
 @pytest.mark.parametrize("agg_name", sorted(AGGS))
-def test_bulk_index_is_the_per_key_index(agg_name, kind, shape, capped):
+def test_bulk_index_is_the_per_key_index(agg_name, kind, form, shape, capped,
+                                         request):
     """Batches longer than the microbatch with keys twice in them,
     scalar calls between them, merges, partial clears, and a swap
     through each other's snapshots mid-way.  Capped: a key space five
     times the budget, so evictions and promotions happen all along;
-    uncapped: the table doubles three times or more."""
+    uncapped: the table doubles three times or more.  `form`: with the
+    native host runtime, a window that integer keys reach under one
+    namespace holds them in an integer table; without it, in a dict."""
+    if form == "dict":
+        request.getfixturevalue("dict_tables_only")
+    STATE_STATS.reset()
     space, make = KEYS[kind]
     rng = np.random.default_rng(
         [sorted(AGGS).index(agg_name), sorted(KEYS).index(kind),
@@ -291,6 +319,14 @@ def test_bulk_index_is_the_per_key_index(agg_name, kind, shape, capped):
         assert st.capacity <= 32 and st.budget_overruns == 0
     else:
         assert st.capacity == ref.capacity >= 64 and st.evictions == 0
+    # which form the batch doors met
+    if form == "native" and shape == "one" and kind == "int":
+        assert 0 < STATE_STATS.int_table_rows <= STATE_STATS.bulk_probe_rows
+    elif kind != "equal" or form == "dict":
+        # (a batch of 1, 1.0 and True is a column of floats: a dict,
+        # unless it happens to hold ints alone)
+        assert STATE_STATS.int_table_rows == 0
+    assert STATE_STATS.int_table_demotions == 0 or kind == "equal"
     # clear everything, a window at a time: nothing is left behind
     for ns, keys in live.items():
         pair.clear_batch(list(keys), ns, None)
@@ -374,18 +410,18 @@ def test_group_rows_and_the_index_alone():
     index = NamespaceIndex()
     assert not index and len(index) == 0 and ("k", "w") not in index
     index.put("k", "w", 3)
-    index.table("v").update(zip([1, 2, 3], [7, 8, 9]))
+    index.enter([1, 2, 3], "v", np.array([7, 8, 9]))
     assert len(index) == 4 and index and ("k", "w") in index
     assert list(index) == [("k", "w"), (1, "v"), (2, "v"), (3, "v")]
     assert index.get(2.0, "v") == 8 and index.get(2, "w") is None
-    assert index.lookup([3, 4, True], "v", 3).tolist() == [9, -1, 7]
-    assert index.lookup([3], "nowhere", 1).tolist() == [-1]
-    assert index.lookup([1, 1, 2], "v", 3, take=True).tolist() == [7, -1, 8]
+    assert index.lookup([3, 4, True], "v").tolist() == [9, -1, 7]
+    assert index.lookup([3], "nowhere").tolist() == [-1]
+    assert index.lookup([1, 1, 2], "v", take=True).tolist() == [7, -1, 8]
     assert index.pop("k", "w") == 3 and index.pop("k", "w") is None
     assert list(index.tables) == ["v"]  # "w" went with its last key
     keys, namespaces, ids = index.columns()
     assert (keys, namespaces, ids.tolist()) == ([3], ["v"], [9])
-    assert index.lookup([3], "v", 1, take=True).tolist() == [9]
+    assert index.lookup([3], "v", take=True).tolist() == [9]
     assert not index and not index.tables
 
 
@@ -479,12 +515,23 @@ def test_phase_attributes_say_rows_and_new_slots():
         tr.reset()
         b.add_batch(st, [1, 2, 1, 3], "w", np.ones(4, np.float32))
         b.add_batch(st, [3, 4], "w", np.ones(2, np.float32))
-        seen = [(e["args"]["rows"], e["args"]["new"])
+        b.add_batch(st, ["a"], "v", np.ones(1, np.float32))
+        b.get_batch(st, [1, 2, 9], "w")
+        b.clear_batch(st, [1, 2], "w")
+        seen = [(e["args"]["rows"], e["args"]["new"], e["args"]["int_table"])
                 for e in tr.recent(50) if e["name"] == "state.add.slots"]
+        counted = {name: tr.stats()[name]["counts"]
+                   for name in ("state.add.slots", "state.get.lookup",
+                                "state.clear.slots")}
     finally:
         tr.enabled = was
         tr.reset()
-    assert seen == [(4, 3), (2, 1)]
+    column = native.available()  # integer keys under one window
+    assert seen == [(4, 3, 4 * column), (2, 1, 2 * column), (1, 1, 0)]
+    assert counted == {
+        "state.add.slots": {"rows": 7, "new": 5, "int_table": 6 * column},
+        "state.get.lookup": {"rows": 3, "int_table": 3 * column},
+        "state.clear.slots": {"rows": 2, "int_table": 2 * column}}
 
 
 @pytest.mark.parametrize("gap", ["static", "per_element"])
@@ -514,3 +561,293 @@ def test_a_session_window_job_stays_on_the_per_key_door(gap):
         assert STATE_STATS.per_key_probe_rows >= 300
     assert not op.window_state.slot_index
     check_invariants(op.window_state)
+
+
+# ---------------------------------------------------------------------
+# the integer table alone, against an index that holds dicts only: the
+# form follows the keys (an int64 column is born an integer table, a
+# list a dict), so the same calls with the same keys as a list drive
+# the reference
+# ---------------------------------------------------------------------
+
+I64 = np.iinfo(np.int64)
+
+needs_native = pytest.mark.skipif(
+    not native.available(), reason="no native host runtime")
+
+
+def _free(n):
+    return list(range(n - 1, -1, -1))
+
+
+def _is_int_table(index, namespace):
+    return isinstance(index.tables[namespace], native.NativeIntTable)
+
+
+class _BothForms:
+    """An index driven with int64 columns beside one driven with the
+    same keys as lists: equal answers, equal free lists, equal
+    columns."""
+
+    def __init__(self, slots=1 << 16):
+        self.ints, self.dicts = NamespaceIndex(), NamespaceIndex()
+        self.free = _free(slots), _free(slots)
+
+    def resolve(self, keys, namespace="w"):
+        ids, fresh, new_keys = self.ints.resolve(
+            np.array(keys, np.int64), namespace, self.free[0])
+        want = self.dicts.resolve(list(keys), namespace, self.free[1])
+        assert ids.tolist() == want[0].tolist()
+        assert fresh.tolist() == want[1].tolist()
+        assert new_keys.tolist() == list(want[2])
+        self.check()
+        return ids, fresh
+
+    def lookup(self, keys, namespace="w", take=False):
+        ids = self.ints.lookup(np.array(keys, np.int64), namespace, take)
+        want = self.dicts.lookup(list(keys), namespace, take)
+        assert ids.tolist() == want.tolist()
+        if take:
+            for free in self.free:
+                free.extend(ids[ids >= 0].tolist())
+        self.check()
+        return ids
+
+    def check(self):
+        assert self.free[0] == self.free[1]
+        got, want = self.ints.columns(), self.dicts.columns()
+        assert got[:2] == want[:2] and got[2].tolist() == want[2].tolist()
+        assert list(self.ints) == list(self.dicts)
+        assert len(self.ints) == len(self.dicts)
+        assert list(self.ints.tables) == list(self.dicts.tables)
+        assert all(type(t) is dict for t in self.dicts.tables.values())
+        assert not any(type(t) is dict for t in self.ints.tables.values())
+
+
+@needs_native
+def test_an_integer_table_holds_every_int64():
+    """0, -1 and both ends of int64 are keys like any other (no value
+    stands for an empty cell), a key twice in one batch is new once,
+    and a take of a key that comes twice finds it once."""
+    both = _BothForms()
+    edge = [0, -1, I64.min, I64.max, 0, I64.max, 7, -1]
+    ids, fresh = both.resolve(edge)
+    assert len(fresh) == 5 and len(set(ids.tolist())) == 5
+    assert ids[0] == ids[4] and ids[3] == ids[5] and ids[1] == ids[7]
+    assert _is_int_table(both.ints, "w")
+    assert both.ints.int_rows == 8
+    assert both.lookup([I64.min, I64.min + 1, I64.max, I64.max - 1, 0, 1]) \
+        .tolist() == [ids[2], -1, ids[3], -1, ids[0], -1]
+    for key in (0, -1, I64.min, I64.max):
+        assert both.ints.get(key, "w") == both.dicts.get(key, "w") >= 0
+        assert (key, "w") in both.ints
+    taken = both.lookup([I64.max, 0, I64.max, 5, 0], take=True)
+    assert taken.tolist() == [ids[3], ids[0], -1, -1, -1]
+    assert both.ints.columns()[0] == [-1, I64.min, 7]
+    # the freed slots come back, most recently freed first
+    both.resolve([I64.max, 0, 9])
+    assert both.ints.columns()[0] == [-1, I64.min, 7, I64.max, 0, 9]
+
+
+@needs_native
+def test_deleted_keys_come_back_through_growth():
+    """Half of 2,000 keys taken out, 20,000 more entered (the table
+    doubles several times over the holes the deletes left), then the
+    taken ones again: every key reads its id, `columns()` is the order
+    of entry, as a dict's."""
+    rng = np.random.default_rng(38)
+    both = _BothForms()
+    first = rng.permutation(2000) - 1000
+    both.resolve(first)
+    both.lookup(first[::2], take=True)
+    later = rng.permutation(20_000) + 5000
+    for lo in range(0, len(later), 3000):
+        both.resolve(later[lo:lo + 3000])
+    both.resolve(first[::2][::-1])
+    assert len(both.ints) == 22_000
+    keys, _, ids = both.ints.columns()
+    assert keys == [*first[1::2].tolist(), *later.tolist(),
+                    *first[::2][::-1].tolist()]
+    assert both.lookup(keys).tolist() == ids.tolist()
+    assert sorted(ids.tolist() + both.free[0]) == list(range(1 << 16))
+
+
+@needs_native
+def test_an_emptied_integer_table_is_dropped():
+    both = _BothForms()
+    both.resolve([1, 2, 3], "w")
+    both.resolve([1, 2], "v")
+    both.lookup([2, 1], "v", take=True)
+    assert list(both.ints.tables) == ["w"] and both.ints
+    assert both.ints.pop(3, "w") == both.dicts.pop(3, "w")
+    both.lookup([1, 5], "w", take=True)
+    assert both.ints.pop(2, "w") == both.dicts.pop(2, "w") is not None
+    assert not both.ints and not both.ints.tables and len(both.ints) == 0
+    # the next integer table is born with room for what this one held
+    assert both.ints._room == 3 and both.dicts._room == 0
+    both.resolve(list(range(500)), "u")
+    both.lookup(list(range(500)), "u", take=True)
+    assert both.ints._room == 500
+    both.resolve([7, 8], "t")
+    assert both.ints.tables["t"].peak() == 2
+    both.lookup([7, 8], "t", take=True)
+    assert both.ints._room == 2
+    # and a namespace is born again as what its next keys ask for
+    both.ints.resolve(["a"], "w", both.free[0])
+    assert type(both.ints.tables["w"]) is dict
+
+
+@needs_native
+def test_the_scalar_doors_keep_dict_equality_on_an_integer_table():
+    """1, 1.0 and True find the same entry, "1" none; a key that is no
+    int64 reads as absent and changes nothing."""
+    index = NamespaceIndex()
+    index.resolve(np.array([1, 0, 5]), "w", _free(8))  # ids 0, 1, 2
+    for one in (1, 1.0, True, np.int64(1), np.float32(1.0)):
+        assert index.get(one, "w") == 0 and (one, "w") in index
+    for zero in (0, 0.0, False, -0.0):
+        assert index.get(zero, "w") == 1
+    for other in ("1", 1.5, (1,), None, 1 << 63, float("nan"), float("inf"),
+                  b"1"):
+        assert index.get(other, "w") is None and (other, "w") not in index
+        assert index.pop(other, "w") is None
+    assert index.lookup([1.0, "1", True, 2.5, 5], "w").tolist() \
+        == [0, -1, 0, -1, 2]
+    index.put(1.0, "w", 6)  # the entry 1 has, not a second one
+    index.put(True, "w", 7)
+    assert index.get(1, "w") == 7 and len(index) == 3
+    assert index.pop(5.0, "w") == 2 and index.pop(5, "w") is None
+    assert _is_int_table(index, "w")
+    assert index.lookup([0.0, True, "x"], "w", take=True).tolist() \
+        == [1, 7, -1]
+    assert not index
+
+
+@needs_native
+@pytest.mark.parametrize("door", ["resolve", "enter", "put"])
+@pytest.mark.parametrize("stranger", ["k", 2.5, (1, 2), 1 << 63],
+                         ids=["str", "float", "tuple", "beyond_int64"])
+def test_a_key_it_cannot_hold_turns_an_integer_table_into_a_dict_once(
+        door, stranger):
+    """Every earlier entry is still found, in its place; the table
+    stays a dict when integer columns come again."""
+    STATE_STATS.reset()
+    index = NamespaceIndex()
+    free = _free(64)
+    ids, _, _ = index.resolve(np.array([5, -3, 9, 5, 0]), "w", free)
+    index.resolve(np.array([1, 2]), "v", free)
+    assert _is_int_table(index, "w")
+    strange_id = 57
+    if door == "resolve":
+        got, fresh, new_keys = index.resolve([9, stranger, 5], "w", free)
+        strange_id = int(got[1])
+        assert got.tolist() == [ids[2], strange_id, ids[0]]
+        assert strange_id not in free and strange_id not in ids
+        assert fresh.tolist() == [strange_id] and list(new_keys) == [stranger]
+    elif door == "enter":
+        index.enter([stranger, 9], "w", np.array([57, ids[2]]))
+    else:
+        index.put(stranger, "w", 57)
+    assert type(index.tables["w"]) is dict and _is_int_table(index, "v")
+    assert STATE_STATS.int_table_demotions == 1
+    assert index.columns()[0] == [5, -3, 9, 0, stranger, 1, 2]
+    assert list(index.tables) == ["w", "v"]
+    assert index.lookup([5, -3, 9, 0, stranger], "w").tolist() \
+        == [*ids[[0, 1, 2, 4]].tolist(), strange_id]
+    was = index.int_rows
+    index.resolve(np.array([5, 77]), "w", free)
+    assert type(index.tables["w"]) is dict and index.int_rows == was
+    assert STATE_STATS.int_table_demotions == 1
+    assert index.get(77, "w") is not None
+
+
+@needs_native
+def test_a_lookup_never_turns_a_table_into_a_dict():
+    STATE_STATS.reset()
+    index = NamespaceIndex()
+    index.resolve(np.array([1, 2, 3]), "w", _free(8))
+    assert index.lookup(["a", 2, (3,)], "w").tolist() == [-1, 1, -1]
+    assert index.missing(["a", 2, "a", 4.5], "w") == 2
+    assert index.missing(np.array([7, 2, 7, 8]), "w") == 2
+    assert index.lookup([2.0, "a"], "w", take=True).tolist() == [1, -1]
+    assert _is_int_table(index, "w") and STATE_STATS.int_table_demotions == 0
+
+
+@needs_native
+def test_what_is_born_an_integer_table():
+    """A table is born from the first keys it gets: an int64 column
+    makes an integer table, a list or a scalar door a dict; `key_column`
+    reads a list as a column where numpy gives it an integer dtype."""
+    column = slot_index.key_column
+    for keys in ([1, 2, 3], [True, 2], [np.int64(4), 5], (6, 7),
+                 np.array([1, 2], np.int32)):
+        got = column(keys)
+        assert isinstance(got, np.ndarray) and got.dtype == np.int64
+        assert got.tolist() == [int(k) for k in keys]
+    for keys in ([1, 2.0], [1, "a"], ["a", 1], [1, (1, 2)], [(1, 2), (3, 4)],
+                 [1, 1 << 63], [1 << 64], [1, None], [True, False], [],
+                 [1.0, 2.0]):
+        assert column(keys) is keys
+    assert column(np.array([1.5, 2.0])) == [1.5, 2.0]
+    assert column(np.array(["a"])) == ["a"]
+    assert column(np.array([1, 2], np.uint64)) == [1, 2]
+    index = NamespaceIndex()
+    index.put(1, "scalar", 0)
+    index.enter([2, 3], "list", np.array([1, 2]))
+    index.enter(np.array([2, 3]), "column", np.array([3, 4]))
+    index.resolve([4], "resolved_list", _free(8))
+    index.resolve(np.array([4]), "resolved_column", _free(8))
+    assert [name for name in index.tables if _is_int_table(index, name)] \
+        == ["column", "resolved_column"]
+    # entries that move take their table's form along
+    other = NamespaceIndex()
+    index.move([3], "column", other, np.array([9]))
+    index.move([3], "list", other, np.array([8]))
+    assert _is_int_table(other, "column") and type(other.tables["list"]) is dict
+    assert other.get(3, "column") == 9 and index.get(3, "column") is None
+    assert index.get(2, "column") == 3
+
+
+def test_without_the_native_runtime_every_table_is_a_dict(dict_tables_only):
+    b, st = _state(TpuKeyedStateBackend, SumAggregate(np.float32), True)
+    STATE_STATS.reset()
+    rng = np.random.default_rng(1)
+    for _ in range(6):
+        keys = rng.integers(0, 100, 40)
+        b.add_batch(st, keys.tolist(), "w", np.ones(40, np.float32))
+        b.add_batch(st, keys, "v", np.ones(40, np.float32))
+    assert st.evictions > 0
+    for index in (st.slot_index, st.host_tier.index):
+        assert all(type(t) is dict for t in index.tables.values())
+    assert STATE_STATS.int_table_rows == 0 < STATE_STATS.bulk_probe_rows
+    res, found, _ = b.get_batch(st, list(range(100)), "v")
+    assert found.sum() == len({k for k, ns in st.active_entries()
+                               if ns == "v"})
+    check_invariants(st)
+
+
+@needs_native
+@pytest.mark.parametrize("seed", range(8))
+def test_any_interleaving_gives_the_ids_a_dict_gives(seed):
+    """Random runs of probe-or-insert, lookup and take over random
+    integer keys in a few namespaces: the same ids, the same free
+    list and the same columns as the dict-only index after every
+    call."""
+    rng = np.random.default_rng([38, seed])
+    both = _BothForms(slots=4096)
+    spaces = [(-40, 40), (I64.min, I64.min + 60), (I64.max - 60, I64.max),
+              (-(1 << 40), 1 << 40)]
+    for _ in range(120):
+        namespace = ("w", int(rng.integers(0, 3)))
+        lo, hi = spaces[int(rng.integers(0, len(spaces)))]
+        n = int(rng.integers(1, 48))
+        keys = rng.integers(lo, hi, n, endpoint=True)
+        if n > 2:
+            keys[-1] = keys[0]
+        call = rng.integers(0, 4)
+        if call <= 1:
+            both.resolve(keys, namespace)
+        elif call == 2:
+            both.lookup(keys, namespace)
+        else:
+            both.lookup(keys, namespace, take=True)

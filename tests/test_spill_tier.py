@@ -11,6 +11,7 @@ import pytest
 from flink_tpu.core.config import Configuration
 from flink_tpu.core.keygroups import KeyGroupRange
 from flink_tpu.core.state import AggregatingStateDescriptor
+from flink_tpu import native
 from flink_tpu.ops.device_agg import SumAggregate
 from flink_tpu.ops.sketches import (
     CountMinSketchAggregate,
@@ -18,6 +19,7 @@ from flink_tpu.ops.sketches import (
 )
 from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.state.backend import decode_obj_column
+from flink_tpu.state import slot_index
 from flink_tpu.state.host_tier import HostTier
 from flink_tpu.state.stats import STATE_STATS
 from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
@@ -261,6 +263,74 @@ def test_bulk_tier_is_the_per_key_tier_bit_for_bit(agg_name, seed):
         assert got == want, got[0]
 
 
+def _tumbling_spill_drive(rows=48, key_space=200, windows=4, seed=5):
+    """Integer keys under ONE window a batch over a key space six
+    times the budget, each window read and cleared at its end, a
+    snapshot taken (its bytes kept) and restored from mid-way: what
+    the index, both tiers and a user see after every call."""
+    rng = np.random.default_rng(seed)
+    b, st = _state(TpuKeyedStateBackend, SumAggregate(np.float32))
+    STATE_STATS.reset()
+    seen = []
+
+    def observe(what):
+        keys, namespaces, slots = st.slot_index.columns()
+        spilled = st.host_tier.index.columns()
+        seen.append((what, keys, namespaces, slots.tolist(), spilled[0],
+                     spilled[1], spilled[2].tolist(), list(st._free),
+                     st._access_stamp.tolist(), st.evictions, st.promotions,
+                     len(st.host_tier._blocks)))
+
+    for window in range(windows):
+        ns = (window * 1000, window * 1000 + 1000)
+        live = {}
+        for _ in range(5):
+            keys = rng.integers(0, key_space, rows)
+            # cold keys once, then a hot set hammered, then fresh ones
+            keys[:rows // 2] = keys[:rows // 2] % 12
+            b.add_batch(st, keys.tolist(), ns,
+                        rng.integers(1, 100, rows).astype(np.float32))
+            live.update(dict.fromkeys(keys.tolist()))
+            observe("add")
+        res, found, _ = b.get_batch(st, list(live) + [10_000], ns)
+        seen.append(("fire", np.asarray(res)[found].tobytes(), found.tolist()))
+        if window == 1:
+            snap = b.snapshot()
+            seen.append(("snapshot", sorted(snap.blobs())))
+            b, st = _state(TpuKeyedStateBackend, SumAggregate(np.float32))
+            b.restore([snap])
+            observe("restored")
+        b.clear_batch(st, list(live), ns)
+        observe("clear")
+    return seen, st, {name: getattr(STATE_STATS, name) for name in (
+        "bulk_probe_rows", "int_table_rows", "int_table_demotions",
+        "evicted_rows", "promoted_rows")}
+
+
+@pytest.mark.skipif(not native.available(), reason="no native host runtime")
+def test_a_spilling_integer_keyed_job_is_the_same_on_both_forms(monkeypatch):
+    """Evictions move a window's keys from the device's integer table
+    into the host tier's, promotions back: the slots, the host rows,
+    the victims, the fires and the snapshot bytes are the dict form's,
+    call for call."""
+    seen, st, counts = _tumbling_spill_drive()
+    assert counts["evicted_rows"] > 0 and counts["promoted_rows"] > 0
+    assert counts["int_table_demotions"] == 0
+    # (the window restored mid-way came back through per-row
+    # namespaces: a dict, so not every row met an integer table)
+    assert 0 < counts["int_table_rows"] < counts["bulk_probe_rows"]
+    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+    want_seen, want_st, want_counts = _tumbling_spill_drive()
+    assert want_counts["int_table_rows"] == 0
+    assert {**want_counts, "int_table_rows": counts["int_table_rows"]} \
+        == counts
+    assert len(seen) == len(want_seen)
+    for step, (got, want) in enumerate(zip(seen, want_seen)):
+        assert got == want, (step, got[0])
+    assert st.capacity == want_st.capacity <= 32
+    assert not st.slot_index and not st.host_tier
+
+
 @pytest.mark.parametrize("n", [1, 5, 16, 40])
 def test_spilled_fire_goes_up_in_tiles_of_the_result_shape(
         n, monkeypatch):
@@ -333,10 +403,25 @@ def _block(lo, hi):
              * np.ones((1, 4), np.uint8)})
 
 
-def test_host_tier_files_slices_releases_and_compacts():
+def _evicted(tier, keys, namespaces, comps):
+    """File a block as an eviction out of an integer table does: the
+    rows' keys enter the index as one column."""
+    base = tier.file(comps)
+    tier.index.enter(np.array(keys), namespaces[0],
+                     base + np.arange(len(keys)))
+
+
+@pytest.mark.parametrize("form", [
+    "dict", pytest.param("integer", marks=pytest.mark.skipif(
+        not native.available(), reason="no native host runtime"))])
+def test_host_tier_files_slices_releases_and_compacts(form):
     tier = HostTier()
-    tier.put(*_block(0, 3000))
-    tier.put(*_block(3000, 5000))
+    put = tier.put if form == "dict" else \
+        lambda *block: _evicted(tier, *block)
+    put(*_block(0, 3000))
+    put(*_block(3000, 5000))
+    assert isinstance(tier.index.tables["w"], native.NativeIntTable) \
+        == (form == "integer")
     assert len(tier) == 5000 and (4999, "w") in tier
     assert tier.get(4000, "w")["a"] == 4000.0
     assert tier.get(9, "x") is None and tier.get(9000, "w") is None
@@ -350,7 +435,7 @@ def test_host_tier_files_slices_releases_and_compacts():
     tier.release([tier.index.pop(k, "w") for k in range(3000, 5000)])
     assert len(tier._blocks) == 1 and tier._rows == 3000
     # released rows outnumber live ones: the live rows move together
-    tier.release(tier.index.lookup(range(0, 2000), "w", 2000, take=True))
+    tier.release(tier.index.lookup(range(0, 2000), "w", take=True))
     assert tier._rows == len(tier) == 1000
     assert tier.get(2500, "w")["a"] == 2500.0
     keys, namespaces, comps = tier.columns()

@@ -6,6 +6,9 @@ eviction/spill boundaries, a batch straddling a checkpoint barrier,
 and a seeded chaos restore.
 """
 
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -23,7 +26,9 @@ from flink_tpu.core.state import (
     ListStateDescriptor,
     ReducingStateDescriptor,
 )
+from flink_tpu import native
 from flink_tpu.ops.device_agg import SumAggregate
+from flink_tpu.state import slot_index
 from flink_tpu.state.loader import load_state_backend
 from flink_tpu.state.stats import STATE_STATS
 from flink_tpu.streaming.elements import RecordBatch
@@ -598,3 +603,146 @@ def test_state_gauges_surface():
     assert dump["state.flushRows"] >= 3
     assert dump["state.device.states"] >= 1
     assert dump["state.device.slotsInUse"] >= 3
+
+
+# ---------------------------------------------------------------------
+# the slot index's two forms (a dict, the native integer table a window
+# of integer keys gets): the same job on both, the doors counted, a
+# reader on another thread
+# ---------------------------------------------------------------------
+
+needs_native = pytest.mark.skipif(
+    not native.available(), reason="no native host runtime")
+
+
+def _observe(st, backend):
+    """What the index, the slots and a snapshot of a state hold."""
+    keys, namespaces, slots = st.slot_index.columns()
+    spilled = st.host_tier.index.columns()
+    return {"index": (keys, namespaces, slots.tolist()),
+            "spilled": (spilled[0], spilled[1], spilled[2].tolist()),
+            "free": list(st._free), "clock": st._clock,
+            "stamps": st._access_stamp.tolist(),
+            "snapshot": sorted(backend.snapshot().blobs())}
+
+
+def _tumbling_job(key_of=int, windows=3, rows=64, key_space=150,
+                  conf=None, seed=11):
+    """Tumbling 1 s windows of sums, `rows`-row batches, every window
+    fired and cleared; returns the emitted rows, an observation after
+    every batch and fire, the state and `STATE_STATS`' counts."""
+    op = _window_op(TumblingEventTimeWindows.of(1000))
+    h = OneInputStreamOperatorTestHarness(
+        op, key_selector=lambda x: x[0], state_backend=conf or "tpu")
+    h.open()
+    STATE_STATS.reset()
+    rng = np.random.default_rng(seed)
+    seen = []
+    for window in range(windows):
+        for _ in range(4):
+            keys = rng.integers(0, key_space, rows)
+            ts = window * 1000 + np.sort(rng.integers(0, 1000, rows))
+            column = keys if key_of is int else \
+                np.array([key_of(k) for k in keys.tolist()], object)
+            h.process_batch(RecordBatch(
+                {"f0": column,
+                 "f1": rng.integers(0, 100, rows).astype(np.float64)}, ts=ts))
+            seen.append(_observe(op.window_state, op.keyed_backend))
+        h.process_watermark(window * 1000 + 999)
+        seen.append(_observe(op.window_state, op.keyed_backend))
+    out = [(r.value, r.timestamp) for r in h.get_output()]
+    counts = {name: getattr(STATE_STATS, name) for name in (
+        "bulk_probe_rows", "per_key_probe_rows", "int_table_rows",
+        "int_table_demotions")}
+    return out, seen, op.window_state, counts
+
+
+@needs_native
+def test_an_integer_keyed_job_is_the_same_on_both_forms(monkeypatch):
+    """Add, fire, clear over three windows: the integer tables give the
+    slots, the free list, the stamps, the results and the snapshot
+    bytes the dicts give, step for step."""
+    out, seen, st, counts = _tumbling_job()
+    assert counts["int_table_rows"] == counts["bulk_probe_rows"] > 0
+    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+    want_out, want_seen, want_st, want_counts = _tumbling_job()
+    assert want_counts["int_table_rows"] == 0
+    assert want_counts["bulk_probe_rows"] == counts["bulk_probe_rows"]
+    assert out == want_out and len(out) > 300
+    for step, (got, want) in enumerate(zip(seen, want_seen)):
+        assert got == want, step
+    assert not st.slot_index and not want_st.slot_index
+    assert st.capacity == want_st.capacity
+
+
+@needs_native
+@pytest.mark.parametrize("job", ["tumbling_int", "tumbling_str", "tumbling_mixed",
+                                 "sessions_int"])
+def test_which_jobs_probe_integer_tables(job):
+    """Integer keys under one window a batch: every bulk-probed row on
+    an integer table, none demoted.  String keys, and session windows
+    (a namespace per row): none."""
+    if job == "sessions_int":
+        STATE_STATS.reset()
+        _, op, _ = _drive("batch", "tpu", EventTimeSessionWindows.with_gap(400))
+        assert op.boxed_fallbacks == 0
+        assert STATE_STATS.bulk_probe_rows > 300
+        assert STATE_STATS.int_table_rows == 0
+        assert STATE_STATS.int_table_demotions == 0
+        return
+    key_of = {"tumbling_int": int, "tumbling_str": lambda k: f"k{k}",
+              "tumbling_mixed": lambda k: k if k % 7 else float(k) + 0.5}[job]
+    _, _, st, counts = _tumbling_job(key_of)
+    assert counts["per_key_probe_rows"] == 0 and not st.slot_index
+    assert counts["bulk_probe_rows"] > 3 * 4 * 64
+    assert counts["int_table_demotions"] == 0
+    assert counts["int_table_rows"] == (
+        counts["bulk_probe_rows"] if job == "tumbling_int" else 0)
+
+
+@needs_native
+def test_a_query_from_another_thread_while_the_owner_inserts_a_million_keys():
+    """`query_by_key` probes the slot index from a foreign thread while
+    the owner's batches grow the window's integer table from 16 cells
+    to 2^21: every read finds the key's own slot (its sum) or, for a
+    key not flushed yet, nothing."""
+    b = make_backend("tpu")
+    st = b.get_or_create_keyed_state(
+        AggregatingStateDescriptor("s", SumAggregate(np.float32)))
+    settled = np.arange(0, 2_000_000, 2000)  # 1,000 keys, value key % 97 + 1
+    value_of = lambda keys: (keys % 97 + 1).astype(np.float32)
+    b.add_batch(st, settled, "w", value_of(settled))
+    b.flush_all()
+    wrong, reads, stop = [], [0], threading.Event()
+
+    def reader():
+        rng = np.random.default_rng(3)
+        while not stop.is_set():
+            for key in rng.integers(0, 2_000_000, 64).tolist():
+                got = st.query_by_key(key, "w")
+                want = float(key % 97 + 1)
+                if not (got == want or (got is None and key % 2000)):
+                    wrong.append((key, got))
+                reads[0] += 1
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    thread = threading.Thread(target=reader)
+    thread.start()
+    try:
+        rng = np.random.default_rng(4)
+        fresh = rng.permutation(np.arange(1, 2_000_000, 2))[:1_000_000]
+        for lo in range(0, len(fresh), 8192):
+            keys = fresh[lo:lo + 8192]
+            b.add_batch(st, keys, "w", value_of(keys))
+    finally:
+        stop.set()
+        thread.join(timeout=60)
+        sys.setswitchinterval(interval)
+    assert not thread.is_alive()
+    assert not wrong and reads[0] > 1000
+    assert isinstance(st.slot_index.tables["w"], native.NativeIntTable)
+    assert len(st.slot_index) == 1_001_000
+    b.flush_all()
+    res, found, _ = b.get_batch(st, fresh[:5000], "w")
+    assert found.all() and res.tolist() == value_of(fresh[:5000]).tolist()

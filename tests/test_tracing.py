@@ -1018,6 +1018,52 @@ def test_period_deltas_sum_to_the_stats_and_only_a_fire_cuts(
     assert only["phases"]["window.watermark"]["count"] == 1
 
 
+def test_counts_of_a_phase_are_summed_in_the_books_and_cut_by_period(
+        forced_collections_only):
+    """`add_count` is an attribute of the event and a sum per name:
+    `stats()[name]["counts"]`, and each period holds its growth."""
+    tr = forced_collections_only
+
+    def batch(rows, column):
+        with tr.phase("window.ingest"):
+            with tr.phase("state.add.slots") as phase:
+                phase.add_count("rows", rows)
+                phase.add_count("int_table", column)
+            with tr.phase("state.flush", rows=rows):  # a label: no sum
+                pass
+
+    batch(8, 8)
+    batch(4, 0)
+    _watermark(tr, 1, end=1000, work=())
+    batch(16, 16)
+    _watermark(tr, 1, end=2000, work=())
+    stats = tr.stats()
+    assert stats["state.add.slots"]["counts"] == {"rows": 28, "int_table": 24}
+    assert "counts" not in stats["state.flush"]
+    first, second = tr.periods()
+    assert first["phases"]["state.add.slots"]["counts"] \
+        == {"rows": 12, "int_table": 8}
+    assert second["phases"]["state.add.slots"]["counts"] \
+        == {"rows": 16, "int_table": 16}
+    assert "counts" not in second["phases"]["state.flush"]
+    # a count that did not grow is left out like any other field
+    with tr.phase("state.add.slots") as phase:
+        phase.add_count("rows", 2)
+        phase.add_count("int_table", 0)
+    _watermark(tr, 1, end=3000, work=())
+    assert tr.periods()[-1]["phases"]["state.add.slots"]["counts"] \
+        == {"rows": 2}
+    # on the event too, when the ring is on
+    tr.enabled = True
+    try:
+        with tr.phase("state.add.slots") as phase:
+            phase.add_count("int_table", 5)
+        [event] = [e for e in tr.recent(5) if e["name"] == "state.add.slots"]
+        assert event["args"] == {"int_table": 5}
+    finally:
+        tr.enabled = False
+
+
 def test_the_period_ring_stops_at_512_and_counts_what_it_dropped():
     tr = Tracer()
     for i in range(tracing.MAX_PERIODS + 8):
