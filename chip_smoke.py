@@ -39,6 +39,17 @@ Legs:
                         eviction, a boxed batch, a late row or a
                         per-key probe of the slot index beyond the
                         merges' targets
+  2e session checkpoint leg 2d's job checkpointing every 5 s
+                        (asynchronous, to a filesystem directory); the
+                        consumer waits two periods before the end of
+                        the log for one of the open sessions at their
+                        working number; one restore from what that
+                        directory retains by a second job, whose rows
+                        must be the first job's for every session that
+                        fired after the checkpoint; prints the
+                        barrier's synchronous ms, the capture's and
+                        the written bytes, trigger -> durable ms,
+                        restore s
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -114,6 +125,8 @@ from flink_tpu.streaming.windowing import (  # noqa: E402
 from flink_tpu.table import StreamTableEnvironment  # noqa: E402
 
 WINDOW_MS = 1000
+#: leg 2e checkpoints as the cell `session_cm_log_ckpt.zipf` does
+CHECKPOINT_INTERVAL_MS = 5000
 #: operators work in batches of this many rows; it also bounds how many
 #: slots a batch that straddles a window end can claim before the old
 #: window's slots are released
@@ -728,13 +741,10 @@ def emit_session(key, window, vals):
              window.end, *vals[0].tolist())]
 
 
-def leg_state_sessions(cfg, seed):
-    """Leg 2's route under the first merging assigner, fed by the
-    program's own log connector: a producer fills a 4-partition
-    columnar log period by period, round-robin, the bounded consumer
-    reads one chunk a partition a step and emits one watermark lagging
-    by a period; every open session is a Count-Min sketch in a slot of
-    its own, and the budget holds them all."""
+def session_stream(cfg, seed):
+    """Legs 2d and 2e's events in a 4-partition columnar log, period by
+    period, round-robin; returns (log, keys, items, timestamps, the
+    tracked items)."""
     se = cfg["session"]
     rng = np.random.default_rng(seed + 3)
     per, periods, parts = se["events_per_period"], se["periods"], \
@@ -754,12 +764,15 @@ def leg_state_sessions(cfg, seed):
             rows = slice(p * per + part, (p + 1) * per, parts)
             log.append_columns(part, {"f0": keys[rows], "f1": items[rows]},
                                ts[rows])
+    return log, keys, items, ts, watch
+
+
+def session_job(cfg, log, source, watch):
+    """Legs 2d and 2e's job over `source`: (env, sink)."""
+    se = cfg["session"]
     env = StreamExecutionEnvironment(Configuration().set(
         "state.backend.tpu.max-device-slots", se["budget"]))
     env.set_state_backend("tpu")
-    source = ReplayableLogSource(log, bounded=True,
-                                 watermark_lag_ms=WINDOW_MS,
-                                 batch_per_partition=per // parts)
     windowed = (env.add_source(source, name="log")
                 .key_by(0)
                 .window(EventTimeSessionWindows.with_gap(se["gap_ms"])))
@@ -768,6 +781,183 @@ def leg_state_sessions(cfg, seed):
     windowed.aggregate(
         ItemCounts(unit_weights=True, queries=watch),
         window_function=emit_session).add_sink(sink)
+    return env, sink
+
+
+class CheckpointedConsumer(ReplayableLogSource):
+    """Leg 2e's consumer: once it has read `wait_at` rows of every
+    partition it reads on only when a checkpoint that was triggered
+    after it stopped is durable (the job checkpoints on its interval;
+    nothing is asked for).  Class attributes: the executor copies a
+    function per attempt."""
+
+    client = None
+    wait_at = 0
+    waiting = None  # the books when it stopped, and the newest checkpoint
+    durable = None  # the books once one triggered since is durable, its id
+
+    @staticmethod
+    def books(**more):
+        return {"sync_ms": tracing.get_tracer().stats().get(
+                    "checkpoint.sync", {}).get("total_ms", 0.0),
+                "rows": STATE_STATS.snapshot_columns,
+                "bytes_device": STATE_STATS.snapshot_bytes_device, **more}
+
+    def emit_step(self, ctx, max_records):
+        cls = type(self)
+        if cls.durable is None \
+                and min(self.offsets.values()) >= cls.wait_at:
+            coordinator = cls.client.executor_state["coordinator"]
+            if cls.waiting is None:
+                cls.waiting = cls.books(
+                    newest=max(coordinator.stats, default=0))
+            done = [cid for cid, st in coordinator.stats.items()
+                    if cid > cls.waiting["newest"]
+                    and st.status == "completed"]
+            if not done:
+                return True
+            cls.durable = cls.books(checkpoint=done[0])
+        return super().emit_step(
+            ctx, self.batch_per_partition * self.log.num_partitions)
+
+
+class TimedConsumer(ReplayableLogSource):
+    """Leg 2e's second consumer: notes when it is first asked for
+    rows (the restore is over then)."""
+
+    first_step = None
+
+    def emit_step(self, ctx, max_records):
+        if type(self).first_step is None:
+            type(self).first_step = time.perf_counter()
+        return super().emit_step(
+            ctx, self.batch_per_partition * self.log.num_partitions)
+
+
+def leg_state_sessions_checkpoint(cfg, seed):
+    """Leg 2d's job with checkpoints every `CHECKPOINT_INTERVAL_MS`
+    to a filesystem directory, asynchronously; the consumer waits for
+    one taken with the open sessions at their working number (two
+    periods before the end of the log) and its numbers are printed; a
+    second job over a plain consumer of the same log starts from what
+    the directory retains through `set_savepoint_restore`, and must
+    emit the first job's rows for every session that fired after that
+    checkpoint, integer for integer."""
+    import shutil
+    import tempfile
+    from flink_tpu.runtime.checkpoints import load_retained_checkpoint
+    se = cfg["session"]
+    log, keys, items, ts, watch = session_stream(cfg, seed)
+    per, periods, parts = se["events_per_period"], se["periods"], \
+        SESSION_PARTITIONS
+    directory = tempfile.mkdtemp(prefix="chip-smoke-chk-")
+    problems = []
+    try:
+        CheckpointedConsumer.client = None
+        CheckpointedConsumer.waiting = CheckpointedConsumer.durable = None
+        CheckpointedConsumer.wait_at = (periods - 2) * per // parts
+        source = CheckpointedConsumer(log, bounded=True,
+                                      watermark_lag_ms=WINDOW_MS,
+                                      batch_per_partition=per // parts)
+        env, sink = session_job(cfg, log, source, watch)
+        env.enable_checkpointing(CHECKPOINT_INTERVAL_MS, async_persist=True)
+        env.set_checkpoint_storage("filesystem", directory, retain=1)
+        env.register_job_listener(
+            lambda client: setattr(CheckpointedConsumer, "client", client))
+        ops = capture_operators(env)
+        env.execute("chip-smoke-sessions-checkpointed")
+        first = np.stack([np.asarray(c, np.int64)
+                          for c in sink.columns()], axis=1)
+        if CheckpointedConsumer.durable is None:
+            return ["no checkpoint became durable while the consumer "
+                    "waited"], {}
+        then, now = CheckpointedConsumer.waiting, CheckpointedConsumer.durable
+        cid = now["checkpoint"]
+        sync_ms = now["sync_ms"] - then["sync_ms"]
+        stats = CheckpointedConsumer.client.executor_state[
+            "coordinator"].stats[cid]
+        wop = one_of(ops, WindowOperator)
+        live = now["rows"] - then["rows"]
+        captured = now["bytes_device"] - then["bytes_device"]
+        # the first job's table goes before the second's comes
+        for arr in wop.window_state.device_state.values():
+            arr.delete()
+        wop.window_state.device_state = {}
+        del ops, wop, env, sink, source
+        gc.collect()
+        point = load_retained_checkpoint(directory)
+        watermark = None
+        for task in point["tasks"].values():
+            for snap in task["operators"].values():
+                timers = snap.get("timers")
+                if timers and timers["event"]:
+                    watermark = timers["watermark"]
+        TimedConsumer.first_step = None
+        env2, sink2 = session_job(cfg, log, TimedConsumer(
+            log, bounded=True, watermark_lag_ms=WINDOW_MS,
+            batch_per_partition=per // parts), watch)
+        env2.set_savepoint_restore(directory)
+        ops2 = capture_operators(env2)
+        t0 = time.perf_counter()
+        env2.execute("chip-smoke-sessions-restored")
+        t1 = time.perf_counter()
+        wop2 = one_of(ops2, WindowOperator)
+        again = np.stack([np.asarray(c, np.int64)
+                          for c in sink2.columns()], axis=1)
+        # columns: key, period, session start, session end, total, ...
+        expected = first[first[:, 3] - 1 > watermark]
+
+        def ordered(rows):
+            return rows[np.lexsort(rows.T[::-1])]
+        if again.shape != expected.shape or not np.array_equal(
+                ordered(again), ordered(expected)):
+            problems.append(
+                f"the restored job emitted {len(again)} rows, the first "
+                f"{len(expected)} for the sessions that fired after "
+                f"checkpoint {point['checkpoint_id']}, and they differ")
+        if point["checkpoint_id"] < cid:
+            problems.append(f"the directory retains checkpoint "
+                            f"{point['checkpoint_id']}, older than {cid}")
+        if wop2.num_late_records_dropped:
+            problems.append(f"{wop2.num_late_records_dropped} rows "
+                            f"dropped as late after the restore")
+        if wop2.window_state.evictions:
+            problems.append(f"{wop2.window_state.evictions} evictions "
+                            f"after the restore")
+        first_step = TimedConsumer.first_step or t1
+        return problems, {
+            "checkpoint": cid, "restored_from": point["checkpoint_id"],
+            "rows_in_checkpoint": live,
+            "sync_ms": round(sync_ms, 1),
+            "capture_bytes_device": captured,
+            "written_bytes": stats.state_bytes,
+            "trigger_to_durable_ms": round(stats.duration_ms, 1),
+            "restore_s": round(first_step - t0, 2),
+            "catch_up_s": round(t1 - first_step, 2),
+            "rows_after_checkpoint": len(expected),
+            "rows_restored_job": len(again),
+            "open_across_checkpoint":
+                int((expected[:, 2] <= watermark).sum())}
+    finally:
+        shutil.rmtree(directory, ignore_errors=True)
+
+
+def leg_state_sessions(cfg, seed):
+    """Leg 2's route under the first merging assigner, fed by the
+    program's own log connector: a producer fills a 4-partition
+    columnar log period by period, round-robin, the bounded consumer
+    reads one chunk a partition a step and emits one watermark lagging
+    by a period; every open session is a Count-Min sketch in a slot of
+    its own, and the budget holds them all."""
+    se = cfg["session"]
+    log, keys, items, ts, watch = session_stream(cfg, seed)
+    per, periods, parts = se["events_per_period"], se["periods"], \
+        SESSION_PARTITIONS
+    n = per * periods
+    source = ReplayableLogSource(log, bounded=True,
+                                 watermark_lag_ms=WINDOW_MS,
+                                 batch_per_partition=per // parts)
+    env, sink = session_job(cfg, log, source, watch)
     ops = capture_operators(env)
     probes = STATE_STATS.per_key_probe_rows
     env.execute("chip-smoke-state-sessions")
@@ -1142,6 +1332,8 @@ def main(argv=None) -> int:
     run("2b spill tier", leg_state_spill, cfg, args.seed)
     run("2c sliding quantiles", leg_state_sliding, cfg, args.seed)
     run("2d session count-min", leg_state_sessions, cfg, args.seed)
+    run("2e session checkpoint", leg_state_sessions_checkpoint, cfg,
+        args.seed)
     sql = run("3a sql", leg_sql, cfg, events, ref)
     run("3b datastream", leg_datastream_default, cfg, events, ref)
     run("4a entry step", leg_entry_step, cfg)

@@ -2,6 +2,8 @@
 bin/flink script).
 
     python -m flink_tpu run <script.py> [args...]   execute a job script
+                                   [-s PATH]         ... from a savepoint or
+                                                     a retained checkpoint
     python -m flink_tpu lint <script.py|dir> [args...] pre-flight checks
                                    [--strict]        without executing:
                                    [--json]          graph linter + UDF
@@ -72,9 +74,15 @@ def main(argv=None) -> int:
     if verb == "info":
         return _info()
     if verb == "run":
+        if len(rest) >= 2 and rest[0] in ("-s", "--from-savepoint"):
+            # (ref: `flink run -s <path>`) a savepoint file, or a
+            # retained checkpoint directory / chk-N file
+            from flink_tpu.streaming import datastream
+            datastream.DEFAULT_RESTORE_PATH = rest[1]
+            rest = rest[2:]
         if not rest:
-            print("usage: flink_tpu run <script.py> [args...]",
-                  file=sys.stderr)
+            print("usage: flink_tpu run [-s <savepoint or retained "
+                  "checkpoint>] <script.py> [args...]", file=sys.stderr)
             return 2
         sys.argv = rest
         runpy.run_path(rest[0], run_name="__main__")

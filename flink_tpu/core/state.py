@@ -129,6 +129,12 @@ class StateDescriptor(Generic[T]):
 
     #: discriminator mirroring StateDescriptor.Type
     TYPE = "value"
+    #: the state's owner sets this on its descriptor to promise that it
+    #: never writes into a stored value (`update` stores a new object):
+    #: a backend whose snapshot is finished after the barrier may then
+    #: hold the values by reference until it serializes them.  Without
+    #: the promise a value is serialized at the barrier
+    copy_on_write = False
 
     def __init__(
         self,

@@ -65,6 +65,42 @@ def _crc_unwrap(data: bytes, path: str) -> bytes:
     return payload
 
 
+def read_chunk(fs, shared_dir: str, h: str, place=None,
+               packs: Optional[dict] = None):
+    """One shared chunk of a filesystem checkpoint directory: a file
+    of its own (`shared/<hash>`), or `place` = (pack, offset, length)
+    inside `shared/<pack>`, whose payload is read once into `packs`."""
+    if place is None:
+        path = f"{shared_dir}/{h}"
+        with fs.open(path, "rb") as f:
+            return pickle.loads(_crc_unwrap(f.read(), path))
+    name, offset, length = place
+    payload = None if packs is None else packs.get(name)
+    if payload is None:
+        path = f"{shared_dir}/{name}"
+        with fs.open(path, "rb") as f:
+            payload = _crc_unwrap(f.read(), path)
+        if packs is not None:
+            packs[name] = payload
+    return pickle.loads(payload[offset:offset + length])
+
+
+def resolve_task_snapshot(snapshot: dict) -> dict:
+    """A task's snapshot (``{"operators": {id: {part: value}}}``) with
+    every part that is a `DeferredSnapshot` handle replaced by what it
+    resolves to; the snapshot itself where it holds none."""
+    from flink_tpu.state.backend import DeferredSnapshot
+    operators = snapshot.get("operators", {})
+    if not any(isinstance(value, DeferredSnapshot)
+               for parts in operators.values() for value in parts.values()):
+        return snapshot
+    return {**snapshot, "operators": {
+        op_id: {part: (value.resolve()
+                       if isinstance(value, DeferredSnapshot) else value)
+                for part, value in parts.items()}
+        for op_id, parts in operators.items()}}
+
+
 class CheckpointStorage:
     """Completed-checkpoint store contract (ref: CompletedCheckpointStore
     + CheckpointStorage).  Keys are (vertex_id, subtask_index)."""
@@ -188,9 +224,20 @@ class FsCheckpointStorage(CheckpointStorage):
         self.registry = SharedStateRegistry(
             store=self._store_chunk,
             delete=self._delete_chunk,
-            exists=lambda h: self.fs.exists(f"{self._shared_dir}/{h}"))
+            exists=lambda h: h in self._packed
+            or self.fs.exists(f"{self._shared_dir}/{h}"))
         self._adopted: Set[int] = set()
         self._chunk_sizes: Dict[str, int] = {}
+        # the chunks a checkpoint stores anew go into ONE file,
+        # `shared/pack-<id>` (a hundred key-group chunks as a hundred
+        # files cost the writer thread a few system calls each, and
+        # every one hands the interpreter lock to the task's thread
+        # and waits to get it back): hash -> (pack, offset, length),
+        # also written into every chk-N that references the chunk, and
+        # per pack the chunks still referenced (none left: it goes)
+        self._packed: Dict[str, Tuple[str, int, int]] = {}
+        self._pack_live: Dict[str, int] = {}
+        self._pending_pack: Optional[list] = None
         # sweep orphaned *.part files first: a crashed predecessor's
         # torn write must never be adopted, and a lingering chunk .part
         # would shadow the next write of the same hash
@@ -208,8 +255,7 @@ class FsCheckpointStorage(CheckpointStorage):
         for cid in self.checkpoint_ids():
             try:
                 entry = self._read_entry(self._path(cid))
-                self.registry.adopt_checkpoint(cid, entry["tasks"])
-                self._adopted.add(cid)
+                self._adopt(cid, entry)
             except Exception:  # noqa: BLE001 — unreadable old file:
                 pass           # rotation will still remove its chk-N
 
@@ -247,34 +293,85 @@ class FsCheckpointStorage(CheckpointStorage):
             data = f.read()
         return pickle.loads(_crc_unwrap(data, path))
 
+    def _adopt(self, cid: int, entry: dict) -> None:
+        """Take a retained checkpoint's chunk references, and where its
+        chunks lie in packs, into this storage's books."""
+        refs = self.registry.adopt_checkpoint(cid, entry["tasks"])
+        for h, place in (entry.get("packs") or {}).items():
+            if h in refs and h not in self._packed:
+                self._packed[h] = tuple(place)
+                self._pack_live[place[0]] = \
+                    self._pack_live.get(place[0], 0) + 1
+        self._adopted.add(cid)
+
     def _store_chunk(self, h: str, payload) -> None:
         data = pickle.dumps(payload, protocol=pickle.HIGHEST_PROTOCOL)
         self._chunk_sizes[h] = len(data)
+        if self._pending_pack is not None:
+            self._pending_pack.append((h, data))  # (persist writes it)
+            return
         self._write_file(f"{self._shared_dir}/{h}.part",
                          f"{self._shared_dir}/{h}", data)
 
+    def _write_pack(self, checkpoint_id: int, chunks: list) -> None:
+        name = f"pack-{checkpoint_id}"
+        self._write_file(f"{self._shared_dir}/{name}.part",
+                         f"{self._shared_dir}/{name}",
+                         b"".join(data for _, data in chunks))
+        offset = 0
+        for h, data in chunks:
+            self._packed[h] = (name, offset, len(data))
+            offset += len(data)
+        self._pack_live[name] = len(chunks)
+
     def _delete_chunk(self, h: str) -> None:
+        place = self._packed.pop(h, None)
+        path = f"{self._shared_dir}/{h}"
+        if place is not None:
+            self._pack_live[place[0]] -= 1
+            if self._pack_live[place[0]] > 0:
+                return
+            del self._pack_live[place[0]]
+            path = f"{self._shared_dir}/{place[0]}"
         try:
-            self.fs.remove(f"{self._shared_dir}/{h}")
+            self.fs.remove(path)
         except OSError:
             pass
 
-    def _fetch_chunk(self, h: str):
+    def _fetch_chunk(self, h: str, packs: Optional[dict] = None):
+        """`packs`: pack name -> its payload, kept by a caller that
+        fetches many chunks."""
         def attempt():
             faults.fire("storage.fetch_chunk")
-            return self._read_entry(f"{self._shared_dir}/{h}")
+            return read_chunk(self.fs, self._shared_dir, h,
+                              self._packed.get(h), packs)
 
         return self._retry(attempt)
 
     _fetch_shared = _fetch_chunk
 
     def persist(self, checkpoint_id, metadata, task_snapshots):
-        tasks = self.registry.register_checkpoint(checkpoint_id,
-                                                  task_snapshots)
+        self._pending_pack = []
+        try:
+            tasks = self.registry.register_checkpoint(checkpoint_id,
+                                                      task_snapshots)
+            new, self._pending_pack = self._pending_pack, None
+            if new:
+                try:
+                    self._write_pack(checkpoint_id, new)
+                except BaseException:
+                    # registered, never stored: out of the books again
+                    self.registry.release_checkpoint(checkpoint_id)
+                    raise
+        finally:
+            self._pending_pack = None
+        referenced = self.registry._by_checkpoint.get(checkpoint_id, ())
         payload = {
             "checkpoint_id": checkpoint_id,
             "metadata": metadata,
             "tasks": tasks,
+            "packs": {h: self._packed[h] for h in referenced
+                      if h in self._packed},
         }
         data = pickle.dumps(payload, protocol=pickle.HIGHEST_PROTOCOL)
         size = len(data)
@@ -321,16 +418,15 @@ class FsCheckpointStorage(CheckpointStorage):
             # recovery in a fresh process: re-register the retained
             # checkpoint's chunk references so future retention
             # rotation refcounts them correctly
-            self.registry.adopt_checkpoint(checkpoint_id,
-                                           entry["tasks"])
-            self._adopted.add(checkpoint_id)
+            self._adopt(checkpoint_id, entry)
         cache: Dict[str, Any] = {}
+        packs: Dict[str, bytes] = {}
 
         def fetch(r):
             if not isinstance(r, ChunkRef):
                 return r
             if r.hash not in cache:
-                cache[r.hash] = self._fetch_chunk(r.hash)
+                cache[r.hash] = self._fetch_chunk(r.hash, packs)
             return cache[r.hash]
 
         return {**entry, "tasks": map_chunks(entry["tasks"], fetch)}
@@ -557,10 +653,71 @@ def write_savepoint(directory: str, checkpoint_id: int, metadata: dict,
 
 
 def load_savepoint(path: str) -> dict:
+    """What a job is started from (`flink run -s <path>`): a savepoint
+    file, or a retained checkpoint of `FsCheckpointStorage`, named by
+    its directory (the newest loadable `chk-N` in it) or by the
+    `chk-N` file itself."""
+    import os
     from flink_tpu.core.fs import get_file_system
     fs, path = get_file_system(path)
+    name = os.path.basename(path.rstrip("/"))
+    if name.startswith("chk-") and name[4:].isdigit():
+        return load_retained_checkpoint(os.path.dirname(path.rstrip("/")),
+                                        int(name[4:]))
+    try:
+        names = fs.listdir(path)
+    except (NotADirectoryError, FileNotFoundError):
+        names = []
+    if any(n.startswith("chk-") for n in names):
+        return load_retained_checkpoint(path)
     with fs.open(path, "rb") as f:
         return pickle.load(f)
+
+
+def load_retained_checkpoint(directory: str,
+                             checkpoint_id: Optional[int] = None) -> dict:
+    """Read a checkpoint out of a directory `FsCheckpointStorage`
+    wrote, its chunks fetched from `shared/`, without opening the
+    directory as a storage (which would sweep and adopt it): the
+    newest one that loads, or the one named."""
+    from flink_tpu.core.fs import get_file_system
+    from flink_tpu.state.shared_registry import ChunkRef, map_chunks
+    fs, directory = get_file_system(directory)
+    root = directory.rstrip("/")
+
+    def read(path):
+        with fs.open(path, "rb") as f:
+            return pickle.loads(_crc_unwrap(f.read(), path))
+
+    ids = sorted(int(n[4:]) for n in fs.listdir(directory)
+                 if n.startswith("chk-") and n[4:].isdigit())
+    if checkpoint_id is not None:
+        ids = [i for i in ids if i == checkpoint_id]
+    error: Optional[BaseException] = None
+    for cid in reversed(ids):
+        cache: Dict[str, Any] = {}
+        packs: Dict[str, bytes] = {}
+        try:
+            entry = read(f"{root}/chk-{cid}")
+            places = entry.get("packs") or {}
+
+            def fetch(ref):
+                if not isinstance(ref, ChunkRef):
+                    return ref
+                if ref.hash not in cache:
+                    cache[ref.hash] = read_chunk(
+                        fs, f"{root}/shared", ref.hash,
+                        places.get(ref.hash), packs)
+                return cache[ref.hash]
+
+            return {**entry, "tasks": map_chunks(entry["tasks"], fetch)}
+        except Exception as e:  # noqa: BLE001 — torn or corrupt: the
+            if checkpoint_id is not None:
+                raise
+            error = e           # next older one, as `latest()` does
+    raise FileNotFoundError(
+        f"no loadable checkpoint in {directory}"
+        + (f" (last error: {error!r})" if error is not None else ""))
 
 
 class CheckpointFailuresExceeded(RuntimeError):
@@ -681,7 +838,11 @@ class CheckpointCoordinator:
         # release its max_concurrent slot on this very call, or a
         # single lost ack pins the slot forever
         self._abort_timed_out(now)
-        if len(self.pending) >= self.max_concurrent:
+        # a checkpoint is in flight until it is durable: its acks may
+        # be in while the writer still holds its state (device buffers
+        # of a capture, the encoded chunks), and writes must not queue
+        # up behind one another faster than they land
+        if len(self.pending) + self._inflight >= self.max_concurrent:
             return None
         # user savepoint requests bypass the periodic gating (ref:
         # triggerSavepoint — props force a trigger regardless of timers)
@@ -870,12 +1031,22 @@ class CheckpointCoordinator:
         self._finish(pc, *self._do_persist(pc), req)
 
     def _do_persist(self, pc: PendingCheckpoint):
+        """On the writer's thread under `async_persist`, else on the
+        loop's: first the acks' asynchronous parts (a backend's
+        capture comes to the host and is encoded: phases
+        `state.snapshot.d2h`, `checkpoint.encode`), put in the place
+        of their handles, then the write."""
+        from flink_tpu.runtime.tracing import get_tracer
         try:
-            state_bytes = self.storage.persist(
-                pc.checkpoint_id,
-                {"timestamp": pc.timestamp, "mode": self.mode,
-                 **self.metadata_extra},
-                pc.acks)
+            pc.acks = {task: resolve_task_snapshot(snapshot)
+                       for task, snapshot in pc.acks.items()}
+            with get_tracer().phase("checkpoint.persist",
+                                    checkpoint_id=pc.checkpoint_id):
+                state_bytes = self.storage.persist(
+                    pc.checkpoint_id,
+                    {"timestamp": pc.timestamp, "mode": self.mode,
+                     **self.metadata_extra},
+                    pc.acks)
             return state_bytes, None
         except Exception as e:  # noqa: BLE001 — a failed write aborts
             # this checkpoint, not the job (ref: abort on IO failure)

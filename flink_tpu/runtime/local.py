@@ -48,6 +48,7 @@ from flink_tpu.runtime.checkpoints import (
     CheckpointCoordinator,
     make_checkpoint_storage,
     make_restart_strategy,
+    resolve_task_snapshot,
 )
 from flink_tpu.runtime import faults
 from flink_tpu.runtime.backpressure import (
@@ -714,10 +715,12 @@ class SubtaskInstance:
         # causally link the source-side snapshot+broadcast span to the
         # coordinator's trigger (the context rides the barrier options)
         ctx = options.get("trace") if isinstance(options, dict) else None
-        with get_tracer().span_linked(self._span_checkpoint, ctx,
-                                      checkpoint_id=cid,
-                                      task=self.vertex.name,
-                                      subtask=self.subtask_index):
+        tracer = get_tracer()
+        with tracer.phase("checkpoint.sync", checkpoint_id=cid), \
+                tracer.span_linked(self._span_checkpoint, ctx,
+                                   checkpoint_id=cid,
+                                   task=self.vertex.name,
+                                   subtask=self.subtask_index):
             snapshot = self.snapshot(cid)
             self.router.broadcast_barrier(barrier)
             if self.ack_fn is not None:
@@ -890,10 +893,16 @@ class SubtaskInstance:
         snapshot, both atomically on this loop)."""
         ctx = (barrier.options.get("trace")
                if isinstance(barrier.options, dict) else None)
-        with get_tracer().span_linked(self._span_checkpoint, ctx,
-                                      checkpoint_id=barrier.checkpoint_id,
-                                      task=self.vertex.name,
-                                      subtask=self.subtask_index):
+        tracer = get_tracer()
+        # the synchronous part of a checkpoint on this task: barrier
+        # taken -> ack handed over (a backend with an asynchronous
+        # part acks a handle the checkpoint's writer resolves)
+        with tracer.phase("checkpoint.sync",
+                          checkpoint_id=barrier.checkpoint_id), \
+                tracer.span_linked(self._span_checkpoint, ctx,
+                                   checkpoint_id=barrier.checkpoint_id,
+                                   task=self.vertex.name,
+                                   subtask=self.subtask_index):
             snapshot = self.snapshot(barrier.checkpoint_id)
             self.router.broadcast_barrier(barrier)
             if self.ack_fn is not None:
@@ -1273,8 +1282,14 @@ class LocalExecutor:
             lambda vid, i: self.pts, self.channel_capacity, self.metrics)
 
     # ---- public API -------------------------------------------------
+    #: callables handed the JobClient of a job `execute` is about to
+    #: run (the environment's job listeners)
+    job_listeners: tuple = ()
+
     def execute(self, job_graph: JobGraph) -> JobExecutionResult:
         client = JobClient()
+        for listener in self.job_listeners:
+            listener(client)
         self._run_job(job_graph, client)
         return client.wait()
 
@@ -1463,6 +1478,10 @@ class LocalExecutor:
         for st in all_tasks:
             st.ack_fn = ack
             st.decline_fn = decline
+            # the coordinator resolves what an ack defers
+            # (`CheckpointCoordinator._do_persist`)
+            for op in st.operators:
+                op.deferred_snapshots = coordinator is not None
             if "alignment_spill_threshold" in cp_cfg:
                 st.alignment_spill_threshold = \
                     cp_cfg["alignment_spill_threshold"]
@@ -1924,7 +1943,7 @@ def _capture_live_state(all_tasks, failed_key):
             continue
         try:
             out[st.task_key] = {
-                "snap": st.snapshot(),
+                "snap": resolve_task_snapshot(st.snapshot()),
                 "finished": st.finished,
                 "queues": [[el for el in ch.queue if not el.is_barrier]
                            for ch in st.input_channels],

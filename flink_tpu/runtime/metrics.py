@@ -625,6 +625,9 @@ def register_state_gauges(metrics: MetricRegistry) -> None:
     g.gauge("resultPaddedRows", lambda: s.result_padded_rows)
     g.gauge("snapshotColumns", lambda: s.snapshot_columns)
     g.gauge("snapshotRows", lambda: s.snapshot_rows)
+    g.gauge("snapshotTiles", lambda: s.snapshot_tiles)
+    g.gauge("snapshotBytesDevice", lambda: s.snapshot_bytes_device)
+    g.gauge("snapshotBytesWritten", lambda: s.snapshot_bytes_written)
 
     def _dev(field):
         return device_state_summary().get(field, 0)

@@ -271,8 +271,13 @@ class MiniCluster:
         self.chain_fusion = chain_fusion
 
     # ---- public API -----------------------------------------------------
+    #: the environment's job listeners (as LocalExecutor's)
+    job_listeners: tuple = ()
+
     def execute(self, job_graph: JobGraph) -> JobExecutionResult:
         client = JobClient()
+        for listener in self.job_listeners:
+            listener(client)
         self._run_job(job_graph, client)
         return client.wait()
 

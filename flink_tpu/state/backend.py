@@ -107,6 +107,31 @@ class KeyedStateSnapshot:
         return KeyedStateSnapshot(mapped, dict(self.meta), wrap=False)
 
 
+class DeferredSnapshot:
+    """A part of a task's snapshot that is finished after the barrier:
+    what a backend with an asynchronous part puts into the ack
+    (its `capture_snapshot`; the timer service's).  `resolve()` (any thread;
+    the work is done once) gives the finished part.  A checkpoint's
+    coordinator resolves the handles of its acks on its writer and
+    puts what they give in their place before anything else reads
+    the acks (`CheckpointCoordinator._do_persist`)."""
+
+    __slots__ = ("_make", "_value", "_lock")
+
+    def __init__(self, make):
+        import threading
+        self._make = make
+        self._value = None
+        self._lock = threading.Lock()
+
+    def resolve(self):
+        with self._lock:
+            if self._make is not None:
+                self._value = self._make()
+                self._make = None
+            return self._value
+
+
 class KeyedStateBackend(abc.ABC):
     """The contract every keyed backend implements
     (ref: AbstractKeyedStateBackend.java:64)."""

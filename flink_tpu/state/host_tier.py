@@ -117,14 +117,7 @@ class HostTier:
         one copy per run of neighbours that lie in one block, straight
         into its place (ids of one fire arrive sorted, so a tile is a
         few runs)."""
-        which = self._block_of(ids)
-        cuts = np.flatnonzero(which[1:] != which[:-1]) + 1
-        for a, b in zip([0, *cuts.tolist()], [*cuts.tolist(), len(ids)]):
-            block = self._blocks[which[a]]
-            local = ids[a:b] - block.base
-            for name, arr in block.comps.items():
-                # (mode="raise" would gather into a buffer first)
-                np.take(arr, local, axis=0, out=out[name][a:b], mode="clip")
+        _gather(self._bases, self._blocks, ids, out)
 
     def release(self, ids: Iterable[int]) -> None:
         """Rows `ids` (already out of the index) hold nothing any
@@ -168,10 +161,49 @@ class HostTier:
     def columns(self) -> Tuple[list, list, Dict[str, np.ndarray]]:
         """Every live row, for a snapshot: keys, namespaces and their
         stacked components, in index order."""
-        keys, namespaces, ids = self.index.columns()
-        if not keys:
-            return keys, namespaces, {}
-        out = {name: np.empty((len(ids),) + arr.shape[1:], arr.dtype)
+        return self.capture().columns()
+
+    def capture(self) -> "HostTierCapture":
+        """The tier as of now, its rows by reference: a block's arrays
+        are never written to once filed (a release marks rows dead, a
+        compaction files a new block), so a capture stays true while
+        the tier moves on."""
+        return HostTierCapture(*self.index.columns(), self._bases,
+                               list(self._blocks))
+
+
+def _gather(bases: np.ndarray, blocks: List[_Block], ids: np.ndarray,
+            out: Dict[str, np.ndarray]) -> None:
+    which = np.searchsorted(bases, ids, side="right") - 1
+    cuts = np.flatnonzero(which[1:] != which[:-1]) + 1
+    for a, b in zip([0, *cuts.tolist()], [*cuts.tolist(), len(ids)]):
+        block = blocks[which[a]]
+        local = ids[a:b] - block.base
+        for name, arr in block.comps.items():
+            # (mode="raise" would gather into a buffer first)
+            np.take(arr, local, axis=0, out=out[name][a:b], mode="clip")
+
+
+class HostTierCapture:
+    """What :meth:`HostTier.capture` took: the index's columns (copies)
+    and the blocks (references)."""
+
+    __slots__ = ("keys", "namespaces", "ids", "_bases", "_blocks")
+
+    def __init__(self, keys, namespaces, ids, bases, blocks):
+        self.keys = keys
+        self.namespaces = namespaces
+        self.ids = ids
+        self._bases = bases
+        self._blocks = blocks
+
+    def __len__(self) -> int:
+        return len(self.keys)
+
+    def columns(self) -> Tuple[list, list, Dict[str, np.ndarray]]:
+        if not self.keys:
+            return self.keys, self.namespaces, {}
+        out = {name: np.empty((len(self.ids),) + arr.shape[1:], arr.dtype)
                for name, arr in self._blocks[0].comps.items()}
-        self.gather(ids, out)
-        return keys, namespaces, out
+        _gather(self._bases, self._blocks, self.ids, out)
+        return self.keys, self.namespaces, out

@@ -354,21 +354,14 @@ def get_introspection() -> StateIntrospection:
 # Offline snapshot inspector (`flink_tpu state inspect`)
 # ====================================================================
 
-def _read_checkpoint_entry(fs, path: str):
-    from flink_tpu.runtime.checkpoints import _crc_unwrap
-    with fs.open(path, "rb") as f:
-        data = f.read()
-    return pickle.loads(_crc_unwrap(data, path))
-
-
 def load_checkpoint_readonly(directory: str,
                              checkpoint_id: Optional[int] = None) -> dict:
     """Read-only twin of FsCheckpointStorage.load: no orphan sweep, no
     chunk adoption, no registry — safe to point at a LIVE job's
-    checkpoint directory.  Resolves ChunkRefs straight off
-    `shared/<hash>` files."""
+    checkpoint directory (`runtime/checkpoints.py`
+    `load_retained_checkpoint` reads the chunks, from their pack
+    files or `shared/<hash>`)."""
     from flink_tpu.core.fs import get_file_system
-    from flink_tpu.state.shared_registry import ChunkRef, map_chunks
     fs, directory = get_file_system(directory)
     ids = []
     for name in fs.listdir(directory):
@@ -385,19 +378,8 @@ def load_checkpoint_readonly(directory: str,
     elif checkpoint_id not in ids:
         raise FileNotFoundError(
             f"checkpoint {checkpoint_id} not in {sorted(ids)}")
-    entry = _read_checkpoint_entry(
-        fs, f"{directory.rstrip('/')}/chk-{checkpoint_id}")
-    shared = f"{directory.rstrip('/')}/shared"
-    cache: Dict[str, Any] = {}
-
-    def fetch(r):
-        if not isinstance(r, ChunkRef):
-            return r
-        if r.hash not in cache:
-            cache[r.hash] = _read_checkpoint_entry(fs, f"{shared}/{r.hash}")
-        return cache[r.hash]
-
-    return {**entry, "tasks": map_chunks(entry["tasks"], fetch)}
+    from flink_tpu.runtime.checkpoints import load_retained_checkpoint
+    return load_retained_checkpoint(directory, checkpoint_id)
 
 
 def _walk_keyed_snapshots(node, out: list) -> None:

@@ -196,15 +196,17 @@ class SharedStateRegistry:
         self._by_checkpoint[checkpoint_id] = hashes
         return out
 
-    def adopt_checkpoint(self, checkpoint_id: int, snapshot: Any) -> None:
+    def adopt_checkpoint(self, checkpoint_id: int,
+                         snapshot: Any) -> Set[str]:
         """Re-register refs of a checkpoint loaded from persistent
-        storage (recovery in a fresh process)."""
+        storage (recovery in a fresh process); returns its hashes."""
         refs: List[ChunkRef] = []
         find_chunks(snapshot, refs, kinds=(ChunkRef,))
         hashes = {r.hash for r in refs}
         for h in hashes:
             self._refs[h] = self._refs.get(h, 0) + 1
         self._by_checkpoint[checkpoint_id] = hashes
+        return hashes
 
     def release_checkpoint(self, checkpoint_id: int) -> None:
         for h in self._by_checkpoint.pop(checkpoint_id, ()):

@@ -23,6 +23,8 @@ class StateStats:
         "row_fallback_calls", "flush_batches", "flush_rows",
         "flush_row_form_batches", "flush_sizes", "result_rows",
         "result_padded_rows", "snapshot_columns", "snapshot_rows",
+        "snapshot_captures", "snapshot_tiles", "snapshot_bytes_device",
+        "snapshot_bytes_written",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
         "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
         "int_table_rows", "int_table_demotions",
@@ -57,6 +59,16 @@ class StateStats:
         #: snapshot rows serialized as columns vs boxed per-row
         self.snapshot_columns = 0
         self.snapshot_rows = 0
+        #: the tpu backend's captures at a barrier: how many, the tiles
+        #: of rows their device programs wrote into buffers of their
+        #: own, those buffers' bytes, and the bytes of the key-group
+        #: chunks the captures were encoded into (a writer resolves a
+        #: capture later: the last grows then).  All stay 0 while
+        #: nothing snapshots
+        self.snapshot_captures = 0
+        self.snapshot_tiles = 0
+        self.snapshot_bytes_device = 0
+        self.snapshot_bytes_written = 0
         #: the tpu backend's spill tier: rows evicted to host RAM, rows
         #: promoted back, rows a batched fire finalised from there, and
         #: times the device budget was overrun (nothing cold to evict)

@@ -55,6 +55,7 @@ from flink_tpu.state.backend import (
     VOID_NAMESPACE,
     KeyedStateBackend,
     KeyedStateSnapshot,
+    DeferredSnapshot,
 )
 from flink_tpu.state.heap_backend import (
     HeapAggregatingState,
@@ -68,12 +69,19 @@ from flink_tpu.state.heap_backend import (
 )
 from flink_tpu.runtime.device_stats import TELEMETRY, tree_nbytes
 from flink_tpu.runtime.tracing import get_tracer, traced_jit
+from flink_tpu.state.device_snapshot import SnapshotPlan, StateCapture
 from flink_tpu.state.host_tier import HostTier
 from flink_tpu.state.slot_index import (
     NamespaceIndex,
     cut_by_namespace,
     object_column,
     pick,
+)
+from flink_tpu.state.sparse_rows import (
+    SparseRows,
+    concat_columns,
+    dense_rows,
+    take_rows,
 )
 from flink_tpu.state.stats import STATE_STATS, register_device_state
 
@@ -93,6 +101,15 @@ PROMOTE_TILE_BYTES = 4 << 20
 #: their copies to the host all in flight at once (one copy of 1 GiB
 #: ran at half the rate of sixteen of 64 MiB on a v5e's host link)
 EVICT_PIECE_BYTES = 64 << 20
+#: rows one `state.restore` dispatch uploads, in bytes, and the (slot,
+#: cell, value) triples one `state.restore_cells` dispatch scatters: a
+#: restore goes up in tiles of these shapes, never as one array the
+#: size of the state beside the table
+RESTORE_TILE_BYTES = 16 << 20
+RESTORE_TILE_CELLS = 1 << 18
+#: dense columns of a restore up to this many bytes are joined across
+#: key groups into one call (sparse ones always are)
+RESTORE_MERGE_BYTES = 256 << 20
 #: fewest (destination, source) pairs one `state.merge_rows` dispatch
 #: is shaped for: a session job merges a state window or two a batch
 MERGE_MIN_WIDTH = 8
@@ -246,6 +263,19 @@ class DeviceAggregatingState(AggregatingState):
         self._jit_clear = traced_jit(self.agg.clear_slots,
                                      name="state.clear", donate_argnums=0)
         self._jit_result = traced_jit(self.agg.result, name="state.result")
+        # a restore's two uploads: whole rows of some components, and
+        # the cells of a sparse column (a triple past the table's last
+        # slot is padding and dropped)
+        self._jit_restore = traced_jit(
+            lambda st, slots, rows: {
+                **st, **{k: st[k].at[slots].set(rows[k]) for k in rows}},
+            name="state.restore", donate_argnums=0)
+        self._jit_restore_cells = traced_jit(
+            lambda table, at, vals: table.at[at].set(vals, mode="drop"),
+            name="state.restore_cells", donate_argnums=0)
+        #: the capture programs of a snapshot, made by the first one:
+        #: a state nobody snapshots keeps no books and warms nothing
+        self._snapshot_plan: Optional[SnapshotPlan] = None
         # queryable-state reads come from foreign threads; every
         # device_state REPLACEMENT donates the old tree's buffers, so
         # a concurrent gather on the old tree would read freed memory.
@@ -529,6 +559,11 @@ class DeviceAggregatingState(AggregatingState):
         with self._device_lock:
             self.device_state = self.agg.grow_state(self.device_state,
                                                     new_capacity)
+        if self._snapshot_plan is not None:
+            # a capture's gather follows the table's shape: whoever
+            # snapshots this state finds it compiled
+            self._snapshot_plan.warm(self.device_state,
+                                     all_programs=False)
         extra = new_capacity - self.capacity
         self._free.extend(range(new_capacity - 1, self.capacity - 1, -1))
         for name in ("slot_key", "slot_ns", "_slot_live", "_access_stamp",
@@ -1044,38 +1079,49 @@ class DeviceAggregatingState(AggregatingState):
             {name: np.stack([row[name] for _, _, row in entries])
              for name in entries[0][2]})
 
+    def capture(self) -> StateCapture:
+        """The synchronous part of a snapshot, on the task's thread:
+        the pending micro-batch flushed, the live entries read off the
+        slot arrays, the host tier's rows taken by reference, and the
+        gathers dispatched that copy the live rows into buffers of
+        their own (``state/device_snapshot.py``).  What comes back
+        holds the state as of now whatever is written behind it;
+        ``columns()`` on it, from any thread, is the rest."""
+        tracer = get_tracer()
+        with tracer.phase("state.snapshot.flush"):
+            self._flush()
+        with tracer.phase("state.snapshot.index") as phase:
+            slots = np.flatnonzero(self._slot_live)
+            keys = self.slot_key[slots]
+            namespaces = self.slot_ns[slots]
+            spilled = self.host_tier.capture() if self.host_tier else None
+            phase.set_attr("rows", len(slots))
+            phase.set_attr("spilled", len(spilled) if spilled else 0)
+        with tracer.phase("state.snapshot.dispatch") as phase:
+            plan = self._snapshot_plan
+            if plan is None:
+                plan = self._snapshot_plan = SnapshotPlan(
+                    self.agg.state_specs(), self._bytes_per_slot())
+                plan.warm(self.device_state, all_programs=True)
+            copies = plan.copy(self.device_state, slots)
+            phase.set_attr("tiles", len(copies))
+        return StateCapture(plan, keys, namespaces, copies, spilled,
+                            self._backend.max_parallelism)
+
     def snapshot_columns(self) -> Dict[int, Tuple[list, list, Dict[str, np.ndarray]]]:
-        """Columnar snapshot: per key group, (keys, namespaces,
-        {component: stacked rows}) — ONE host transfer per component,
-        ONE fancy-index gather, and the key-group split done in one
-        vectorized hash pass."""
-        self._flush()
-        keys, nss, slots = self.slot_index.columns()
-        t0 = _perf_ns()
-        host = {name: np.asarray(arr)
-                for name, arr in self.device_state.items()}
-        if TELEMETRY.enabled:
-            TELEMETRY.record_transfer(
-                "d2h", sum(a.nbytes for a in host.values()),
-                t0, _perf_ns(), "state.snapshot")
-        comps = {name: arr[slots] for name, arr in host.items()}
-        if self.host_tier:
-            spilled_keys, spilled_nss, spill_cols = self.host_tier.columns()
-            keys += spilled_keys
-            nss += spilled_nss
-            comps = {name: np.concatenate([comps[name], spill_cols[name]])
-                     for name in host}
-        out: Dict[int, Tuple[list, list, Dict[str, np.ndarray]]] = {}
-        mp = self._backend.max_parallelism
-        for kg, sel in split_column_by_key_group(keys, mp):
-            out[kg] = ([keys[i] for i in sel], [nss[i] for i in sel],
-                       {name: arr[sel] for name, arr in comps.items()})
-        return out
+        """The whole snapshot at once, dense: per key group, (keys,
+        namespaces, {component: stacked rows})."""
+        return {kg: (keys, nss, {name: dense_rows(col)
+                                 for name, col in comps.items()})
+                for kg, (keys, nss, comps) in self.capture().columns().items()}
 
     def restore_columns(self, keys: list, namespaces: list,
-                        comps: Dict[str, np.ndarray]) -> None:
+                        comps: Dict[str, Any]) -> None:
         """Columnar restore: the rows' slots through the batch door,
-        ONE device upload per component (no per-row dict boxing)."""
+        then the rows go up in tiles of fixed shapes, a dense column
+        `RESTORE_TILE_BYTES` of rows a dispatch, a sparse one
+        (`SparseRows`) `RESTORE_TILE_CELLS` of its cells.  Rows past
+        the device budget go to the host tier."""
         n = len(keys)
         if n == 0:
             return
@@ -1088,30 +1134,177 @@ class DeviceAggregatingState(AggregatingState):
             budget = max(self.max_device_slots - live, 0)
             self.host_tier.put(
                 keys[budget:], namespaces[budget:],
-                {name: np.array(arr[budget:])
-                 for name, arr in comps.items()})
+                {name: np.array(dense_rows(col, slice(budget, None)))
+                 for name, col in comps.items()})
             keys = keys[:budget]
             namespaces = namespaces[:budget]
-            comps = {name: arr[:budget] for name, arr in comps.items()}
+            comps = {name: take_rows(col, slice(0, budget))
+                     for name, col in comps.items()}
             n = budget
             if n == 0:
                 return
             needed = live + n
         if needed > self.capacity - len(self._pending_slots):
             self._grow(max(self.capacity * 2, _round_up_pow2(needed)))
-        slots, _ = self._resolve_column(list(keys), None, namespaces)
-        idx = jnp.asarray(slots.astype(np.int32))
+        slots, new = self._restore_slots(list(keys), list(namespaces))
         with self._device_lock:
-            new_state = dict(self.device_state)
-            for name, arr in comps.items():
-                new_state[name] = new_state[name].at[idx].set(
-                    jnp.asarray(np.ascontiguousarray(arr)))
-            self.device_state = new_state
+            if new < n:
+                # an entry that was here already: a sparse column only
+                # sets cells, so its row starts from the fill
+                for i in range(0, n, self.microbatch):
+                    part = slots[i:i + self.microbatch]
+                    self.device_state = self._jit_clear(
+                        self.device_state, jnp.asarray(
+                            _pad_slots(part, _round_up_pow2(len(part)))))
+            self._upload_rows(slots, {name: col for name, col in comps.items()
+                                      if not isinstance(col, SparseRows)})
+            for name, col in comps.items():
+                if isinstance(col, SparseRows):
+                    self._upload_cells(name, slots, col)
             self._slot_flushed[slots] = True
+
+    def _restore_slots(self, keys: list,
+                       namespaces: list) -> Tuple[np.ndarray, int]:
+        """Slots for a restore's entries, and how many are new.  Into
+        an index that holds nothing (a restore's one call, after the
+        reset) every entry of a snapshot is new: they take their slots
+        in one go and enter the index a namespace at a time, with none
+        of the batch door's probing, promoting and stamping per
+        namespace (a session job's restore brings a namespace per
+        row).  Otherwise, or if an entry comes twice, the batch door."""
+        n = len(keys)
+        if len(self.slot_index) or len(self._spilled) \
+                or len(set(zip(keys, namespaces))) != n:
+            return self._resolve_column(keys, None, namespaces)
+        free = self._free
+        slots = np.array(free[:-n - 1:-1], np.int64)
+        del free[-n:]
+        for namespace, rows, part in cut_by_namespace(keys, None,
+                                                      namespaces):
+            self.slot_index.enter(part, namespace, slots[rows])
+        self.slot_key[slots] = object_column(keys, n)
+        self.slot_ns[slots] = object_column(namespaces, n)
+        self._slot_live[slots] = True
+        self._stamp(slots)
+        STATE_STATS.bulk_probe_rows += n
+        return slots, n
+
+    def _upload_rows(self, slots: np.ndarray,
+                     comps: Dict[str, np.ndarray]) -> None:
+        if not comps:
+            return
+        n = len(slots)
+        row_bytes = sum(col[:1].nbytes for col in comps.values())
+        tile = max(1, min(_round_up_pow2(n),
+                          1 << max(0, (RESTORE_TILE_BYTES
+                                       // max(1, row_bytes)).bit_length() - 1)))
+        for i in range(0, n, tile):
+            m = min(tile, n - i)
+            rows = {}
+            for name, col in comps.items():
+                part = np.empty((tile, *col.shape[1:]), col.dtype)
+                part[:m] = col[i:i + m]
+                part[m:] = part[0]  # pad: the tile's first row again
+                rows[name] = jnp.asarray(part)
+            self.device_state = self._jit_restore(
+                self.device_state,
+                jnp.asarray(_pad_slots(slots[i:i + m], tile)), rows)
+
+    def _upload_cells(self, name: str, slots: np.ndarray,
+                      col: SparseRows) -> None:
+        total = len(col.cells)
+        if not total:
+            return
+        at_slot = np.repeat(slots.astype(np.int32), col.counts)
+        at_cell = np.unravel_index(col.cells.astype(np.int64), col.row_shape)
+        tile = RESTORE_TILE_CELLS
+        for i in range(0, total, tile):
+            m = min(tile, total - i)
+            # (a pad triple's slot is past the table: dropped)
+            index = [np.full(tile, self.capacity, np.int32)]
+            index[0][:m] = at_slot[i:i + m]
+            for dim in at_cell:
+                part = np.zeros(tile, np.int32)
+                part[:m] = dim[i:i + m]
+                index.append(part)
+            vals = np.zeros(tile, col.dtype)
+            vals[:m] = col.vals[i:i + m]
+            self.device_state = {
+                **self.device_state,
+                name: self._jit_restore_cells(
+                    self.device_state[name],
+                    tuple(jnp.asarray(a) for a in index),
+                    jnp.asarray(vals))}
 
     def active_entries(self) -> Iterable[Tuple[Any, Any]]:
         yield from self.slot_index
         yield from self.host_tier
+
+
+def _merged_blocks(blocks: list) -> list:
+    """A state's key-group blocks as ONE block where that costs no
+    large copy (sparse columns, or dense ones of RESTORE_MERGE_BYTES at
+    most): the table then grows once, to its last size, the entries
+    take their slots in one pass and the uploads' tiles are full.
+    Dense columns beyond that restore a key group at a time."""
+    if len(blocks) < 2:
+        return blocks
+    dense = sum(col.nbytes for _, _, comps in blocks
+                for col in comps.values() if not isinstance(col, SparseRows))
+    if dense > RESTORE_MERGE_BYTES:
+        return blocks
+    return [([k for keys, _, _ in blocks for k in keys],
+             [ns for _, nss, _ in blocks for ns in nss],
+             {name: concat_columns([comps[name] for _, _, comps in blocks])
+              for name in blocks[0][2]})]
+
+
+def _join_rows(at_barrier, rows: list) -> list:
+    """A key group's host-table rows: those read at the barrier (a
+    list, or its pickle where the snapshot was finished later) and
+    those of the tables held by reference."""
+    if isinstance(at_barrier, bytes):
+        at_barrier = pickle.loads(at_barrier)
+    return (at_barrier or []) + rows
+
+
+def _encode_chunks(at_barrier: Dict[int, object], held: list,
+                   captures: Dict[str, StateCapture],
+                   max_parallelism: int) -> Dict[int, bytes]:
+    """What `TpuKeyedStateBackend` does of a snapshot after the
+    barrier: `at_barrier`, per key group the host-table rows read
+    there (serialized there, where this runs later); `held`,
+    ``(name, namespace, {key: value})`` of the host tables whose
+    values were taken by reference; `captures`, the device states'."""
+    from flink_tpu.state.backend import encode_obj_column
+    per_kg_cols: Dict[int, Dict[str, list]] = defaultdict(dict)
+    for name, capture in captures.items():
+        for kg, (keys, nss, comps) in capture.columns().items():
+            per_kg_cols[kg].setdefault(name, []).append({
+                "keys": encode_obj_column(keys),
+                "ns": ("col", encode_obj_column(nss)),
+                "comps": comps,
+                "kind": "acc",
+            })
+    with get_tracer().phase("checkpoint.encode"):
+        per_kg_rows: Dict[int, list] = defaultdict(list)
+        for name, namespace, by_key in held:
+            keys = list(by_key)
+            values = list(by_key.values())
+            for kg, sel in split_column_by_key_group(keys, max_parallelism):
+                per_kg_rows[kg].extend((name, namespace, keys[i], values[i])
+                                       for i in sel.tolist())
+        chunks = {}
+        for kg in set(at_barrier) | set(per_kg_rows) | set(per_kg_cols):
+            chunks[kg] = pickle.dumps(
+                {"v": 2,
+                 "rows": _join_rows(at_barrier.get(kg),
+                                    per_kg_rows.get(kg, [])),
+                 "cols": per_kg_cols.get(kg, {})},
+                protocol=pickle.HIGHEST_PROTOCOL)
+        STATE_STATS.snapshot_bytes_written += sum(
+            len(blob) for blob in chunks.values())
+    return chunks
 
 
 class TpuKeyedStateBackend(KeyedStateBackend):
@@ -1237,39 +1430,57 @@ class TpuKeyedStateBackend(KeyedStateBackend):
 
     # ---- snapshot / restore -----------------------------------------
     def snapshot(self) -> KeyedStateSnapshot:
-        """v2 columnar chunk format: device states serialize as ONE
-        gather + one column per component per key group (key and
-        namespace columns through the wire codec), host-table entries
-        stay per-row."""
-        from flink_tpu.state.backend import encode_obj_column
+        """v2 columnar chunk format: `capture_snapshot` resolved at
+        once, on the caller's thread (nothing can change a value
+        between the two parts, so the host tables' rows are
+        serialized once, with their chunk)."""
+        return self._capture(deferred=False).resolve()
+
+    def capture_snapshot(self) -> DeferredSnapshot:
+        """The snapshot in two parts.  Here, at the barrier, on the
+        caller's thread: every device state is captured
+        (`DeviceAggregatingState.capture`: index columns, and gathers
+        dispatched that copy its live rows aside), and the host
+        tables' rows are serialized per key group, as their values may
+        be changed in place behind the barrier (a join's buffer, an
+        accumulator, a list); only of a table whose owner declares its
+        values `copy_on_write` (the window operator's session
+        mappings) is a copy of the entries taken, the values by
+        reference.  The handle's `resolve()`, on the thread that
+        calls it (a checkpoint's writer), gives the
+        `KeyedStateSnapshot`: the captures are reduced, come to the
+        host and are cut by key group, one column per component (key
+        and namespace columns through the wire codec, a sketch's rows
+        as the cells off their fill), and the chunks are pickled."""
+        return self._capture(deferred=True)
+
+    def _capture(self, deferred: bool) -> DeferredSnapshot:
+        captures = {name: dstate.capture()
+                    for name, dstate in self._device_states.items()}
         per_kg_rows: Dict[int, list] = defaultdict(list)
-        per_kg_cols: Dict[int, Dict[str, list]] = defaultdict(dict)
+        #: (name, namespace, {key: value}), the values by reference
+        held = []
         for name, table in self._tables.items():
+            if getattr(self._descriptors.get(name), "copy_on_write", False):
+                for namespace, by_key in table.by_namespace.items():
+                    held.append((name, namespace, dict(by_key)))
+                    STATE_STATS.snapshot_rows += len(by_key)
+                continue
             for namespace, key, value in table.entries():
                 kg = assign_to_key_group(key, self.max_parallelism)
                 per_kg_rows[kg].append((name, namespace, key, value))
                 STATE_STATS.snapshot_rows += 1
-        for name, dstate in self._device_states.items():
-            for kg, (keys, nss, comps) in dstate.snapshot_columns().items():
-                per_kg_cols[kg].setdefault(name, []).append({
-                    "keys": encode_obj_column(keys),
-                    "ns": ("col", encode_obj_column(nss)),
-                    "comps": comps,
-                    "kind": "acc",
-                })
-                STATE_STATS.snapshot_columns += len(keys)
-        chunks = {}
-        for kg in set(per_kg_rows) | set(per_kg_cols):
-            chunks[kg] = pickle.dumps(
-                {"v": 2, "rows": per_kg_rows.get(kg, []),
-                 "cols": per_kg_cols.get(kg, {})},
-                protocol=pickle.HIGHEST_PROTOCOL)
-        return KeyedStateSnapshot(
-            chunks,
-            meta={"backend": self.name,
-                  "max_parallelism": self.max_parallelism,
-                  "serializers": self.serializer_config_snapshots()},
-        )
+        at_barrier = per_kg_rows if not deferred else {
+            kg: pickle.dumps(entries, protocol=pickle.HIGHEST_PROTOCOL)
+            for kg, entries in per_kg_rows.items()}
+        STATE_STATS.snapshot_columns += sum(
+            len(capture) for capture in captures.values())
+        mp = self.max_parallelism
+        meta = {"backend": self.name,
+                "max_parallelism": self.max_parallelism,
+                "serializers": self.serializer_config_snapshots()}
+        return DeferredSnapshot(lambda: KeyedStateSnapshot(
+            _encode_chunks(at_barrier, held, captures, mp), meta))
 
     def _restore_norm_rows(self, rows, pending_device) -> None:
         """Per-row entries: values in the scalar-twin accumulator
@@ -1338,7 +1549,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         for name, blocks in pending_cols.items():
             dstate = self._device_states.get(name)
             if dstate is not None:
-                for keys, namespaces, comps in blocks:
+                for keys, namespaces, comps in _merged_blocks(blocks):
                     dstate.restore_columns(keys, namespaces, comps)
             else:
                 # descriptor not bound yet: park per-row accumulator

@@ -87,6 +87,11 @@ from flink_tpu.streaming.windowing import (
 )
 
 
+#: what `python -m flink_tpu run -s <path> <script>` sets: the restore
+#: point of every job of the process whose environment names none
+DEFAULT_RESTORE_PATH: Optional[str] = None
+
+
 class StreamExecutionEnvironment:
     """(ref: StreamExecutionEnvironment.java)"""
 
@@ -99,6 +104,7 @@ class StreamExecutionEnvironment:
         self.checkpoint_interval: Optional[int] = None
         self.checkpoint_mode = "exactly_once"
         self.checkpoint_storage: dict = {"storage": "memory", "retain": 1}
+        self._job_listeners: list = []
         self.processing_time_service = None  # executor default if None
         self.state_backend: str = self.config.get_string("state.backend", "heap")
         self.restart_strategy: Optional[dict] = {"strategy": "none"}
@@ -252,11 +258,26 @@ class StreamExecutionEnvironment:
         self.failover_strategy = strategy
         return self
 
+    def register_job_listener(self, on_job_submitted
+                              ) -> "StreamExecutionEnvironment":
+        """`on_job_submitted(client)` is called with the `JobClient` of
+        every job this environment submits, before `execute()` waits
+        for it (ref: StreamExecutionEnvironment.registerJobListener /
+        JobListener.onJobSubmitted): the handle on a job run through
+        the blocking `execute()` (savepoints, cancel, its live
+        state)."""
+        self._job_listeners.append(on_job_submitted)
+        return self
+
     def set_savepoint_restore(self, path: str,
                               allow_non_restored_state: bool = False
                               ) -> "StreamExecutionEnvironment":
         """Start the next execution from a savepoint — the
-        `flink run -s <path>` contract.  Restoring at a different
+        `flink run -s <path>` contract.  `path` is a savepoint file,
+        or a retained checkpoint of the filesystem checkpoint storage:
+        its directory (the newest `chk-N` in it that loads) or one
+        `chk-N` file, the chunks it shares with other checkpoints read
+        from the directory's `shared/`.  Restoring at a different
         parallelism re-splits keyed state by key-group range and
         operator list state round-robin (ref: SavepointRestoreSettings
         + StateAssignmentOperation).  Snapshot state whose operator
@@ -324,7 +345,7 @@ class StreamExecutionEnvironment:
                 jg.checkpoint_config["alignment_abort_limit"] = \
                     self.alignment_abort_limit
         jg.savepoint_restore_path = getattr(
-            self, "savepoint_restore_path", None)
+            self, "savepoint_restore_path", None) or DEFAULT_RESTORE_PATH
         jg.allow_non_restored_state = getattr(
             self, "allow_non_restored_state", False)
         return jg
@@ -487,6 +508,8 @@ class StreamExecutionEnvironment:
         self.graph.job_name = job_name
         executor = self._make_executor()
         self._publish_lint_metrics(report)
+        if self._job_listeners:
+            executor.job_listeners = list(self._job_listeners)
         return executor.execute(self.get_job_graph())
 
     def execute_async(self, job_name: str = "job"):
@@ -496,7 +519,10 @@ class StreamExecutionEnvironment:
         self.graph.job_name = job_name
         executor = self._make_executor()
         self._publish_lint_metrics(report)
-        return executor.execute_async(self.get_job_graph())
+        client = executor.execute_async(self.get_job_graph())
+        for listener in self._job_listeners:
+            listener(client)
+        return client
 
 
 def _source_factory(source_function: SourceFunction, time_characteristic: str):

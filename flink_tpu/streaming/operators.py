@@ -337,16 +337,27 @@ class StreamOperator(abc.ABC):
         pass
 
     # ---- snapshot ---------------------------------------------------
+    #: set by an executor whose checkpoint coordinator resolves the
+    #: acks' `DeferredSnapshot` handles (`LocalExecutor`): a keyed
+    #: backend with an asynchronous part (`capture_snapshot`) then
+    #: finishes its snapshot, and the timers', after the barrier
+    deferred_snapshots = False
+
     def snapshot_state(self, checkpoint_id: Optional[int] = None) -> dict:
         snap = {}
+        capture = (getattr(self.keyed_backend, "capture_snapshot", None)
+                   if self.deferred_snapshots else None)
         if self.keyed_backend is not None:
             if hasattr(self.keyed_backend, "flush_all"):
                 self.keyed_backend.flush_all()
-            snap["keyed"] = self.keyed_backend.snapshot()
+            snap["keyed"] = (capture() if capture is not None
+                             else self.keyed_backend.snapshot())
         if self.operator_state_backend is not None:
             snap["operator"] = self.operator_state_backend.snapshot()
         if self.timer_service is not None:
-            snap["timers"] = self.timer_service.snapshot()
+            snap["timers"] = (self.timer_service.capture_snapshot()
+                              if capture is not None
+                              else self.timer_service.snapshot())
         return snap
 
     def restore_state(self, snapshots: List[dict]) -> None:

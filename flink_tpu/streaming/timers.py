@@ -253,6 +253,16 @@ class _TimerStore:
         return sum(1 if type(run) is tuple else len(run)
                    for run in self.runs.values())
 
+    def capture(self) -> Tuple[list, list, list]:
+        """The runs as of now, in the order they were first seen, as
+        three columns no later registration or removal reaches: each
+        run's (timestamp, namespace), its keys frozen (`tuple(run)`:
+        of a dict its keys in registration order, of a run of one key
+        the pair it is), and its type, which tells the two apart.  One
+        C-level pass a column: a session job has a run a session."""
+        runs = list(self.runs.values())
+        return list(self.runs), list(map(tuple, runs)), list(map(type, runs))
+
     def __iter__(self):
         """Live (timestamp, key, namespace), runs in the order they
         were first seen, keys in registration order."""
@@ -463,6 +473,82 @@ class _TimerStore:
                     break  # an earlier timer was registered: it goes first
 
 
+class TimerRows:
+    """One key group's timers inside a snapshot, as three columns
+    (timestamps, keys, namespaces, the last two through the wire
+    codec's column tier); it iterates as the ``(timestamp, key,
+    namespace)`` tuples a list of them would.  A checkpoint's storage
+    walks a snapshot for shared chunks: there are none in here, and it
+    is told so instead of visiting a tuple per timer."""
+
+    __slots__ = ("stamps", "keys", "namespaces")
+
+    def __init__(self, stamps, keys, namespaces):
+        from flink_tpu.state.backend import encode_obj_column
+        import numpy as np
+        self.stamps = np.asarray(stamps, np.int64)
+        self.keys = encode_obj_column(keys)
+        # windows are tuples of ints, all of one length: one int64
+        # array (numpy infers int64 for nothing else: a float, a bool,
+        # a longer int or a ragged row gives another dtype or shape)
+        rows = np.array(namespaces) if len(namespaces) else None
+        if rows is not None and rows.dtype == np.int64 and rows.ndim == 2:
+            self.namespaces = ("int-tuples", rows)
+        else:
+            self.namespaces = encode_obj_column(namespaces)
+
+    def __len__(self) -> int:
+        return len(self.stamps)
+
+    def __iter__(self):
+        from flink_tpu.state.backend import decode_obj_column
+        n = len(self.stamps)
+        namespaces = (map(tuple, self.namespaces[1].tolist())
+                      if self.namespaces[0] == "int-tuples"
+                      else decode_obj_column(self.namespaces, n))
+        return zip(self.stamps.tolist(), decode_obj_column(self.keys, n),
+                   namespaces)
+
+    def __eq__(self, other):
+        return list(self) == list(other)
+
+    def __getstate__(self):
+        return (self.stamps, self.keys, self.namespaces)
+
+    def __setstate__(self, state):
+        self.stamps, self.keys, self.namespaces = state
+
+    def _map_chunks_(self, fn):
+        return self
+
+
+def _runs_by_key_group(captured: Tuple[list, list, list],
+                       max_parallelism: int) -> Dict[int, "TimerRows"]:
+    """`(timestamp, key, namespace)` of every timer of the captured
+    runs (`_TimerStore.capture`), per key group, in the store's order:
+    one vectorized hash of the key column."""
+    import numpy as np
+    from flink_tpu.state.heap_backend import split_column_by_key_group
+    from flink_tpu.state.slot_index import object_column
+    stamps, keys, namespaces = [], [], []
+    for (timestamp, namespace), run, kind in zip(*captured):
+        if kind is tuple:  # (key, registration number)
+            keys.append(run[0])
+            stamps.append(timestamp)
+            namespaces.append(namespace)
+            continue
+        keys.extend(run)
+        stamps.extend(itertools.repeat(timestamp, len(run)))
+        namespaces.extend(itertools.repeat(namespace, len(run)))
+    n = len(keys)
+    stamps = np.array(stamps, np.int64)
+    key_column, ns_column = object_column(keys, n), \
+        object_column(namespaces, n)
+    return {kg: TimerRows(stamps[sel], key_column[sel].tolist(),
+                          ns_column[sel].tolist())
+            for kg, sel in split_column_by_key_group(keys, max_parallelism)}
+
+
 class InternalTimerService:
     """Keyed event-time + processing-time timers for one operator
     (ref: HeapInternalTimerService.java), both kept in a
@@ -598,6 +684,20 @@ class InternalTimerService:
             per_kg.setdefault(assign_to_key_group(key, mp), []).append(
                 (ts, key, namespace))
         return per_kg
+
+    def capture_snapshot(self):
+        """`snapshot` in two parts, for an operator whose keyed
+        backend finishes its own after the barrier: here a copy of
+        each store's runs (`_TimerStore.capture`); the handle's
+        `resolve()` cuts them by key group, a `TimerRows` each, which
+        `restore` reads as it reads a list of tuples."""
+        from flink_tpu.state.backend import DeferredSnapshot
+        watermark, mp = self.current_watermark, self._backend.max_parallelism
+        event, proc = self._event.capture(), self._proc.capture()
+        return DeferredSnapshot(lambda: {
+            "watermark": watermark,
+            "event": _runs_by_key_group(event, mp),
+            "proc": _runs_by_key_group(proc, mp)})
 
     def restore(self, snapshots: List[dict]) -> None:
         self._event.clear()

@@ -809,6 +809,10 @@ class WindowOperator(AbstractUdfStreamOperator):
         self.collector = TimestampedCollector(self.output)
         if self.assigner.is_merging():
             self._mapping_desc = ValueStateDescriptor(self.MAPPING_STATE_NAME)
+            # a key's mapping is written as a new dict of tuples
+            # (`MergingWindowSet.persist`, `_store_mappings`; a reader
+            # copies before it changes one)
+            self._mapping_desc.copy_on_write = True
 
     # namespace encoding: window -> hashable tuple (state namespaces)
     def _namespace_of(self, window):
@@ -1362,6 +1366,14 @@ class WindowOperator(AbstractUdfStreamOperator):
     #: path even for batch-eligible operators (the differential suite
     #: and the bench A/B flip this)
     batch_fires = True
+
+    def snapshot_state(self, checkpoint_id=None) -> dict:
+        """At the barrier: the backend's capture (its own phases,
+        `state.snapshot.*`) and, as this phase's own time, the copies
+        of the host tables (the sessions' window -> state-window
+        mappings) and of the timer runs."""
+        with get_tracer().phase("window.snapshot"):
+            return super().snapshot_state(checkpoint_id)
 
     def process_watermark(self, watermark) -> None:
         """Watermark: the batch-eligible shape (tumbling/sliding

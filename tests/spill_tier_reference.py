@@ -631,6 +631,11 @@ class PerKeySpillState(DeviceAggregatingState):
             for s_ in slots:
                 self._slot_flushed[s_] = 1
 
+    def capture(self):
+        """The reference reads its snapshot at once."""
+        from flink_tpu.state.device_snapshot import ColumnsCapture
+        return ColumnsCapture(self.snapshot_columns())
+
     def snapshot_columns(self) -> Dict[int, Tuple[list, list, Dict[str, np.ndarray]]]:
         """Columnar snapshot: per key group, (keys, namespaces,
         {component: stacked rows}) — ONE host transfer per component,
