@@ -15,7 +15,11 @@ Legs:
   1  device gate        platform must be "tpu"
   2  state backend      env.set_state_backend("tpu"): WindowOperator →
                         TpuKeyedStateBackend → DeviceAggregatingState,
-                        ~4 GiB of registers live in HBM per window
+                        ~4 GiB of registers live in HBM per window;
+                        fails (as 2b and 2c) unless every batch's keys
+                        stayed one int64 column into add_batch and the
+                        timer runs, none of which became a dict (2d:
+                        unless none did: a session's keys go as a list)
   2b spill tier         the same route at the north star's 10,000,000
                         keys under state.backend.tpu.max-device-slots =
                         2^20, set in the environment's Configuration:
@@ -493,6 +497,22 @@ def timer_run_problems(op, windows, result_rows):
             f"rows in {op.timer_runs} runs, {windows} windows fired"]
 
 
+def key_column_problems(op, expected):
+    """The operator kept every batch's keys as one int64 column (into
+    `add_batch` and the timer runs) where `expected` says so, none
+    where not (a session job), and no integer timer run became a dict;
+    returns (problems, facts)."""
+    facts = {"key_column_rows": op.key_column_rows,
+             "int_run_demotions": op.timer_service.int_run_demotions}
+    want = op.columnar_rows if expected else 0
+    if op.key_column_rows == want and not facts["int_run_demotions"]:
+        return [], facts
+    return [f"key_column_rows {op.key_column_rows} of {op.columnar_rows} "
+            f"columnar rows ({want} expected), "
+            f"{facts['int_run_demotions']} integer timer runs became "
+            f"dicts"], facts
+
+
 #: how the tpu backend took a leg's integer columns: the values hashed
 #: whole, the keys probed on a window's integer table
 COLUMN_COUNTS = ("hash_column_rows", "hash_per_value_rows",
@@ -540,6 +560,8 @@ def leg_state_backend(cfg, events, ref):
     problems += timer_run_problems(wop, len(ref), len(cols[0]))
     column_faults, column_facts = column_problems(counted, len(keys))
     problems += column_faults
+    key_faults, key_facts = key_column_problems(wop, True)
+    problems += key_faults
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -552,7 +574,7 @@ def leg_state_backend(cfg, events, ref):
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
-        "evictions": state.evictions, **column_facts, **facts}
+        "evictions": state.evictions, **column_facts, **key_facts, **facts}
 
 
 def leg_state_spill(cfg, seed):
@@ -579,6 +601,8 @@ def leg_state_spill(cfg, seed):
     problems += fire_tail_problems(wop, len(cols[0]))
     column_faults, column_facts = column_problems(counted, len(events[0]))
     problems += column_faults
+    key_faults, key_facts = key_column_problems(wop, True)
+    problems += key_faults
     if state.max_device_slots != spill["budget"]:
         problems.append(f"the backend's budget is {state.max_device_slots}, "
                         f"the Configuration says {spill['budget']}")
@@ -598,7 +622,7 @@ def leg_state_spill(cfg, seed):
         "evictions": state.evictions, "promotions": state.promotions,
         "budget_overruns": state.budget_overruns,
         "live_keys_per_window": [len(k) for k, _ in ref.values()],
-        **column_facts, **facts}
+        **column_facts, **key_facts, **facts}
 
 
 #: config #3's windows; the slide is the source period too
@@ -681,6 +705,8 @@ def leg_state_sliding(cfg, seed):
                         f"quantiles failed")
     problems += boxed_problems(wop, n)
     problems += fire_tail_problems(wop, len(got_keys))
+    key_faults, key_facts = key_column_problems(wop, True)
+    problems += key_faults
     if state.evictions or state.budget_overruns \
             or state.capacity > sl["budget"]:
         problems.append(f"{state.evictions} evictions, "
@@ -714,7 +740,7 @@ def leg_state_sliding(cfg, seed):
         "batches_marked": len(source.marks),
         "flushes_at_full_size": flushes, "flushes_in_place": in_place,
         "rows_per_event": wop.window_rows / n,
-        "windows_touched": wop.windows_touched,
+        "windows_touched": wop.windows_touched, **key_facts,
         "evictions": state.evictions,
         "budget_overruns": state.budget_overruns,
         "result_rows": len(got_keys), "windows_fired": len(results),
@@ -980,6 +1006,8 @@ def leg_state_sessions(cfg, seed):
                         f"session facts failed")
     problems += boxed_problems(wop, n)
     problems += fire_tail_problems(wop, len(cols[0]) if cols else 0)
+    key_faults, key_facts = key_column_problems(wop, False)
+    problems += key_faults
     if state.evictions or state.budget_overruns \
             or state.capacity > se["budget"]:
         problems.append(f"{state.evictions} evictions, "
@@ -1014,7 +1042,7 @@ def leg_state_sessions(cfg, seed):
         "budget_overruns": state.budget_overruns,
         "result_rows": len(cols[0]) if cols else 0,
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
-        **verdict["facts"]}
+        **key_facts, **verdict["facts"]}
 
 
 def leg_sql(cfg, events, ref, mesh=None):

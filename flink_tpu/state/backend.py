@@ -22,6 +22,7 @@ from typing import Any, Dict, Iterable, Optional
 
 import numpy as np
 
+from flink_tpu.core.key_columns import as_list
 from flink_tpu.core.keygroups import (
     KeyGroupRange,
     assign_key_groups_np,
@@ -264,10 +265,12 @@ class KeyedStateBackend(abc.ABC):
         """
         from flink_tpu.state.introspect import INTROSPECTION
         from flink_tpu.state.stats import STATE_STATS
+        keys = _keys_for(state, keys)
         n = len(keys)
         name = _state_name(state)
         if INTROSPECTION.enabled:
-            INTROSPECTION.note_ingest(name, keys, self.max_parallelism)
+            INTROSPECTION.note_ingest(name, as_list(keys),
+                                      self.max_parallelism)
         native = getattr(state, "add_batch", None)
         if native is not None:
             if pre_extracted:
@@ -314,6 +317,7 @@ class KeyedStateBackend(abc.ABC):
         callers in a row context must re-establish it.
         """
         from flink_tpu.state.stats import STATE_STATS
+        keys = _keys_for(state, keys)
         n = len(keys)
         name = _state_name(state)
         native = getattr(state, "get_batch", None)
@@ -344,6 +348,7 @@ class KeyedStateBackend(abc.ABC):
 
         Leaves the backend's current key/namespace context undefined.
         """
+        keys = _keys_for(state, keys)
         native = getattr(state, "clear_batch", None)
         if native is not None:
             native(keys, namespace, namespaces=namespaces)
@@ -497,6 +502,16 @@ class KeyedStateBackend(abc.ABC):
 def _state_name(state) -> str:
     d = getattr(state, "_descriptor", None)
     return getattr(d, "name", "?") if d is not None else "?"
+
+
+def _keys_for(state, keys):
+    """`keys` as `state` takes them: an int64 column as it is where the
+    state says `takes_key_column`, else the list of Python objects every
+    other state has always been handed."""
+    if isinstance(keys, np.ndarray) \
+            and not getattr(state, "takes_key_column", False):
+        return keys.tolist()
+    return keys
 
 
 def encode_obj_column(values) -> tuple:

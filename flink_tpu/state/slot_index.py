@@ -39,77 +39,20 @@ last key — so the index is falsy exactly when it holds no entry.
 from __future__ import annotations
 
 import itertools
-from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
+from typing import Any, Dict, Iterator, List, Tuple, Union
 
 import numpy as np
 
-from flink_tpu import native
+from flink_tpu import native  # noqa: F401 (tests turn the runtime off here)
+from flink_tpu.core.key_columns import (
+    as_list,
+    int_column,
+    int_key,
+    key_column,
+    object_column,
+)
 from flink_tpu.native import NativeIntTable
 from flink_tpu.state.stats import STATE_STATS
-
-_INT64_MIN, _INT64_MAX = -(1 << 63), (1 << 63) - 1
-
-
-def object_column(values, n: int) -> np.ndarray:
-    """`values` as object[n], each element the object it was (a tuple
-    key stays one cell)."""
-    return np.fromiter(values, object, n)
-
-
-def key_column(keys):
-    """A column of keys as the int64 array it is, else as it came (an
-    array of another kind as a list of Python objects): an integer
-    `ndarray`, or a list numpy reads as one of ``dtype.kind == "i"``
-    (Python ints, bools among them, that fit int64; a float, a string,
-    a tuple, a mixed list or an int beyond int64 gives another dtype).
-    A list whose first key is no int is not read at all, and nothing
-    is where no integer table could take the column."""
-    if isinstance(keys, np.ndarray):
-        if keys.ndim == 1 and keys.dtype.kind == "i" \
-                and native.available():
-            return np.ascontiguousarray(keys, np.int64)
-        return keys.tolist()
-    if not keys or not isinstance(keys[0], (int, np.integer)) \
-            or not native.available():
-        return keys
-    column = _int_column(keys, kinds="i")
-    return keys if column is None else column
-
-
-def _int_column(keys, kinds: str = "iub") -> Optional[np.ndarray]:
-    """`keys` as an int64 column if numpy reads them as one of
-    `kinds`, else None."""
-    if isinstance(keys, np.ndarray):
-        column = keys
-    else:
-        try:
-            column = np.array(keys)
-        except ValueError:  # ragged: a tuple among scalars
-            return None
-    if column.ndim != 1 or column.dtype.kind not in kinds \
-            or column.dtype == np.uint64:
-        return None
-    return np.ascontiguousarray(column, np.int64)
-
-
-def _int_key(key) -> Optional[int]:
-    """The int64 that `key` equals as a dict key (``1.0`` and ``True``
-    are ``1``), None if there is none."""
-    if type(key) is not int:
-        try:
-            whole = int(key)
-        except (TypeError, ValueError, OverflowError):
-            return None
-        if whole != key:
-            return None
-        key = whole
-    return key if _INT64_MIN <= key <= _INT64_MAX else None
-
-
-def _as_list(keys):
-    """A column of keys as the list of Python objects a dict takes."""
-    return keys.tolist() if isinstance(keys, np.ndarray) else keys
-
 
 def pick(keys, rows: np.ndarray):
     """The keys at `rows` (indexes or a mask), of a list as a list, of
@@ -143,7 +86,7 @@ def cut_by_namespace(keys, namespace, namespaces):
     if namespaces is None:
         yield namespace, np.arange(n), key_column(keys)
         return
-    keys = _as_list(keys)
+    keys = as_list(keys)
     groups = group_rows(namespaces)
     if len(groups) == 1:
         yield (*groups[0], keys)
@@ -192,7 +135,7 @@ class NamespaceIndex:
     def __iter__(self) -> Iterator[Tuple[Any, Any]]:
         """Entries as ``(key, namespace)``, namespace by namespace."""
         for namespace, table in self.tables.items():
-            yield from zip(_as_list(_export(table)[0]),
+            yield from zip(as_list(_export(table)[0]),
                            itertools.repeat(namespace))
 
     def __contains__(self, entry) -> bool:
@@ -206,7 +149,7 @@ class NamespaceIndex:
         ids: List[np.ndarray] = []
         for namespace, table in self.tables.items():
             table_keys, table_ids = _export(table)
-            keys.extend(_as_list(table_keys))
+            keys.extend(as_list(table_keys))
             namespaces.extend(itertools.repeat(namespace, len(table_ids)))
             ids.append(table_ids)
         return keys, namespaces, (np.concatenate(ids) if ids
@@ -246,14 +189,14 @@ class NamespaceIndex:
             return default
         if type(table) is dict:
             return table.get(key, default)
-        key = _int_key(key)
+        key = int_key(key)
         found = -1 if key is None else table.get(key)
         return default if found < 0 else found
 
     def put(self, key, namespace, value: int) -> None:
         table = self._born(namespace, None)
         if type(table) is not dict:
-            whole = _int_key(key)
+            whole = int_key(key)
             if whole is not None:
                 table.put(whole, value)
                 return
@@ -267,7 +210,7 @@ class NamespaceIndex:
         if type(table) is dict:
             value = table.pop(key, default)
         else:
-            key = _int_key(key)
+            key = int_key(key)
             found = -1 if key is None else table.pop(key)
             value = default if found < 0 else found
         self._drop_if_empty(namespace, table)
@@ -290,7 +233,7 @@ class NamespaceIndex:
         if table is None:
             table = self._born(namespace, keys)
         if type(table) is not dict:
-            column = _int_column(keys)
+            column = int_column(keys)
             if column is not None:
                 ids, first = table.probe(column)
                 m = len(first)
@@ -313,7 +256,7 @@ class NamespaceIndex:
                 return ids, fresh, column[first]
             table = self._demote(namespace)
         if type(keys) is not list:
-            keys = _as_list(keys)
+            keys = as_list(keys)
         if len(free) >= n:
             # every row offers its key the id `free.pop()` would hand
             # out n-th: a candidate that comes back as its own key's id
@@ -354,13 +297,13 @@ class NamespaceIndex:
             return np.full(n, -1, np.int64)
         if type(table) is dict:
             if type(keys) is not list:
-                keys = _as_list(keys)
+                keys = as_list(keys)
             ids = np.fromiter(map(table.pop if take else table.get, keys,
                                   itertools.repeat(-1)), np.int64, n)
             if take and not table:
                 del self.tables[namespace]
             return ids
-        column = _int_column(keys)
+        column = int_column(keys)
         if column is not None:
             ids = table.lookup(column, take)
             self.int_rows += n
@@ -369,7 +312,7 @@ class NamespaceIndex:
             # doors would find it
             door = table.pop if take else table.get
             ids = np.fromiter((-1 if k is None else door(k)
-                               for k in map(_int_key, keys)), np.int64, n)
+                               for k in map(int_key, keys)), np.int64, n)
         if take:
             self._drop_if_empty(namespace, table)
         return ids
@@ -390,12 +333,12 @@ class NamespaceIndex:
             return
         table = self._born(namespace, keys)
         if type(table) is not dict:
-            column = _int_column(keys)
+            column = int_column(keys)
             if column is not None:
                 table.set(column, np.ascontiguousarray(ids, np.int64))
                 return
             table = self._demote(namespace)
-        table.update(zip(_as_list(keys), ids.tolist()))
+        table.update(zip(as_list(keys), ids.tolist()))
 
     def move(self, keys, namespace, other: "NamespaceIndex",
              ids: np.ndarray) -> None:
@@ -405,11 +348,11 @@ class NamespaceIndex:
         the other.  A table born there takes the form of the one they
         leave."""
         if type(self.tables[namespace]) is dict:
-            keys = _as_list(keys)
+            keys = as_list(keys)
         else:
-            column = _int_column(keys)
+            column = int_column(keys)
             keys = column if column is not None else np.fromiter(
-                map(_int_key, keys), np.int64, len(keys))
+                map(int_key, keys), np.int64, len(keys))
         other.enter(keys, namespace, ids)
         self.lookup(keys, namespace, take=True)
 

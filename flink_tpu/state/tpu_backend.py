@@ -190,6 +190,11 @@ class DeviceAggregatingState(AggregatingState):
     group.
     """
 
+    #: `add_batch`, `get_batch` and `clear_batch` take an int64 key
+    #: column as it is (`slot_index.key_column`): a caller that holds
+    #: one hands it over instead of a list
+    takes_key_column = True
+
     def __init__(self, backend: "TpuKeyedStateBackend",
                  descriptor: AggregatingStateDescriptor,
                  initial_capacity: int = DEFAULT_INITIAL_CAPACITY,
@@ -743,6 +748,12 @@ class DeviceAggregatingState(AggregatingState):
         lo = np.zeros(padded, np.uint32)
         if self.agg.needs_value_hash:
             hi[:n], lo[:n] = split_hash64_np(self._pending_hash.take())
+        # one update in flight at most: with its columns ready, a flush
+        # waits for the table's last program before it dispatches the
+        # next, so a host that takes batches in faster than the device
+        # applies them waits here, a flush at a time, and a fire's
+        # gather never queues behind a window's worth of updates
+        jax.block_until_ready(self.device_state)
         if TELEMETRY.enabled:
             t0 = _perf_ns()
             self.device_state = self._jit_update(

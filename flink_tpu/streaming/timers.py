@@ -23,6 +23,17 @@ import threading
 import time as _time
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
+import numpy as np
+
+from flink_tpu import native
+from flink_tpu.core.key_columns import (
+    INT64_MAX,
+    INT64_MIN,
+    as_list,
+    int_column,
+    int_key,
+    object_column,
+)
 from flink_tpu.core.keygroups import assign_to_key_group
 from flink_tpu.streaming.elements import MIN_TIMESTAMP
 
@@ -205,6 +216,29 @@ def _keys(run) -> list:
     return [run[0]] if type(run) is tuple else list(run)
 
 
+class _IntRun(native.NativeIntTable):
+    """A run of integer keys: int64 key -> registration number in the
+    native table.  Numbers only grow and the table exports its entries
+    in the order they came, so its entry order is its firing order, as
+    a dict run's is.  It iterates as the Python ints a dict run of the
+    same keys would."""
+
+    __slots__ = ()
+
+    def __iter__(self):
+        return iter(self.export()[0].tolist())
+
+    def __reduce__(self):
+        # (a copy or a pickle gets a table of its own, never the handle)
+        return _int_run_of, self.export()
+
+
+def _int_run_of(keys: np.ndarray, numbers: np.ndarray) -> _IntRun:
+    run = _IntRun(len(keys))
+    run.set(keys, numbers)
+    return run
+
+
 class _TimerStore:
     """The timers of one time domain, kept by run: the keys registered
     for ONE (timestamp, namespace) are one dict, in registration
@@ -226,15 +260,24 @@ class _TimerStore:
     merged numbers every key on its own: the run then maps key ->
     number and has no `spans`.
 
+    A run born from an int64 COLUMN of keys (`add_many`) is an
+    `_IntRun` instead: key -> number in a native table, a batch's keys
+    probed and numbered in one C call, the keys of a sweep handed on
+    as the int64 column the table exports.  The same calls give it the
+    keys, numbers and order a dict run gets.  The first key it cannot
+    hold (a string, a tuple, a float, an int beyond int64) turns it
+    into a dict, once (`int_run_demotions`); the per-timer drain reads
+    it as one.
+
     Removing a key is a real removal and an emptied run goes, so every
     run here has a key and a heap node.  The node of a run that went
     stays behind only until such nodes outnumber the runs (then the
     heap is rebuilt from the runs), so deletes never pile up."""
 
-    __slots__ = ("runs", "spans", "heap", "seq")
+    __slots__ = ("runs", "spans", "heap", "seq", "room", "int_run_demotions")
 
     def __init__(self):
-        #: (timestamp, namespace) -> {key: number} | (key, number)
+        #: (timestamp, namespace) -> {key: number} | (key, number) | _IntRun
         self.runs: Dict[Tuple[int, Any], Any] = {}
         #: (timestamp, namespace) -> [[how many, first number], ...]
         self.spans: Dict[Tuple[int, Any], list] = {}
@@ -243,6 +286,11 @@ class _TimerStore:
         self.heap: List[Tuple[int, int, Tuple[int, Any]]] = []
         #: the next registration number
         self.seq = 0
+        #: keys the integer run swept last held at its fullest: the next
+        #: one is born with room for as many
+        self.room = 0
+        #: integer runs a key they could not hold turned into dicts
+        self.int_run_demotions = 0
 
     def clear(self) -> None:
         self.runs.clear()
@@ -257,9 +305,10 @@ class _TimerStore:
         """The runs as of now, in the order they were first seen, as
         three columns no later registration or removal reaches: each
         run's (timestamp, namespace), its keys frozen (`tuple(run)`:
-        of a dict its keys in registration order, of a run of one key
-        the pair it is), and its type, which tells the two apart.  One
-        C-level pass a column: a session job has a run a session."""
+        of a dict or an integer run its keys in registration order, of
+        a run of one key the pair it is), and its type, which tells
+        the two apart.  One C-level pass a column: a session job has a
+        run a session."""
         runs = list(self.runs.values())
         return list(self.runs), list(map(tuple, runs)), list(map(type, runs))
 
@@ -273,7 +322,16 @@ class _TimerStore:
     def _dict(self, at, run) -> dict:
         if type(run) is tuple:
             run = self.runs[at] = {run[0]: run[1]}
+        elif type(run) is _IntRun:
+            keys, numbers = run.export()
+            run = self.runs[at] = dict(zip(keys.tolist(), numbers.tolist()))
         return run
+
+    def _demote(self, at, run) -> dict:
+        """An integer run as a dict from now on: a key came that it
+        cannot hold."""
+        self.int_run_demotions += 1
+        return self._dict(at, run)
 
     def add(self, timestamp: int, namespace, key) -> bool:
         """Register one timer; False if it is registered already."""
@@ -284,6 +342,14 @@ class _TimerStore:
             self.runs[at] = (key, seq)
             heapq.heappush(self.heap, (timestamp, seq, at))
         else:
+            if type(run) is _IntRun:
+                if type(key) is int and INT64_MIN <= key <= INT64_MAX:
+                    if run.get(key) >= 0:
+                        return False
+                    run.put(key, seq)
+                    self.seq = seq + 1
+                    return True
+                run = self._demote(at, run)
             run = self._dict(at, run)
             if key in run:
                 return False
@@ -294,24 +360,43 @@ class _TimerStore:
         self.seq = seq + 1
         return True
 
-    def add_many(self, timestamp: int, namespace, keys) -> None:
+    def add_many(self, timestamp: int, namespace, keys) -> int:
         """`keys` in row order, repeats allowed (a dict is taken for
-        its keys).  Keys that are in the run keep their place, the
-        others append in first-occurrence order under consecutive
-        numbers."""
+        its keys; an integer `ndarray` is a column).  Keys that are in
+        the run keep their place, the others append in first-occurrence
+        order under consecutive numbers.  Returns how many timers it
+        added."""
+        at = (timestamp, namespace)
+        run = self.runs.get(at)
+        if isinstance(keys, np.ndarray) \
+                and (keys.ndim != 1 or keys.dtype.kind != "i"):
+            # an unsigned, float or object array: the Python objects it
+            # holds, which an integer run takes or is demoted by below
+            keys = keys.tolist()
+        if type(run) is _IntRun and not isinstance(keys, np.ndarray):
+            keys = list(keys)
+            if not keys:
+                return 0
+            column = int_column(keys, kinds="i")
+            if column is None:
+                run = self._demote(at, run)
+            else:
+                keys = column
+        if isinstance(keys, np.ndarray):
+            if (run is None or type(run) is _IntRun) and native.available():
+                return self._add_column(at, run, keys)
+            keys = keys.tolist()
         if not isinstance(keys, dict):
             keys = dict.fromkeys(keys)
         if not keys:
-            return
-        at = (timestamp, namespace)
-        run = self.runs.get(at)
+            return 0
         seq = self.seq
         if run is None:
             self.runs[at] = dict(keys)
             self.spans[at] = [[len(keys), seq]]
             heapq.heappush(self.heap, (timestamp, seq, at))
             self.seq = seq + len(keys)
-            return
+            return len(keys)
         spans = self.spans.get(at)
         if spans is None:
             run = self._dict(at, run)
@@ -319,14 +404,33 @@ class _TimerStore:
                 if key not in run:
                     run[key] = seq
                     seq += 1
+            added = seq - self.seq
             self.seq = seq
-            return
+            return added
         before = len(run)
         run.update(keys)
         added = len(run) - before
         if added:
             _extend(spans, added, seq)
             self.seq = seq + added
+        return added
+
+    def _add_column(self, at, run, keys: np.ndarray) -> int:
+        """`add_many` of an int64 column onto an integer run (made if
+        there is none): one probe, the new keys numbered in the order
+        they first came."""
+        if not len(keys):
+            return 0
+        seq = self.seq
+        if run is None:
+            run = self.runs[at] = _IntRun(self.room)
+            heapq.heappush(self.heap, (at[0], seq, at))
+        numbers, first = run.probe(np.ascontiguousarray(keys, np.int64))
+        added = len(first)
+        if added:
+            run.assign(np.arange(seq, seq + added, dtype=np.int64), numbers)
+            self.seq = seq + added
+        return added
 
     def _numbered(self, at, run) -> dict:
         """`run` as the dict key -> registration number, which it is
@@ -342,6 +446,9 @@ class _TimerStore:
         grow, so they are those of the first and the last key)."""
         if type(run) is tuple:
             return run[1], run[1]
+        if type(run) is _IntRun:
+            numbers = run.export()[1]
+            return int(numbers[0]), int(numbers[-1])
         spans = self.spans.get(at)
         if spans is not None:
             return spans[0][1], spans[-1][1] + spans[-1][0] - 1
@@ -356,11 +463,22 @@ class _TimerStore:
         run = self.runs.get(at)
         if run is None:
             return
-        if type(run) is tuple:
-            if run[0] not in keys:
+        if type(run) is _IntRun:
+            if isinstance(keys, np.ndarray) and keys.dtype.kind == "i":
+                column = np.ascontiguousarray(keys, np.int64)
+            else:
+                # each key as the int64 it equals as a dict key, if any
+                column = np.fromiter(
+                    (k for k in map(int_key, as_list(keys))
+                     if k is not None), np.int64)
+            run.lookup(column, take=True)
+            if len(run):
+                return
+        elif type(run) is tuple:
+            if run[0] not in as_list(keys):
                 return
         else:
-            for key in keys:
+            for key in as_list(keys):
                 if key in run:
                     del self._numbered(at, run)[key]
             if run:
@@ -399,35 +517,53 @@ class _TimerStore:
                 heapq.heappush(heap, node)
         return {at: runs[at] for at in nodes}
 
-    def _by_number(self, tied: dict) -> list:
+    def _by_number(self, tied: dict, exported: Optional[dict] = None) -> list:
         """``(registration number, key, (timestamp, namespace), run)``
-        of every timer of the runs in `tied`, in firing order."""
-        rows = [(seq, key, at, run) for at, run in
-                [(at, self._numbered(at, run)) for at, run in tied.items()]
-                for key, seq in run.items()]
+        of every timer of the runs in `tied`, in firing order; an
+        integer run in `exported` gives the numbers and keys it
+        exported, every other run is numbered key by key."""
+        rows = []
+        for at, run in tied.items():
+            if exported and at in exported:
+                keys, numbers = exported[at]
+                rows.extend(zip(numbers.tolist(), keys.tolist(),
+                                itertools.repeat(at), itertools.repeat(run)))
+            else:
+                run = self._numbered(at, run)
+                rows.extend((seq, key, at, run) for key, seq in run.items())
         if len(tied) > 1:
             rows.sort(key=operator.itemgetter(0))
         return rows
 
-    def pop_runs(self, limit: int) -> List[Tuple[int, Any, list]]:
+    def pop_runs(self, limit: int) -> List[Tuple[int, Any, Any]]:
         """Take every timer <= limit out: ``(timestamp, namespace,
-        keys)`` runs in firing order.  A timestamp with one namespace
-        is its run, whole; runs that tie on a timestamp are cut where
-        their registration numbers interleave."""
+        keys)`` runs in firing order, the keys a list, or of an integer
+        run the int64 column it exported.  A timestamp with one
+        namespace is its run, whole; runs that tie on a timestamp are
+        cut where their registration numbers interleave."""
         out = []
         heap = self.heap
         while heap and heap[0][0] <= limit:
             timestamp = heap[0][0]
             tied = self._pop_tied(timestamp, keep_nodes=False)
-            ranges = sorted(self._number_range(at, run) + (at,)
-                            for at, run in tied.items())
+            exported = {}
+            for at, run in tied.items():
+                if type(run) is _IntRun:
+                    exported[at] = run.export()
+                    self.room = run.peak()
+            ranges = sorted(
+                ((int(exported[at][1][0]), int(exported[at][1][-1]))
+                 if at in exported else self._number_range(at, run))
+                + (at,) for at, run in tied.items())
             if any(a[1] > b[0] for a, b in zip(ranges, ranges[1:])):
                 out.extend(
                     (timestamp, at[1], [row[1] for row in rows])
                     for at, rows in itertools.groupby(
-                        self._by_number(tied), key=operator.itemgetter(2)))
+                        self._by_number(tied, exported),
+                        key=operator.itemgetter(2)))
             else:
-                out.extend((timestamp, at[1], _keys(tied[at]))
+                out.extend((timestamp, at[1], exported[at][0]
+                            if at in exported else _keys(tied[at]))
                            for _, _, at in ranges)
             for at in tied:
                 del self.runs[at]
@@ -485,7 +621,6 @@ class TimerRows:
 
     def __init__(self, stamps, keys, namespaces):
         from flink_tpu.state.backend import encode_obj_column
-        import numpy as np
         self.stamps = np.asarray(stamps, np.int64)
         self.keys = encode_obj_column(keys)
         # windows are tuples of ints, all of one length: one int64
@@ -527,9 +662,7 @@ def _runs_by_key_group(captured: Tuple[list, list, list],
     """`(timestamp, key, namespace)` of every timer of the captured
     runs (`_TimerStore.capture`), per key group, in the store's order:
     one vectorized hash of the key column."""
-    import numpy as np
     from flink_tpu.state.heap_backend import split_column_by_key_group
-    from flink_tpu.state.slot_index import object_column
     stamps, keys, namespaces = [], [], []
     for (timestamp, namespace), run, kind in zip(*captured):
         if kind is tuple:  # (key, registration number)
@@ -577,14 +710,15 @@ class InternalTimerService:
         self._event.add(timestamp, namespace, self._backend.current_key)
 
     def register_event_time_timers_bulk(self, namespace, timestamp: int,
-                                        keys) -> None:
+                                        keys) -> int:
         """Register the same (namespace, timestamp) timer for MANY keys
         without touching the backend's current-key context: one update
         of that run with the keys in row order (repeats allowed; a
-        dict is taken for its keys).  Per key as
-        register_event_time_timer: a key that has the timer keeps its
-        place, the others follow in first-occurrence order."""
-        self._event.add_many(timestamp, namespace, keys)
+        dict is taken for its keys; an int64 column keeps the run in a
+        native table).  Per key as register_event_time_timer: a key
+        that has the timer keeps its place, the others follow in
+        first-occurrence order.  Returns how many timers it added."""
+        return self._event.add_many(timestamp, namespace, keys)
 
     def delete_event_time_timer(self, namespace, timestamp: int) -> None:
         self._event.discard(timestamp, namespace, self._backend.current_key)
@@ -625,6 +759,12 @@ class InternalTimerService:
     def delete_processing_time_timer(self, namespace, timestamp: int) -> None:
         self._proc.discard(timestamp, namespace, self._backend.current_key)
 
+    @property
+    def int_run_demotions(self) -> int:
+        """Integer runs that a key they could not hold turned into
+        dicts (`_TimerStore`)."""
+        return self._event.int_run_demotions + self._proc.int_run_demotions
+
     def num_event_time_timers(self) -> int:
         return len(self._event)
 
@@ -647,9 +787,10 @@ class InternalTimerService:
                           self._triggerable.on_event_time)
 
     def pop_due_event_time_timers(
-            self, watermark: int) -> List[Tuple[int, Any, list]]:
+            self, watermark: int) -> List[Tuple[int, Any, Any]]:
         """Bulk sweep: take EVERY due event-time timer <= watermark
-        and return them as runs ``(timestamp, namespace, keys)``; the
+        and return them as runs ``(timestamp, namespace, keys)``, the
+        keys a list or an int64 column (`_TimerStore.pop_runs`); the
         runs end to end are the exact order advance_watermark would
         have fired them in.  The watermark advances exactly as
         advance_watermark does; FIRING is the caller's job.

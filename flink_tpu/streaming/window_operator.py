@@ -24,6 +24,7 @@ from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from flink_tpu.core.key_columns import as_list, key_column
 from flink_tpu.core.state import (
     AggregatingStateDescriptor,
     ListStateDescriptor,
@@ -715,13 +716,14 @@ class _FireBufferOutput(Output):
 
 def _run_columns(runs):
     """``[(namespace, keys)]`` end to end: the key column with the one
-    namespace they share (a sweep of one run hands its key list on as
-    it is), or with a namespace per key."""
+    namespace they share (a sweep of one run hands its keys on as they
+    are: a list, or the int64 column of an integer run), or a list with
+    a namespace per key."""
     if len(runs) == 1:
         namespace, keys = runs[0]
         return keys, namespace, None
     chain = itertools.chain.from_iterable
-    return (list(chain(keys for _, keys in runs)), None,
+    return (list(chain(as_list(keys) for _, keys in runs)), None,
             list(chain(itertools.repeat(ns, len(keys)) for ns, keys in runs)))
 
 
@@ -773,6 +775,10 @@ class WindowOperator(AbstractUdfStreamOperator):
         #: sliding assigner of size 10 x slide reads 10 rows per event
         self.windows_touched = 0
         self.window_rows = 0
+        #: rows whose keys the vectorized ingest kept as one int64
+        #: column into `add_batch` (the backend hands a state without a
+        #: column door the Python ints) and `timers.register`
+        self.key_column_rows = 0
         #: the batched session ingest: rows that opened a session, rows
         #: that joined an open one, windows a row's merge swallowed
         #: beyond the first it joined
@@ -929,16 +935,21 @@ class WindowOperator(AbstractUdfStreamOperator):
             if self.metrics is not None:
                 self.metrics.counter("numLateRecordsDropped").inc(cnt)
 
-    def _batch_keys(self, batch, values) -> list:
-        """Key column for a batch as a python list — bit-identical to
-        what set_key_context would have extracted per row (same idiom
-        as the generic engine's _batch_keys)."""
+    def _batch_keys(self, batch):
+        """``(keys, rows)`` of a batch: the keys cell for cell what
+        set_key_context would have extracted per row, as one int64
+        array where the selector reads an integer column
+        (`key_column`: ``key_by(<int>)`` over an integer field, the
+        native runtime there), else as a list of Python objects; the
+        rows (`row_values`) where the keys had to be read off them,
+        else None."""
         from flink_tpu.streaming.columnar import field_key_column
         sel = self.key_selector
         col = field_key_column(sel, batch)
         if col is not None:
-            return col.tolist()
-        return [sel.get_key(v) for v in values]
+            return key_column(col), None
+        values = batch.row_values()
+        return [sel.get_key(v) for v in values], values
 
     def process_batch(self, batch) -> None:
         """Columnar ingest: assign tumbling/sliding panes for the whole
@@ -1006,8 +1017,13 @@ class WindowOperator(AbstractUdfStreamOperator):
         tracer = get_tracer()
         ts = np.asarray(batch.ts, np.int64)
         with tracer.phase("window.ingest.box"):
-            values = batch.row_values()
-            keys = self._batch_keys(batch, values)
+            # the rows are boxed only where they are needed: no value
+            # column, an immediate fire, a late side output
+            vcol = self._value_column(batch, n)
+            keys, values = self._batch_keys(batch)
+            if vcol is None and values is None:
+                values = batch.row_values()
+        column = isinstance(keys, np.ndarray)
         size = self.assigner.size
         lateness = self.allowed_lateness
         state = self.window_state
@@ -1016,7 +1032,6 @@ class WindowOperator(AbstractUdfStreamOperator):
             wm = self.timer_service.current_watermark
             slide = getattr(self.assigner, "slide", size)
             offset = self.assigner.offset
-            vcol = self._value_column(batch, n)
             if last_start is None:
                 last_start = ts - ((ts - offset) % slide)
             else:
@@ -1059,8 +1074,8 @@ class WindowOperator(AbstractUdfStreamOperator):
                 lo = 0
                 for hi in [*bounds.tolist(), len(sidx)]:
                     gidx = sidx[lo:hi]
-                    groups.append((int(sstarts[lo]), gidx,
-                                   [keys[i] for i in gidx]))
+                    groups.append((int(sstarts[lo]), gidx, keys[gidx]
+                                   if column else [keys[i] for i in gidx]))
                     lo = hi
                 self.window_rows += len(sidx)
             phase.set_attr("windows", len(groups))
@@ -1076,23 +1091,29 @@ class WindowOperator(AbstractUdfStreamOperator):
             with tracer.phase("timers.register") as phase:
                 # first-occurrence order, NOT a set: same-timestamp
                 # timers fire in registration order, and the scalar
-                # path registers them in row order
-                dkeys = dict.fromkeys(gkeys)
-                phase.set_attr("keys", len(dkeys))
+                # path registers them in row order (an int64 column
+                # is numbered so by the store's probe)
+                if not column:
+                    gkeys = dict.fromkeys(gkeys)
                 maxt = start + size - 1
                 # trigger timer (what EventTimeTrigger.on_element
                 # registers on CONTINUE) + GC timer, which with
                 # lateness 0 is the trigger timer; keys that have
                 # theirs keep their place
                 register = self.timer_service.register_event_time_timers_bulk
-                register(ns, maxt, dkeys)
+                phase.set_attr("keys", register(ns, maxt, gkeys))
+                phase.add_count("int_run", len(gkeys) if column else 0)
                 cleanup = maxt + lateness
                 if maxt < cleanup < MAX_TIMESTAMP:
-                    register(ns, cleanup, dkeys)
+                    register(ns, cleanup, gkeys)
+        if column:
+            self.key_column_rows += n
         if immediate.any():
+            if values is None:
+                values = batch.row_values()
             tlist = ts.tolist()
             for i in np.nonzero(immediate)[0]:
-                backend.set_current_key(keys[i])
+                backend.set_current_key(int(keys[i]) if column else keys[i])
                 self._replay_immediate(values[i], tlist[i], wm)
         dropped = ~assigned & ~immediate & ((ts + lateness) <= wm)
         if dropped.any():
@@ -1420,6 +1441,9 @@ class WindowOperator(AbstractUdfStreamOperator):
             n = sum(len(keys) for _, _, keys in runs)
             phase.set_attr("timers", n)
             phase.set_attr("runs", len(runs))
+            phase.add_count("int_run", sum(
+                len(keys) for _, _, keys in runs
+                if isinstance(keys, np.ndarray)))
         if not runs:
             return
         self.timers_swept += n
@@ -1449,8 +1473,9 @@ class WindowOperator(AbstractUdfStreamOperator):
             contents_col, found_mask, _path = backend.get_batch(
                 self.window_state, columns[0], columns[1],
                 namespaces=columns[2])
+            # the emit tail hands the window function Python ints
             emitted = self._emit_fired_columns(
-                *columns, contents_col, found_mask)
+                as_list(columns[0]), *columns[1:], contents_col, found_mask)
         if TELEMETRY.enabled and emitted:
             TELEMETRY.note_windows_fired(emitted)
         if cleaned:
@@ -1468,7 +1493,7 @@ class WindowOperator(AbstractUdfStreamOperator):
                 wt = self.assigner.window_type()
                 for ns, keys in cleaned:
                     window = wt.from_namespace(ns)
-                    for key in keys:
+                    for key in as_list(keys):
                         backend.set_current_key(key)
                         self._internal_fn.clear(key, window, self)
 

@@ -425,6 +425,30 @@ void ft_itab_set(void* p, const int64_t* keys, const int64_t* ids,
 // Every entry in the order it was entered (buffers sized >= size).
 int64_t ft_itab_export(void* p, int64_t* keys_out, int64_t* ids_out) {
   const FtIntTable& t = *static_cast<FtIntTable*>(p);
+  const uint64_t dense = 4 * static_cast<uint64_t>(t.n) + 64;
+  uint64_t lo = 0;
+  if (t.next_seq > dense) {  // the oldest live entry's number
+    lo = t.next_seq;
+    for (size_t i = 0; i < t.cells.size(); ++i)
+      if (t.cells[i].id != FtIntTable::kEmpty && t.seq[i] < lo) lo = t.seq[i];
+  }
+  uint64_t span = t.next_seq - lo;
+  if (span <= dense) {
+    // few entries were taken out since the oldest live one came: each
+    // is placed by its entry number, no sort
+    std::vector<int64_t> at(span, -1);
+    for (size_t i = 0; i < t.cells.size(); ++i)
+      if (t.cells[i].id != FtIntTable::kEmpty)
+        at[t.seq[i] - lo] = static_cast<int64_t>(i);
+    int64_t k = 0;
+    for (int64_t cell : at) {
+      if (cell < 0) continue;
+      keys_out[k] = t.cells[cell].key;
+      ids_out[k] = t.cells[cell].id;
+      ++k;
+    }
+    return k;
+  }
   std::vector<std::pair<uint64_t, uint64_t>> order;  // (seq, cell)
   order.reserve(static_cast<size_t>(t.n));
   for (size_t i = 0; i < t.cells.size(); ++i)

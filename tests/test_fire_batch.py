@@ -46,6 +46,11 @@ N_CHUNKS = 4
 CHUNK = 192
 N_KEYS = 7
 
+#: how the operator reads a row's key: a function of the row boxes the
+#: keys into a list (the parent's path); `key_by(0)` over the integer
+#: field keeps them as one int64 column, into the timer runs too
+SELECTORS = {"lambda": lambda x: x[0], "field": 0}
+
 
 class _KVSum(SumAggregate):
     def __init__(self):
@@ -355,14 +360,15 @@ WINDOW_FUNCTIONS = {
 }
 
 
-def _tail_harness(kind, backend, fn, batch_fires, lateness=0):
+def _tail_harness(kind, backend, fn, batch_fires, lateness=0,
+                  selector="lambda"):
     from flink_tpu.runtime.metrics import MetricRegistry
     op = WindowOperator(_assigner(kind),
                         AggregatingStateDescriptor("fire-sum", _KVSum()),
                         window_function=fn, allowed_lateness=lateness)
     op.batch_fires = batch_fires
     h = OneInputStreamOperatorTestHarness(
-        op, key_selector=lambda x: x[0], state_backend=backend)
+        op, key_selector=SELECTORS[selector], state_backend=backend)
     op.register_standard_metrics(MetricRegistry().job_group("tail"))
     spy = op.output = FireSpy()  # before open(): the collector binds it
     h.open()
@@ -380,10 +386,12 @@ def _tail_harness(kind, backend, fn, batch_fires, lateness=0):
     return op, h, spy
 
 
-def _drive_fires(kind, backend, fn, batch_fires, lateness=0, chunks=None):
+def _drive_fires(kind, backend, fn, batch_fires, lateness=0, chunks=None,
+                 selector="lambda"):
     """One list of what left the operator per watermark; the sweeps
     take in four tumbling (or five sliding) windows each."""
-    op, h, spy = _tail_harness(kind, backend, fn, batch_fires, lateness)
+    op, h, spy = _tail_harness(kind, backend, fn, batch_fires, lateness,
+                               selector)
     per_watermark = []
     for keys, vals, ts, wm in chunks or _chunks():
         h.process_batch(RecordBatch({"f0": keys, "f1": vals}, ts=ts))
@@ -442,16 +450,20 @@ def _maximal_runs(timers):
     # lateness = slide, = size: ties among three sliding windows
     ("sliding", 100), ("sliding", 200), ("tumbling", 150)])
 @pytest.mark.parametrize("shape", ["one_row", "several_rows"])
-def test_swept_runs_fire_as_the_per_timer_drain(shape, kind, lateness,
-                                                backend):
+@pytest.mark.parametrize("selector", list(SELECTORS))
+def test_swept_runs_fire_as_the_per_timer_drain(selector, shape, kind,
+                                                lateness, backend):
     """The batched fire over the sweep's runs against the per-timer
     drain, cell for cell, where timers of several windows tie on a
     timestamp; and the counters that say how the timers came: every
     timer the drain fires is swept once, in as many runs as the
-    drain's order has stretches of one (timestamp, window)."""
+    drain's order has stretches of one (timestamp, window).  The
+    drain's side boxes its keys; the batched side keeps them as an
+    int64 column where the selector reads the field."""
     fn = WINDOW_FUNCTIONS[shape]
     scalar_op, scalar = _drive_fires(kind, backend, fn, False, lateness)
-    op, batched = _drive_fires(kind, backend, fn, True, lateness)
+    op, batched = _drive_fires(kind, backend, fn, True, lateness,
+                               selector=selector)
     assert len(batched) == len(scalar)
     for events, reference in zip(batched, scalar):
         assert_fire_left_as(events, [what for _, what in reference])
@@ -478,7 +490,9 @@ def _rows(keys, ts, wm):
 
 
 @pytest.mark.parametrize("backend", ["heap", "tpu"])
-def test_tied_runs_are_cut_where_their_registrations_interleave(backend):
+@pytest.mark.parametrize("selector", list(SELECTORS))
+def test_tied_runs_are_cut_where_their_registrations_interleave(selector,
+                                                                backend):
     """Tumbling 100 with lateness 100: at 199 window [0, 100)'s
     cleanup timers tie with window [100, 200)'s fire timers, and keys
     reached the two windows in turns, batch after batch.  The sweep
@@ -489,7 +503,7 @@ def test_tied_runs_are_cut_where_their_registrations_interleave(backend):
     scalar_op, scalar = _drive_fires("tumbling", backend, _one_row, False,
                                      100, chunks)
     op, batched = _drive_fires("tumbling", backend, _one_row, True, 100,
-                               chunks)
+                               chunks, selector)
     for events, reference in zip(batched, scalar):
         assert_fire_left_as(events, [what for _, what in reference])
     tie = [sweep for sweep in _timers_by_watermark(scalar_op)
@@ -555,9 +569,12 @@ class _WritesThroughOut(WindowFunction):
 @pytest.mark.parametrize("backend", ["heap", "tpu"])
 @pytest.mark.parametrize("fn", [_CountsPerWindowAndKey, _WritesThroughOut],
                          ids=["process_window_function", "window_function"])
-def test_collector_functions_keep_key_context_and_records(fn, backend):
+@pytest.mark.parametrize("selector", list(SELECTORS))
+def test_collector_functions_keep_key_context_and_records(selector, fn,
+                                                          backend):
     scalar_op, scalar = _drive_fires("sliding", backend, fn(), False, 150)
-    op, batched = _drive_fires("sliding", backend, fn(), True, 150)
+    op, batched = _drive_fires("sliding", backend, fn(), True, 150,
+                               selector=selector)
     fired_rows = 0
     for events, reference in zip(batched, scalar):
         rows = [what for _, what in reference]
@@ -611,12 +628,17 @@ def test_a_plain_callable_fire_builds_no_stream_record(fn, direct,
         assert CountedRecord.made == rows
 
 
-def test_timer_phases_carry_keys_timers_and_runs(monkeypatch):
-    """`timers.register` says how many distinct keys a (window, batch)
-    handed to the store, `timers.sweep` how many timers a watermark
-    took out and in how many runs: 300 timers in one run for a
-    tumbling window, whatever the number of batches that brought them."""
-    op, h, spy = _tail_harness("tumbling", "heap", _one_row, True)
+@pytest.mark.parametrize("selector", ["lambda", "field"])
+def test_timer_phases_carry_keys_timers_and_runs(selector, monkeypatch):
+    """`timers.register` says how many timers a (window, batch) added
+    to the store, `timers.sweep` how many timers a watermark took out
+    and in how many runs: 300 timers in one run for a tumbling window,
+    whatever the number of batches that brought them.  `int_run` on
+    both says how many keys went as an int64 column: all of them where
+    the selector reads the integer field (`key_by(0)`), none where it
+    is a function of the row."""
+    op, h, spy = _tail_harness("tumbling", "heap", _one_row, True,
+                               selector=selector)
     tr = get_tracer()
     tr.reset()
     monkeypatch.setattr(tr, "enabled", True)
@@ -627,11 +649,15 @@ def test_timer_phases_carry_keys_timers_and_runs(monkeypatch):
     h.process_watermark(98)
     h.process_watermark(99)
     events = tr.recent()
+    column = 400 if selector == "field" else 0
     assert [e["args"] for e in events if e["name"] == "timers.register"] \
-        == [{"keys": 200}, {"keys": 200}]
+        == [{"keys": 200, "int_run": column}, {"keys": 100, "int_run": column}]
     assert [e["args"] for e in events if e["name"] == "timers.sweep"] \
-        == [{"timers": 0, "runs": 0}, {"timers": 300, "runs": 1}]
+        == [{"timers": 0, "runs": 0, "int_run": 0},
+            {"timers": 300, "runs": 1, "int_run": 300 if column else 0}]
     assert (op.timers_swept, op.timer_runs) == (300, 1)
+    assert op.key_column_rows == 2 * column == (op.columnar_rows
+                                                 if column else 0)
     assert len(spy.rows()) == 300
 
 
@@ -666,3 +692,221 @@ def test_histogram_update_many_is_update_for_each(filled, n):
     many.update_many(values)
     assert (many.total_count, many._values, many._pos) \
         == (one.total_count, one._values, one._pos)
+
+
+# ---- the key column: one int64 array from the batch to the fire ------
+# `key_by(0)` over an integer field keeps a batch's keys as the column
+# they came in, through `add_batch` (where the state takes one), the
+# timer runs (a native table) and the fire; a selector that is a
+# function of the row boxes them into a list, as the parent did.  The
+# two must give the same rows in the same order, the same late counts,
+# the same sweeps and the same checkpoint bytes.
+
+def _late_chunks():
+    """Rows up to 400 ms behind the last watermark: windows that are
+    past their lateness (dropped or sent to the side output), windows
+    within it (an immediate fire, a cleanup timer registered one key
+    at a time into an integer run), windows not due yet."""
+    rng = np.random.default_rng(41)
+    for c in range(5):
+        keys = rng.integers(-3, N_KEYS, CHUNK)
+        vals = rng.integers(0, 50, CHUNK).astype(np.float64)
+        ts = rng.integers(max(0, c * 400 - 800), c * 400 + 400,
+                          CHUNK).astype(np.int64)
+        yield keys, vals, ts, c * 400
+
+
+class _SweepSpy:
+    """Every sweep's runs as the store handed them over."""
+
+    def __init__(self, svc):
+        self.runs = []
+        self._pop = svc.pop_due_event_time_timers
+        svc.pop_due_event_time_timers = self
+
+    def __call__(self, watermark):
+        runs = self._pop(watermark)
+        self.runs.append([(ts, ns, type(keys).__name__, list(keys))
+                          for ts, ns, keys in runs])
+        return runs
+
+
+def _key_path_job(kind, lateness, backend, selector, *, chunks,
+                  batch_fires=True, side=False, snapshot_at=None,
+                  restore_selector=None, fused=False):
+    """The job over `chunks`; what left it (main and side), its late
+    count, its sweeps, the timer snapshot's bytes at `snapshot_at`
+    (restored into an operator of `restore_selector`), its counters."""
+    import pickle
+
+    from flink_tpu.streaming.operators import OutputTag
+    tag = OutputTag("late") if side else None
+
+    def fresh(sel):
+        op = WindowOperator(_assigner(kind),
+                            AggregatingStateDescriptor("fire-sum", _KVSum()),
+                            window_function=_one_row,
+                            allowed_lateness=lateness, late_data_tag=tag)
+        op.batch_fires = batch_fires
+        h = OneInputStreamOperatorTestHarness(
+            op, key_selector=SELECTORS[sel], state_backend=backend)
+        h.open()
+        return op, h, _SweepSpy(op.timer_service)
+
+    op, h, spy = fresh(selector)
+    seen = {"out": [], "side": [], "sweeps": [], "snapshot": None,
+            "late": 0, "key_column_rows": 0, "columnar_rows": 0,
+            "demotions": 0}
+
+    def count(op, h, spy):
+        seen["out"] += [(r.value, r.timestamp) for r in h.get_output()]
+        seen["side"] += [(r.value, r.timestamp)
+                         for r in h.get_side_output("late")]
+        seen["sweeps"].extend(spy.runs)
+        seen["late"] += op.num_late_records_dropped
+        seen["key_column_rows"] += op.key_column_rows
+        seen["columnar_rows"] += op.columnar_rows
+        seen["demotions"] += op.timer_service.int_run_demotions
+
+    for c, (keys, vals, ts, wm) in enumerate(chunks):
+        batch = RecordBatch({"f0": keys, "f1": vals}, ts=ts)
+        if fused:
+            slide = getattr(op.assigner, "slide", op.assigner.size)
+            op.process_batch_fused(batch, last_start=ts - ts % slide)
+        else:
+            h.process_batch(batch)
+        h.process_watermark(wm)
+        if snapshot_at == c:
+            snap = h.snapshot()
+            seen["snapshot"] = (
+                pickle.dumps(snap["timers"]),
+                list(op.timer_service.capture_snapshot().resolve()["event"]
+                     .items()))
+            count(op, h, spy)
+            op, h, spy = fresh(restore_selector or selector)
+            h.initialize_state(snap)
+    h.process_watermark(10 ** 13)
+    count(op, h, spy)
+    return seen
+
+
+def _same_job(column, listed):
+    for name in ("out", "side", "late", "snapshot"):
+        assert column[name] == listed[name], name
+    # the sweeps: the same timers in the same runs, the column path's
+    # integer runs handed over as arrays, the list path's as lists
+    strip = [[(ts, ns, keys) for ts, ns, _, keys in sweep]
+             for sweep in column["sweeps"]]
+    assert strip == [[(ts, ns, keys) for ts, ns, _, keys in sweep]
+                     for sweep in listed["sweeps"]]
+    assert {kind for sweep in listed["sweeps"] for _, _, kind, _ in sweep} \
+        <= {"list"}
+    assert column["key_column_rows"] == column["columnar_rows"] > 0
+    assert listed["key_column_rows"] == 0
+    assert column["demotions"] == listed["demotions"] == 0
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("lateness", [0, 100, 150])
+@pytest.mark.parametrize("kind", ["tumbling", "sliding"])
+@pytest.mark.parametrize("batch_fires", [True, False],
+                         ids=["sweep", "drain"])
+def test_key_column_path_fires_as_the_list_path(batch_fires, kind, lateness,
+                                                backend):
+    """Late rows dropped and counted, rows within the lateness fired at
+    once, ties of one timestamp under two windows (lateness 100 and
+    150), and the fire by the sweep or by the per-timer drain over the
+    integer runs."""
+    chunks = list(_late_chunks())
+    listed = _key_path_job(kind, lateness, backend, "lambda", chunks=chunks,
+                           batch_fires=batch_fires)
+    column = _key_path_job(kind, lateness, backend, "field", chunks=chunks,
+                           batch_fires=batch_fires)
+    assert listed["out"] and listed["late"]
+    _same_job(column, listed)
+    if batch_fires:
+        kinds = {kind for sweep in column["sweeps"] for _, _, kind, _ in sweep}
+        assert "ndarray" in kinds
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("kind", ["tumbling", "sliding"])
+def test_key_column_late_rows_take_the_side_output(kind, backend):
+    chunks = list(_late_chunks())
+    listed = _key_path_job(kind, 100, backend, "lambda", chunks=chunks,
+                           side=True)
+    column = _key_path_job(kind, 100, backend, "field", chunks=chunks,
+                           side=True)
+    assert listed["side"] and listed["late"] == 0
+    _same_job(column, listed)
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("kind", ["tumbling", "sliding"])
+@pytest.mark.parametrize("restore", ["lambda", "field"])
+def test_key_column_checkpoint_restores_into_either_path(restore, kind,
+                                                         backend):
+    """A checkpoint mid-window: the timer snapshot's bytes (and the
+    deferred capture's rows) are the list path's, and restored into
+    either path the job goes on as the list path restored into the
+    list path."""
+    chunks = list(_late_chunks())
+    listed = _key_path_job(kind, 150, backend, "lambda", chunks=chunks,
+                           snapshot_at=2)
+    column = _key_path_job(kind, 150, backend, "field", chunks=chunks,
+                           snapshot_at=2, restore_selector=restore)
+    assert listed["snapshot"] is not None and listed["snapshot"][1]
+    assert column["snapshot"] == listed["snapshot"]
+    assert column["out"] == listed["out"] and column["late"] == listed["late"]
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+@pytest.mark.parametrize("kind", ["tumbling", "sliding"])
+def test_key_column_through_the_fused_door(kind, backend):
+    """`process_batch_fused` (the pane starts computed upstream) takes
+    the same column path."""
+    chunks = list(_chunks())
+    listed = _key_path_job(kind, 0, backend, "lambda", chunks=chunks)
+    column = _key_path_job(kind, 0, backend, "field", chunks=chunks,
+                           fused=True)
+    assert listed["out"]
+    _same_job(column, listed)
+
+
+@pytest.mark.parametrize("backend", ["heap", "tpu"])
+def test_a_state_without_a_column_door_is_handed_python_ints(backend,
+                                                             monkeypatch):
+    """What the state's `add_batch`, `get_batch` and `clear_batch` are
+    handed through the backend's doors: the int64 column where the
+    state says it takes one (the tpu backend's device state; a sweep of
+    several runs hands on a list beside their namespaces), else the
+    list of Python ints the parent handed."""
+    import sys
+
+    from flink_tpu.state import backend as backend_module
+    handed = []
+    orig = backend_module._keys_for
+
+    def spy(state, keys):
+        keys = orig(state, keys)
+        handed.append((sys._getframe(1).f_code.co_name, type(keys).__name__,
+                       {type(k).__name__ for k in keys}))
+        return keys
+    monkeypatch.setattr(backend_module, "_keys_for", spy)
+    # one window a watermark: a sweep of one run each
+    rng = np.random.default_rng(5)
+    chunks = [(rng.integers(0, 50, 64), np.ones(64),
+               c * 100 + rng.integers(0, 100, 64), c * 100 + 99)
+              for c in range(3)]
+    _key_path_job("tumbling", 0, backend, "field", chunks=chunks)
+    assert {(kind, frozenset(types)) for _, kind, types in handed} \
+        <= {("ndarray", frozenset({"int64"})), ("list", frozenset({"int"}))}
+    columns = {door for door, kind, _ in handed if kind == "ndarray"}
+    if backend == "tpu":
+        assert columns == {"add_batch", "get_batch", "clear_batch"}
+        assert all(kind == "ndarray" for door, kind, _ in handed
+                   if door == "add_batch")
+    else:
+        assert columns == set()
+    assert {door for door, _, _ in handed} == {"add_batch", "get_batch",
+                                                "clear_batch"}

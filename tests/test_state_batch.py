@@ -180,10 +180,16 @@ def _window_op(assigner, **kw):
         window_function=fn, **kw)
 
 
-def _drive(mode, backend, assigner, seed=7, chunks=6, late_every=0, **kw):
+#: a function of the row boxes a batch's keys into a list; `key_by(0)`
+#: over the integer field keeps them as one int64 column
+SELECTORS = {"lambda": lambda x: x[0], "field": 0}
+
+
+def _drive(mode, backend, assigner, seed=7, chunks=6, late_every=0,
+           selector="lambda", **kw):
     op = _window_op(assigner, **kw)
     h = OneInputStreamOperatorTestHarness(
-        op, key_selector=lambda x: x[0], state_backend=backend)
+        op, key_selector=SELECTORS[selector], state_backend=backend)
     h.open()
     rng = np.random.default_rng(seed)
     for chunk in range(chunks):
@@ -208,36 +214,46 @@ def _drive(mode, backend, assigner, seed=7, chunks=6, late_every=0, **kw):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("lateness", [0, 700])
-def test_window_batch_vs_row_tumbling(backend, lateness):
+@pytest.mark.parametrize("selector", list(SELECTORS))
+def test_window_batch_vs_row_tumbling(selector, backend, lateness):
     asg = TumblingEventTimeWindows.of(1000)
     a, op_a, _ = _drive("row", backend, asg, allowed_lateness=lateness,
                         late_every=17)
     asg = TumblingEventTimeWindows.of(1000)
     b, op_b, _ = _drive("batch", backend, asg, allowed_lateness=lateness,
-                        late_every=17)
+                        late_every=17, selector=selector)
     assert a == b  # values AND timestamps, in emission order
     assert op_a.num_late_records_dropped == op_b.num_late_records_dropped
     # every batch row was consumed columnar — no boxed fallback
     assert op_b.boxed_fallbacks == 0 and op_b.columnar_rows == 300
+    assert op_b.key_column_rows == (300 if selector == "field" else 0)
+    assert op_b.timer_service.int_run_demotions == 0
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_window_batch_vs_row_sliding(backend):
+@pytest.mark.parametrize("selector", list(SELECTORS))
+def test_window_batch_vs_row_sliding(selector, backend):
     a, _, _ = _drive("row", backend, SlidingEventTimeWindows.of(1500, 500))
     b, op_b, _ = _drive("batch", backend,
-                        SlidingEventTimeWindows.of(1500, 500))
+                        SlidingEventTimeWindows.of(1500, 500),
+                        selector=selector)
     assert a == b
     assert op_b.boxed_fallbacks == 0
+    assert op_b.key_column_rows == (300 if selector == "field" else 0)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_window_batch_timers_survive_snapshot(backend):
+@pytest.mark.parametrize("before, after", [
+    ("lambda", "lambda"), ("field", "field"), ("field", "lambda"),
+    ("lambda", "field")])
+def test_window_batch_timers_survive_snapshot(before, after, backend):
     """Timers registered by the bulk path are part of operator state:
     snapshot mid-stream, restore into a fresh harness, watermark fires
-    the same windows."""
+    the same windows, whichever path (a key list, an int64 column)
+    took the keys on either side of the barrier."""
     asg = TumblingEventTimeWindows.of(1000)
     op = _window_op(asg)
-    h = OneInputStreamOperatorTestHarness(op, key_selector=lambda x: x[0],
+    h = OneInputStreamOperatorTestHarness(op, key_selector=SELECTORS[before],
                                           state_backend=backend)
     h.open()
     keys = np.array([1, 2, 1, 3], np.int64)
@@ -247,7 +263,7 @@ def test_window_batch_timers_survive_snapshot(backend):
     snap = h.snapshot()
 
     op2 = _window_op(TumblingEventTimeWindows.of(1000))
-    h2 = OneInputStreamOperatorTestHarness(op2, key_selector=lambda x: x[0],
+    h2 = OneInputStreamOperatorTestHarness(op2, key_selector=SELECTORS[after],
                                            state_backend=backend)
     h2.open()
     h2.initialize_state(snap)
@@ -461,6 +477,73 @@ def test_snapshot_straddles_batch_with_pending_ring():
         assert st2.get() == pytest.approx(rst.get()), k
 
 
+class _KeyDoor:
+    """A state with the three batch doors, noting the keys each got."""
+
+    def __init__(self, takes_key_column):
+        self.takes_key_column = takes_key_column
+        self.seen = []
+
+    def add_batch(self, keys, namespace, values, namespaces=None):
+        self.seen.append(keys)
+
+    def get_batch(self, keys, namespace, namespaces=None):
+        self.seen.append(keys)
+        return [None] * len(keys), np.zeros(len(keys), bool)
+
+    def clear_batch(self, keys, namespace, namespaces=None):
+        self.seen.append(keys)
+
+
+@pytest.mark.parametrize("takes", [False, True])
+@pytest.mark.parametrize("name", ["heap", "tpu"])
+def test_the_backend_hands_a_key_column_on_only_to_a_state_that_takes_one(
+        name, takes):
+    """An int64 column given to the backend's batch doors reaches a
+    state with a column door as it is, and every other state as the
+    list of Python ints it always got."""
+    b = make_backend(name)
+    door = _KeyDoor(takes)
+    keys = np.array([7, -3, 2 ** 40], np.int64)
+    b.add_batch(door, keys, ("w",), np.ones(3), pre_extracted=False)
+    b.get_batch(door, keys, ("w",))
+    b.clear_batch(door, keys, ("w",))
+    assert len(door.seen) == 3
+    for seen in door.seen:
+        if takes:
+            assert seen is keys
+        else:
+            assert type(seen) is list and seen == [7, -3, 2 ** 40]
+            assert {type(k) for k in seen} == {int}
+
+
+def test_flush_waits_for_the_table_it_updates(monkeypatch):
+    """Every flush, a full micro-batch's or a read's, waits for the
+    program last dispatched on the table before it dispatches the next,
+    so one update is in flight at most and a fire's gather never queues
+    behind a window of them."""
+    from flink_tpu.state import tpu_backend
+    b = make_backend("tpu", microbatch=16)
+    st = b.get_or_create_keyed_state(
+        AggregatingStateDescriptor("s", SumAggregate(np.float32)))
+    waited = []
+    real = tpu_backend.jax.block_until_ready
+    monkeypatch.setattr(tpu_backend.jax, "block_until_ready",
+                        lambda x: waited.append(x) or real(x))
+    tables = []
+    for i in range(3):
+        tables.append(st.device_state)
+        b.add_batch(st, np.arange(16, dtype=np.int64), ("w",),
+                    np.ones(16))
+    # a part batch, flushed by the read
+    tables.append(st.device_state)
+    b.add_batch(st, np.arange(4, dtype=np.int64), ("w",), np.ones(4))
+    got, found, _ = b.get_batch(st, np.array([3, 9]), ("w",))
+    # each flush waited for the very state the one before it made
+    assert waited == tables
+    assert found.all() and got.tolist() == pytest.approx([4.0, 3.0])
+
+
 def test_eviction_spill_boundary_bit_equal():
     """A capped device tier must evict/spill under batch ingest and
     still agree with heap — including across a snapshot taken while
@@ -627,13 +710,13 @@ def _observe(st, backend):
 
 
 def _tumbling_job(key_of=int, windows=3, rows=64, key_space=150,
-                  conf=None, seed=11):
+                  conf=None, seed=11, selector="lambda"):
     """Tumbling 1 s windows of sums, `rows`-row batches, every window
     fired and cleared; returns the emitted rows, an observation after
     every batch and fire, the state and `STATE_STATS`' counts."""
     op = _window_op(TumblingEventTimeWindows.of(1000))
     h = OneInputStreamOperatorTestHarness(
-        op, key_selector=lambda x: x[0], state_backend=conf or "tpu")
+        op, key_selector=SELECTORS[selector], state_backend=conf or "tpu")
     h.open()
     STATE_STATS.reset()
     rng = np.random.default_rng(seed)
@@ -654,6 +737,9 @@ def _tumbling_job(key_of=int, windows=3, rows=64, key_space=150,
     counts = {name: getattr(STATE_STATS, name) for name in (
         "bulk_probe_rows", "per_key_probe_rows", "int_table_rows",
         "int_table_demotions")}
+    counts.update(key_column_rows=op.key_column_rows,
+                  columnar_rows=op.columnar_rows,
+                  int_run_demotions=op.timer_service.int_run_demotions)
     return out, seen, op.window_state, counts
 
 
@@ -676,28 +762,57 @@ def test_an_integer_keyed_job_is_the_same_on_both_forms(monkeypatch):
 
 
 @needs_native
+@pytest.mark.parametrize("key_of", [int, lambda k: f"k{k}"],
+                         ids=["int", "str"])
+def test_a_job_is_the_same_on_both_key_paths(key_of):
+    """The keys as one int64 column (`key_by(0)` over an integer field)
+    against the list the parent made of them: the slots, the free
+    list, the stamps, the results and the snapshot bytes step for
+    step.  A string column takes the list path on both."""
+    out, seen, st, counts = _tumbling_job(key_of, selector="field")
+    want_out, want_seen, _, want_counts = _tumbling_job(key_of)
+    assert out == want_out and len(out) > 300
+    for step, (got, want) in enumerate(zip(seen, want_seen)):
+        assert got == want, step
+    assert counts["key_column_rows"] == (
+        counts["columnar_rows"] if key_of is int else 0)
+    assert want_counts["key_column_rows"] == 0
+    assert counts["int_run_demotions"] == want_counts["int_run_demotions"] == 0
+    for name in ("bulk_probe_rows", "per_key_probe_rows", "int_table_rows"):
+        assert counts[name] == want_counts[name], name
+
+
+@needs_native
 @pytest.mark.parametrize("job", ["tumbling_int", "tumbling_str", "tumbling_mixed",
-                                 "sessions_int"])
+                                 "sessions_int", "tumbling_int_field",
+                                 "sessions_int_field"])
 def test_which_jobs_probe_integer_tables(job):
     """Integer keys under one window a batch: every bulk-probed row on
     an integer table, none demoted.  String keys, and session windows
     (a namespace per row): none."""
-    if job == "sessions_int":
+    if job.startswith("sessions_int"):
         STATE_STATS.reset()
-        _, op, _ = _drive("batch", "tpu", EventTimeSessionWindows.with_gap(400))
+        _, op, _ = _drive("batch", "tpu", EventTimeSessionWindows.with_gap(400),
+                          selector="field" if "field" in job else "lambda")
         assert op.boxed_fallbacks == 0
+        assert op.key_column_rows == 0
         assert STATE_STATS.bulk_probe_rows > 300
         assert STATE_STATS.int_table_rows == 0
         assert STATE_STATS.int_table_demotions == 0
         return
-    key_of = {"tumbling_int": int, "tumbling_str": lambda k: f"k{k}",
+    key_of = {"tumbling_int": int, "tumbling_int_field": int,
+              "tumbling_str": lambda k: f"k{k}",
               "tumbling_mixed": lambda k: k if k % 7 else float(k) + 0.5}[job]
-    _, _, st, counts = _tumbling_job(key_of)
+    field = job.endswith("_field")
+    _, _, st, counts = _tumbling_job(key_of,
+                                     selector="field" if field else "lambda")
+    assert counts["key_column_rows"] == (counts["columnar_rows"] if field
+                                         else 0)
     assert counts["per_key_probe_rows"] == 0 and not st.slot_index
     assert counts["bulk_probe_rows"] > 3 * 4 * 64
     assert counts["int_table_demotions"] == 0
     assert counts["int_table_rows"] == (
-        counts["bulk_probe_rows"] if job == "tumbling_int" else 0)
+        counts["bulk_probe_rows"] if key_of is int else 0)
 
 
 @needs_native

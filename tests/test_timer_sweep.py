@@ -230,12 +230,14 @@ class _Driver:
     ones per key, the bulk ones where a step asks for them; the
     processing-time ones when `domain` says so."""
 
-    def __init__(self, domain, on_fire):
+    def __init__(self, domain, on_fire, columns=False):
         from flink_tpu.streaming.timers import TestProcessingTimeService
         self.backend = _FakeBackend()
         self.pts = TestProcessingTimeService()
         self.domain = domain
         self.on_fire = on_fire
+        #: bulk steps of int64 keys go as an int64 column
+        self.columns = columns
         self.fired = []
         self.svc = InternalTimerService("t", self.backend, self.pts, self)
 
@@ -255,9 +257,12 @@ class _Driver:
             entry = (svc.delete_processing_time_timer
                      if op.startswith("delete")
                      else svc.register_processing_time_timer)
-        elif op == "register_bulk":
-            return svc.register_event_time_timers_bulk(ns, ts, keys)
-        elif op == "delete_bulk":
+        elif op in ("register_bulk", "delete_bulk"):
+            if self.columns and all(type(k) is int and -2 ** 63 <= k < 2 ** 63
+                                    for k in keys):
+                keys = np.array(keys, np.int64)
+            if op == "register_bulk":
+                return svc.register_event_time_timers_bulk(ns, ts, keys)
             return svc.delete_event_time_timers_bulk(ns, ts, keys)
         else:
             entry = (svc.delete_event_time_timer if op == "delete"
@@ -381,6 +386,13 @@ SCENARIOS = {
          + [("register", 40, C, ["new"]), ("delete", 50, B, ["k"])],
          (95, "k", A): [("register", 20, A, ["back"])]},
         [200]),
+    # bulk runs emptied by bulk deletes until the heap is rebuilt from
+    # the runs that are left
+    "bulk_runs_deleted_enough_for_a_heap_rebuild": (
+        [("register_bulk", t, A, [1, 2, 1]) for t in range(60)]
+        + [("delete_bulk", t, A, [2, 1]) for t in range(1, 50)]
+        + [("register_bulk", 30, B, [5, 1]), ("delete_bulk", 55, A, [1])],
+        {}, [40, 100]),
 }
 
 
@@ -604,3 +616,181 @@ def test_restore_keeps_only_the_key_groups_of_its_range():
         halves.append(sorted(svc.event_time_timers()))
     assert sorted(halves[0] + halves[1]) == sorted(event)
     assert halves[0] and halves[1]
+
+
+# ---- integer runs: the same store behind an int64 column -------------
+# A bulk registration of an int64 column keeps its run in a native
+# table (`_IntRun`); fed the same calls, it must give what the dict run
+# of the same Python ints gives: iteration, capture, snapshot bytes,
+# firing and sweeping order.
+
+def _with_int_keys(steps, on_fire, watermarks):
+    """A scenario with every key an int (its letters' place among all
+    the scenario's keys)."""
+    names = sorted({k for step in steps for k in step[3]}
+                   | {k for timer in on_fire for k in timer[1:2]}
+                   | {k for more in on_fire.values() for step in more
+                      for k in step[3]}, key=str)
+    code = {name: i * 7 - 20 for i, name in enumerate(names)}
+
+    def steps_of(steps):
+        return [(op, ts, ns, [code[k] for k in keys])
+                for op, ts, ns, keys in steps]
+    return (steps_of(steps),
+            {(ts, code[k], ns): steps_of(more)
+             for (ts, k, ns), more in on_fire.items()}, watermarks)
+
+
+def _store_view(driver):
+    """Everything of the event-time store a reader sees, an integer
+    run's type named as the dict it stands for."""
+    import pickle
+
+    from flink_tpu.streaming.timers import _IntRun
+    svc = driver.svc
+    ats, frozen, kinds = svc._event.capture()
+    return {"iter": list(svc._event),
+            "capture": (ats, frozen,
+                        [dict if kind is _IntRun else kind for kind in kinds]),
+            "len": len(svc._event),
+            "snapshot": pickle.dumps(svc.snapshot()),
+            "deferred": pickle.dumps(svc.capture_snapshot().resolve())}
+
+
+def _int_runs(driver):
+    from flink_tpu.streaming.timers import _IntRun
+    return sum(type(run) is _IntRun for run in driver.svc._event.runs.values())
+
+
+def _pair(steps, on_fire, watermarks, sweep):
+    """The steps through a store fed lists and one fed int64 columns:
+    after the steps, and after every watermark, the two are one."""
+    drivers = [_Driver("event", _Once(on_fire), columns=c)
+               for c in (False, True)]
+    for driver in drivers:
+        for step in steps:
+            driver.apply(step)
+    listed, column = drivers
+    assert _store_view(column) == _store_view(listed)
+    assert _int_runs(listed) == 0
+    column.int_runs = _int_runs(column)
+    for wm in watermarks:
+        if sweep:
+            got = [(ts, ns, type(keys), list(keys)) for ts, ns, keys in
+                   column.svc.pop_due_event_time_timers(wm)]
+            want = [(ts, ns, type(keys), list(keys)) for ts, ns, keys in
+                    listed.svc.pop_due_event_time_timers(wm)]
+            assert [(ts, ns, keys) for ts, ns, _, keys in got] \
+                == [(ts, ns, keys) for ts, ns, _, keys in want]
+            assert {kind for _, _, kind, _ in want} <= {list}
+            assert all(type(k) is int for _, _, kind, keys in got
+                       if kind is list for k in keys)
+        else:
+            assert column.advance(wm) == listed.advance(wm)
+        assert _store_view(column) == _store_view(listed)
+    return listed, column
+
+
+@pytest.mark.parametrize("name, sweep", [
+    (name, sweep) for name, scenario in SCENARIOS.items()
+    for sweep in (False, True) if not (sweep and scenario[1])])
+def test_an_integer_run_is_the_dict_run_of_its_keys(name, sweep):
+    steps, on_fire, watermarks = _with_int_keys(*SCENARIOS[name])
+    listed, column = _pair(steps, on_fire, watermarks, sweep)
+    assert column.svc.int_run_demotions == 0
+    if name.startswith(("bulk", "two_namespaces_tie_in_order")):
+        assert column.int_runs
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_random_steps_on_integer_runs_are_the_dict_runs(seed):
+    rng = np.random.default_rng(100 + seed)
+    steps = _random_steps(rng, 60)
+    on_fire = {(int(rng.integers(0, 8)), int(rng.integers(0, 6)),
+                [A, B, C][rng.integers(3)]): [
+        ("delete" if step[0].startswith("delete") else "register",
+         *step[1:]) for step in _random_steps(rng, 3)] for _ in range(10)}
+    for sweep in (False, True):
+        _, column = _pair(steps, {} if sweep else on_fire, [2, 5, 9], sweep)
+        assert column.int_runs
+
+
+def test_a_bulk_run_of_an_int64_column_is_one_native_table():
+    from flink_tpu.streaming.timers import _IntRun
+    svc, backend, _ = _service()
+    for batch in range(4):
+        assert svc.register_event_time_timers_bulk(
+            A, 9, np.arange(batch * 500, batch * 500 + 1000)) \
+            == (1000 if batch == 0 else 500)
+    store = svc._event
+    (run,) = store.runs.values()
+    assert type(run) is _IntRun and store.spans == {}
+    assert store.heap == [(9, 0, (9, A))] and svc.num_event_time_timers() == 2500
+    ((ts, ns, keys),) = svc.pop_due_event_time_timers(9)
+    assert (ts, ns) == (9, A) and keys.dtype == np.int64
+    assert keys.tolist() == list(range(2500))
+    assert store.heap == [] and store.runs == {}
+    # the next run is born with room for as many keys
+    assert store.room == 2500
+
+
+def _array_of(keys):
+    """`keys` as the array a caller would hand over: of no int64 dtype
+    where one of them is no int64 (2^63 as uint64, a tuple in an object
+    array)."""
+    if any(type(k) is tuple for k in keys):
+        column = np.empty(len(keys), object)
+        column[:] = keys
+        return column
+    if 2 ** 63 in keys:
+        return np.array(keys, np.uint64)
+    return np.array(keys)
+
+
+@pytest.mark.parametrize("intruder", ["a string", 2 ** 63, -2 ** 63 - 1, 1.5,
+                                      (1, 2), True])
+@pytest.mark.parametrize("door", ["scalar", "bulk", "array"])
+def test_a_key_an_integer_run_cannot_hold_turns_it_into_a_dict_once(
+        door, intruder):
+    """Demoted by the scalar entry, by a bulk list or by a bulk array of
+    another kind (unsigned, float, str, object): the run goes on as the
+    dict run of the same keys, and the service counts it once, however
+    many such keys follow."""
+    from flink_tpu.streaming.timers import _IntRun
+    intrude = ("register" if door == "scalar" else "register_bulk", 9, A,
+               [intruder] if door == "scalar" else [4, intruder, 3])
+    if door == "array":
+        intrude = intrude[:3] + (_array_of(intrude[3]),)
+    steps = [("register_bulk", 9, A, [3, 1, 3, 2]),
+             ("register_bulk", 19, B, [5, 6]), intrude,
+             ("register", 9, A, ["more", 2 ** 64]),
+             ("register_bulk", 9, A, [8, 1]), ("delete_bulk", 9, A, [1]),
+             ("delete", 9, A, [intruder]), ("register_bulk", 19, B, [6, 7])]
+    listed, column = _pair(steps, {}, [], False)
+    store = column.svc._event
+    assert type(store.runs[(19, B)]) is _IntRun
+    # (True is the key 1 to a dict, and numpy reads [4, True, 3] as
+    # ints: the bulk list or array keeps that run an integer run until
+    # "more")
+    assert column.svc.int_run_demotions == 1
+    assert type(store.runs[(9, A)]) is dict
+    assert listed.svc.int_run_demotions == 0
+    for wm in (9, 19):
+        assert column.advance(wm) == listed.advance(wm)
+
+
+def test_an_integer_run_copies_and_pickles_as_its_keys():
+    """A copy of the store (a session test's deepcopy, a pickle) holds
+    a table of its own, never the handle of the one it copied."""
+    import copy
+    import pickle
+    svc, backend, _ = _service()
+    svc.register_event_time_timers_bulk(A, 9, np.array([5, 3, 5, 8]))
+    twin = copy.deepcopy(svc._event)
+    again = pickle.loads(pickle.dumps(svc._event))
+    svc.register_event_time_timers_bulk(A, 9, np.array([1]))
+    for store in (twin, again):
+        ((ts, ns, keys),) = store.pop_runs(9)
+        assert keys.tolist() == [5, 3, 8]
+    ((_, _, keys),) = svc.pop_due_event_time_timers(9)
+    assert keys.tolist() == [5, 3, 8, 1]
