@@ -19,7 +19,12 @@ Legs:
                         fails (as 2b and 2c) unless every batch's keys
                         stayed one int64 column into add_batch and the
                         timer runs, none of which became a dict (2d:
-                        unless none did: a session's keys go as a list)
+                        unless none did: a session's keys go as a list),
+                        and (as 2b and 2c) unless every flush against
+                        the full-grown table took the form of
+                        state.update the sketch's rule gives it (on the
+                        TPU; 2^20 slots of HLL registers scatter cells,
+                        in place)
   2b spill tier         the same route at the north star's 10,000,000
                         keys under state.backend.tpu.max-device-slots =
                         2^20, set in the environment's Configuration:
@@ -305,14 +310,18 @@ def one_of(ops, cls):
 
 
 def run_window_job(name, arrays, agg, on_state_backend=False,
-                   configuration=None):
+                   configuration=None, source=None):
     """source → keyBy(field 0) → 1 s tumbling window → aggregate →
     sink, through env.execute().  `on_state_backend` takes the scalar
     WindowOperator with its state in the `tpu` backend; otherwise the
     default aggregate() picks the operator.  `configuration` is the
-    environment's.  Returns (operators, sink)."""
+    environment's; `source` an EventSource over `arrays` (a
+    FlushMarkingSource is handed the job's operators).  Returns
+    (operators, sink)."""
     env = StreamExecutionEnvironment(configuration)
-    windowed = (env.add_source(EventSource(*arrays), name="events")
+    if source is None:
+        source = EventSource(*arrays)
+    windowed = (env.add_source(source, name="events")
                 .key_by(0)
                 .window(TumblingEventTimeWindows.of(WINDOW_MS)))
     if on_state_backend:
@@ -321,6 +330,8 @@ def run_window_job(name, arrays, agg, on_state_backend=False,
     sink = ArraySink()
     windowed.aggregate(agg, window_function=emit_row).add_sink(sink)
     ops = capture_operators(env)
+    if isinstance(source, FlushMarkingSource):
+        source.ops = ops
     env.execute(name)
     return ops, sink
 
@@ -513,6 +524,32 @@ def key_column_problems(op, expected):
             f"dicts"], facts
 
 
+def flush_form_problems(cfg, source, state, agg, flushed_before):
+    """The flushes since the table reached the size it ended at (a
+    flush is two batches' rows in one window, or the fire's one) took
+    the form of `state.update` that the aggregate's rule gives such a
+    flush, every one: the tile kernel where it says tiles, the cell
+    scatter elsewhere.  Off the TPU there is no kernel to take, and
+    the check is off.  `flushed_before` is `flush_row_form_batches`
+    when the leg began; returns (problems, facts)."""
+    grown = [m for m in source.marks if m[0] == state.capacity][:1]
+    flushes, in_place = (
+        (STATE_STATS.flush_batches - grown[0][1],
+         STATE_STATS.flush_row_form_batches - grown[0][2])
+        if grown else (0, 0))
+    tiles = agg.update_runs_in_place(2 * BATCH_ROWS, state.capacity)
+    want = flushes if tiles else 0
+    facts = {"flushes_at_full_size": flushes, "flushes_in_place": in_place,
+             "flushes_in_place_all_sizes":
+                 STATE_STATS.flush_row_form_batches - flushed_before}
+    if cfg["preflight"] or 0 < flushes and in_place == want:
+        return [], facts
+    return [f"of {flushes} flushes of {2 * BATCH_ROWS} rows against the "
+            f"full-grown table of {state.capacity} slots {in_place} ran "
+            f"in place, {want} should have (ops/sketches.py: "
+            f"hll_update_form, quantile_update_form)"], facts
+
+
 #: how the tpu backend took a leg's integer columns: the values hashed
 #: whole, the keys probed on a window's integer table
 COLUMN_COUNTS = ("hash_column_rows", "hash_per_value_rows",
@@ -548,9 +585,11 @@ def column_problems(before, events):
 def leg_state_backend(cfg, events, ref):
     keys = events[0]
     counted = column_counts()
-    ops, sink = run_window_job("chip-smoke-state-backend", events,
-                               UserHll(cfg["precision"]),
-                               on_state_backend=True)
+    flushed = STATE_STATS.flush_row_form_batches
+    source = FlushMarkingSource(*events)
+    agg = UserHll(cfg["precision"])
+    ops, sink = run_window_job("chip-smoke-state-backend", events, agg,
+                               on_state_backend=True, source=source)
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
     cols = sink.columns()
@@ -562,6 +601,9 @@ def leg_state_backend(cfg, events, ref):
     problems += column_faults
     key_faults, key_facts = key_column_problems(wop, True)
     problems += key_faults
+    form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
+                                                  flushed)
+    problems += form_faults
     regs = state.device_state["regs"]
     return problems, {
         "route": "WindowOperator.process_batch -> "
@@ -574,7 +616,8 @@ def leg_state_backend(cfg, events, ref):
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
         "slots": state.capacity,
         "register_bytes": int(regs.size) * regs.dtype.itemsize,
-        "evictions": state.evictions, **column_facts, **key_facts, **facts}
+        "evictions": state.evictions, **column_facts, **key_facts,
+        **form_facts, **facts}
 
 
 def leg_state_spill(cfg, seed):
@@ -590,9 +633,12 @@ def leg_state_spill(cfg, seed):
     if spill["microbatch"] is not None:
         conf.set("state.backend.tpu.microbatch-size", spill["microbatch"])
     counted = column_counts()
-    ops, sink = run_window_job("chip-smoke-state-spill", events,
-                               UserHll(cfg["precision"]),
-                               on_state_backend=True, configuration=conf)
+    flushed = STATE_STATS.flush_row_form_batches
+    source = FlushMarkingSource(*events)
+    agg = UserHll(cfg["precision"])
+    ops, sink = run_window_job("chip-smoke-state-spill", events, agg,
+                               on_state_backend=True, configuration=conf,
+                               source=source)
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
     cols = sink.columns()
@@ -603,6 +649,9 @@ def leg_state_spill(cfg, seed):
     problems += column_faults
     key_faults, key_facts = key_column_problems(wop, True)
     problems += key_faults
+    form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
+                                                  flushed)
+    problems += form_faults
     if state.max_device_slots != spill["budget"]:
         problems.append(f"the backend's budget is {state.max_device_slots}, "
                         f"the Configuration says {spill['budget']}")
@@ -622,7 +671,7 @@ def leg_state_spill(cfg, seed):
         "evictions": state.evictions, "promotions": state.promotions,
         "budget_overruns": state.budget_overruns,
         "live_keys_per_window": [len(k) for k, _ in ref.values()],
-        **column_facts, **key_facts, **facts}
+        **column_facts, **key_facts, **form_facts, **facts}
 
 
 #: config #3's windows; the slide is the source period too
@@ -673,6 +722,7 @@ def leg_state_sliding(cfg, seed):
         "state.backend.tpu.max-device-slots", sl["budget"]))
     env.set_state_backend("tpu")
     source = FlushMarkingSource(keys, values, ts)
+    flushed = STATE_STATS.flush_row_form_batches
     windowed = (env.add_source(source, name="events")
                 .key_by(0)
                 .window(SlidingEventTimeWindows.of(SLIDING_SIZE_MS,
@@ -717,28 +767,17 @@ def leg_state_sliding(cfg, seed):
     if wop.window_rows != panes * n:
         problems.append(f"{wop.window_rows} state rows for {n} events, "
                         f"{panes} an event expected")
-    # the flushes since the table reached the size it ended at (a flush
-    # is two batches' rows in one window: small against that table,
-    # not against the 4,096 slots it started from) took the tile
-    # kernel, every one; off the TPU there is no such kernel to take
-    grown = [m for m in source.marks if m[0] == state.capacity][:1]
-    flushes, in_place = (
-        (STATE_STATS.flush_batches - grown[0][1],
-         STATE_STATS.flush_row_form_batches - grown[0][2])
-        if grown else (0, 0))
-    if not cfg["preflight"] and not 0 < flushes == in_place:
-        problems.append(
-            f"of {flushes} flushes of {2 * BATCH_ROWS} rows against the "
-            f"full-grown table of {state.capacity} slots {in_place} ran "
-            f"in place: state.update rewrites the whole table around "
-            f"the others (ops/sketches.py quantile_update_form)")
+    # a flush is two batches' rows in one window: small against the
+    # full-grown table, not against the 4,096 slots it started from
+    form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
+                                                  flushed)
+    problems += form_faults
     hist = state.device_state["hist"]
     return problems, {
         "keys": sl["keys"], "events": n, "budget": sl["budget"],
         "slots": state.capacity,
         "table_bytes": int(hist.size) * hist.dtype.itemsize,
-        "batches_marked": len(source.marks),
-        "flushes_at_full_size": flushes, "flushes_in_place": in_place,
+        "batches_marked": len(source.marks), **form_facts,
         "rows_per_event": wop.window_rows / n,
         "windows_touched": wop.windows_touched, **key_facts,
         "evictions": state.evictions,

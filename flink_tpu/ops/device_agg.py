@@ -114,11 +114,14 @@ class DeviceAggregateFunction(AggregateFunction):
 
     def update_runs_in_place(self, rows: int, capacity: int) -> bool:
         """Whether `update` of a `rows`-row batch against `capacity`
-        slots, on the platform the backend's programs run on, adds to
-        the table where it lies and not by a scatter of single cells:
-        what the state backend notes per flush
-        (`STATE_STATS.flush_row_form_batches`).  Only an aggregate
-        whose update has such a form says yes."""
+        slots, on the platform the backend's programs run on, takes a
+        tile kernel (each cell combined into the layout's tile that
+        holds it, the table left where it lies) and not a scatter of
+        single cells: what the state backend notes per flush
+        (`STATE_STATS.flush_row_form_batches`).  The quantile and HLL
+        sketches say yes where their rules give tiles
+        (`ops/sketches.py`); a cell scatter that XLA runs in place (HLL
+        registers from 2^31 cells on) is not counted."""
         return False
 
     @abc.abstractmethod

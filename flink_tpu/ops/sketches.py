@@ -8,9 +8,17 @@ backend (scalar twin, see DeviceAggregateFunction) or micro-batched on
 TPU where the whole key-group's sketches update in one scatter.
 
 Design notes (TPU-first):
-- HLL registers are uint8 `[slots, m]`; a batch update is one
-  scatter-max into the flattened `[slots*m]` view.  Rank/register come
-  from exact uint32 bit ops (flink_tpu/ops/hashing.py), never float log.
+- HLL registers are uint8 `[slots, m]`; a batch update is a scatter-max
+  of its (slot, register, rank) cells, in one of two forms picked from
+  the static shapes (`hll_update_form`).  On the TPU, below 2^31 cells
+  XLA lowers a 2-d cell scatter through a flattened `[slots*m]` view,
+  copying the whole table into it and out again around every batch; a
+  batch that is small against such a table maxes each rank into the
+  (32 slots x 128 registers) u8 tile that holds its cell instead, with
+  the table left where it is.  From 2^31 cells on no flat index fits
+  int32, XLA scatters the cells in place, and that is the faster form.
+  Rank/register come from exact uint32 bit ops
+  (flink_tpu/ops/hashing.py), never float log.
 - Count-Min is `[slots, depth, width]` int32 with Kirsch–Mitzenmacher
   row hashing; a batch is one scatter-add of depth*N entries.
 - Quantiles use a DDSketch-style log-bucketed histogram (relative-error
@@ -96,10 +104,15 @@ class HyperLogLogAggregate(DeviceAggregateFunction):
         else:
             reg, rank = hll_register_and_rank(vh_hi, vh_lo, self.precision)
         rank = jnp.where(mask, rank, 0).astype(jnp.uint8)
-        # 2-d scatter-max: no flattened index, so capacity*m may exceed
-        # int32 range (TPU indices are per-dimension 32-bit)
+        regs = state["regs"]
+        max_in = _max_tiles if hll_update_form(
+            slots.shape[0], regs.shape[0], self.m) == "tiles" else _max_cells
         return {**state,
-                "regs": state["regs"].at[slots.astype(jnp.int32), reg].max(rank)}
+                "regs": max_in(regs, slots.astype(jnp.int32), reg, rank)}
+
+    def update_runs_in_place(self, rows: int, capacity: int) -> bool:
+        return _tile_form_runs() and hll_update_form(
+            rows, capacity, self.m) == "tiles"
 
     def result(self, state, slots):
         return self._estimate(state["regs"][slots])
@@ -208,14 +221,17 @@ class CountMinSketchAggregate(DeviceAggregateFunction):
 
 
 # ---------------------------------------------------------------------
-# The two forms of the quantile sketch's batch update.  Both give the
-# same table bit for bit (integer addition in any order; a padding row
-# carries an increment of 0 and changes nothing).
+# The two forms of a sketch's batch update: a scatter of cells, and on
+# the TPU a kernel that combines each cell into the layout's tile that
+# holds it.  Both give the same table bit for bit (integer addition and
+# max, in any order; a padding row carries 0 and changes nothing).
 # ---------------------------------------------------------------------
 
 #: the TPU's vector registers: 8 sublanes of 128 lanes, and the tile
-#: its 2-d layouts keep contiguous in HBM
+#: its 2-d layouts of 32-bit cells keep contiguous in HBM
 _SUBLANES, _LANES = 8, 128
+#: a u8 tile packs four rows into each 32-bit cell: 32 rows x 128 lanes
+_U8_TILE_ROWS = 4 * _SUBLANES
 #: a batch adds tiles while the table has this many slots to each of
 #: its rows.  On a v5e the tile form costs 0.25 us a batch row whatever
 #: the table (4.1 ms for 16,384), the cell form 0.14 us a slot of the
@@ -225,6 +241,18 @@ _TILE_FORM_TABLE_ROWS_PER_BATCH_ROW = 2
 #: the tile form keeps two scalars a batch row in the TPU's scalar
 #: memory (65,536 rows compile on a v5e, 131,072 do not)
 _TILE_FORM_MAX_ROWS = 1 << 15
+#: an HLL batch maxes tiles while the table has this many bytes of
+#: registers to each of its rows.  On a v5e, 16,384 rows against
+#: `u8[slots, 4096]`: the tile form 4.6 ms whatever the table (0.28 us
+#: a row), the flattened cell scatter 5.75 ms at 2^17 slots and 9.4 ms
+#: at 2^18 (0.14 us a row for the scatter, 6.8 ps a byte of table for
+#: the two copies around it): they cross at (0.28 - 0.14) us / 6.8 ps
+#: = 20 KiB a row
+_HLL_TILE_FORM_TABLE_BYTES_PER_BATCH_ROW = 20 * 1024
+#: from this many cells on no flat index fits int32, and XLA scatters
+#: an HLL batch's cells into the 2-d table in place: 2.3 ms for 16,384
+#: rows at 2^20 slots of 4,096 registers, half the tile form's time
+_CELLS_IN_PLACE = 1 << 31
 
 
 def quantile_update_form(rows: int, capacity: int, buckets: int) -> str:
@@ -244,6 +272,77 @@ def quantile_update_form(rows: int, capacity: int, buckets: int) -> str:
     return "cells"
 
 
+def hll_update_form(rows: int, capacity: int, m: int) -> str:
+    """How a batch of `rows` ranks reaches `u8[capacity, m]` on a TPU,
+    from the static shapes alone: "tiles" (the table, held slot-major
+    in (32 slots x 128 registers) tiles, stays in place) for a batch
+    small against a table that XLA would flatten, "cells" otherwise:
+    a table of 2^31 cells or more, which XLA scatters in place, a batch
+    of one row (the scalar twin) or beyond the kernel's scalars, and a
+    table without whole tiles."""
+    if rows < 2 or rows > _TILE_FORM_MAX_ROWS \
+            or capacity * m >= _CELLS_IN_PLACE \
+            or capacity * m < rows * _HLL_TILE_FORM_TABLE_BYTES_PER_BATCH_ROW:
+        return "cells"
+    if capacity % _U8_TILE_ROWS == 0 and m % _LANES == 0:
+        return "tiles"
+    return "cells"
+
+
+def _tiles_in_place(table, row, col, value, tile_rows, combine, name,
+                    interpret):
+    """`combine` each `value` into the (tile_rows x 128) tile of the
+    2-d `table` that holds its cell (`row`, `col`), in place: the tiles
+    are the units the TPU keeps contiguous, so a grid step reads and
+    writes 4 KiB where a cell scatter would relayout the whole table."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    tile_cells = tile_rows * _LANES
+    shape = (tile_rows, _LANES)
+
+    def kernel(tile_ref, cell_ref, table_in, table_out):
+        # one grid step = one cell; the steps of one tile follow each
+        # other (the batch is sorted by tile), so the tile stays in VMEM
+        # from its first step to its last, written back once
+        i = pl.program_id(0)
+        tile = tile_ref[i]
+
+        @pl.when((i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
+        def _():
+            table_out[...] = table_in[...]
+
+        at = cell_ref[i] % tile_cells
+        hit = ((jax.lax.broadcasted_iota(jnp.int32, shape, 0) == at // _LANES)
+               & (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                  == at % _LANES))
+        table_out[...] = combine(
+            table_out[...], jnp.where(hit, cell_ref[i] // tile_cells, 0))
+
+    tiles_a_row = table.shape[1] // _LANES
+    tile = (row // tile_rows) * tiles_a_row + col // _LANES
+    # one scalar a cell beside its tile: where in the tile, and above
+    # that the value (0 for a padding row)
+    cell = (row % tile_rows) * _LANES + col % _LANES + value * tile_cells
+    order = jnp.argsort(tile)
+    block = pl.BlockSpec(
+        shape, lambda i, tile, cell: (tile[i] // tiles_a_row,
+                                      tile[i] % tiles_a_row))
+    return pl.pallas_call(
+        kernel,
+        # (inside a `shard_map` the table varies over the mesh axes)
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype,
+                                       vma=jax.typeof(table).vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(row.shape[0],),
+            in_specs=[block], out_specs=block),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(tile[order], cell[order], table)
+
+
 def _add_cells(hist, slots, b, inc):
     # 2-d scatter: no flattened index, so capacity*buckets may exceed
     # int32 range (same rationale as the HLL kernel)
@@ -256,53 +355,9 @@ def _add_tiles_tpu(hist, slots, b, inc, interpret=False):
     bucket-major when only the capacity is a multiple of 128, so the
     transposed view is the buffer as it lies and the tiles are its
     4 KiB units."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    tile_cells = _SUBLANES * _LANES
-
-    def kernel(tile_ref, cell_ref, table_in, table_out):
-        # one grid step = one increment; the steps of one tile follow
-        # each other (the batch is sorted by tile), so the tile stays
-        # in VMEM from its first step to its last, written back once
-        i = pl.program_id(0)
-        tile = tile_ref[i]
-
-        @pl.when((i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile))
-        def _():
-            table_out[...] = table_in[...]
-
-        at = cell_ref[i] % tile_cells
-        shape = (_SUBLANES, _LANES)
-        hit = ((jax.lax.broadcasted_iota(jnp.int32, shape, 0) == at // _LANES)
-               & (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                  == at % _LANES))
-        table_out[...] += jnp.where(hit, cell_ref[i] // tile_cells, 0)
-
-    table = hist.T                                   # [buckets, capacity]
-    tiles_a_row = hist.shape[0] // _LANES
-    tile = (b // _SUBLANES) * tiles_a_row + slots // _LANES
-    # one scalar a row beside its tile: where in the tile, and above
-    # that the increment (0 for a padding row, else 1)
-    cell = (b % _SUBLANES) * _LANES + slots % _LANES + inc * tile_cells
-    order = jnp.argsort(tile)
-    block = pl.BlockSpec(
-        (_SUBLANES, _LANES),
-        lambda i, tile, cell: (tile[i] // tiles_a_row, tile[i] % tiles_a_row))
-    out = pl.pallas_call(
-        kernel,
-        # (inside a `shard_map` the table varies over the mesh axes)
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype,
-                                       vma=jax.typeof(table).vma),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(slots.shape[0],),
-            in_specs=[block], out_specs=block),
-        input_output_aliases={2: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="quantile_add_tiles",
-    )(tile[order], cell[order], table)
-    return out.T
+    return _tiles_in_place(hist.T, b, slots, inc, _SUBLANES,
+                           lambda cur, inc: cur + inc, "quantile_add_tiles",
+                           interpret).T
 
 
 def _add_tiles(hist, slots, b, inc):
@@ -312,8 +367,32 @@ def _add_tiles(hist, slots, b, inc):
                                       tpu=_add_tiles_tpu, default=_add_cells)
 
 
+def _max_cells(regs, slots, reg, rank):
+    # 2-d scatter-max: no flattened index, so capacity*m may exceed
+    # int32 range (TPU indices are per-dimension 32-bit); XLA flattens
+    # it itself where the flat index fits, below 2^31 cells
+    return regs.at[slots, reg].max(rank)
+
+
+def _max_tiles_tpu(regs, slots, reg, rank, interpret=False):
+    """Each rank into the (32 slots x 128 registers) tile that holds
+    its register, in place: the TPU keeps `u8[capacity, m]` slot-major
+    with four slots packed into each 32-bit cell of an (8 x 128) tile.
+    The max is taken in int32 inside the kernel."""
+    return _tiles_in_place(
+        regs, slots, reg, rank.astype(jnp.int32), _U8_TILE_ROWS,
+        lambda cur, rank: jnp.maximum(cur.astype(jnp.int32), rank)
+        .astype(cur.dtype), "hll_max_tiles", interpret)
+
+
+def _max_tiles(regs, slots, reg, rank):
+    return jax.lax.platform_dependent(regs, slots, reg, rank,
+                                      tpu=_max_tiles_tpu, default=_max_cells)
+
+
 def _tile_form_runs() -> bool:
-    # what `_add_tiles` resolves to where the backend's programs run
+    # what `_add_tiles` / `_max_tiles` resolve to where the backend's
+    # programs run
     return jax.default_backend() == "tpu"
 
 

@@ -47,8 +47,9 @@ class StateStats:
         #: device micro-batch flushes and the rows they carried
         self.flush_batches = 0
         self.flush_rows = 0
-        #: flushes whose `state.update` ran in place (the quantile
-        #: sketch's tile kernel, on a TPU) where the others scatter cells
+        #: flushes whose `state.update` ran a tile kernel, in place (the
+        #: quantile and HLL sketches', on a TPU) where the others
+        #: scatter cells
         self.flush_row_form_batches = 0
         #: recent flush batch sizes (for mean/max gauges)
         self.flush_sizes = deque(maxlen=512)

@@ -401,15 +401,16 @@ def _small_tile_state(agg, monkeypatch, **backend_kw):
 
 @pytest.mark.parametrize("agg, tile_form_runs, in_place", [
     ("quantile", True, True), ("quantile", False, False),
-    ("hll_p12", True, False), ("countmin", True, False),
+    ("hll_p12", True, True), ("hll_p12", False, False),
+    ("countmin", True, False),
     ("sum", True, False)])
 def test_a_flush_notes_whether_it_ran_in_place(agg, tile_form_runs, in_place,
                                                monkeypatch):
     """`flush_row_form_batches` beside `flush_batches`: every flush of
-    a quantile sketch that is small against the table where the tile
-    kernel runs (a TPU; stood in for here, where each flush scatters
-    cells and none is noted), none of any other aggregate's, and none
-    while the batch is not small."""
+    a quantile or HLL sketch that is small against the table where the
+    tile kernels run (a TPU; stood in for here, where each flush
+    scatters cells and none is noted), none of any other aggregate's,
+    and none while the batch is not small."""
     from flink_tpu.ops import sketches
     monkeypatch.setattr(sketches, "_tile_form_runs", lambda: tile_form_runs)
     backend = TpuKeyedStateBackend(FULL_RANGE, MAX_PAR,
