@@ -96,6 +96,7 @@ except ImportError as e:  # alone in a directory: nothing to smoke
 
 import flink_tpu.native as nat  # noqa: E402
 from flink_tpu.core.config import Configuration  # noqa: E402
+from flink_tpu.core.key_columns import KeyTable  # noqa: E402
 from flink_tpu.ops import link_probe  # noqa: E402
 from flink_tpu.ops.device_agg import AvgAggregate, SumAggregate  # noqa: E402
 from flink_tpu.connectors.log_connector import (  # noqa: E402
@@ -508,20 +509,20 @@ def timer_run_problems(op, windows, result_rows):
             f"rows in {op.timer_runs} runs, {windows} windows fired"]
 
 
-def key_column_problems(op, expected):
+def key_column_problems(op, expected, demoted):
     """The operator kept every batch's keys as one int64 column (into
     `add_batch` and the timer runs) where `expected` says so, none
-    where not (a session job), and no integer timer run became a dict;
+    where not (a session job), and no key table (a timer run's, a
+    window's) became a dict since `KeyTable.demotions` read `demoted`;
     returns (problems, facts)."""
     facts = {"key_column_rows": op.key_column_rows,
-             "int_run_demotions": op.timer_service.int_run_demotions}
+             "demotions": KeyTable.demotions - demoted}
     want = op.columnar_rows if expected else 0
-    if op.key_column_rows == want and not facts["int_run_demotions"]:
+    if op.key_column_rows == want and not facts["demotions"]:
         return [], facts
     return [f"key_column_rows {op.key_column_rows} of {op.columnar_rows} "
             f"columnar rows ({want} expected), "
-            f"{facts['int_run_demotions']} integer timer runs became "
-            f"dicts"], facts
+            f"{facts['demotions']} key tables became dicts"], facts
 
 
 def flush_form_problems(cfg, source, state, agg, flushed_before):
@@ -551,13 +552,15 @@ def flush_form_problems(cfg, source, state, agg, flushed_before):
 
 
 #: how the tpu backend took a leg's integer columns: the values hashed
-#: whole, the keys probed on a window's integer table
+#: whole, the keys probed on a window's integer table, and the key
+#: tables that became dicts (`KeyTable.demotions`)
 COLUMN_COUNTS = ("hash_column_rows", "hash_per_value_rows",
-                 "bulk_probe_rows", "int_table_rows", "int_table_demotions")
+                 "bulk_probe_rows", "int_table_rows", "demotions")
 
 
 def column_counts():
-    return tuple(getattr(STATE_STATS, name) for name in COLUMN_COUNTS)
+    return tuple(KeyTable.demotions if name == "demotions"
+                 else getattr(STATE_STATS, name) for name in COLUMN_COUNTS)
 
 
 def column_problems(before, events):
@@ -574,11 +577,11 @@ def column_problems(before, events):
             f"events, {facts['hash_per_value_rows']} hashed a value at a "
             f"time")
     if facts["int_table_rows"] != facts["bulk_probe_rows"] \
-            or facts["int_table_demotions"]:
+            or facts["demotions"]:
         problems.append(
             f"int_table_rows {facts['int_table_rows']} of "
             f"{facts['bulk_probe_rows']} bulk-probed rows, "
-            f"{facts['int_table_demotions']} tables became dicts")
+            f"{facts['demotions']} tables became dicts")
     return problems, facts
 
 
@@ -599,7 +602,8 @@ def leg_state_backend(cfg, events, ref):
     problems += timer_run_problems(wop, len(ref), len(cols[0]))
     column_faults, column_facts = column_problems(counted, len(keys))
     problems += column_faults
-    key_faults, key_facts = key_column_problems(wop, True)
+    key_faults, key_facts = key_column_problems(
+        wop, True, counted[COLUMN_COUNTS.index("demotions")])
     problems += key_faults
     form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
                                                   flushed)
@@ -647,7 +651,8 @@ def leg_state_spill(cfg, seed):
     problems += fire_tail_problems(wop, len(cols[0]))
     column_faults, column_facts = column_problems(counted, len(events[0]))
     problems += column_faults
-    key_faults, key_facts = key_column_problems(wop, True)
+    key_faults, key_facts = key_column_problems(
+        wop, True, counted[COLUMN_COUNTS.index("demotions")])
     problems += key_faults
     form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
                                                   flushed)
@@ -723,6 +728,7 @@ def leg_state_sliding(cfg, seed):
     env.set_state_backend("tpu")
     source = FlushMarkingSource(keys, values, ts)
     flushed = STATE_STATS.flush_row_form_batches
+    demoted = KeyTable.demotions
     windowed = (env.add_source(source, name="events")
                 .key_by(0)
                 .window(SlidingEventTimeWindows.of(SLIDING_SIZE_MS,
@@ -755,7 +761,7 @@ def leg_state_sliding(cfg, seed):
                         f"quantiles failed")
     problems += boxed_problems(wop, n)
     problems += fire_tail_problems(wop, len(got_keys))
-    key_faults, key_facts = key_column_problems(wop, True)
+    key_faults, key_facts = key_column_problems(wop, True, demoted)
     problems += key_faults
     if state.evictions or state.budget_overruns \
             or state.capacity > sl["budget"]:
@@ -1025,6 +1031,7 @@ def leg_state_sessions(cfg, seed):
     env, sink = session_job(cfg, log, source, watch)
     ops = capture_operators(env)
     probes = STATE_STATS.per_key_probe_rows
+    demoted = KeyTable.demotions
     env.execute("chip-smoke-state-sessions")
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
@@ -1045,7 +1052,7 @@ def leg_state_sessions(cfg, seed):
                         f"session facts failed")
     problems += boxed_problems(wop, n)
     problems += fire_tail_problems(wop, len(cols[0]) if cols else 0)
-    key_faults, key_facts = key_column_problems(wop, False)
+    key_faults, key_facts = key_column_problems(wop, False, demoted)
     problems += key_faults
     if state.evictions or state.budget_overruns \
             or state.capacity > se["budget"]:

@@ -27,7 +27,7 @@ class StateStats:
         "snapshot_bytes_written",
         "evicted_rows", "promoted_rows", "spill_fired_rows",
         "budget_overruns", "bulk_probe_rows", "per_key_probe_rows",
-        "int_table_rows", "int_table_demotions",
+        "int_table_rows",
         "merged_rows",
         "hash_column_rows", "hash_per_value_rows",
         "per_state_batch_rows", "per_state_batch_calls",
@@ -83,12 +83,10 @@ class StateStats:
         self.bulk_probe_rows = 0
         self.per_key_probe_rows = 0
         #: of the bulk-probed rows, those the slot index took as one
-        #: int64 column (`state/slot_index.py`: one C call on the
-        #: namespace's integer table, or no table there at all; the
-        #: rest went through a dict, a call per key), and integer
-        #: tables that met a key they could not hold and became dicts
+        #: int64 column (`core/key_columns.py` `KeyTable.column_rows`:
+        #: one C call on the namespace's integer table, or no table
+        #: there at all; the rest went through a dict, a call per key)
         self.int_table_rows = 0
-        self.int_table_demotions = 0
         #: source slots the tpu backend's batched session merge
         #: (`merge_namespaces_batch`) folded into their targets
         self.merged_rows = 0

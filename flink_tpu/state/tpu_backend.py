@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from flink_tpu.core.key_columns import KeyTable
 from flink_tpu.core.keygroups import (
     KeyGroupRange,
     assign_to_key_group,
@@ -128,12 +129,12 @@ def _pad_slots(slots, width: int) -> np.ndarray:
     return arr
 
 
-def _count_probed(phase, rows: int, on_int_tables: int) -> None:
-    """On a slot phase's books: the rows it probed in bulk, and those
-    of them that went as a column since `on_int_tables`
-    (`STATE_STATS.int_table_rows` then)."""
+def _count_probed(phase, rows: int, columns: int) -> None:
+    """On a slot phase's books (and in `STATE_STATS.int_table_rows`):
+    the rows it probed, the `columns` of them that went as a column."""
     phase.add_count("rows", rows)
-    phase.add_count("int_table", STATE_STATS.int_table_rows - on_int_tables)
+    phase.add_count("int_table", columns)
+    STATE_STATS.int_table_rows += columns
 
 
 class _PendingRing:
@@ -579,17 +580,17 @@ class DeviceAggregatingState(AggregatingState):
                 [column, np.full(extra, fill, column.dtype)]))
         self.capacity = new_capacity
 
-    def _resolve(self, keys, namespace) -> Tuple[np.ndarray, int]:
+    def _resolve(self, keys, namespace) -> Tuple[np.ndarray, int, int]:
         """The batch door into the slot index: the slots of one
         namespace's `keys` (a list, or the int64 column they are) as
-        int64[n], new keys taking theirs in the same pass, and how many
-        were new.  Room first, then the chunk's spilled entries come
-        up, then ONE probe of the namespace's table; nothing is evicted
-        between the last two, so no entry the probe misses has a row in
-        the host tier."""
+        int64[n], new keys taking theirs in the same pass, how many
+        were new and how many rows went as one column.  Room first,
+        then the chunk's spilled entries come up, then ONE probe of the
+        namespace's table; nothing is evicted between the last two, so
+        no entry the probe misses has a row in the host tier."""
         n = len(keys)
         if n == 0:
-            return np.zeros(0, np.int64), 0
+            return np.zeros(0, np.int64), 0, 0
         free = self._free
         index = self.slot_index
         if len(free) < n:
@@ -601,53 +602,56 @@ class DeviceAggregatingState(AggregatingState):
                 self._make_room(ahead=n)
         if self._spilled:
             self._promote_spilled(keys, namespace)
-        on_int_tables = index.int_rows
+        columns = KeyTable.column_rows
         slots, fresh, new_keys = index.resolve(keys, namespace, free)
-        if index.int_rows != on_int_tables:
-            STATE_STATS.int_table_rows += index.int_rows - on_int_tables
+        columns = KeyTable.column_rows - columns
         if len(fresh):
             self._claim(fresh, new_keys, namespace)
         self._stamp(slots)
         STATE_STATS.bulk_probe_rows += n
-        return slots, len(fresh)
+        return slots, len(fresh), columns
 
     def _resolve_column(self, keys, namespace,
-                        namespaces) -> Tuple[np.ndarray, int]:
+                        namespaces) -> Tuple[np.ndarray, int, int]:
         """`_resolve` for a column of rows of ONE namespace, or
         (`namespaces=`) each of its own: the rows of a namespace go
         through the batch door together."""
         slots = np.empty(len(keys), np.int64)
-        new = 0
+        new = columns = 0
         for namespace, rows, part in cut_by_namespace(keys, namespace,
                                                       namespaces):
-            slots[rows], made = self._resolve(part, namespace)
+            slots[rows], made, took = self._resolve(part, namespace)
             new += made
-        return slots, new
+            columns += took
+        return slots, new, columns
 
     def _find(self, keys: list, namespace, namespaces, take: bool = False):
         """Where a column of entries lives, one bulk probe per
         namespace and tier: their device slots as int64[n] (-1: none),
         and of those rows the host tier holds, positions and row ids.
-        `take` takes what it finds out of both indexes.  Allocates
-        nothing, promotes nothing."""
+        `take` takes what it finds out of both indexes; and how many
+        rows went to the device's as one column.  Allocates nothing,
+        promotes nothing."""
         n = len(keys)
         STATE_STATS.bulk_probe_rows += n
         slots = np.empty(n, np.int64)
         spill_rows = [np.zeros(0, np.int64)]
         spill_ids = [np.zeros(0, np.int64)]
         index, spilled = self.slot_index, self._spilled
-        on_int_tables = index.int_rows
+        columns = 0
         for namespace, rows, part in cut_by_namespace(keys, namespace,
                                                       namespaces):
+            took = KeyTable.column_rows
             got = index.lookup(part, namespace, take)
+            columns += KeyTable.column_rows - took
             slots[rows] = got
             if namespace in spilled.tables:
                 miss = got < 0
                 ids = spilled.lookup(pick(part, miss), namespace, take)
                 spill_rows.append(rows[miss][ids >= 0])
                 spill_ids.append(ids[ids >= 0])
-        STATE_STATS.int_table_rows += index.int_rows - on_int_tables
-        return slots, np.concatenate(spill_rows), np.concatenate(spill_ids)
+        return (slots, np.concatenate(spill_rows), np.concatenate(spill_ids),
+                columns)
 
     # ---- write path -------------------------------------------------
     def add(self, value) -> None:
@@ -691,10 +695,10 @@ class DeviceAggregatingState(AggregatingState):
         tracer = get_tracer()
         n = len(keys)
         with tracer.phase("state.add.slots") as phase:
-            on_int_tables = STATE_STATS.int_table_rows
-            slots, new = self._resolve_column(keys, namespace, namespaces)
+            slots, new, columns = self._resolve_column(keys, namespace,
+                                                       namespaces)
             phase.add_count("new", new)
-            _count_probed(phase, n, on_int_tables)
+            _count_probed(phase, n, columns)
             self._pending_slots.extend(slots)
         with tracer.phase("state.add.hash", rows=n) as phase:
             extract = self.agg.extract_value
@@ -811,10 +815,9 @@ class DeviceAggregatingState(AggregatingState):
             if not isinstance(keys, (list, tuple, np.ndarray)):
                 keys = list(keys)
             n = len(keys)
-            on_int_tables = STATE_STATS.int_table_rows
-            slots, spill_idx, spill_ids = self._find(keys, namespace,
-                                                     namespaces)
-            _count_probed(phase, n, on_int_tables)
+            slots, spill_idx, spill_ids, columns = self._find(
+                keys, namespace, namespaces)
+            _count_probed(phase, n, columns)
             found = slots >= 0
             # reads stamp the LRU clock exactly as scalar get()
             self._stamp(slots[found])
@@ -950,10 +953,9 @@ class DeviceAggregatingState(AggregatingState):
         with tracer.phase("state.clear.slots") as phase:
             if not isinstance(keys, (list, tuple, np.ndarray)):
                 keys = list(keys)
-            on_int_tables = STATE_STATS.int_table_rows
-            slots, _, spilled_ids = self._find(keys, namespace, namespaces,
-                                               take=True)
-            _count_probed(phase, len(keys), on_int_tables)
+            slots, _, spilled_ids, columns = self._find(
+                keys, namespace, namespaces, take=True)
+            _count_probed(phase, len(keys), columns)
             slots = slots[slots >= 0]
             self._release(slots)
         if len(spilled_ids):
@@ -1186,7 +1188,7 @@ class DeviceAggregatingState(AggregatingState):
         n = len(keys)
         if len(self.slot_index) or len(self._spilled) \
                 or len(set(zip(keys, namespaces))) != n:
-            return self._resolve_column(keys, None, namespaces)
+            return self._resolve_column(keys, None, namespaces)[:2]
         free = self._free
         slots = np.array(free[:-n - 1:-1], np.int64)
         del free[-n:]

@@ -25,15 +25,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
-from flink_tpu import native
-from flink_tpu.core.key_columns import (
-    INT64_MAX,
-    INT64_MIN,
-    as_list,
-    int_column,
-    int_key,
-    object_column,
-)
+from flink_tpu.core.key_columns import KeyTable, as_list, object_column
 from flink_tpu.core.keygroups import assign_to_key_group
 from flink_tpu.streaming.elements import MIN_TIMESTAMP
 
@@ -205,9 +197,8 @@ class InternalTimer:
 
 
 def _extend(spans: list, how_many: int, seq: int) -> None:
-    last = spans[-1]
-    if last[0] + last[1] == seq:
-        last[0] += how_many
+    if spans and spans[-1][0] + spans[-1][1] == seq:
+        spans[-1][0] += how_many
     else:
         spans.append([how_many, seq])
 
@@ -216,68 +207,37 @@ def _keys(run) -> list:
     return [run[0]] if type(run) is tuple else list(run)
 
 
-class _IntRun(native.NativeIntTable):
-    """A run of integer keys: int64 key -> registration number in the
-    native table.  Numbers only grow and the table exports its entries
-    in the order they came, so its entry order is its firing order, as
-    a dict run's is.  It iterates as the Python ints a dict run of the
-    same keys would."""
-
-    __slots__ = ()
-
-    def __iter__(self):
-        return iter(self.export()[0].tolist())
-
-    def __reduce__(self):
-        # (a copy or a pickle gets a table of its own, never the handle)
-        return _int_run_of, self.export()
-
-
-def _int_run_of(keys: np.ndarray, numbers: np.ndarray) -> _IntRun:
-    run = _IntRun(len(keys))
-    run.set(keys, numbers)
-    return run
-
-
 class _TimerStore:
     """The timers of one time domain, kept by run: the keys registered
-    for ONE (timestamp, namespace) are one dict, in registration
-    order.  The dict is the exactly-once contract (a key is in it
-    once) and the firing order among them; the heap orders RUNS, one
-    node each, not timers.  A tumbling window's 230k fire timers are
-    one run and one heap node.  A process function that arms a timer
-    per event has runs of one key: such a run is kept as the pair
-    ``(key, number)`` until a second key joins it, which spares the
-    store a dict for each.
+    for ONE (timestamp, namespace) are one run, in registration order.
+    The run is the exactly-once contract (a key is in it once) and the
+    firing order among them; the heap orders RUNS, one node each, not
+    timers.  A tumbling window's 230k fire timers are one run and one
+    heap node.
 
     Every timer has a registration number, and the timers of one
     timestamp fire in the order of their numbers, across namespaces.
-    A run that only ever grew by the bulk call holds its numbers in
-    `spans`, ``[how many, first number]`` per stretch of consecutive
-    numbers, so a bulk registration is one ``dict.update`` however
-    many keys it brings (the dict's values mean nothing then).  The
-    first removal of a key, a per-timer drain or a tie that has to be
-    merged numbers every key on its own: the run then maps key ->
-    number and has no `spans`.
+    A run is kept in one of three ways:
 
-    A run born from an int64 COLUMN of keys (`add_many`) is an
-    `_IntRun` instead: key -> number in a native table, a batch's keys
-    probed and numbered in one C call, the keys of a sweep handed on
-    as the int64 column the table exports.  The same calls give it the
-    keys, numbers and order a dict run gets.  The first key it cannot
-    hold (a string, a tuple, a float, an int beyond int64) turns it
-    into a dict, once (`int_run_demotions`); the per-timer drain reads
-    it as one.
+    - the pair ``(key, number)``: a run of one key (a process function
+      arms a timer per event) until a second joins it;
+    - a dict of its keys numbered by `spans`, ``[how many, first
+      number]`` per stretch: a run born from a list of keys that only
+      grew by `add_many`, one ``dict.update`` however many keys come;
+    - a `KeyTable` of key -> number (`core/key_columns.py`): a run born
+      from an int64 COLUMN of keys (its integer form: one C call a
+      batch, a sweep hands on the column it exports), or any other run
+      once a removal, a per-timer drain or a tie numbers it.
 
     Removing a key is a real removal and an emptied run goes, so every
     run here has a key and a heap node.  The node of a run that went
     stays behind only until such nodes outnumber the runs (then the
     heap is rebuilt from the runs), so deletes never pile up."""
 
-    __slots__ = ("runs", "spans", "heap", "seq", "room", "int_run_demotions")
+    __slots__ = ("runs", "spans", "heap", "seq", "room")
 
     def __init__(self):
-        #: (timestamp, namespace) -> {key: number} | (key, number) | _IntRun
+        #: (timestamp, namespace) -> (key, number) | {key: None} | KeyTable
         self.runs: Dict[Tuple[int, Any], Any] = {}
         #: (timestamp, namespace) -> [[how many, first number], ...]
         self.spans: Dict[Tuple[int, Any], list] = {}
@@ -286,11 +246,8 @@ class _TimerStore:
         self.heap: List[Tuple[int, int, Tuple[int, Any]]] = []
         #: the next registration number
         self.seq = 0
-        #: keys the integer run swept last held at its fullest: the next
-        #: one is born with room for as many
+        #: the room a run born here gets (`KeyTable.dropped`)
         self.room = 0
-        #: integer runs a key they could not hold turned into dicts
-        self.int_run_demotions = 0
 
     def clear(self) -> None:
         self.runs.clear()
@@ -305,10 +262,10 @@ class _TimerStore:
         """The runs as of now, in the order they were first seen, as
         three columns no later registration or removal reaches: each
         run's (timestamp, namespace), its keys frozen (`tuple(run)`:
-        of a dict or an integer run its keys in registration order, of
-        a run of one key the pair it is), and its type, which tells
-        the two apart.  One C-level pass a column: a session job has a
-        run a session."""
+        of a dict or a `KeyTable` its keys in registration order, of a
+        run of one key the pair it is), and its type, which tells the
+        two apart.  One C-level pass a column: a session job has a run
+        a session."""
         runs = list(self.runs.values())
         return list(self.runs), list(map(tuple, runs)), list(map(type, runs))
 
@@ -319,19 +276,20 @@ class _TimerStore:
             for key in _keys(run):
                 yield timestamp, key, namespace
 
-    def _dict(self, at, run) -> dict:
+    def _numbered(self, at, run) -> KeyTable:
+        """`run` as a `KeyTable` of key -> registration number, which
+        it is from here on."""
         if type(run) is tuple:
-            run = self.runs[at] = {run[0]: run[1]}
-        elif type(run) is _IntRun:
-            keys, numbers = run.export()
-            run = self.runs[at] = dict(zip(keys.tolist(), numbers.tolist()))
-        return run
-
-    def _demote(self, at, run) -> dict:
-        """An integer run as a dict from now on: a key came that it
-        cannot hold."""
-        self.int_run_demotions += 1
-        return self._dict(at, run)
+            table = self.runs[at] = KeyTable()
+            table[run[0]] = run[1]
+        elif type(run) is dict:
+            table = self.runs[at] = KeyTable(zip(
+                run, itertools.chain.from_iterable(
+                    range(first, first + n)
+                    for n, first in self.spans.pop(at))))
+        else:
+            table = run
+        return table
 
     def add(self, timestamp: int, namespace, key) -> bool:
         """Register one timer; False if it is registered already."""
@@ -342,19 +300,12 @@ class _TimerStore:
             self.runs[at] = (key, seq)
             heapq.heappush(self.heap, (timestamp, seq, at))
         else:
-            if type(run) is _IntRun:
-                if type(key) is int and INT64_MIN <= key <= INT64_MAX:
-                    if run.get(key) >= 0:
-                        return False
-                    run.put(key, seq)
-                    self.seq = seq + 1
-                    return True
-                run = self._demote(at, run)
-            run = self._dict(at, run)
+            spans = self.spans.get(at)
+            if spans is None:
+                run = self._numbered(at, run)
             if key in run:
                 return False
             run[key] = seq
-            spans = self.spans.get(at)
             if spans is not None:
                 _extend(spans, 1, seq)
         self.seq = seq + 1
@@ -368,92 +319,45 @@ class _TimerStore:
         added."""
         at = (timestamp, namespace)
         run = self.runs.get(at)
-        if isinstance(keys, np.ndarray) \
-                and (keys.ndim != 1 or keys.dtype.kind != "i"):
-            # an unsigned, float or object array: the Python objects it
-            # holds, which an integer run takes or is demoted by below
-            keys = keys.tolist()
-        if type(run) is _IntRun and not isinstance(keys, np.ndarray):
-            keys = list(keys)
-            if not keys:
-                return 0
-            column = int_column(keys, kinds="i")
-            if column is None:
-                run = self._demote(at, run)
-            else:
-                keys = column
         if isinstance(keys, np.ndarray):
-            if (run is None or type(run) is _IntRun) and native.available():
-                return self._add_column(at, run, keys)
-            keys = keys.tolist()
-        if not isinstance(keys, dict):
-            keys = dict.fromkeys(keys)
-        if not keys:
-            return 0
-        seq = self.seq
-        if run is None:
-            self.runs[at] = dict(keys)
-            self.spans[at] = [[len(keys), seq]]
-            heapq.heappush(self.heap, (timestamp, seq, at))
-            self.seq = seq + len(keys)
-            return len(keys)
-        spans = self.spans.get(at)
-        if spans is None:
-            run = self._dict(at, run)
-            for key in keys:
-                if key not in run:
-                    run[key] = seq
-                    seq += 1
-            added = seq - self.seq
-            self.seq = seq
-            return added
-        before = len(run)
-        run.update(keys)
-        added = len(run) - before
-        if added:
-            _extend(spans, added, seq)
-            self.seq = seq + added
-        return added
-
-    def _add_column(self, at, run, keys: np.ndarray) -> int:
-        """`add_many` of an int64 column onto an integer run (made if
-        there is none): one probe, the new keys numbered in the order
-        they first came."""
+            if keys.ndim != 1 or keys.dtype.kind != "i":
+                # an unsigned, float or object array: the Python objects
+                # it holds
+                keys = keys.tolist()
+        elif type(keys) is not list:
+            keys = list(keys)
         if not len(keys):
             return 0
         seq = self.seq
+        spans = self.spans.get(at)
         if run is None:
-            run = self.runs[at] = _IntRun(self.room)
-            heapq.heappush(self.heap, (at[0], seq, at))
-        numbers, first = run.probe(np.ascontiguousarray(keys, np.int64))
-        added = len(first)
-        if added:
-            run.assign(np.arange(seq, seq + added, dtype=np.int64), numbers)
-            self.seq = seq + added
+            heapq.heappush(self.heap, (timestamp, seq, at))
+            if isinstance(keys, np.ndarray):
+                run = self.runs[at] = KeyTable.born(keys, self.room)
+            else:
+                run = self.runs[at] = {}
+                spans = self.spans[at] = []
+        if spans is None:
+            added = len(self._numbered(at, run).append(
+                keys, np.arange(seq, seq + len(keys)))[1])
+        else:
+            before = len(run)
+            run.update(dict.fromkeys(as_list(keys)))
+            added = len(run) - before
+            if added:
+                _extend(spans, added, seq)
+        self.seq = seq + added
         return added
-
-    def _numbered(self, at, run) -> dict:
-        """`run` as the dict key -> registration number, which it is
-        from here on."""
-        spans = self.spans.pop(at, None)
-        if spans is not None:
-            run.update(zip(list(run), itertools.chain.from_iterable(
-                range(first, first + n) for n, first in spans)))
-        return self._dict(at, run)
 
     def _number_range(self, at, run) -> Tuple[int, int]:
         """First and last registration number of a run (numbers only
         grow, so they are those of the first and the last key)."""
         if type(run) is tuple:
             return run[1], run[1]
-        if type(run) is _IntRun:
-            numbers = run.export()[1]
-            return int(numbers[0]), int(numbers[-1])
         spans = self.spans.get(at)
         if spans is not None:
             return spans[0][1], spans[-1][1] + spans[-1][0] - 1
-        numbers = run.values()
-        return next(iter(numbers)), next(reversed(numbers))
+        return run.ends()[1:]
 
     def discard(self, timestamp: int, namespace, key) -> None:
         self.discard_many(timestamp, namespace, (key,))
@@ -463,25 +367,16 @@ class _TimerStore:
         run = self.runs.get(at)
         if run is None:
             return
-        if type(run) is _IntRun:
-            if isinstance(keys, np.ndarray) and keys.dtype.kind == "i":
-                column = np.ascontiguousarray(keys, np.int64)
-            else:
-                # each key as the int64 it equals as a dict key, if any
-                column = np.fromiter(
-                    (k for k in map(int_key, as_list(keys))
-                     if k is not None), np.int64)
-            run.lookup(column, take=True)
-            if len(run):
-                return
-        elif type(run) is tuple:
+        if type(run) is tuple:
             if run[0] not in as_list(keys):
                 return
         else:
-            for key in as_list(keys):
-                if key in run:
-                    del self._numbered(at, run)[key]
-            if run:
+            if at in self.spans:
+                keys = as_list(keys)
+                if not any(map(run.__contains__, keys)):
+                    return
+                run = self._numbered(at, run)
+            if run.take(keys, 0)[1] is None:
                 return
         del self.runs[at]  # its heap node is stale now
         heap = self.heap
@@ -517,53 +412,48 @@ class _TimerStore:
                 heapq.heappush(heap, node)
         return {at: runs[at] for at in nodes}
 
-    def _by_number(self, tied: dict, exported: Optional[dict] = None) -> list:
+    def _by_number(self, tied: dict) -> list:
         """``(registration number, key, (timestamp, namespace), run)``
-        of every timer of the runs in `tied`, in firing order; an
-        integer run in `exported` gives the numbers and keys it
-        exported, every other run is numbered key by key."""
+        of every timer of the runs in `tied`, in firing order, each
+        run numbered first."""
         rows = []
         for at, run in tied.items():
-            if exported and at in exported:
-                keys, numbers = exported[at]
-                rows.extend(zip(numbers.tolist(), keys.tolist(),
-                                itertools.repeat(at), itertools.repeat(run)))
-            else:
-                run = self._numbered(at, run)
-                rows.extend((seq, key, at, run) for key, seq in run.items())
+            run = self._numbered(at, run)
+            keys, numbers = run.export()
+            rows.extend(zip(numbers.tolist(), as_list(keys),
+                            itertools.repeat(at), itertools.repeat(run)))
         if len(tied) > 1:
             rows.sort(key=operator.itemgetter(0))
         return rows
 
     def pop_runs(self, limit: int) -> List[Tuple[int, Any, Any]]:
         """Take every timer <= limit out: ``(timestamp, namespace,
-        keys)`` runs in firing order, the keys a list, or of an integer
-        run the int64 column it exported.  A timestamp with one
-        namespace is its run, whole; runs that tie on a timestamp are
-        cut where their registration numbers interleave."""
+        keys)`` runs in firing order, the keys a list, or of a run in
+        a `KeyTable`'s integer form the int64 column it exported.  A
+        timestamp with one namespace is its run, whole; runs that tie
+        on a timestamp are cut where their registration numbers
+        interleave."""
         out = []
         heap = self.heap
         while heap and heap[0][0] <= limit:
             timestamp = heap[0][0]
             tied = self._pop_tied(timestamp, keep_nodes=False)
-            exported = {}
+            ends = {}
             for at, run in tied.items():
-                if type(run) is _IntRun:
-                    exported[at] = run.export()
-                    self.room = run.peak()
+                if isinstance(run, KeyTable):
+                    ends[at] = run.ends()
+                    self.room = run.dropped(self.room)
             ranges = sorted(
-                ((int(exported[at][1][0]), int(exported[at][1][-1]))
-                 if at in exported else self._number_range(at, run))
+                (ends[at][1:] if at in ends else self._number_range(at, run))
                 + (at,) for at, run in tied.items())
             if any(a[1] > b[0] for a, b in zip(ranges, ranges[1:])):
                 out.extend(
                     (timestamp, at[1], [row[1] for row in rows])
                     for at, rows in itertools.groupby(
-                        self._by_number(tied, exported),
-                        key=operator.itemgetter(2)))
+                        self._by_number(tied), key=operator.itemgetter(2)))
             else:
-                out.extend((timestamp, at[1], exported[at][0]
-                            if at in exported else _keys(tied[at]))
+                out.extend((timestamp, at[1], ends[at][0]
+                            if at in ends else _keys(tied[at]))
                            for _, _, at in ranges)
             for at in tied:
                 del self.runs[at]
@@ -600,7 +490,7 @@ class _TimerStore:
             for seq, key, at, run in rows:
                 if run.get(key) != seq:
                     continue  # deleted; registered anew: a later number
-                del run[key]
+                run.pop(key)
                 if not run:
                     del runs[at]
                 set_current_key(key)
@@ -758,12 +648,6 @@ class InternalTimerService:
 
     def delete_processing_time_timer(self, namespace, timestamp: int) -> None:
         self._proc.discard(timestamp, namespace, self._backend.current_key)
-
-    @property
-    def int_run_demotions(self) -> int:
-        """Integer runs that a key they could not hold turned into
-        dicts (`_TimerStore`)."""
-        return self._event.int_run_demotions + self._proc.int_run_demotions
 
     def num_event_time_timers(self) -> int:
         return len(self._event)

@@ -21,6 +21,7 @@ from fire_tail_reference import (
     assert_fire_left_as,
 )
 
+from flink_tpu.core.key_columns import KeyTable
 from flink_tpu.core.state import (
     AggregatingStateDescriptor,
     ListStateDescriptor,
@@ -757,6 +758,7 @@ def _key_path_job(kind, lateness, backend, selector, *, chunks,
     seen = {"out": [], "side": [], "sweeps": [], "snapshot": None,
             "late": 0, "key_column_rows": 0, "columnar_rows": 0,
             "demotions": 0}
+    demoted = KeyTable.demotions
 
     def count(op, h, spy):
         seen["out"] += [(r.value, r.timestamp) for r in h.get_output()]
@@ -766,7 +768,7 @@ def _key_path_job(kind, lateness, backend, selector, *, chunks,
         seen["late"] += op.num_late_records_dropped
         seen["key_column_rows"] += op.key_column_rows
         seen["columnar_rows"] += op.columnar_rows
-        seen["demotions"] += op.timer_service.int_run_demotions
+        seen["demotions"] = KeyTable.demotions - demoted
 
     for c, (keys, vals, ts, wm) in enumerate(chunks):
         batch = RecordBatch({"f0": keys, "f1": vals}, ts=ts)

@@ -23,6 +23,7 @@ from flink_tpu.ops.device_agg import SumAggregate
 from flink_tpu.ops.sketches import HyperLogLogAggregate
 from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu import native
+from flink_tpu.core.key_columns import KeyTable
 from flink_tpu.state import slot_index
 from flink_tpu.state.backend import KeyedStateSnapshot, decode_obj_column
 from flink_tpu.state.slot_index import NamespaceIndex, group_rows
@@ -217,7 +218,7 @@ def _namespaces(shape, rng, n, round_):
 def dict_tables_only(monkeypatch):
     """No native host runtime as far as the slot index can tell: every
     table is a dict."""
-    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+    monkeypatch.setattr(native, "available", lambda: False)
 
 
 #: the kinds of keys an integer table can take run on both forms
@@ -244,6 +245,7 @@ def test_bulk_index_is_the_per_key_index(agg_name, kind, form, shape, capped,
     if form == "dict":
         request.getfixturevalue("dict_tables_only")
     STATE_STATS.reset()
+    demoted = KeyTable.demotions
     space, make = KEYS[kind]
     rng = np.random.default_rng(
         [sorted(AGGS).index(agg_name), sorted(KEYS).index(kind),
@@ -326,7 +328,7 @@ def test_bulk_index_is_the_per_key_index(agg_name, kind, form, shape, capped,
         # (a batch of 1, 1.0 and True is a column of floats: a dict,
         # unless it happens to hold ints alone)
         assert STATE_STATS.int_table_rows == 0
-    assert STATE_STATS.int_table_demotions == 0 or kind == "equal"
+    assert KeyTable.demotions == demoted or kind == "equal"
     # clear everything, a window at a time: nothing is left behind
     for ns, keys in live.items():
         pair.clear_batch(list(keys), ns, None)
@@ -580,8 +582,13 @@ def _free(n):
     return list(range(n - 1, -1, -1))
 
 
+def _is_int(table):
+    """Whether a `KeyTable` is in its integer form."""
+    return isinstance(getattr(table, "_ints", None), native.NativeIntTable)
+
+
 def _is_int_table(index, namespace):
-    return isinstance(index.tables[namespace], native.NativeIntTable)
+    return _is_int(index.tables[namespace])
 
 
 class _BothForms:
@@ -620,8 +627,8 @@ class _BothForms:
         assert list(self.ints) == list(self.dicts)
         assert len(self.ints) == len(self.dicts)
         assert list(self.ints.tables) == list(self.dicts.tables)
-        assert all(type(t) is dict for t in self.dicts.tables.values())
-        assert not any(type(t) is dict for t in self.ints.tables.values())
+        assert not any(map(_is_int, self.dicts.tables.values()))
+        assert all(map(_is_int, self.ints.tables.values()))
 
 
 @needs_native
@@ -631,11 +638,12 @@ def test_an_integer_table_holds_every_int64():
     and a take of a key that comes twice finds it once."""
     both = _BothForms()
     edge = [0, -1, I64.min, I64.max, 0, I64.max, 7, -1]
+    columns = KeyTable.column_rows
     ids, fresh = both.resolve(edge)
     assert len(fresh) == 5 and len(set(ids.tolist())) == 5
     assert ids[0] == ids[4] and ids[3] == ids[5] and ids[1] == ids[7]
     assert _is_int_table(both.ints, "w")
-    assert both.ints.int_rows == 8
+    assert KeyTable.column_rows - columns == 8
     assert both.lookup([I64.min, I64.min + 1, I64.max, I64.max - 1, 0, 1]) \
         .tolist() == [ids[2], -1, ids[3], -1, ids[0], -1]
     for key in (0, -1, I64.min, I64.max):
@@ -689,12 +697,12 @@ def test_an_emptied_integer_table_is_dropped():
     both.lookup(list(range(500)), "u", take=True)
     assert both.ints._room == 500
     both.resolve([7, 8], "t")
-    assert both.ints.tables["t"].peak() == 2
+    assert both.ints.tables["t"].dropped(0) == 2
     both.lookup([7, 8], "t", take=True)
     assert both.ints._room == 2
     # and a namespace is born again as what its next keys ask for
     both.ints.resolve(["a"], "w", both.free[0])
-    assert type(both.ints.tables["w"]) is dict
+    assert not _is_int_table(both.ints, "w")
 
 
 @needs_native
@@ -731,7 +739,7 @@ def test_a_key_it_cannot_hold_turns_an_integer_table_into_a_dict_once(
         door, stranger):
     """Every earlier entry is still found, in its place; the table
     stays a dict when integer columns come again."""
-    STATE_STATS.reset()
+    demoted = KeyTable.demotions
     index = NamespaceIndex()
     free = _free(64)
     ids, _, _ = index.resolve(np.array([5, -3, 9, 5, 0]), "w", free)
@@ -748,29 +756,29 @@ def test_a_key_it_cannot_hold_turns_an_integer_table_into_a_dict_once(
         index.enter([stranger, 9], "w", np.array([57, ids[2]]))
     else:
         index.put(stranger, "w", 57)
-    assert type(index.tables["w"]) is dict and _is_int_table(index, "v")
-    assert STATE_STATS.int_table_demotions == 1
+    assert not _is_int_table(index, "w") and _is_int_table(index, "v")
+    assert KeyTable.demotions - demoted == 1
     assert index.columns()[0] == [5, -3, 9, 0, stranger, 1, 2]
     assert list(index.tables) == ["w", "v"]
     assert index.lookup([5, -3, 9, 0, stranger], "w").tolist() \
         == [*ids[[0, 1, 2, 4]].tolist(), strange_id]
-    was = index.int_rows
+    was = KeyTable.column_rows
     index.resolve(np.array([5, 77]), "w", free)
-    assert type(index.tables["w"]) is dict and index.int_rows == was
-    assert STATE_STATS.int_table_demotions == 1
+    assert not _is_int_table(index, "w") and KeyTable.column_rows == was
+    assert KeyTable.demotions - demoted == 1
     assert index.get(77, "w") is not None
 
 
 @needs_native
 def test_a_lookup_never_turns_a_table_into_a_dict():
-    STATE_STATS.reset()
+    demoted = KeyTable.demotions
     index = NamespaceIndex()
     index.resolve(np.array([1, 2, 3]), "w", _free(8))
     assert index.lookup(["a", 2, (3,)], "w").tolist() == [-1, 1, -1]
     assert index.missing(["a", 2, "a", 4.5], "w") == 2
     assert index.missing(np.array([7, 2, 7, 8]), "w") == 2
     assert index.lookup([2.0, "a"], "w", take=True).tolist() == [1, -1]
-    assert _is_int_table(index, "w") and STATE_STATS.int_table_demotions == 0
+    assert _is_int_table(index, "w") and KeyTable.demotions == demoted
 
 
 @needs_native
@@ -803,7 +811,7 @@ def test_what_is_born_an_integer_table():
     other = NamespaceIndex()
     index.move([3], "column", other, np.array([9]))
     index.move([3], "list", other, np.array([8]))
-    assert _is_int_table(other, "column") and type(other.tables["list"]) is dict
+    assert _is_int_table(other, "column") and not _is_int_table(other, "list")
     assert other.get(3, "column") == 9 and index.get(3, "column") is None
     assert index.get(2, "column") == 3
 
@@ -818,7 +826,7 @@ def test_without_the_native_runtime_every_table_is_a_dict(dict_tables_only):
         b.add_batch(st, keys, "v", np.ones(40, np.float32))
     assert st.evictions > 0
     for index in (st.slot_index, st.host_tier.index):
-        assert all(type(t) is dict for t in index.tables.values())
+        assert not any(map(_is_int, index.tables.values()))
     assert STATE_STATS.int_table_rows == 0 < STATE_STATS.bulk_probe_rows
     res, found, _ = b.get_batch(st, list(range(100)), "v")
     assert found.sum() == len({k for k, ns in st.active_entries()

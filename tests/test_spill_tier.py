@@ -12,6 +12,7 @@ from flink_tpu.core.config import Configuration
 from flink_tpu.core.keygroups import KeyGroupRange
 from flink_tpu.core.state import AggregatingStateDescriptor
 from flink_tpu import native
+from flink_tpu.core.key_columns import KeyTable
 from flink_tpu.ops.device_agg import SumAggregate
 from flink_tpu.ops.sketches import (
     CountMinSketchAggregate,
@@ -19,7 +20,6 @@ from flink_tpu.ops.sketches import (
 )
 from flink_tpu.runtime.tracing import get_tracer
 from flink_tpu.state.backend import decode_obj_column
-from flink_tpu.state import slot_index
 from flink_tpu.state.host_tier import HostTier
 from flink_tpu.state.stats import STATE_STATS
 from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
@@ -271,6 +271,7 @@ def _tumbling_spill_drive(rows=48, key_space=200, windows=4, seed=5):
     rng = np.random.default_rng(seed)
     b, st = _state(TpuKeyedStateBackend, SumAggregate(np.float32))
     STATE_STATS.reset()
+    demoted = KeyTable.demotions
     seen = []
 
     def observe(what):
@@ -302,9 +303,10 @@ def _tumbling_spill_drive(rows=48, key_space=200, windows=4, seed=5):
             observe("restored")
         b.clear_batch(st, list(live), ns)
         observe("clear")
-    return seen, st, {name: getattr(STATE_STATS, name) for name in (
-        "bulk_probe_rows", "int_table_rows", "int_table_demotions",
-        "evicted_rows", "promoted_rows")}
+    return seen, st, {"demotions": KeyTable.demotions - demoted, **{
+        name: getattr(STATE_STATS, name) for name in (
+            "bulk_probe_rows", "int_table_rows", "evicted_rows",
+            "promoted_rows")}}
 
 
 @pytest.mark.skipif(not native.available(), reason="no native host runtime")
@@ -315,11 +317,11 @@ def test_a_spilling_integer_keyed_job_is_the_same_on_both_forms(monkeypatch):
     call for call."""
     seen, st, counts = _tumbling_spill_drive()
     assert counts["evicted_rows"] > 0 and counts["promoted_rows"] > 0
-    assert counts["int_table_demotions"] == 0
+    assert counts["demotions"] == 0
     # (the window restored mid-way came back through per-row
     # namespaces: a dict, so not every row met an integer table)
     assert 0 < counts["int_table_rows"] < counts["bulk_probe_rows"]
-    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+    monkeypatch.setattr(native, "available", lambda: False)
     want_seen, want_st, want_counts = _tumbling_spill_drive()
     assert want_counts["int_table_rows"] == 0
     assert {**want_counts, "int_table_rows": counts["int_table_rows"]} \
@@ -420,8 +422,8 @@ def test_host_tier_files_slices_releases_and_compacts(form):
         lambda *block: _evicted(tier, *block)
     put(*_block(0, 3000))
     put(*_block(3000, 5000))
-    assert isinstance(tier.index.tables["w"], native.NativeIntTable) \
-        == (form == "integer")
+    ints = getattr(tier.index.tables["w"], "_ints", None)
+    assert isinstance(ints, native.NativeIntTable) == (form == "integer")
     assert len(tier) == 5000 and (4999, "w") in tier
     assert tier.get(4000, "w")["a"] == 4000.0
     assert tier.get(9, "x") is None and tier.get(9000, "w") is None

@@ -27,8 +27,8 @@ from flink_tpu.core.state import (
     ReducingStateDescriptor,
 )
 from flink_tpu import native
+from flink_tpu.core.key_columns import KeyTable
 from flink_tpu.ops.device_agg import SumAggregate
-from flink_tpu.state import slot_index
 from flink_tpu.state.loader import load_state_backend
 from flink_tpu.state.stats import STATE_STATS
 from flink_tpu.streaming.elements import RecordBatch
@@ -220,6 +220,7 @@ def test_window_batch_vs_row_tumbling(selector, backend, lateness):
     a, op_a, _ = _drive("row", backend, asg, allowed_lateness=lateness,
                         late_every=17)
     asg = TumblingEventTimeWindows.of(1000)
+    demoted = KeyTable.demotions
     b, op_b, _ = _drive("batch", backend, asg, allowed_lateness=lateness,
                         late_every=17, selector=selector)
     assert a == b  # values AND timestamps, in emission order
@@ -227,7 +228,7 @@ def test_window_batch_vs_row_tumbling(selector, backend, lateness):
     # every batch row was consumed columnar — no boxed fallback
     assert op_b.boxed_fallbacks == 0 and op_b.columnar_rows == 300
     assert op_b.key_column_rows == (300 if selector == "field" else 0)
-    assert op_b.timer_service.int_run_demotions == 0
+    assert KeyTable.demotions == demoted
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -719,6 +720,7 @@ def _tumbling_job(key_of=int, windows=3, rows=64, key_space=150,
         op, key_selector=SELECTORS[selector], state_backend=conf or "tpu")
     h.open()
     STATE_STATS.reset()
+    demoted = KeyTable.demotions
     rng = np.random.default_rng(seed)
     seen = []
     for window in range(windows):
@@ -735,11 +737,10 @@ def _tumbling_job(key_of=int, windows=3, rows=64, key_space=150,
         seen.append(_observe(op.window_state, op.keyed_backend))
     out = [(r.value, r.timestamp) for r in h.get_output()]
     counts = {name: getattr(STATE_STATS, name) for name in (
-        "bulk_probe_rows", "per_key_probe_rows", "int_table_rows",
-        "int_table_demotions")}
+        "bulk_probe_rows", "per_key_probe_rows", "int_table_rows")}
     counts.update(key_column_rows=op.key_column_rows,
                   columnar_rows=op.columnar_rows,
-                  int_run_demotions=op.timer_service.int_run_demotions)
+                  demotions=KeyTable.demotions - demoted)
     return out, seen, op.window_state, counts
 
 
@@ -750,7 +751,7 @@ def test_an_integer_keyed_job_is_the_same_on_both_forms(monkeypatch):
     bytes the dicts give, step for step."""
     out, seen, st, counts = _tumbling_job()
     assert counts["int_table_rows"] == counts["bulk_probe_rows"] > 0
-    monkeypatch.setattr(slot_index.native, "available", lambda: False)
+    monkeypatch.setattr(native, "available", lambda: False)
     want_out, want_seen, want_st, want_counts = _tumbling_job()
     assert want_counts["int_table_rows"] == 0
     assert want_counts["bulk_probe_rows"] == counts["bulk_probe_rows"]
@@ -777,7 +778,7 @@ def test_a_job_is_the_same_on_both_key_paths(key_of):
     assert counts["key_column_rows"] == (
         counts["columnar_rows"] if key_of is int else 0)
     assert want_counts["key_column_rows"] == 0
-    assert counts["int_run_demotions"] == want_counts["int_run_demotions"] == 0
+    assert counts["demotions"] == want_counts["demotions"] == 0
     for name in ("bulk_probe_rows", "per_key_probe_rows", "int_table_rows"):
         assert counts[name] == want_counts[name], name
 
@@ -792,13 +793,14 @@ def test_which_jobs_probe_integer_tables(job):
     (a namespace per row): none."""
     if job.startswith("sessions_int"):
         STATE_STATS.reset()
+        demoted = KeyTable.demotions
         _, op, _ = _drive("batch", "tpu", EventTimeSessionWindows.with_gap(400),
                           selector="field" if "field" in job else "lambda")
         assert op.boxed_fallbacks == 0
         assert op.key_column_rows == 0
         assert STATE_STATS.bulk_probe_rows > 300
         assert STATE_STATS.int_table_rows == 0
-        assert STATE_STATS.int_table_demotions == 0
+        assert KeyTable.demotions == demoted
         return
     key_of = {"tumbling_int": int, "tumbling_int_field": int,
               "tumbling_str": lambda k: f"k{k}",
@@ -810,7 +812,7 @@ def test_which_jobs_probe_integer_tables(job):
                                          else 0)
     assert counts["per_key_probe_rows"] == 0 and not st.slot_index
     assert counts["bulk_probe_rows"] > 3 * 4 * 64
-    assert counts["int_table_demotions"] == 0
+    assert counts["demotions"] == 0
     assert counts["int_table_rows"] == (
         counts["bulk_probe_rows"] if key_of is int else 0)
 
@@ -856,7 +858,8 @@ def test_a_query_from_another_thread_while_the_owner_inserts_a_million_keys():
         sys.setswitchinterval(interval)
     assert not thread.is_alive()
     assert not wrong and reads[0] > 1000
-    assert isinstance(st.slot_index.tables["w"], native.NativeIntTable)
+    assert isinstance(getattr(st.slot_index.tables["w"], "_ints", None),
+                      native.NativeIntTable)
     assert len(st.slot_index) == 1_001_000
     b.flush_all()
     res, found, _ = b.get_batch(st, fresh[:5000], "w")

@@ -619,10 +619,16 @@ def test_restore_keeps_only_the_key_groups_of_its_range():
 
 
 # ---- integer runs: the same store behind an int64 column -------------
-# A bulk registration of an int64 column keeps its run in a native
-# table (`_IntRun`); fed the same calls, it must give what the dict run
-# of the same Python ints gives: iteration, capture, snapshot bytes,
-# firing and sweeping order.
+# A bulk registration of an int64 column keeps its run in a `KeyTable`
+# in its integer form; fed the same calls, it must give what the dict
+# run of the same Python ints gives: iteration, capture, snapshot
+# bytes, firing and sweeping order.
+
+def _is_int(run):
+    """Whether a run is a `KeyTable` in its integer form."""
+    from flink_tpu import native
+    return isinstance(getattr(run, "_ints", None), native.NativeIntTable)
+
 
 def _with_int_keys(steps, on_fire, watermarks):
     """A scenario with every key an int (its letters' place among all
@@ -642,24 +648,22 @@ def _with_int_keys(steps, on_fire, watermarks):
 
 
 def _store_view(driver):
-    """Everything of the event-time store a reader sees, an integer
+    """Everything of the event-time store a reader sees, a `KeyTable`
     run's type named as the dict it stands for."""
     import pickle
-
-    from flink_tpu.streaming.timers import _IntRun
     svc = driver.svc
     ats, frozen, kinds = svc._event.capture()
     return {"iter": list(svc._event),
             "capture": (ats, frozen,
-                        [dict if kind is _IntRun else kind for kind in kinds]),
+                        [dict if issubclass(kind, dict) else kind
+                         for kind in kinds]),
             "len": len(svc._event),
             "snapshot": pickle.dumps(svc.snapshot()),
             "deferred": pickle.dumps(svc.capture_snapshot().resolve())}
 
 
 def _int_runs(driver):
-    from flink_tpu.streaming.timers import _IntRun
-    return sum(type(run) is _IntRun for run in driver.svc._event.runs.values())
+    return sum(map(_is_int, driver.svc._event.runs.values()))
 
 
 def _pair(steps, on_fire, watermarks, sweep):
@@ -695,9 +699,11 @@ def _pair(steps, on_fire, watermarks, sweep):
     (name, sweep) for name, scenario in SCENARIOS.items()
     for sweep in (False, True) if not (sweep and scenario[1])])
 def test_an_integer_run_is_the_dict_run_of_its_keys(name, sweep):
+    from flink_tpu.core.key_columns import KeyTable
     steps, on_fire, watermarks = _with_int_keys(*SCENARIOS[name])
+    demoted = KeyTable.demotions
     listed, column = _pair(steps, on_fire, watermarks, sweep)
-    assert column.svc.int_run_demotions == 0
+    assert KeyTable.demotions == demoted
     if name.startswith(("bulk", "two_namespaces_tie_in_order")):
         assert column.int_runs
 
@@ -716,7 +722,6 @@ def test_random_steps_on_integer_runs_are_the_dict_runs(seed):
 
 
 def test_a_bulk_run_of_an_int64_column_is_one_native_table():
-    from flink_tpu.streaming.timers import _IntRun
     svc, backend, _ = _service()
     for batch in range(4):
         assert svc.register_event_time_timers_bulk(
@@ -724,7 +729,7 @@ def test_a_bulk_run_of_an_int64_column_is_one_native_table():
             == (1000 if batch == 0 else 500)
     store = svc._event
     (run,) = store.runs.values()
-    assert type(run) is _IntRun and store.spans == {}
+    assert _is_int(run) and store.spans == {}
     assert store.heap == [(9, 0, (9, A))] and svc.num_event_time_timers() == 2500
     ((ts, ns, keys),) = svc.pop_due_event_time_timers(9)
     assert (ts, ns) == (9, A) and keys.dtype == np.int64
@@ -756,7 +761,7 @@ def test_a_key_an_integer_run_cannot_hold_turns_it_into_a_dict_once(
     another kind (unsigned, float, str, object): the run goes on as the
     dict run of the same keys, and the service counts it once, however
     many such keys follow."""
-    from flink_tpu.streaming.timers import _IntRun
+    from flink_tpu.core.key_columns import KeyTable
     intrude = ("register" if door == "scalar" else "register_bulk", 9, A,
                [intruder] if door == "scalar" else [4, intruder, 3])
     if door == "array":
@@ -766,15 +771,18 @@ def test_a_key_an_integer_run_cannot_hold_turns_it_into_a_dict_once(
              ("register", 9, A, ["more", 2 ** 64]),
              ("register_bulk", 9, A, [8, 1]), ("delete_bulk", 9, A, [1]),
              ("delete", 9, A, [intruder]), ("register_bulk", 19, B, [6, 7])]
+    demoted = KeyTable.demotions
     listed, column = _pair(steps, {}, [], False)
     store = column.svc._event
-    assert type(store.runs[(19, B)]) is _IntRun
+    assert _is_int(store.runs[(19, B)])
     # (True is the key 1 to a dict, and numpy reads [4, True, 3] as
     # ints: the bulk list or array keeps that run an integer run until
     # "more")
-    assert column.svc.int_run_demotions == 1
-    assert type(store.runs[(9, A)]) is dict
-    assert listed.svc.int_run_demotions == 0
+    # (the store fed lists has no integer run to turn: the one count
+    # is the other store's)
+    assert KeyTable.demotions - demoted == 1
+    assert type(store.runs[(9, A)]) is KeyTable
+    assert not _is_int(store.runs[(9, A)])
     for wm in (9, 19):
         assert column.advance(wm) == listed.advance(wm)
 
