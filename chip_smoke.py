@@ -59,6 +59,11 @@ Legs:
                         barrier's synchronous ms, the capture's and
                         the written bytes, trigger -> durable ms,
                         restore s
+  2f sliding t-digest   leg 2c's job with a merging t-digest (delta 100,
+                        a buffer of 512, 128 centroids) for the sketch,
+                        every window against tests/tdigest_reference.py;
+                        its ingest and fire counts on the leg's line;
+                        fails if no digest compressed in ingest
   3a SQL                TUMBLE + APPROX_COUNT_DISTINCT (config #5)
   3b DataStream default aggregate() → DeviceWindowOperator's batch door
   4  device kernels     the entry() step, the log tier's device finish
@@ -110,9 +115,10 @@ from flink_tpu.ops.sketches import (  # noqa: E402
     HyperLogLogAggregate,
     QuantileSketchAggregate,
 )
+from flink_tpu.ops.tdigest import TDigestAggregate  # noqa: E402
 from flink_tpu.runtime import tracing  # noqa: E402
 from flink_tpu.runtime.device_stats import get_telemetry  # noqa: E402
-from flink_tpu.state.stats import STATE_STATS  # noqa: E402
+from flink_tpu.state.stats import STATE_STATS, agg_counters  # noqa: E402
 from flink_tpu.streaming import chain_fusion  # noqa: E402
 from flink_tpu.streaming.columnar import (  # noqa: E402
     ColumnarSource,
@@ -135,6 +141,9 @@ from flink_tpu.streaming.windowing import (  # noqa: E402
 from flink_tpu.table import StreamTableEnvironment  # noqa: E402
 
 WINDOW_MS = 1000
+#: leg 2f holds the t-digest to the bounds of the cell
+#: `tdigest_literal.zipf_values` (benchmark/configs/tdigest_literal.json)
+TDIGEST_BOUNDS = {"estimate_tolerance": 1e-4, "rank_bound_units": 1}
 #: leg 2e checkpoints as the cell `session_cm_log_ckpt.zipf` does
 CHECKPOINT_INTERVAL_MS = 5000
 #: operators work in batches of this many rows; it also bounds how many
@@ -256,6 +265,17 @@ class UserHll(HyperLogLogAggregate):
 
 class ValueQuantiles(QuantileSketchAggregate):
     """p50 / p99 over field 1 (the value) of a (key, value) row."""
+
+    def extract_value(self, value):
+        return value[1]
+
+    def extract_column(self, values):
+        return values[1]
+
+
+class ValueDigest(TDigestAggregate):
+    """A merging t-digest over field 1 (the value) of a (key, value)
+    row."""
 
     def extract_value(self, value):
         return value[1]
@@ -711,11 +731,13 @@ def zipf_column(rng, n, space):
         .astype(np.int64)
 
 
-def leg_state_sliding(cfg, seed):
+def leg_state_sliding(cfg, seed, digest=False):
     """Leg 2's route under a sliding assigner and a value-carrying
     aggregate: every event lands in ten (key, window) sketches, a
     window fires every slide, and the budget holds all ten windows, so
-    the spill tier must stay idle."""
+    the spill tier must stay idle.  `digest`: the sketch is a merging
+    t-digest (leg 2f), held to tests/tdigest_reference.py, its own
+    counts on the leg's line."""
     sl = cfg["sliding"]
     rng = np.random.default_rng(seed + 2)
     n = sl["events_per_slide"] * sl["slides"]
@@ -735,10 +757,14 @@ def leg_state_sliding(cfg, seed):
                                                    SLIDING_SLIDE_MS)))
     windowed.disable_device_operator()
     sink = ArraySink()
-    agg = ValueQuantiles()
+    agg = ValueDigest() if digest else ValueQuantiles()
     windowed.aggregate(agg, window_function=emit_quantiles).add_sink(sink)
     source.ops = ops = capture_operators(env)
+    counted = agg_counters()
     env.execute("chip-smoke-state-sliding")
+    counted = {name: value - counted.get(name, 0)
+               for name, value in agg_counters().items()
+               if name.startswith("tdigest_")}
     wop = one_of(ops, WindowOperator)
     state = wop.window_state
     got_keys, got_starts, p50, p99 = sink.columns()
@@ -751,10 +777,18 @@ def leg_state_sliding(cfg, seed):
     emitted = [(p, None, lambda p=p: (keys[p * per:(p + 1) * per],
                                       values[p * per:(p + 1) * per]))
                for p in range(sl["slides"])]
-    verdict = load_reference("quantile_sliding_reference").check(
-        {"slide_ms": SLIDING_SLIDE_MS, "window_size_ms": SLIDING_SIZE_MS,
-         "quantiles": list(agg.quantiles), "relative_accuracy": 0.01},
-        emitted, results)
+    if digest:
+        verdict = load_reference("tdigest_reference").check(
+            {"slide_ms": SLIDING_SLIDE_MS, "window_size_ms": SLIDING_SIZE_MS,
+             "key_space": sl["keys"], "quantiles": list(agg.quantiles),
+             "delta": agg.delta, "buffer": agg.buffer,
+             "centroids": agg.centroids, **TDIGEST_BOUNDS},
+            emitted, results)
+    else:
+        verdict = load_reference("quantile_sliding_reference").check(
+            {"slide_ms": SLIDING_SLIDE_MS, "window_size_ms": SLIDING_SIZE_MS,
+             "quantiles": list(agg.quantiles), "relative_accuracy": 0.01},
+            emitted, results)
     problems = list(verdict["problems"])
     if verdict["failed"]:
         problems.append(f"{verdict['failed']} of {verdict['attempted']} "
@@ -778,11 +812,14 @@ def leg_state_sliding(cfg, seed):
     form_faults, form_facts = flush_form_problems(cfg, source, state, agg,
                                                   flushed)
     problems += form_faults
-    hist = state.device_state["hist"]
+    if digest and not counted.get("tdigest_ingest_compresses"):
+        problems.append("no digest compressed in ingest: the leg's hot "
+                        "keys were to overflow their buffers")
     return problems, {
         "keys": sl["keys"], "events": n, "budget": sl["budget"],
         "slots": state.capacity,
-        "table_bytes": int(hist.size) * hist.dtype.itemsize,
+        "table_bytes": sum(int(a.size) * a.dtype.itemsize
+                           for a in state.device_state.values()),
         "batches_marked": len(source.marks), **form_facts,
         "rows_per_event": wop.window_rows / n,
         "windows_touched": wop.windows_touched, **key_facts,
@@ -790,7 +827,7 @@ def leg_state_sliding(cfg, seed):
         "budget_overruns": state.budget_overruns,
         "result_rows": len(got_keys), "windows_fired": len(results),
         "timers_swept": wop.timers_swept, "timer_runs": wop.timer_runs,
-        **verdict["facts"]}
+        **counted, **verdict["facts"]}
 
 
 SESSION_PARTITIONS = 4
@@ -1405,6 +1442,7 @@ def main(argv=None) -> int:
     run("2 state backend", leg_state_backend, cfg, events, ref)
     run("2b spill tier", leg_state_spill, cfg, args.seed)
     run("2c sliding quantiles", leg_state_sliding, cfg, args.seed)
+    run("2f sliding t-digest", leg_state_sliding, cfg, args.seed, True)
     run("2d session count-min", leg_state_sessions, cfg, args.seed)
     run("2e session checkpoint", leg_state_sessions_checkpoint, cfg,
         args.seed)

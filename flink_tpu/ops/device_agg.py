@@ -17,7 +17,12 @@ backend for differential testing and on the TPU backend for speed.
 
 Slots are dense indices handed out by the backend's per-window key
 index (flink_tpu/state/tpu_backend.py); duplicate slots within a batch
-are legal and resolved by the scatter combinator (add/max/min).
+are legal, and a slot's rows are applied in ROW ORDER: the batch is
+the slot's next values as they arrived.  For the scatter sketches
+(add/max/min, HyperLogLog, Count-Min, the quantile histogram) order is
+immaterial and the combinator resolves duplicates; a t-digest
+(flink_tpu/ops/tdigest.py) is a function of its values in arrival
+order, and its update keeps each slot's rows in theirs.
 """
 
 from __future__ import annotations
@@ -55,6 +60,19 @@ class DeviceAggregateFunction(AggregateFunction):
     needs_value_hash: bool = False
     #: dtype the batcher should coerce values to
     value_dtype: np.dtype = np.float32
+    #: whether a merge of two accumulators is the accumulator of their
+    #: rows together: what an engine that composes windows out of pane
+    #: merges relies on
+    pane_merges_exact: bool = True
+    #: names of the counts an aggregate that has them returns, as
+    #: int32[len], beside its new state from `counted_update(...)` (the
+    #: arguments of `update`) and beside its results from
+    #: `counted_result(state, slots, rows)` (counting the first `rows`
+    #: slots, the rest being padding): the state backend calls those in
+    #: place of `update` / a fire's `result` and sums the counts on the
+    #: device (`flink_tpu/state/stats.py` `agg_counters`); none by
+    #: default
+    counters: Tuple[str, ...] = ()
 
     # ---- device contract -------------------------------------------
     def extract_value(self, value):

@@ -290,20 +290,26 @@ def hll_update_form(rows: int, capacity: int, m: int) -> str:
 
 
 def _tiles_in_place(table, row, col, value, tile_rows, combine, name,
-                    interpret):
-    """`combine` each `value` into the (tile_rows x 128) tile of the
-    2-d `table` that holds its cell (`row`, `col`), in place: the tiles
-    are the units the TPU keeps contiguous, so a grid step reads and
-    writes 4 KiB where a cell scatter would relayout the whole table."""
+                    interpret, wide=False):
+    """`combine(cur, hit, v)` each `value` into the (tile_rows x 128)
+    tile of the 2-d `table` that holds its cell (`row`, `col`), in
+    place (`hit` the cell, `v` the value there and 0 elsewhere): the
+    tiles are the units the TPU keeps contiguous, so a grid step reads
+    and writes 4 KiB where a cell scatter would relayout the whole
+    table.  A small non-negative `value` (a count, a rank) rides
+    in one scalar above its cell's place in the tile; a `wide` one (any
+    int32, such as a float's bits) is a scalar array of its own, and a
+    row past the table's last then changes nothing."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     tile_cells = tile_rows * _LANES
     shape = (tile_rows, _LANES)
 
-    def kernel(tile_ref, cell_ref, table_in, table_out):
+    def kernel(tile_ref, cell_ref, *refs):
         # one grid step = one cell; the steps of one tile follow each
         # other (the batch is sorted by tile), so the tile stays in VMEM
         # from its first step to its last, written back once
+        table_in, table_out = refs[-2:]
         i = pl.program_id(0)
         tile = tile_ref[i]
 
@@ -311,36 +317,43 @@ def _tiles_in_place(table, row, col, value, tile_rows, combine, name,
         def _():
             table_out[...] = table_in[...]
 
-        at = cell_ref[i] % tile_cells
+        at = cell_ref[i] if wide else cell_ref[i] % tile_cells
         hit = ((jax.lax.broadcasted_iota(jnp.int32, shape, 0) == at // _LANES)
                & (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
                   == at % _LANES))
-        table_out[...] = combine(
-            table_out[...], jnp.where(hit, cell_ref[i] // tile_cells, 0))
+        table_out[...] = combine(table_out[...], hit, jnp.where(
+            hit, refs[0][i] if wide else cell_ref[i] // tile_cells, 0))
 
     tiles_a_row = table.shape[1] // _LANES
     tile = (row // tile_rows) * tiles_a_row + col // _LANES
-    # one scalar a cell beside its tile: where in the tile, and above
-    # that the value (0 for a padding row)
-    cell = (row % tile_rows) * _LANES + col % _LANES + value * tile_cells
-    order = jnp.argsort(tile)
+    # one scalar a cell beside its tile: where in the tile
+    cell = (row % tile_rows) * _LANES + col % _LANES
+    if wide:
+        # a row past the table: tile 0, at a place past its cells
+        live = row < table.shape[0]
+        scalars = (jnp.where(live, tile, 0),
+                   jnp.where(live, cell, tile_cells), value)
+    else:
+        # and above that the value (0 for a padding row)
+        scalars = (tile, cell + value * tile_cells)
+    order = jnp.argsort(scalars[0])
     block = pl.BlockSpec(
-        shape, lambda i, tile, cell: (tile[i] // tiles_a_row,
-                                      tile[i] % tiles_a_row))
+        shape, lambda i, tile, *_: (tile[i] // tiles_a_row,
+                                    tile[i] % tiles_a_row))
     return pl.pallas_call(
         kernel,
         # (inside a `shard_map` the table varies over the mesh axes)
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype,
                                        vma=jax.typeof(table).vma),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(row.shape[0],),
+            num_scalar_prefetch=len(scalars), grid=(row.shape[0],),
             in_specs=[block], out_specs=block),
-        input_output_aliases={2: 0},
+        input_output_aliases={len(scalars): 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
-    )(tile[order], cell[order], table)
+    )(*(a[order] for a in scalars), table)
 
 
 def _add_cells(hist, slots, b, inc):
@@ -356,8 +369,8 @@ def _add_tiles_tpu(hist, slots, b, inc, interpret=False):
     transposed view is the buffer as it lies and the tiles are its
     4 KiB units."""
     return _tiles_in_place(hist.T, b, slots, inc, _SUBLANES,
-                           lambda cur, inc: cur + inc, "quantile_add_tiles",
-                           interpret).T
+                           lambda cur, hit, inc: cur + inc,
+                           "quantile_add_tiles", interpret).T
 
 
 def _add_tiles(hist, slots, b, inc):
@@ -381,7 +394,7 @@ def _max_tiles_tpu(regs, slots, reg, rank, interpret=False):
     The max is taken in int32 inside the kernel."""
     return _tiles_in_place(
         regs, slots, reg, rank.astype(jnp.int32), _U8_TILE_ROWS,
-        lambda cur, rank: jnp.maximum(cur.astype(jnp.int32), rank)
+        lambda cur, hit, rank: jnp.maximum(cur.astype(jnp.int32), rank)
         .astype(cur.dtype), "hll_max_tiles", interpret)
 
 

@@ -32,6 +32,7 @@ class StateStats:
         "hash_column_rows", "hash_per_value_rows",
         "per_state_batch_rows", "per_state_batch_calls",
         "per_state_fallback_rows", "per_state_fallback_calls",
+        "agg_counters",
     )
 
     def __init__(self) -> None:
@@ -103,6 +104,15 @@ class StateStats:
         self.per_state_batch_calls = {}
         self.per_state_fallback_rows = {}
         self.per_state_fallback_calls = {}
+        #: an aggregate's own counts (`counters` of `ops/device_agg.py`)
+        #: by name: summed on the device and added here when read
+        #: (`agg_counters()`), never at a flush or a fire
+        self.agg_counters = {}
+
+    def note_agg_counts(self, names, counts) -> None:
+        for name, count in zip(names, counts):
+            self.agg_counters[name] = self.agg_counters.get(name, 0) \
+                + int(count)
 
     def note_batch(self, name: str, n: int) -> None:
         """One backend-native add_batch/get_batch call of `n` rows on
@@ -186,3 +196,17 @@ def device_state_summary() -> dict:
         "budget_overruns": overruns,
         "pending_depth": pending,
     }
+
+
+def agg_counters() -> dict:
+    """`STATE_STATS.agg_counters` with every live device state's counts
+    read in first: this reads the device (a few bytes a state), so call
+    it outside a timed window."""
+    with _LIVE_LOCK:
+        live = list(_LIVE_DEVICE_STATES)
+    for st in live:
+        try:
+            st.read_agg_counts()
+        except Exception:  # noqa: BLE001 — racing dispose
+            continue
+    return dict(STATE_STATS.agg_counters)

@@ -241,8 +241,12 @@ class DeviceAggregatingState(AggregatingState):
         # per padded batch shape), under labels jit_stats() keeps.  None
         # of them may close over this object: jax keeps a jitted
         # function, and with it the registers, long after the job
-        self._jit_update = traced_jit(self.agg.update, name="state.update",
-                                      donate_argnums=0)
+        self._jit_update = traced_jit(
+            self.agg.counted_update if agg.counters else self.agg.update,
+            name="state.update", donate_argnums=0)
+        #: the aggregate's counts (`agg.counters`) since they were last
+        #: read, summed on the device: no flush or fire reads them
+        self._counts: Optional[jnp.ndarray] = None
         self._jit_upload = traced_jit(
             lambda st, slot, row: {k: st[k].at[slot].set(row[k])
                                    for k in st},
@@ -269,6 +273,11 @@ class DeviceAggregatingState(AggregatingState):
         self._jit_clear = traced_jit(self.agg.clear_slots,
                                      name="state.clear", donate_argnums=0)
         self._jit_result = traced_jit(self.agg.result, name="state.result")
+        #: a fire's read of an aggregate that counts: the results and
+        #: the counts of a tile's real rows
+        self._jit_counted_result = traced_jit(
+            self.agg.counted_result, name="state.result") \
+            if agg.counters else None
         # a restore's two uploads: whole rows of some components, and
         # the cells of a sparse column (a triple past the table's last
         # slot is padding and dropped)
@@ -758,25 +767,47 @@ class DeviceAggregatingState(AggregatingState):
         # applies them waits here, a flush at a time, and a fire's
         # gather never queues behind a window's worth of updates
         jax.block_until_ready(self.device_state)
+        t0 = _perf_ns()
+        out = self._jit_update(self.device_state, slots, values, hi, lo,
+                               mask)
+        self.device_state = self._count(out) if self.agg.counters else out
         if TELEMETRY.enabled:
-            t0 = _perf_ns()
-            self.device_state = self._jit_update(
-                self.device_state, slots, values, hi, lo, mask)
             TELEMETRY.record_transfer(
                 "h2d",
                 slots.nbytes + mask.nbytes + values.nbytes
                 + hi.nbytes + lo.nbytes,
                 t0, _perf_ns(), "state.flush")
             TELEMETRY.note_flush(n)
-        else:
-            self.device_state = self._jit_update(
-                self.device_state, slots, values, hi, lo, mask)
         STATE_STATS.note_flush(
             n, self.agg.update_runs_in_place(padded, self.capacity))
         self._slot_flushed[pending] = True
         self._pending_slots.clear()
         self._pending_values.clear()
         self._pending_hash.clear()
+
+    def _count(self, out):
+        """The first part of a counting program's `out`; its counts go
+        to the sum on the device."""
+        out, counts = out
+        self._counts = counts if self._counts is None \
+            else self._counts + counts
+        return out
+
+    def read_agg_counts(self) -> None:
+        """Add the aggregate's counts so far to
+        `STATE_STATS.agg_counters`: a read of the device."""
+        if self._counts is not None:
+            counts = np.asarray(self._counts)
+            self._counts = None
+            STATE_STATS.note_agg_counts(self.agg.counters, counts)
+
+    def _fire_read(self, state, slots, rows: int):
+        """`state.result` of a fire's tile of `slots`, dispatched; an
+        aggregate that counts counts the tile's first `rows` rows."""
+        if not self.agg.counters:
+            return self._jit_result(state, slots)
+        return self._count(self._jit_counted_result(state, slots,
+                                                    np.int32(rows)))
 
     # ---- read path --------------------------------------------------
     def get(self):
@@ -838,7 +869,8 @@ class DeviceAggregatingState(AggregatingState):
             arr = _pad_slots(slots, padded)
             state = self.device_state
             # every tile is dispatched before the first is waited for
-            parts = [self._jit_result(state, jnp.asarray(arr[i:i + width]))
+            parts = [self._fire_read(state, jnp.asarray(arr[i:i + width]),
+                                     n - i)
                      for i in range(0, padded, width)]
             res = np.concatenate([np.asarray(p) for p in parts])[:n]
             if TELEMETRY.enabled:
@@ -884,9 +916,9 @@ class DeviceAggregatingState(AggregatingState):
                 self.host_tier.gather(part, rows)
                 for arr in rows.values():
                     arr[len(part):] = arr[0]  # pad rows, cut off below
-                in_flight.append(self._jit_result(
+                in_flight.append(self._fire_read(
                     {name: jnp.asarray(arr) for name, arr in rows.items()},
-                    every_row))
+                    every_row, len(part)))
                 if len(in_flight) > 1:
                     done.append(np.asarray(in_flight.pop(0)))
             done.extend(np.asarray(p) for p in in_flight)

@@ -193,6 +193,15 @@ def select_engine(host, key_dtype: np.dtype):
           engines, never a reason to choose another engine (PR 21)."""
     if host.shape is None:
         raise ValueError(f"no device engine for assigner {host.assigner!r}")
+    if host.shape.kind != "tumbling" and not host.agg.pane_merges_exact:
+        # every engine of this door composes sliding windows out of
+        # panes and sessions out of merges
+        raise ValueError(
+            f"{type(host.agg).__name__} over {host.shape.kind} windows: "
+            f"the batched engines merge panes or sessions, which this "
+            f"aggregate does not answer exactly; pin the state route with "
+            f".disable_device_operator() on the windowed stream (the "
+            f"WindowOperator over the keyed-state backend)")
     integer_keys = np.issubdtype(key_dtype, np.integer)
     ladder = ["mesh_log"] if host.mesh is not None and integer_keys else []
     if host.mesh is None or not host.mesh_scatter:

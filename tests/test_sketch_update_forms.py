@@ -591,3 +591,31 @@ def test_on_four_chips_the_mesh_step_holds_the_hll_kernel(topo):
     kinds = [kind for _, kind in table_sized_ops(
         compiled, ring * region * agg.m)]
     assert "reshape" not in kinds and "copy" not in kinds, kinds
+
+
+TDIGEST_CAPACITY = 1 << 19
+
+
+def test_on_the_chip_the_tdigest_flush_leaves_the_table_in_place(one_chip):
+    """The t-digest cell's flush compiled for a described v5e: the
+    buffer table (1 GiB of 1.5) takes the append kernel, the compress
+    its pass kernel, the overflowing digests' rows go back by row, and
+    nothing holds a second copy of the state."""
+    from flink_tpu.ops.tdigest import TDigestAggregate
+    agg = TDigestAggregate()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = {k: arg((TDIGEST_CAPACITY, *spec.shape), spec.dtype)
+             for k, spec in agg.state_specs().items()}
+    compiled = compile_for_chip(
+        agg.counted_update, state, arg((CELL_ROWS,), jnp.int32),
+        arg((CELL_ROWS,), jnp.float32), arg((CELL_ROWS,), jnp.uint32),
+        arg((CELL_ROWS,), jnp.uint32), arg((CELL_ROWS,), jnp.bool_))
+    table_bytes = TDIGEST_CAPACITY * 3088
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= table_bytes      # donated, reused
+    assert memory.temp_size_in_bytes < table_bytes // 64  # no second copy
+    assert "tdigest_append_tiles" in compiled.as_text()
+    assert "tdigest_compress_pass" in compiled.as_text()
